@@ -115,3 +115,15 @@ class TestPerWorkerBlacklist:
     def test_blacklist_after_validation(self):
         with pytest.raises(ValueError, match="blacklist_after"):
             launch([sys.executable, FLAKY], nprocs=2, blacklist_after=0)
+
+
+@pytest.mark.parametrize("platform,env", [
+    ("tpu", {}),                        # asked for outright
+    ("", {"JAX_PLATFORMS": "tpu"}),     # '' = inherit the caller's
+])
+def test_many_workers_on_a_chip_platform_rejected(platform, env):
+    """A chip belongs to one process: N workers that would all reach for
+    this host's chips are refused up front instead of hanging there."""
+    with pytest.raises(ValueError, match="share this host's chips"):
+        launch([sys.executable, FLAKY], nprocs=2, platform=platform,
+               env=env, coord_server=False)

@@ -597,6 +597,25 @@ class TestXlaTelemetry:
         assert xla.note_step(0.0, flops, registry=reg) is None
         assert xla.note_step(0.001, None, registry=reg) is None
 
+    def test_cost_flops_compiles_a_lowered_stage_that_has_no_analysis(self):
+        """On a PJRT-plugin backend (the TPU) ``Lowered.cost_analysis()``
+        is None and only the compiled program reports FLOPs."""
+        from tpudist.obs import xla
+
+        class Compiled:
+            def cost_analysis(self):
+                return [{"flops": 42.0}]
+
+        class Lowered:
+            def cost_analysis(self):
+                return None
+
+            def compile(self):
+                return Compiled()
+
+        assert xla.cost_flops(Lowered()) == 42.0
+        assert xla.cost_flops(Compiled()) == 42.0
+
     def test_memory_and_peak_degrade_on_cpu(self):
         from tpudist.obs import xla
 

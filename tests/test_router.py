@@ -113,6 +113,23 @@ class TestWireFormat:
         assert got.trace.enqueued_at == tc.enqueued_at
 
 
+def test_spawn_replica_pins_child_platform(monkeypatch):
+    """A chip belongs to one process: the replica child gets the platform
+    the CALLER asked for, even when the parent's environment names a
+    chip platform (setdefault used to let the parent's value through)."""
+    from tpudist.runtime import router
+
+    seen = {}
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(router.subprocess, "Popen",
+                        lambda argv, env: seen.update(argv=argv, env=env))
+    router._spawn_replica("127.0.0.1:1", 3, namespace="ns", platform="cpu",
+                          env_extra={"TPUDIST_X": 1})
+    assert seen["env"]["JAX_PLATFORMS"] == "cpu"
+    assert seen["env"]["TPUDIST_X"] == "1"
+    assert "r3" in seen["argv"]
+
+
 class TestNoHang:
     def test_timeout_instead_of_hang_with_no_fleet(self):
         server, client = _coord_pair()

@@ -1,8 +1,7 @@
 """scan_layers: one lax.scan over stacked layer params == the unrolled stack.
 
 The point of the feature is compile-size/length scaling (HLO holds ONE
-block body regardless of depth — what keeps deep rollouts under
-remote-compile size limits); the tests pin the part that must not drift:
+block body regardless of depth); the tests pin the part that must not drift:
 numerics identical to the unrolled layout in forward, training (grads),
 decode (KV cache), remat, and speculative rollouts.
 """

@@ -22,10 +22,6 @@ differentiates across devices natively.  What remains — and what this package
 provides — are the *capabilities*, re-expressed mesh-first.
 """
 
-from tpudist.utils.compat import install_jax_compat
-
-install_jax_compat()  # before any module touches renamed jax symbols
-
 from tpudist import data, elastic, models, obs, ops, parallel, runtime, train, utils
 from tpudist.runtime.mesh import (
     MeshSpec,

@@ -33,7 +33,8 @@ def serving_layout(cfg: TransformerConfig, params: Any,
     ``scan_layers=True`` is the right layout for TRAINING (depth-
     independent compile size) but the wrong one for token-at-a-time
     decode: every step pays a per-layer dynamic-slice of the stacked
-    cache (~4× slower at 8k context, BASELINE.md), and the sharded entry
+    cache (~4× slower at 8k context; pre-PR 1 capture, not
+    re-measured), and the sharded entry
     points' Megatron rules match per-layer kernel names.  Every serving
     entry point calls this, so a checkpoint trained scanned serves at
     unrolled speed with no manual conversion step: stacked ``blocks``

@@ -247,8 +247,7 @@ def speculative_generate(
         # is PRESERVED: it only ever runs chunk verifies, which amortize
         # the stacked-cache slicing, so a scanned target keeps its
         # depth-independent compile size at ~no step-time cost — the
-        # configuration bench.py relies on (the unrolled 8-layer rollout
-        # exceeds the remote-compile request limit).  The sharded entry
+        # configuration bench.py uses.  The sharded entry
         # points normalize BOTH unconditionally (their sharding rules
         # need per-layer names).
         from tpudist.models.generate import serving_layout

@@ -188,8 +188,7 @@ class TransformerConfig:
     # Compile the layer stack as ONE lax.scan over stacked parameters
     # instead of a Python loop (the maxtext-style "scan over layers").
     # The traced program holds one block body regardless of depth, so
-    # HLO size and compile time stop scaling with num_layers — which is
-    # what keeps deep-model rollouts under remote-compile size limits.
+    # HLO size and compile time stop scaling with num_layers.
     # Param layout changes from block{i}/... to blocks/block/... with a
     # leading layer axis; convert with stack_layer_params /
     # unstack_layer_params.  Lives on the config so every cache-decode

@@ -197,19 +197,35 @@ def update_memory_gauges(registry: Any = None) -> dict[str, float]:
 
 # -- cost / MFU telemetry ----------------------------------------------------
 
-def cost_flops(stage: Any) -> float | None:
-    """Total FLOPs from a ``Lowered``/``Compiled`` stage's
-    ``cost_analysis()`` (handles both the flat-dict and the
-    list-of-dicts shapes jax has shipped), or None when unavailable."""
+def _cost_analysis(stage: Any) -> dict | None:
     try:
         cost = stage.cost_analysis()
     except Exception:  # noqa: BLE001 - analysis unsupported here
         return None
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else None
-    if not isinstance(cost, dict):
-        return None
-    flops = cost.get("flops")
+    return cost if isinstance(cost, dict) else None
+
+
+def cost_flops(stage: Any) -> float | None:
+    """Total FLOPs from a ``Lowered``/``Compiled`` stage's
+    ``cost_analysis()`` (handles both the flat-dict and the
+    list-of-dicts shapes jax has shipped), or None when unavailable.
+
+    A ``Lowered`` stage answers only where jax can analyse unoptimized
+    HLO in process (the CPU backend).  On a PJRT-plugin backend — the
+    TPU — ``Lowered.cost_analysis()`` is None and only the compiled
+    program has an analysis, so the stage is compiled for it: a
+    persistent-cache hit when the step itself compiles the same program
+    (:func:`tpudist.runtime.cache.enable_compilation_cache`).  FLOPs
+    inside Pallas custom calls are not in XLA's count."""
+    cost = _cost_analysis(stage)
+    if cost is None and hasattr(stage, "compile"):
+        try:
+            cost = _cost_analysis(stage.compile())
+        except Exception:  # noqa: BLE001 - telemetry must not stop a run
+            cost = None
+    flops = (cost or {}).get("flops")
     if flops is None or flops <= 0:
         return None
     return float(flops)

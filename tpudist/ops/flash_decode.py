@@ -21,7 +21,7 @@ whole cache — at the bandwidth-bound decode op that is a ~S/window
 speedup.  Positions beyond the cache index, or older than the window,
 mask to -inf as before.
 
-Measured guideline (BASELINE.md round 3): ``head_dim < 128`` underfills
+Guideline (pre-PR 1 capture, not re-measured): ``head_dim < 128`` underfills
 the 128-lane tile width of the K/V blocks (measured: half DMA
 bandwidth).  With EVEN ``h_kv`` both the bf16 AND int8 paths recover
 full width by HEAD PAIRING (see ``_flash_decode_impl``; since round 4

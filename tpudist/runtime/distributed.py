@@ -60,7 +60,7 @@ def _launcher_env() -> tuple[str, int, int] | None:
 def _detected_multihost() -> bool:
     """True only for an actual multi-host topology: a coordinator address,
     or a TPU worker list naming more than one host (a single-entry
-    ``TPU_WORKER_HOSTNAMES`` — e.g. a tunneled single-chip dev box — needs
+    ``TPU_WORKER_HOSTNAMES`` — one host with its chips attached — needs
     no bootstrap and ``initialize`` would fail on it)."""
     if any(os.environ.get(k) for k in _CLUSTER_ENV_HINTS):
         return True
@@ -85,10 +85,10 @@ def initialize(
     """
     global _initialized
     # Elastic recovery = process restart + re-jit (SURVEY.md §5), so a
-    # restarted worker's compiles should be warm: honor an ambient
-    # persistent-cache directory (the launcher/test env exports it; the
-    # flag is harmless to set repeatedly).
-    if os.environ.get("TPUDIST_CACHE_DIR"):
+    # restarted worker's compiles should be warm.  JAX itself reads an
+    # ambient JAX_COMPILATION_CACHE_DIR (the launcher/test env exports
+    # it); the call adds the compile telemetry and sets no directory.
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         from tpudist.runtime.cache import enable_compilation_cache
 
         enable_compilation_cache()

@@ -592,11 +592,11 @@ class IciCollectives:
                         x, self.axis, axis=0, tiled=True)
 
                 out_spec = jax.sharding.PartitionSpec()
-            # check_rep can't statically infer that a tiled all-gather's
+            # check_vma can't statically infer that a tiled all-gather's
             # output is replicated; the gather itself guarantees it
             fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=spec, out_specs=out_spec,
-                check_rep=(kind == "rs")))
+                check_vma=(kind == "rs")))
             arg = jax.ShapeDtypeStruct(shape, dtype, sharding=self._sharding)
             with compile_watch("ici"):
                 exe = fn.lower(arg).compile()

@@ -130,6 +130,14 @@ def launch(
     )
     if env:
         base_env.update(env)
+    worker_platform = platform or base_env.get("JAX_PLATFORMS", "")
+    if nprocs > 1 and worker_platform not in ("", "cpu"):
+        # a chip belongs to one process: N workers on one host would all
+        # reach for the same chips, and all but one fail or hang
+        raise ValueError(
+            f"{nprocs} workers on platform {worker_platform!r} would "
+            "share this host's chips; use platform='cpu' (simulated "
+            "devices) or one process that drives every local chip")
     if coord_server:
         try:
             from tpudist.runtime.coord import CoordServer
@@ -313,7 +321,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--max-restarts", type=int, default=0,
                     help="gang restarts on worker failure (torchrun semantics)")
     ap.add_argument("--platform", default="cpu",
-                    help="JAX_PLATFORMS for workers ('' = inherit)")
+                    help="JAX_PLATFORMS for workers ('' = inherit; more "
+                         "than one worker only on 'cpu')")
     ap.add_argument("--devices-per-proc", type=int, default=1,
                     help="simulated CPU devices per worker")
     ap.add_argument("--min-nprocs", type=int, default=None,

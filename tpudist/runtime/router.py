@@ -2677,7 +2677,9 @@ def _spawn_replica(coord_addr: str, index: int, *,
     env["PYTHONPATH"] = os.pathsep.join(
         [pkg_root] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH")
                       else []))
-    env.setdefault("JAX_PLATFORMS", platform)
+    # set outright: a chip belongs to one process, so a replica child
+    # must not inherit the parent's platform over the caller's choice
+    env["JAX_PLATFORMS"] = platform
     env.update({k: str(v) for k, v in (env_extra or {}).items()})
     return subprocess.Popen(
         [sys.executable, "-m", "tpudist.runtime.router",

@@ -2,11 +2,11 @@
 
 The TPU-native analog of the reference's ``mp.spawn``-on-localhost pattern
 (`model_parallel_ResNet50.py:260`, SURVEY.md §4): N fake CPU devices let
-mesh/sharding/elastic code run anywhere.  Forcing is belt-and-braces because
-ambient environments may register a real TPU backend at startup AND pin
-``jax_platforms`` via ``jax.config`` (which overrides the env var): we set
-the env vars (read at backend initialization) and update the config after
-import.  Must be called before anything initializes a JAX backend.
+mesh/sharding/elastic code run anywhere.  ``JAX_PLATFORMS`` is read when jax
+is imported and ``XLA_FLAGS`` when the backend initializes; importing this
+module imports jax (through ``tpudist``), so the platform is set in the
+environment (for child processes) AND in ``jax.config`` (for this one).
+Must be called before anything initializes a JAX backend.
 """
 
 from __future__ import annotations

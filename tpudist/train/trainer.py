@@ -215,7 +215,9 @@ class Trainer:
     def _probe_cost(self, fn, steps_per_dispatch: int, *args) -> None:
         """One-time lower() of the step program: cost_analysis() FLOPs for
         the live ``xla/mfu`` gauge, HLO text for the flight recorder's
-        post-mortem bundle.  Pure analysis — no compile, no dispatch."""
+        post-mortem bundle.  No dispatch; no compile either where the
+        lowered stage has a cost analysis (the CPU) — on the TPU only
+        the compiled program has one (see ``obs.xla.cost_flops``)."""
         if self._cost_probed:
             return
         self._cost_probed = True
