@@ -13,7 +13,9 @@ its interpret branch, so the lowering runs with that one function patched
 (the program grows no option for it).
 """
 
+import dataclasses
 import os
+import re
 from unittest import mock
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 import pytest
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from tpudist.models import ServeLoop, TransformerConfig, TransformerLM
@@ -153,14 +156,24 @@ def test_flash_attention_forward_backward_8k(v5e, heads, kv_heads):
     assert _kernel_calls(hlo) >= 3  # forward, dQ, dK/dV
 
 
+def _abstract_params(model):
+    """Parameter shapes only (a loop or a step only stores them)."""
+    return jax.eval_shape(model.init, jax.random.key(0),
+                          jnp.ones((1, 8), jnp.int32))["params"]
+
+
+def _segment_args(v5e, loop):
+    return _on(v5e, (loop.params, loop.cache, loop._tok, loop._active,
+                     loop._remaining, loop._first, loop._key,
+                     jnp.int32(STEPS), jnp.bool_(False)))
+
+
 @pytest.fixture(scope="module")
 def serve_loop():
     """The serve phase of chip_smoke.py, built as ``ServeLoop.__init__``
-    builds it; params stay abstract (the loop only stores them)."""
+    builds it; params stay abstract."""
     cfg = _cfg(4, 1)
-    params = jax.eval_shape(
-        TransformerLM(cfg).init, jax.random.key(0),
-        jnp.ones((1, 8), jnp.int32))["params"]
+    params = _abstract_params(TransformerLM(cfg))
     return ServeLoop(cfg, params, num_slots=SLOTS, steps_per_sync=STEPS,
                      decode_attention="flash", prefill_chunk=CHUNK,
                      cache_layout="paged", kv_block_size=BLOCK)
@@ -168,9 +181,7 @@ def serve_loop():
 
 def test_serve_segment_program(v5e, serve_loop):
     loop = serve_loop
-    args = _on(v5e, (loop.params, loop.cache, loop._tok, loop._active,
-                     loop._remaining, loop._first, loop._key,
-                     jnp.int32(STEPS), jnp.bool_(False)))
+    args = _segment_args(v5e, loop)
     assert _kernel_calls(_compile(loop._segment, *args)) == LAYERS
 
 
@@ -180,6 +191,74 @@ def test_serve_prefill_chunk_program(v5e, serve_loop):
                      jnp.zeros((1, CHUNK), jnp.int32), jnp.int32(0)))
     hlo = _compile(loop._prefill_chunk, *args, chunk=CHUNK)
     assert _kernel_calls(hlo) == LAYERS
+
+
+# Every Pallas call of the hot path carries a stable ``name=``: it becomes
+# the op's ``<name>/pallas_call`` scope, which the chip's compiler turns
+# into the instruction's name (``%paged_flash_decode.7``) and a profiler
+# trace shows.  Lowering is enough to see it; nothing is compiled here.
+
+def _kernel_scopes(fn, *args, **static) -> set[str]:
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jitted.lower(*args, **static).as_text(debug_info=True)
+    return set(re.findall(r"/(\w+)/pallas_call", text))
+
+
+def test_segment_names_its_kernel(v5e, serve_loop):
+    loop = serve_loop
+    args = _segment_args(v5e, loop)
+    assert _kernel_scopes(loop._segment, *args) == {"paged_flash_decode"}
+
+
+def test_prefill_chunk_names_its_kernel(v5e, serve_loop):
+    loop = serve_loop
+    args = _on(v5e, (loop.params, loop._blank1,
+                     jnp.zeros((1, CHUNK), jnp.int32), jnp.int32(0)))
+    assert _kernel_scopes(loop._prefill_chunk, *args,
+                          chunk=CHUNK) == {"flash_fwd"}
+
+
+def test_dense_layout_segment_names_its_kernel(v5e):
+    cfg = _cfg(4, 1)
+    loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),
+                     num_slots=SLOTS, steps_per_sync=STEPS,
+                     decode_attention="flash", prefill_chunk=CHUNK)
+    args = _segment_args(v5e, loop)
+    assert _kernel_scopes(loop._segment, *args) == {"flash_decode"}
+
+
+def test_composed_train_step_names_its_kernels(v5e):
+    """The composed ``dp=1`` step with the flash kernels and ``remat``, as
+    the training cell builds it, two layers deep."""
+    import optax
+
+    from tpudist.ops.flash_attention import flash_attention_fn
+    from tpudist.ops.losses import cross_entropy
+    from tpudist.parallel import MeshSpec, make_composed_train_step
+    from tpudist.train.state import TrainState
+
+    model = TransformerLM(dataclasses.replace(_cfg(4, 1), num_layers=2),
+                          attention_fn=flash_attention_fn(), remat=True)
+
+    def loss_fn(params, batch, rng):
+        x, y = batch
+        return cross_entropy(model.apply({"params": params}, x), y), {}
+
+    spec = MeshSpec.parse("dp=1")
+    with mock.patch.object(jax, "devices",
+                           lambda *a: list(v5e.device_set)):
+        mesh = spec.build()
+    step = make_composed_train_step(spec, mesh, loss_fn)
+    params = _abstract_params(model)
+    state = jax.eval_shape(
+        lambda p: TrainState.create(model.apply, p, optax.adamw(1e-4),
+                                    rng=0), params)
+    batch = jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
+    scopes = _kernel_scopes(
+        step, _on(NamedSharding(mesh, P()), state),
+        *_on(NamedSharding(mesh, spec.batch_spec()), (batch, batch)))
+    assert scopes == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
 
 
 # Off this PR's path (ROADMAP R1 and D7): compiled and REPORTED, not gated —

@@ -707,6 +707,25 @@ class TestWindowedHistogram:
              1: {"histograms": {"w": snap}}})
         assert merged["histograms"]["w"]["count"] == 2
 
+    def test_summary_keeps_the_lifetime_totals(self):
+        """What the window forgets stays countable: a difference of two
+        ``lifetime_*`` readings is an interval's own total."""
+        h, clock = self._h(window_s=10.0)
+        h.record(64.0)
+        h.record([1.0, 3.0])
+        first = h.summary()
+        assert (first["count"], first["lifetime_count"]) == (3, 3)
+        assert first["sum"] == first["lifetime_sum"] == 68.0
+        clock["t"] = 100.0                  # the window drops all three
+        h.record(2.0)
+        later = h.summary()
+        assert (later["count"], later["sum"]) == (1, 2.0)
+        assert later["lifetime_count"] - first["lifetime_count"] == 1
+        assert later["lifetime_sum"] - first["lifetime_sum"] == 2.0
+        # the wire format and an unwindowed histogram are as they were
+        assert "lifetime_sum" not in h._snap()
+        assert "lifetime_sum" not in obs.Histogram("h").summary()
+
     def test_registry_window_kwarg(self):
         r = obs.MetricRegistry()
         h = r.histogram("serve/queue_wait_s", unit="s", window_s=30.0)

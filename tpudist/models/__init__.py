@@ -29,7 +29,12 @@ from tpudist.models.speculative import (
 )
 from tpudist.models.moe import MoEConfig, MoEMLP, MoETransformerLM
 from tpudist.models.resnet import ResNet50, resnet50_stages
-from tpudist.models.serving import Completion, Request, ServeLoop
+from tpudist.models.serving import (
+    Completion,
+    Request,
+    RequestTiming,
+    ServeLoop,
+)
 from tpudist.models.transformer import (
     TransformerConfig,
     TransformerLM,
@@ -46,6 +51,7 @@ __all__ = [
     "Completion",
     "ConvNet",
     "Request",
+    "RequestTiming",
     "ServeLoop",
     "adaptive_speculative_generate",
     "beam_search_generate",

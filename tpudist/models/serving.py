@@ -72,6 +72,12 @@ from tpudist.models.transformer import TransformerConfig, TransformerLM
 _NO_PAGES = np.zeros((0,), np.int32)
 
 
+def _span_rid(rid: Any):
+    """A request id as a span argument: JSON-ready as it is, or its
+    ``str``."""
+    return rid if isinstance(rid, (str, int, float, type(None))) else str(rid)
+
+
 def _park_hash(rid: str, i: int) -> int:
     """Synthetic host-tier key for a parked (preempted) slot's i-th KV
     block: a 63-bit blake2b digest of ``(rid, i)`` — int-typed as the
@@ -151,6 +157,30 @@ class Completion:
     # payload and the DECODE stage produces the tokens)
     reason: str
     handoff: Any = None           # KV-migration payload (prefill role)
+    # the serving loop's own clock for this request (RequestTiming);
+    # None on completions built outside a ServeLoop (the router's: a
+    # perf_counter stamp means nothing in another process, so the fleet
+    # wire format leaves it out)
+    timing: Any = None
+
+
+@dataclasses.dataclass(frozen=True)
+class RequestTiming:
+    """Where a request's time went inside one :meth:`ServeLoop.run`, as
+    ``time.perf_counter()`` stamps of this process, each taken where the
+    thing happens.  A request that never reached a lane has ``enqueue``
+    and ``done`` only; one resumed from a park or adopted from a peer is
+    stamped from its latest enqueue here."""
+
+    enqueue: float                     # intake put it in the queue
+    done: float                        # its completion left the loop
+    admit: float | None = None         # _admit returned (lane taken)
+    # the admission's finish was DISPATCHED (== admit when one-shot)
+    prefill_done: float | None = None
+    # the fetch that carried its first token RETURNED to the host
+    first_token: float | None = None
+    chunks: int = 0                    # prefill chunks computed for it
+    tokens: int = 0                    # tokens in the completion
 
 
 def _index_leaves(cache: Any) -> tuple[jnp.ndarray, jnp.ndarray | None]:
@@ -630,11 +660,24 @@ class ServeLoop:
         # lifetime tokens drained to the host: the trip point for the
         # TPUDIST_FAULT_NAN_AFTER_TOKENS injection
         self._served_tokens = 0
+        # ticked once per DRAINED segment with that segment's sums:
+        # tokens appended to requests (what _served_tokens counts), the
+        # decode steps the segment's while_loop ran before its last lane
+        # froze, and num_slots times that (the lane-steps it paid for)
+        self._obs_tokens_drained = obs.counter("serve/tokens_drained",
+                                               unit="tokens")
+        self._obs_decode_steps = obs.counter("serve/decode_steps",
+                                             unit="steps")
+        self._obs_lane_steps = obs.counter("serve/lane_steps",
+                                           unit="steps")
         self._obs_segments = obs.counter("serve/segments", unit="segments")
         self._obs_queue = obs.gauge("serve/queue_depth", unit="reqs")
         self._obs_degraded = obs.gauge("serve/degraded", unit="bool")
         self._obs_degrade_clamped = obs.counter("serve/degrade_clamped",
                                                 unit="reqs")
+        # both from ENQUEUE, on the loop's perf_counter: to the drain
+        # that brought the first token to the host, and to the completion
+        self._obs_ttft = obs.histogram("serve/ttft_s", unit="s")
         self._obs_latency = obs.histogram("serve/request_latency", unit="s")
         # enqueue -> admit: how long requests sit behind busy lanes (and,
         # paged, behind a full block pool).  Sliding-window so the SLO
@@ -664,18 +707,19 @@ class ServeLoop:
         self._obs_spec_k = obs.gauge("serve/spec_k", unit="tokens")
         self._obs_spec_accept = obs.gauge("serve/spec_accept_rate",
                                           unit="ratio")
-        # EMA of measured seconds per generated token (dispatch -> drain
-        # wall time / tokens; an OVERestimate under pipelining, which
-        # only clamps harder) — feeds the deadline-aware segment-length
-        # clamp in _plan_steps.  Published as a gauge (and stamped into
-        # segment events) so the offline fleet simulator and postmortem
-        # bundles can read REAL service rates from recorded traces.
+        # EMA of seconds per generated token as the deadline clamp in
+        # _plan_steps sees them: dispatch -> drain wall time / tokens of
+        # the segment.  Under pipelining that wall spans the segment in
+        # front too, so it OVERestimates (which only clamps harder): the
+        # clamp's control input, not a service rate.  Published as a
+        # gauge and stamped into segment events (the fleet simulator
+        # replays it).
         self._step_ema: float | None = None
         self._obs_spt = obs.gauge(
             "serve/seconds_per_token", unit="s",
-            help="EMA of realized seconds per generated token "
-                 "(dispatch->drain wall / tokens; the replica's "
-                 "service rate)")
+            help="deadline clamp's control input: EMA of dispatch->drain "
+                 "wall / tokens of a segment (an overestimate under "
+                 "pipelining; not a service rate)")
         # donate every rebound carry: cache, tok, active, remaining, key
         # (argnums 2-4 and 6) mirror _admit_dev — their inputs are dead
         # the moment the segment returns replacements.  `first` (argnum 5)
@@ -1713,7 +1757,8 @@ class ServeLoop:
                 self._remaining, self._first, padded, np.int32(L),
                 np.int32(slot), np.int32(req.max_new_tokens), pages, pk,
                 true_chunk=chunk)
-        return {"req": req, "tokens": [], "pending_first": True}
+        return {"req": req, "tokens": [], "pending_first": True,
+                "chunks": -(-Lp // chunk)}
 
     def _admit_start(self, slot: int, req: Request, prompt: np.ndarray,
                      L: int) -> dict:
@@ -1775,6 +1820,7 @@ class ServeLoop:
             chunks.append((off, w))
             off += w
         return {"req": req, "tokens": [], "pending_first": True,
+                "chunks": 0,  # counted as the run loop dispatches them
                 "prefill": {"cache1": cache1, "padded": padded,
                             "chunks": chunks, "logits": None,
                             "off_last": 0, "L": L, "max_new": max_new,
@@ -1973,7 +2019,7 @@ class ServeLoop:
         # the terminal completion replaces the exporter's partial state
         # wholesale, so the router never assembles tokens across hops
         return {"req": req, "tokens": list(generated),
-                "pending_first": True}
+                "pending_first": True, "chunks": 0}
 
     def _plan_steps(self, slot_state) -> int:
         """Per-dispatch segment length: ``steps_per_sync``, CLAMPED
@@ -2113,7 +2159,31 @@ class ServeLoop:
         closed = source is None
         swap_pause_logged = False   # one swap_pause event per barrier
 
-        def emit(comp: Completion) -> None:
+        def complete(req: Request, tokens, reason: str, stamps: dict, *,
+                     slot: int | None = None, chunks: int = 0,
+                     handoff=None) -> None:
+            """The one way a request leaves the loop: its stamps become
+            ``Completion.timing`` and ONE ``serve/request`` span
+            (enqueue -> now, the inner stamps as offsets from enqueue),
+            then the completion goes to the list and the ``sink``."""
+            now = time.perf_counter()
+            t_q = stamps["enqueue"]
+            inner = {k: stamps.get(k)
+                     for k in ("admit", "prefill_done", "first_token")}
+            timing = RequestTiming(enqueue=t_q, done=now, chunks=chunks,
+                                   tokens=len(tokens), **inner)
+            if timing.admit is not None:
+                self._obs_latency.record(now - t_q)
+            obs.tracer.complete(
+                "serve/request", t_q, now, rid=_span_rid(req.rid),
+                slot=slot, prompt_len=int(np.asarray(req.prompt).size),
+                chunks=chunks, tokens=timing.tokens, reason=reason,
+                **{k: None if t is None else t - t_q
+                   for k, t in inner.items()})
+            comp = Completion(
+                rid=req.rid, prompt=np.asarray(req.prompt),
+                tokens=np.asarray(tokens, np.int32), reason=reason,
+                handoff=handoff, timing=timing)
             done.append(comp)
             if sink is not None:
                 sink(comp)
@@ -2126,7 +2196,8 @@ class ServeLoop:
             if tc is not None:
                 obs.events.record(kind, trace=tc.trace_id, **fields)
 
-        def complete_unadmitted(req: Request, reason: str) -> None:
+        def complete_unadmitted(req: Request, reason: str,
+                                t_q: float) -> None:
             """Finalize a request that never reached a slot (shed,
             expired in queue, or invalid): no tokens, no lane state."""
             if reason == "rejected":
@@ -2134,21 +2205,20 @@ class ServeLoop:
             elif reason == "timeout":
                 self._obs_timeouts.inc()
             tev(reason, req, stage="queue")
-            emit(Completion(
-                rid=req.rid, prompt=np.asarray(req.prompt),
-                tokens=np.zeros((0,), np.int32), reason=reason))
+            complete(req, (), reason, {"enqueue": t_q})
 
         def intake(batch, strict: bool) -> None:
             """Enqueue new requests; service mode (strict=False) turns
             validation failures into ``reason="invalid"`` completions."""
             for req in batch:
+                t_q = time.perf_counter()
                 if not strict:
                     try:
                         self._validate(req)
                     except ValueError:
-                        complete_unadmitted(req, "invalid")
+                        complete_unadmitted(req, "invalid", t_q)
                         continue
-                pending.append((req, time.perf_counter()))
+                pending.append((req, t_q))
 
         def shed() -> None:
             """Overload ladder.  Past the soft ``degrade_queue``
@@ -2166,9 +2236,9 @@ class ServeLoop:
                 lowest = min(r.priority for r, _ in pending)
                 victim = max(i for i, (r, _) in enumerate(pending)
                              if r.priority == lowest)
-                req, _ = pending[victim]
+                req, t_q = pending[victim]
                 del pending[victim]
-                complete_unadmitted(req, "rejected")
+                complete_unadmitted(req, "rejected", t_q)
             self._obs_queue.set(len(pending))
 
         def finalize(slot: int, reason: str, *,
@@ -2176,12 +2246,9 @@ class ServeLoop:
             st = slot_state[slot]
             tev("finalize", st["req"], slot=slot, reason=reason,
                 tokens=len(st["tokens"]))
-            emit(Completion(
-                rid=st["req"].rid, prompt=np.asarray(st["req"].prompt),
-                tokens=np.asarray(st["tokens"], np.int32), reason=reason))
+            complete(st["req"], st["tokens"], reason, st["stamps"],
+                     slot=slot, chunks=st["chunks"])
             self._obs_tokens.inc(len(st["tokens"]))
-            if "t_admit" in st:
-                self._obs_latency.record(time.perf_counter() - st["t_admit"])
             slot_state[slot] = None
             if self.pool is not None and free_pool:
                 # free-on-finalize: blocks AND the unused reservation
@@ -2241,7 +2308,7 @@ class ServeLoop:
                         if now is None:
                             now = self._clock()
                         if now > req.deadline_s:
-                            complete_unadmitted(req, "timeout")
+                            complete_unadmitted(req, "timeout", t_q)
                             continue
                     kept.append((req, t_q))
                 pending = kept
@@ -2305,16 +2372,20 @@ class ServeLoop:
                         tev("degrade_clamp", req, stage="replica",
                             max_new=self.degrade_max_new)
                     self._obs_queue_wait.record(time.perf_counter() - t_q)
-                    with obs.span("serve/admit", slot=slot):
-                        slot_state[slot] = self._admit(slot, req)
+                    with obs.span("serve/admit", slot=slot,
+                                  rid=_span_rid(req.rid)):
+                        st = slot_state[slot] = self._admit(slot, req)
                     # stamped here, not in _admit: benches wrap
-                    # loop._admit, and latency must cover the wrapper.
-                    # A chunked admission gets its seq stamp at the
-                    # FINISH dispatch (advance_admissions) — its tokens
-                    # cannot surface before that segment.
-                    slot_state[slot]["t_admit"] = time.perf_counter()
-                    if "prefill" not in slot_state[slot]:
-                        slot_state[slot]["seq"] = seq
+                    # loop._admit, and the stamp must cover the wrapper.
+                    # A chunked admission gets its seq stamp (and its
+                    # prefill_done) at the FINISH dispatch
+                    # (advance_admissions) — its tokens cannot surface
+                    # before that segment.
+                    st["stamps"] = {"enqueue": t_q,
+                                    "admit": time.perf_counter()}
+                    if "prefill" not in st:
+                        st["stamps"]["prefill_done"] = st["stamps"]["admit"]
+                        st["seq"] = seq
                     self._obs_requests.inc()
                     obs.recorder.record(
                         "serve_admit", slot=slot, seq=seq,
@@ -2325,19 +2396,25 @@ class ServeLoop:
                         max_new=req.max_new_tokens)
             self._obs_queue.set(len(pending))
 
-        def drain(slot: int, emit_row) -> None:
+        def drain(slot: int, emit_row, t_fetched: float) -> int:
             """Feed a slot's newly visible tokens (column 0 = the
             admission-deferred first token, then the segment's emits)
             through the stop/budget rules; the first hit finalizes
             BEFORE any frozen-row pad could be consumed, mirroring the
-            compiled freeze rule token for token."""
+            compiled freeze rule token for token.  Returns how many
+            tokens the request took.  ``t_fetched`` is when this
+            segment's emits reached the host: the first-token time of a
+            request whose deferred first token rode in column 0."""
             st = slot_state[slot]
             row = [int(t) for t in emit_row]
             if st["pending_first"]:
                 st["pending_first"] = False
+                st["stamps"]["first_token"] = t_fetched
+                self._obs_ttft.record(t_fetched - st["stamps"]["enqueue"])
             else:
                 row = row[1:]               # column 0 is a stale first
             vocab = self.cfg.vocab_size
+            had = len(st["tokens"])
             for t in row:
                 if not 0 <= t < vocab:
                     # host-side range net: an id outside the vocab can
@@ -2361,15 +2438,16 @@ class ServeLoop:
                                             "free_at": seq}
                     else:
                         finalize(slot, "corrupt_segment")
-                    return
+                    break
                 st["tokens"].append(t)
                 self._served_tokens += 1
                 if t in self._stop_set:
                     finalize(slot, "stop")
-                    return
+                    break
                 if len(st["tokens"]) >= st["req"].max_new_tokens:
                     finalize(slot, "length")
-                    return
+                    break
+            return len(st["tokens"]) - had
 
         def advance_admissions() -> None:
             """Chunked prefill: advance every prefilling lane by ONE
@@ -2396,16 +2474,19 @@ class ServeLoop:
                     off, w = pf["chunks"].pop(0)
                     toks = pf["padded"][:, off:off + w]
                     with obs.span("serve/prefill_chunk", slot=slot,
-                                  off=off, width=w):
+                                  rid=_span_rid(st["req"].rid), off=off,
+                                  width=w):
                         pf["cache1"], pf["logits"] = self._prefill_chunk(
                             self.params, pf["cache1"], toks,
                             np.int32(off), chunk=w)
+                    st["chunks"] += 1
                     pf["off_last"] = off
                     tev("prefill_chunk", st["req"], slot=slot,
                         off=off, width=w, left=len(pf["chunks"]))
                     continue
                 self._key, pk = jax.random.split(self._key)
-                with obs.span("serve/admit_finish", slot=slot):
+                with obs.span("serve/admit_finish", slot=slot,
+                              rid=_span_rid(st["req"].rid)):
                     (self.cache, self._tok, self._active,
                      self._remaining, self._first) = self._admit_finish(
                         self.cache, self._tok, self._active,
@@ -2414,6 +2495,7 @@ class ServeLoop:
                         np.int32(pf["L"]), np.int32(slot),
                         np.int32(pf["max_new"]), pf["pages"],
                         np.int32(pf["write_block"]), pk)
+                st["stamps"]["prefill_done"] = time.perf_counter()
                 if self._prefix_cache is not None:
                     # register AFTER the insert dispatch: any later
                     # match's gather is host-ordered behind the write
@@ -2449,14 +2531,9 @@ class ServeLoop:
                     tev("handoff_export", st["req"], slot=slot, seq=seq,
                         prompt_len=pf["L"],
                         blocks=-(-pf["L"] // self.kv_block_size))
-                    emit(Completion(
-                        rid=st["req"].rid,
-                        prompt=np.asarray(st["req"].prompt),
-                        tokens=np.zeros((0,), np.int32),
-                        reason="handoff", handoff=payload))
-                    if "t_admit" in st:
-                        self._obs_latency.record(
-                            time.perf_counter() - st["t_admit"])
+                    complete(st["req"], (), "handoff", st["stamps"],
+                             slot=slot, chunks=st["chunks"],
+                             handoff=payload)
                     del st["prefill"]
                     slot_state[slot] = None
                     freed_by_handoff.append(slot)
@@ -2540,32 +2617,34 @@ class ServeLoop:
             """Chain one more segment on device and start its emits'
             async device→host copy — no host block."""
             nonlocal seq
-            n = self._plan_steps(slot_state)
-            live = sum(1 for st in slot_state
-                       if st is not None and not st.get("zombie"))
-            k = (self._spec_k(live)
-                 if self.decode_mode == "speculative" else 0)
-            if self.pool is not None:
-                # grow-on-decode-boundary: advance every live lane's page
-                # coverage by the segment's worst case (drawn from its
-                # admit-time reservation, so this cannot fail), then
-                # stamp the fresh table into the carry this segment
-                # consumes.  Speculative segments can emit up to n + k
-                # tokens (the last round's full K+1 window).  Lanes
-                # already frozen on device (host hasn't drained the stop
-                # yet) grow harmlessly within their reservation and
-                # refund it at finalize.  Zombie lanes are dead (their
-                # reservation was dropped at finalize); their held
-                # blocks just wait for the refund.
-                for slot in range(self.B):
-                    st = slot_state[slot]
-                    if (st is not None and not st.get("zombie")
-                            and "prefill" not in st):
-                        # prefill-phase lanes don't grow: nothing
-                        # decodes there yet, and their prompt coverage
-                        # was allocated at admit
-                        self.pool.grow(slot, n + k)
-                self._stamp_table()
+            with obs.span("serve/segment_plan", seq=seq):
+                n = self._plan_steps(slot_state)
+                live = sum(1 for st in slot_state
+                           if st is not None and not st.get("zombie"))
+                k = (self._spec_k(live)
+                     if self.decode_mode == "speculative" else 0)
+                if self.pool is not None:
+                    # grow-on-decode-boundary: advance every live lane's
+                    # page coverage by the segment's worst case (drawn
+                    # from its admit-time reservation, so this cannot
+                    # fail), then stamp the fresh table into the carry
+                    # this segment consumes.  Speculative segments can
+                    # emit up to n + k tokens (the last round's full K+1
+                    # window).  Lanes already frozen on device (host
+                    # hasn't drained the stop yet) grow harmlessly within
+                    # their reservation and refund it at finalize.
+                    # Zombie lanes are dead (their reservation was
+                    # dropped at finalize); their held blocks just wait
+                    # for the refund.
+                    for slot in range(self.B):
+                        st = slot_state[slot]
+                        if (st is not None and not st.get("zombie")
+                                and "prefill" not in st):
+                            # prefill-phase lanes don't grow: nothing
+                            # decodes there yet, and their prompt
+                            # coverage was allocated at admit
+                            self.pool.grow(slot, n + k)
+                    self._stamp_table()
             # the segment splits per-step keys and returns the advanced
             # key — no per-wave host-side split dispatch needed
             t_disp = time.perf_counter()
@@ -2621,7 +2700,17 @@ class ServeLoop:
             past the deferred-first column; speculative ones carry
             ``stats[0]`` (the emitted count) — either way the drain
             slices to the real width so pad columns past a short segment
-            are never consumed."""
+            are never consumed.
+
+            Leaves one ``serve/segment_fetch`` span (the block on the
+            device) and one ``serve/segment_drain`` span (the host's work
+            on what came), the latter with the segment's sums: ``steps``
+            dispatched, ``steps_run`` (the most decode tokens any lane
+            took: the device's ``while_loop`` runs until its last live
+            lane freezes and the host's rules mirror its freeze token for
+            token, so this is its exit step; a lane the HOST killed is
+            not fed and not counted), ``lanes`` fed, ``tokens`` appended
+            and how many of them were ``first_tokens``."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
              t_disp) = inflight.popleft()
             self._obs_depth.set(len(inflight))
@@ -2629,22 +2718,27 @@ class ServeLoop:
                    and "seq" in st and st["seq"] <= s_idx
                    for st in slot_state):
                 t0 = time.perf_counter()
-                emits = np.asarray(emits_dev)
-                stats = (np.asarray(stats_dev)
-                         if stats_dev is not None else None)
-                self._obs_host_wait.record(time.perf_counter() - t0)
+                with obs.span("serve/segment_fetch", seq=s_idx):
+                    emits = np.asarray(emits_dev)
+                    stats = (np.asarray(stats_dev)
+                             if stats_dev is not None else None)
+                # the one stamp of "this segment's tokens are on the
+                # host": host_wait's end, the first-token time of the
+                # requests whose first token it carried, the inter-token
+                # sample and the clamp's EMA all read it
+                t_fetched = time.perf_counter()
+                self._obs_host_wait.record(t_fetched - t0)
                 n_tok = n_disp if stats is None else int(stats[0])
                 # inter-token latency sample: wall gap between
                 # consecutive decode-segment drains, per token of this
                 # segment.  A one-shot long-prompt admission lands
                 # between two segments and shows up here as one huge
                 # gap — exactly the stall chunked prefill removes.
-                now_t = time.perf_counter()
                 if self._last_drain_t is not None and n_tok > 0:
                     self.intertoken_samples.append(
-                        ((now_t - self._last_drain_t) / n_tok, n_tok))
-                self._last_drain_t = now_t
-                dt = time.perf_counter() - t_disp
+                        ((t_fetched - self._last_drain_t) / n_tok, n_tok))
+                self._last_drain_t = t_fetched
+                dt = t_fetched - t_disp
                 if n_tok > 0:
                     # dispatch->drain wall time per token; under
                     # pipelining this spans overlapped segments, so it
@@ -2678,10 +2772,12 @@ class ServeLoop:
                             self._spec_uses.get(k_disp, 0) + 1)
                 corrupt = (np.asarray(corrupt_dev)
                            if corrupt_dev is not None else None)
+                lanes = tokens = first_tokens = steps_run = 0
                 for slot in range(self.B):
                     st = slot_state[slot]
                     if (st is not None and not st.get("zombie")
                             and "seq" in st and st["seq"] <= s_idx):
+                        lanes += 1
                         if corrupt is not None and bool(corrupt[slot]):
                             # the in-graph guard froze this lane before
                             # emitting anything from the bad step, but
@@ -2699,7 +2795,20 @@ class ServeLoop:
                                 seq=s_idx, tokens=len(st["tokens"]))
                             finalize(slot, "corrupt_segment")
                         else:
-                            drain(slot, emits[slot, :1 + n_tok])
+                            first = int(st["pending_first"])
+                            got = drain(slot, emits[slot, :1 + n_tok],
+                                        t_fetched)
+                            first = min(first, got)  # none from a corrupt column 0
+                            tokens += got
+                            first_tokens += first
+                            steps_run = max(steps_run, got - first)
+                self._obs_tokens_drained.inc(tokens)
+                self._obs_decode_steps.inc(steps_run)
+                self._obs_lane_steps.inc(self.B * steps_run)
+                obs.tracer.complete(
+                    "serve/segment_drain", t_fetched, time.perf_counter(),
+                    seq=s_idx, steps=n_disp, steps_run=steps_run,
+                    lanes=lanes, tokens=tokens, first_tokens=first_tokens)
             # zombie refund: every segment dispatched before the kill
             # (index < free_at) has drained once s_idx reaches
             # free_at - 1 — no stale merge can touch the blocks now
@@ -2804,7 +2913,7 @@ class ServeLoop:
             entry.pop("payload", None)
 
         def migrate_out(req: Request, payload: dict | None,
-                        stage: str) -> None:
+                        stage: str, stamps: dict) -> None:
             """Hand one request back to the router as a
             ``reason="migrate"`` completion — with its exported KV
             (in-flight) or ref-less (queued/prefill-phase: the
@@ -2814,10 +2923,7 @@ class ServeLoop:
                 tokens=(len(payload.get("generated", ()))
                         + 1 if payload else 0),
                 refless=payload is None)
-            emit(Completion(
-                rid=req.rid, prompt=np.asarray(req.prompt),
-                tokens=np.zeros((0,), np.int32),
-                reason="migrate", handoff=payload))
+            complete(req, (), "migrate", stamps, handoff=payload)
 
         def do_migrates() -> bool:
             """Router-initiated migration: evacuate everything (fast
@@ -2840,7 +2946,7 @@ class ServeLoop:
                 kept: deque[tuple[Request, float]] = deque()
                 for req, t_q in pending:
                     if evac or req.rid in wanted:
-                        migrate_out(req, None, "queue")
+                        migrate_out(req, None, "queue", {"enqueue": t_q})
                         moved = True
                     else:
                         kept.append((req, t_q))
@@ -2849,7 +2955,8 @@ class ServeLoop:
                 if evac or rid in wanted:
                     entry = self._parked.pop(rid)
                     payload = unpark(entry)
-                    migrate_out(entry["req"], payload, "parked")
+                    migrate_out(entry["req"], payload, "parked",
+                                {"enqueue": entry["t_q"]})
                     moved = True
             if self.pool is not None and any(
                     st is not None and not st.get("zombie")
@@ -2868,10 +2975,10 @@ class ServeLoop:
                         # target re-prefills to identical bytes
                         self.pool.free_slot(slot)
                         slot_state[slot] = None
-                        migrate_out(req, None, "prefill")
+                        migrate_out(req, None, "prefill", st["stamps"])
                     else:
                         migrate_out(req, export_slot_payload(slot),
-                                    "decode")
+                                    "decode", st["stamps"])
                     moved = True
             self._migrate_rids.clear()
             self._evacuate = False
@@ -2931,7 +3038,7 @@ class ServeLoop:
                     and self._clock() > req.deadline_s):
                 drop_parked(entry)
                 del self._parked[rid]
-                complete_unadmitted(req, "timeout")
+                complete_unadmitted(req, "timeout", entry["t_q"])
                 return True
             if not any(s is None for s in slot_state):
                 return False
@@ -2961,25 +3068,30 @@ class ServeLoop:
             admit_free()
             shed()
             while True:
-                if not closed:
-                    batch = source()
-                    if batch is None:
-                        closed = True
-                    elif batch:
-                        intake(batch, strict=False)
+                # the loop's own phases are spans (admit_poll, admit,
+                # prefill_chunk, admit_finish, segment_plan, segment,
+                # segment_fetch, segment_drain): together they account
+                # for the wall of run() but the idle sleep
+                with obs.span("serve/admit_poll"):
+                    if not closed:
+                        batch = source()
+                        if batch is None:
+                            closed = True
+                        elif batch:
+                            intake(batch, strict=False)
+                            admit_free()
+                            shed()
+                    expire_inflight()
+                    if (self.preempt == "migrate"
+                            and self._pending_swap is not None
+                            and source is not None):
+                        # fast swap: evacuate in-flight work to peers so
+                        # the swap barrier drains in ~one handoff RTT
+                        # instead of the longest remaining decode
+                        self._evacuate = True
+                    if do_migrates() | maybe_preempt() | maybe_resume():
                         admit_free()
                         shed()
-                expire_inflight()
-                if (self.preempt == "migrate"
-                        and self._pending_swap is not None
-                        and source is not None):
-                    # fast swap: evacuate in-flight work to peers so
-                    # the swap barrier drains in ~one handoff RTT
-                    # instead of the longest remaining decode
-                    self._evacuate = True
-                if do_migrates() | maybe_preempt() | maybe_resume():
-                    admit_free()
-                    shed()
                 advance_admissions()
                 if can_work():
                     dispatch()
@@ -2989,7 +3101,8 @@ class ServeLoop:
                         len(inflight) >= self.pipeline_depth
                         or not can_work()):
                     drain_oldest()
-                    admit_free()
+                    with obs.span("serve/admit_poll"):
+                        admit_free()
                 maybe_swap()
                 if not (pending or inflight or self._parked or any(
                         st is not None for st in slot_state)):

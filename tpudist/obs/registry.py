@@ -241,6 +241,9 @@ class Histogram:
         self._clock = clock
         self._gens: list[dict] = [self._new_gen()]
         self._pending: list = []
+        # what the window forgets: every observation since creation
+        self._life_count = 0
+        self._life_sum = 0.0
 
     def _new_gen(self) -> dict:
         return {"start": self._clock(), "buckets": {}, "zero": 0,
@@ -274,8 +277,11 @@ class Histogram:
             flat = np.asarray(v, dtype=np.float64).reshape(-1)
             if not flat.size:
                 continue
-            g["count"] += int(flat.size)
-            g["sum"] += float(flat.sum())
+            n, total = int(flat.size), float(flat.sum())
+            g["count"] += n
+            g["sum"] += total
+            self._life_count += n
+            self._life_sum += total
             lo, hi = float(flat.min()), float(flat.max())
             g["min"] = lo if g["min"] is None else min(g["min"], lo)
             g["max"] = hi if g["max"] is None else max(g["max"], hi)
@@ -292,9 +298,16 @@ class Histogram:
 
     def summary(self) -> dict:
         """p50/p90/p99 + count/sum/mean/min/max (syncs this histogram's
-        own pending only)."""
+        own pending only).  A sliding-window histogram also reports
+        ``lifetime_count`` / ``lifetime_sum`` over every observation
+        since it was created, so that a difference of two readings is an
+        interval's own total whatever the window dropped meanwhile."""
         self._fold(_sync_pending({"v": self._take_pending()})["v"])
-        return summarize(self._snap())
+        out = summarize(self._snap())
+        if self.window_s is not None:
+            out["lifetime_count"] = self._life_count
+            out["lifetime_sum"] = self._life_sum
+        return out
 
     def _snap(self) -> dict:
         self._rotate()

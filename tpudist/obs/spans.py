@@ -130,7 +130,7 @@ class SpanTracer:
             dur_us = (time.perf_counter() - start) * 1e6
             depth = len(stack) - 1
             stack.pop()
-            event = {
+            self._append({
                 "name": name,
                 "ph": "X",
                 # perf_counter origin is arbitrary but shared across the
@@ -140,11 +140,25 @@ class SpanTracer:
                 "pid": self._pid,
                 "tid": threading.get_ident(),
                 "args": {"depth": depth, **args},
-            }
-            with self._lock:
-                if len(self._events) == self.max_events:
-                    self.dropped += 1  # deque maxlen evicts the oldest
-                self._events.append(event)
+            })
+
+    def complete(self, name: str, start: float, end: float, **args) -> None:
+        """Record a span from two ``time.perf_counter()`` stamps already
+        taken: for a phase whose ends, or whose ``args``, are only known
+        after it is over (a request's life, a drained segment's sums).
+        No nesting depth and no ``TraceAnnotation``: it lies in the ring,
+        not on a profiler's host plane."""
+        self._append({
+            "name": name, "ph": "X", "ts": start * 1e6,
+            "dur": (end - start) * 1e6, "pid": self._pid,
+            "tid": threading.get_ident(), "args": args,
+        })
+
+    def _append(self, event: dict) -> None:
+        with self._lock:
+            if len(self._events) == self.max_events:
+                self.dropped += 1  # deque maxlen evicts the oldest
+            self._events.append(event)
 
     def events(self) -> list[dict]:
         with self._lock:

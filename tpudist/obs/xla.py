@@ -98,17 +98,24 @@ def mfu(tflops: float | None, device: Any = None) -> float | None:
 def note_compile(seconds: float, registry: Any = None,
                  source: str = "jit") -> None:
     """Record one compilation: count, duration histogram, and a
-    flight-recorder event (the recompile-storm breadcrumb)."""
+    flight-recorder event (the recompile-storm breadcrumb).  The
+    innermost ``obs.span`` open on the compiling thread names the cause:
+    it rides the event as ``span`` and ticks
+    ``xla/compiles~span=<name>`` beside ``xla/compiles``."""
+    from tpudist import obs
+
     reg = _registry(registry)
     reg.counter("xla/compiles", unit="compiles").inc()
     reg.histogram("xla/compile_seconds", unit="s").record(float(seconds))
-    try:
-        from tpudist import obs
-
-        obs.recorder.record("xla_compile", seconds=round(float(seconds), 4),
-                            source=source)
-    except Exception:  # noqa: BLE001 - recorder is optional context
-        pass
+    stack = obs.tracer._depth()
+    span = stack[-1] if stack else None
+    if span is not None:
+        try:
+            reg.counter(f"xla/compiles~span={span}", unit="compiles").inc()
+        except ValueError:  # a span name that cannot be a label value
+            pass
+    obs.recorder.record("xla_compile", seconds=round(float(seconds), 4),
+                        source=source, span=span)
 
 
 def install_compile_telemetry(registry: Any = None) -> bool:

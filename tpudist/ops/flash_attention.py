@@ -248,6 +248,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(_offsets_arg(q_offset, k_offset), q3, k3, v3)
     return _unfuse(out, b, h), lse.reshape(b, h, s)
 
@@ -490,6 +491,7 @@ def flash_block_grads(q, k, v, dout, lse, delta, *, causal, block_q,
             ],
             compiler_params=semantics,
             interpret=interpret,
+            name="flash_bwd_dq",
         )(offs, q3, k3, v3, do3, lse3, o3)
     else:
         dq = pl.pallas_call(
@@ -505,6 +507,7 @@ def flash_block_grads(q, k, v, dout, lse, delta, *, causal, block_q,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=semantics,
             interpret=interpret,
+            name="flash_bwd_dq",
         )(offs, q3, k3, v3, do3, lse3, delta3)[0]
 
     dk, dv = pl.pallas_call(
@@ -527,6 +530,7 @@ def flash_block_grads(q, k, v, dout, lse, delta, *, causal, block_q,
         ],
         compiler_params=semantics,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(offs, q3, k3, v3, do3, lse3, delta3)
 
     return _unfuse(dq, b, h), _unfuse(dk, b, h_kv), _unfuse(dv, b, h_kv)

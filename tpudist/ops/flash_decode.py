@@ -588,6 +588,7 @@ def _flash_decode_impl(q, k_cache, k_scale, v_cache, v_scale, cache_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(*args)
     def unpack_out(out):
         if paired:
@@ -808,6 +809,7 @@ def paged_flash_decode(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_flash_decode",
     )(*args)
     if paired:
         d0 = d // 2
