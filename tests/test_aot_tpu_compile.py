@@ -93,18 +93,39 @@ def _kernel_calls(hlo: str) -> int:
     return hlo.count('custom_call_target="tpu_custom_call"')
 
 
+def _custom_call(hlo: str, name: str) -> dict:
+    """The one custom call whose instruction is named ``name``, parsed as
+    the benchmark parses a trace's event (a device event is named by its
+    whole instruction)."""
+    from benchmarks.trace.reduce import parse_op
+
+    (line,) = [l for l in hlo.splitlines()
+               if re.match(rf"\s*%{name}[.\d]* = ", l)]
+    return parse_op(line.strip())
+
+
+# (lanes, heads, kv heads, head_dim, page-table entries, pool blocks): the
+# two layouts at the smoke's size, and the benchmark's serving cell
+# (starcoderbase-3b: 24 lanes, 22 heads, 1 K/V head of 128, 64 entries,
+# 1024 blocks) so that a VMEM or lowering refusal shows here
+PAGED_SHAPES = {
+    "4q1kv": (SLOTS, 4, 1, EMBED // 4, SEQ // BLOCK, SLOTS * (SEQ // BLOCK)),
+    "8q2kv": (SLOTS, 8, 2, EMBED // 8, SEQ // BLOCK, SLOTS * (SEQ // BLOCK)),
+    "cell_sc3b": (24, 22, 1, 128, 64, 1024),
+}
+
+
 @pytest.mark.parametrize("side", [True, False], ids=["side", "noside"])
-@pytest.mark.parametrize("heads,kv_heads", LAYOUTS)
-def test_paged_flash_decode(v5e, heads, kv_heads, side):
-    d = EMBED // heads
+@pytest.mark.parametrize("shape", PAGED_SHAPES)
+def test_paged_flash_decode(v5e, shape, side):
+    lanes, heads, kv_heads, d, entries, n_blocks = PAGED_SHAPES[shape]
     flat = kv_heads * d
-    n_blocks = SLOTS * (SEQ // BLOCK)
-    q = _sds(v5e, (SLOTS, 1, heads, d))
+    q = _sds(v5e, (lanes, 1, heads, d))
     pool = _sds(v5e, (n_blocks, BLOCK, flat))
-    table = _sds(v5e, (SLOTS, SEQ // BLOCK), jnp.int32)
-    lens = _sds(v5e, (SLOTS,), jnp.int32)
+    table = _sds(v5e, (lanes, entries), jnp.int32)
+    lens = _sds(v5e, (lanes,), jnp.int32)
     if side:
-        buf = _sds(v5e, (SLOTS, STEPS, flat))
+        buf = _sds(v5e, (lanes, STEPS, flat))
         hlo = _compile(
             lambda q, k, v, t, n, sk, sv, sl: paged_flash_decode(
                 q, k, v, t, n, packed_kv_heads=kv_heads, side_k=sk,
@@ -116,6 +137,13 @@ def test_paged_flash_decode(v5e, heads, kv_heads, side):
                 q, k, v, t, n, packed_kv_heads=kv_heads),
             q, pool, pool, table, lens)
     assert _kernel_calls(hlo) == 1
+    # what the roofline's reader tells the kernel by: a Pallas call of
+    # meta, q, the two pools (and the two side buffers) with ONE output
+    from benchmarks.roofline import paged_decode
+
+    op = _custom_call(hlo, "paged_flash_decode")
+    assert op["pallas"] and op["operands"] == (6 if side else 4)
+    assert len(op["outputs"]) == 1 and paged_decode.is_kernel(op) == side
 
 
 @pytest.mark.parametrize("heads,kv_heads", LAYOUTS)
@@ -202,7 +230,9 @@ def _kernel_scopes(fn, *args, **static) -> set[str]:
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         text = jitted.lower(*args, **static).as_text(debug_info=True)
-    return set(re.findall(r"/(\w+)/pallas_call", text))
+    # a call under its own ``jit`` (paged_flash_decode) is located from
+    # the callee: its scope then starts the location, without a slash
+    return set(re.findall(r"\b(\w+)/pallas_call", text))
 
 
 def test_segment_names_its_kernel(v5e, serve_loop):

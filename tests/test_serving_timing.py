@@ -140,6 +140,62 @@ def test_steps_run_is_the_early_exit(lm, layout, chunked):
     assert all(a["steps"] == STEPS for a in full)
 
 
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["oneshot", "chunked"])
+def test_released_lane_walks_no_page(lm, chunked):
+    """The paged decode kernel walks ``ceil(cache_index / block)`` pages a
+    lane, so a lane that holds no request has to enter a segment at
+    length 0 whatever its last request left there; and the drained
+    segment's ``pages`` is the pool's own count of what the live lanes
+    held when it was dispatched."""
+    from tpudist.models.serving import _index_leaves
+
+    loop = make_loop(lm, "paged", chunked, decode_attention="flash")
+    block = loop.kv_block_size
+    segment, grow = loop._segment, loop.pool.grow
+    entered, grown = [], []
+
+    def spy_grow(slot, steps):
+        grown.append(loop.pool.covered_pages(slot))
+        return grow(slot, steps)
+
+    def spy_segment(params, cache, tok, active, *rest):
+        rec = {"active": np.asarray(active),
+               "len_in": np.asarray(_index_leaves(cache)[0]),
+               "host_pages": sum(grown)}
+        grown.clear()
+        out = segment(params, cache, tok, active, *rest)
+        rec["len_out"] = np.asarray(_index_leaves(out[0])[0])
+        entered.append(rec)
+        return out
+
+    loop._segment, loop.pool.grow = spy_segment, spy_grow
+    walked = obs.counter("serve/decode_pages_walked")
+    before = walked.value()
+    # lane 0 finishes in the first segment; lane 1 decodes for three more,
+    # and the third request takes the freed lane in the meantime
+    _, sp = run_traced(loop, requests([20, 30, 9], [3, 3 * STEPS, 4]))
+    stale = 0
+    for rec in entered:
+        idle = ~rec["active"]
+        # inactive at entry: length 0 for the whole segment, and after it
+        assert not rec["len_out"][idle].any()
+        assert (rec["len_out"][~idle] >= rec["len_in"][~idle]).all()
+        stale += int((rec["len_in"][idle] > 0).sum())
+        device_pages = int((-(-rec["len_in"][~idle] // block)).sum())
+        # the host counts a lane the device froze and it has not drained
+        assert rec["host_pages"] >= device_pages
+    assert stale, "no released lane with a stale length ever entered"
+    assert any(rec["host_pages"] == int((-(-rec["len_in"][rec["active"]]
+                                           // block)).sum()) > 0
+               for rec in entered)
+    drains = [e["args"] for e in sp["serve/segment_drain"]]
+    assert drains and all(
+        a["pages"] == entered[a["seq"]]["host_pages"] for a in drains)
+    assert (walked.value() - before
+            == sum(a["pages"] * a["steps_run"] for a in drains) > 0)
+
+
 @pytest.mark.parametrize("layout,chunked", CASES)
 def test_chunks_count_the_prompt(lm, layout, chunked):
     lengths = [1, CHUNK, CHUNK + 1, 3 * CHUNK + 5]

@@ -133,8 +133,9 @@ class BlockPool:
     The page table (:attr:`table`) is a ``[num_slots,
     max_blocks_per_slot]`` int32 array; rows are filled left-to-right
     with the slot's allocated blocks and UNALLOCATED entries hold 0 — a
-    valid pool index, so the kernel's page-gather DMA always reads real
-    memory (the per-row length mask is what protects correctness).
+    valid pool index, so the dense fallback's gather and the merge's
+    clamped lookups always read real memory; the decode kernel reads a
+    row's first ``ceil(length / block_size)`` entries only.
     """
 
     def __init__(self, num_blocks: int, block_size: int, num_slots: int,
@@ -387,6 +388,11 @@ class BlockPool:
         self._obs_cow.inc()
         self._publish()
         return new
+
+    def covered_pages(self, slot: int) -> int:
+        """Pages under ``slot``'s coverage watermark: its KV length by the
+        host's own count, in blocks — what a decode step walks for it."""
+        return blocks_for(self._watermark[slot], self.block_size)
 
     def grow(self, slot: int, steps: int) -> None:
         """Advance ``slot``'s coverage by ``steps`` decode tokens (capped

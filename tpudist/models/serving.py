@@ -220,6 +220,25 @@ def _shift_index_leaves(cache: Any, delta, names) -> Any:
     return walk(cache)
 
 
+def _bound_paged_walk(cache: Any, active) -> Any:
+    """Entry of a segment, paged layout: a lane that is not active holds
+    no request (released, finalised, or still prefilling through the
+    batch-1 cache) and stays inactive for the whole segment, so its main
+    length is set to 0 and the paged decode kernel, which walks
+    ``ceil(cache_index / block)`` pages a lane, walks none for it.
+    Admission and adoption stamp the length anew with the lane; the merge
+    advances it by the lane's ``lived`` (0 here); the host keeps its own
+    lengths in the pool and never reads this leaf."""
+    def walk(node):
+        if not isinstance(node, dict):
+            return node
+        out = {k: walk(v) for k, v in node.items()}
+        if "paged_key" in out:
+            out["cache_index"] = jnp.where(active, out["cache_index"], 0)
+        return out
+    return walk(cache)
+
+
 class ServeLoop:
     """Continuous-batching server over one model.
 
@@ -670,6 +689,12 @@ class ServeLoop:
                                              unit="steps")
         self._obs_lane_steps = obs.counter("serve/lane_steps",
                                            unit="steps")
+        # paged layout: pages the decode kernel walked, a layer — the
+        # segment's live pages at dispatch times the steps it ran.  Over
+        # lane_steps x max_blocks_per_slot it is the share of the page
+        # table a walk touches
+        self._obs_pages_walked = obs.counter("serve/decode_pages_walked",
+                                             unit="pages")
         self._obs_segments = obs.counter("serve/segments", unit="segments")
         self._obs_queue = obs.gauge("serve/queue_depth", unit="reqs")
         self._obs_degraded = obs.gauge("serve/degraded", unit="bool")
@@ -910,6 +935,7 @@ class ServeLoop:
         lived0 = jnp.zeros((self.B,), jnp.int32)
         corrupt0 = jnp.zeros((self.B,), bool)
         E0 = jnp.full((self.B, self.steps), pad, jnp.int32)
+        cache = _bound_paged_walk(cache, active)
         (_, cache, tok, active, remaining, lived, corrupt, key,
          E) = lax.while_loop(
             cond, step,
@@ -1296,6 +1322,7 @@ class ServeLoop:
 
         lived0 = jnp.zeros((self.B,), jnp.int32)
         E0 = jnp.full((self.B, cap_out), pad, jnp.int32)
+        cache = _bound_paged_walk(cache, active)
         (com, cache, d_cache, tok, active, remaining, lived, key, E,
          rounds, acc_sum, act_rounds) = lax.while_loop(
             cond, round_body,
@@ -2623,6 +2650,7 @@ class ServeLoop:
                            if st is not None and not st.get("zombie"))
                 k = (self._spec_k(live)
                      if self.decode_mode == "speculative" else 0)
+                pages = 0
                 if self.pool is not None:
                     # grow-on-decode-boundary: advance every live lane's
                     # page coverage by the segment's worst case (drawn
@@ -2643,6 +2671,7 @@ class ServeLoop:
                             # prefill-phase lanes don't grow: nothing
                             # decodes there yet, and their prompt
                             # coverage was allocated at admit
+                            pages += self.pool.covered_pages(slot)
                             self.pool.grow(slot, n + k)
                     self._stamp_table()
             # the segment splits per-step keys and returns the advanced
@@ -2685,7 +2714,8 @@ class ServeLoop:
                 emits.copy_to_host_async()
             except AttributeError:  # non-jax array (test doubles)
                 pass
-            inflight.append((seq, emits, corrupt, stats, n, k, t_disp))
+            inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
+                             pages))
             seq += 1
             self._obs_depth.set(len(inflight))
             # fault harness: a configured kill-after-K-segments SIGKILLs
@@ -2709,10 +2739,14 @@ class ServeLoop:
             took: the device's ``while_loop`` runs until its last live
             lane freezes and the host's rules mirror its freeze token for
             token, so this is its exit step; a lane the HOST killed is
-            not fed and not counted), ``lanes`` fed, ``tokens`` appended
-            and how many of them were ``first_tokens``."""
+            not fed and not counted), ``lanes`` fed, ``tokens`` appended,
+            how many of them were ``first_tokens``, and ``pages`` (paged
+            layout: the pages under the live lanes' lengths when the
+            segment was dispatched, by the pool's own count — what one
+            call of the decode kernel walks; a lane frozen on the device
+            that the host has not drained yet is still counted)."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
-             t_disp) = inflight.popleft()
+             t_disp, pages) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
                    and "seq" in st and st["seq"] <= s_idx
@@ -2805,10 +2839,12 @@ class ServeLoop:
                 self._obs_tokens_drained.inc(tokens)
                 self._obs_decode_steps.inc(steps_run)
                 self._obs_lane_steps.inc(self.B * steps_run)
+                self._obs_pages_walked.inc(pages * steps_run)
                 obs.tracer.complete(
                     "serve/segment_drain", t_fetched, time.perf_counter(),
                     seq=s_idx, steps=n_disp, steps_run=steps_run,
-                    lanes=lanes, tokens=tokens, first_tokens=first_tokens)
+                    lanes=lanes, tokens=tokens, first_tokens=first_tokens,
+                    pages=pages)
             # zombie refund: every segment dispatched before the kill
             # (index < free_at) has drained once s_idx reaches
             # free_at - 1 — no stale merge can touch the blocks now

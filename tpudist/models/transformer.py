@@ -522,7 +522,8 @@ class CausalSelfAttention(nn.Module):
 
         Attention: ``decode_attention="flash"`` runs
         :func:`tpudist.ops.flash_decode.paged_flash_decode` (the dense
-        kernel's online softmax with a page-table-driven K/V index map);
+        kernel's online softmax over the pages a lane really holds,
+        fetched by the page table's ids);
         ``"dense"`` gathers the slot's pages into a contiguous view and
         masks — the CPU/test fallback."""
         cfg = self.cfg
