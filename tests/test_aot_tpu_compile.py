@@ -30,7 +30,8 @@ from jax.sharding import SingleDeviceSharding
 
 from tpudist.models import ServeLoop, TransformerConfig, TransformerLM
 from tpudist.ops.flash_attention import _flash_forward, flash_attention
-from tpudist.ops.flash_decode import flash_decode, paged_flash_decode
+from tpudist.ops.flash_decode import (flash_decode, paged_flash_decode,
+                                      paged_mla_decode)
 
 VOCAB, LAYERS, EMBED, SEQ = 32000, 8, 512, 8192
 SLOTS, STEPS, CHUNK, BLOCK = 4, 32, 512, 128
@@ -146,6 +147,81 @@ def test_paged_flash_decode(v5e, shape, side):
     assert len(op["outputs"]) == 1 and paged_decode.is_kernel(op) == side
 
 
+# The DeepSeek-V3 cell (dsv3_reason_batch): 128 lanes, 128 heads, a latent
+# row of 640 (512 latent + 64 rotary + 64 zeros), block 128, 64 table
+# entries, side 32; 16 held experts of 7168 x 2048.
+MLA_LANES, MLA_HEADS, MLA_ROW, MLA_LATENT, MLA_BLOCKS = 128, 128, 640, 512, 2048
+
+
+@pytest.mark.parametrize("side", [True, False], ids=["side", "noside"])
+def test_paged_mla_decode_at_the_cells_shapes(v5e, side):
+    q = _sds(v5e, (MLA_LANES, MLA_HEADS, MLA_ROW))
+    pool = _sds(v5e, (MLA_BLOCKS, BLOCK, MLA_ROW))
+    table = _sds(v5e, (MLA_LANES, SEQ // BLOCK), jnp.int32)
+    lens = _sds(v5e, (MLA_LANES,), jnp.int32)
+    kw = dict(d_v=MLA_LATENT, scale=0.1352)
+    if side:
+        hlo = _compile(
+            lambda q, p, t, n, s, sl: paged_mla_decode(
+                q, p, t, n, side=s, side_len=sl, **kw),
+            q, pool, table, lens, _sds(v5e, (MLA_LANES, STEPS, MLA_ROW)),
+            _sds(v5e, (), jnp.int32))
+    else:
+        hlo = _compile(lambda q, p, t, n: paged_mla_decode(q, p, t, n, **kw),
+                       q, pool, table, lens)
+    assert _kernel_calls(hlo) == 1
+    # the benchmark's readers find this kernel BY NAME; by operands it is
+    # meta, q, ONE pool (and one side buffer), so the shape test that tells
+    # paged_flash_decode (six operands) never counts it
+    from benchmarks.layer_metrics.mla_decode_us_per_call import KERNEL
+    from benchmarks.roofline import paged_decode
+
+    (line,) = [l.strip() for l in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%paged_mla_decode[.\d]* = ", l)]
+    assert KERNEL.match(line.removeprefix("ROOT "))
+    op = _custom_call(hlo.replace("ROOT %", "%"), "paged_mla_decode")
+    assert op["pallas"] and op["operands"] == (4 if side else 3)
+    assert len(op["outputs"]) == 1 and not paged_decode.is_kernel(op)
+
+
+@pytest.mark.parametrize("tokens", [MLA_LANES, CHUNK],
+                         ids=["decode_step", "prefill_chunk"])
+def test_grouped_expert_product_at_the_cells_shapes(v5e, tokens):
+    """16 held experts of 7168 x 2048, 8 choices a token over 256: a decode
+    step of 128 lanes (row block 16: about 64 live rows) and a prefill
+    chunk of 512."""
+    from benchmarks.layer_metrics.moe_experts_roofline import KERNELS
+    from tpudist.ops.moe_dispatch import grouped_gated_mlp, row_block
+
+    d, f, held, top_k, experts = 7168, 2048, 16, 8, 256
+    assert row_block(MLA_LANES * top_k, experts) == 16
+    hlo = _compile(
+        lambda x, wg, wu, wd, i, w: grouped_gated_mlp(
+            x, wg, wu, wd, i, w, num_experts=experts),
+        _sds(v5e, (tokens, d)), _sds(v5e, (held, d, f)),
+        _sds(v5e, (held, d, f)), _sds(v5e, (held, f, d)),
+        _sds(v5e, (tokens, top_k), jnp.int32),
+        _sds(v5e, (tokens, top_k), jnp.float32))
+    assert _kernel_calls(hlo) == 2
+    for name in ("moe_experts_gate_up", "moe_experts_down"):
+        op = _custom_call(hlo, name)
+        assert op["pallas"] and len(op["outputs"]) == 1
+    assert sum(bool(KERNELS.match(l.strip())) for l in hlo.splitlines()) == 2
+
+
+def test_flash_forward_with_a_narrower_value(v5e):
+    """Expanded latent attention in a prefill chunk: q/k 192 wide, v 128."""
+    hlo = _compile(
+        lambda q, k, v, off: _flash_forward(
+            q, k, v, True, CHUNK, 1024, False, q_offset=off,
+            scale=0.1352)[0],
+        _sds(v5e, (1, CHUNK, MLA_HEADS, 192)),
+        _sds(v5e, (1, 1024, MLA_HEADS, 192)),
+        _sds(v5e, (1, 1024, MLA_HEADS, 128)), _sds(v5e, (), jnp.int32))
+    assert _kernel_calls(hlo) == 1
+    assert f"bf16[{MLA_HEADS},{CHUNK},128]" in hlo
+
+
 @pytest.mark.parametrize("heads,kv_heads", LAYOUTS)
 def test_per_row_flash_decode(v5e, heads, kv_heads):
     d = EMBED // heads
@@ -247,6 +323,36 @@ def test_prefill_chunk_names_its_kernel(v5e, serve_loop):
                      jnp.zeros((1, CHUNK), jnp.int32), jnp.int32(0)))
     assert _kernel_scopes(loop._prefill_chunk, *args,
                           chunk=CHUNK) == {"flash_fwd"}
+
+
+def test_latent_expert_model_names_its_kernels(v5e):
+    """A model with latent attention and expert layers (DeepSeek-V3's
+    block at a small size): the segment's kernels and the prefill chunk's,
+    by the names a trace shows."""
+    from tpudist.models import MLAConfig, MoEConfig, YarnScaling
+
+    moe = MoEConfig(num_experts=16, top_k=4, experts="gated_silu", d_ff=128,
+                    scoring="sigmoid", n_group=4, topk_group=2,
+                    routed_scale=2.5, correction_bias=True, n_shared=1,
+                    held=(0, 4))
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=2, num_heads=8, embed_dim=512,
+        max_seq_len=2048, compute_dtype=jnp.bfloat16, norm="rmsnorm",
+        positions="rotary", rope_scaling=YarnScaling(mscale_all_dim=1.0),
+        mlp="gated_silu", mlp_dim=1024,
+        mla=MLAConfig(384, 512, 128, 64, 128), moe=moe, first_k_dense=1)
+    loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),
+                     num_slots=SLOTS, steps_per_sync=STEPS,
+                     decode_attention="flash", prefill_chunk=CHUNK,
+                     cache_layout="paged", kv_block_size=BLOCK)
+    assert _kernel_scopes(loop._segment, *_segment_args(v5e, loop)) == {
+        "paged_mla_decode", "moe_experts_gate_up", "moe_experts_down"}
+    args = _on(v5e, (loop.params, loop._blank1,
+                     jnp.zeros((1, CHUNK), jnp.int32), jnp.int32(0)))
+    assert _kernel_scopes(loop._prefill_chunk, *args, chunk=CHUNK) == {
+        "flash_fwd", "moe_experts_gate_up", "moe_experts_down"}
+    assert _kernel_calls(_compile(loop._segment,
+                                  *_segment_args(v5e, loop))) == 4
 
 
 def test_dense_layout_segment_names_its_kernel(v5e):

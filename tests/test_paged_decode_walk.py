@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpudist.ops.flash_decode import paged_flash_decode, paged_gather_kv
+from tpudist.ops.flash_decode import (paged_flash_decode, paged_gather_kv,
+                                      paged_mla_decode)
 
 BLOCK, M_BLOCKS, CAP, HEADS = 128, 20, 8, 4
 P = 1024 // BLOCK                      # pages a tile, as the kernel derives
@@ -124,4 +125,69 @@ def test_walk_matches_gather_reference(lengths, layout, side):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     if SIDES[side] in (None, 0):
         # nothing to attend: an empty lane's output is 0
+        assert not got[lens == 0].any()
+
+
+# -- the same walk over ONE pool whose rows are keys and, in their first
+# columns, values: paged_mla_decode (absorbed latent attention) -------------
+
+MLA_HEADS, MLA_W, MLA_DV, MLA_SCALE = 12, 128, 64, 0.21
+
+
+def _mla_reference(q, pool, table, lens, side, side_len):
+    b = q.shape[0]
+    rows = paged_gather_kv(pool, table)
+    keep = jnp.arange(rows.shape[1])[None, :] < lens[:, None]
+    if side is not None:
+        rows = jnp.concatenate([rows, side], axis=1)
+        keep = jnp.concatenate(
+            [keep, jnp.broadcast_to(
+                jnp.arange(side.shape[1])[None, :] < side_len,
+                (b, side.shape[1]))], axis=1)
+    rows = jnp.where(keep[:, :, None], rows, 0)   # dead rows hold NaN
+    s = jnp.einsum("bhw,bsw->bhs", q, rows, precision="highest") * MLA_SCALE
+    s = jnp.where(keep[:, None, :], s, -jnp.inf)
+    top = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0))
+    out = jnp.einsum("bhs,bsc->bhc", p, rows[..., :MLA_DV],
+                     precision="highest")
+    return out / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30)
+
+
+@functools.cache
+def _mla_setup(side: str):
+    ks = jax.random.split(jax.random.key(27), 3)
+    n_pool = LANES * M_BLOCKS + 1
+    q = jax.random.normal(ks[0], (LANES, MLA_HEADS, MLA_W), jnp.float32)
+    pool = jax.random.normal(ks[1], (n_pool, BLOCK, MLA_W), jnp.float32)
+    pool = pool.at[0].set(jnp.nan)             # the block dead entries name
+    side_len = SIDES[side]
+    buf = (None if side_len is None else
+           jax.random.normal(ks[2], (LANES, CAP, MLA_W), jnp.float32))
+
+    @jax.jit
+    def both(table, lens):
+        got = paged_mla_decode(
+            q, pool, table, lens, d_v=MLA_DV, scale=MLA_SCALE, side=buf,
+            side_len=side_len or 0, interpret=True)
+        return got, _mla_reference(q, pool, table, lens, buf, side_len or 0)
+
+    return both
+
+
+@pytest.mark.parametrize("side", SIDES)
+@pytest.mark.parametrize("lengths", LENGTHS)
+def test_latent_walk_matches_gather_reference(lengths, side):
+    lens = np.asarray(LENGTHS[lengths], np.int32)
+    pages = -(-lens // BLOCK)
+    owned = 1 + np.random.default_rng(7).permutation(
+        LANES * M_BLOCKS).reshape(LANES, M_BLOCKS)
+    table = np.where(np.arange(M_BLOCKS)[None, :] < pages[:, None], owned, 0)
+    got, want = _mla_setup(side)(jnp.asarray(table, jnp.int32),
+                                 jnp.asarray(lens))
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == (LANES, MLA_HEADS, MLA_DV)
+    assert np.isfinite(got).all(), "a dead (poisoned) page reached the result"
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    if SIDES[side] in (None, 0):
         assert not got[lens == 0].any()

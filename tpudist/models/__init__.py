@@ -36,8 +36,10 @@ from tpudist.models.serving import (
     ServeLoop,
 )
 from tpudist.models.transformer import (
+    MLAConfig,
     TransformerConfig,
     TransformerLM,
+    YarnScaling,
     repeat_kv,
     sdpa,
     stack_layer_params,
@@ -56,6 +58,7 @@ __all__ = [
     "adaptive_speculative_generate",
     "beam_search_generate",
     "EmbeddingBagClassifier",
+    "MLAConfig",
     "MLP",
     "MoEConfig",
     "MoEMLP",
@@ -63,6 +66,7 @@ __all__ = [
     "ResNet50",
     "TransformerConfig",
     "TransformerLM",
+    "YarnScaling",
     "greedy_generate",
     "sample_generate",
     "sp_generate",

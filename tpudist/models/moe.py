@@ -52,6 +52,28 @@ class MoEConfig:
     # this, wasting up to E*fused_block_rows rows of expert FLOPs — at
     # small token counts (decode-time MoE) shrink it or use "ragged".
     fused_block_rows: int = 128
+    # -- the expert layer's vocabulary.  "gelu" experts are the up/down
+    # MLPs above, dispatched as ``dispatch`` says.  "gated_silu" experts
+    # (down(silu(gate x) * up x)) take the served path: sorted dispatch
+    # and the gated grouped product of tpudist.ops.moe_dispatch, which
+    # picks its own row block — ``dispatch`` / ``fused_block_rows`` /
+    # ``capacity_factor`` are not read there, and no token is dropped.
+    experts: str = "gelu"
+    d_ff: int | None = None            # expert width (None: the block's)
+    scoring: str = "softmax"           # | "sigmoid"
+    # group-limited routing: experts in n_group groups, a group scored by
+    # the sum of its two best, topk_group groups eligible
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0          # after renormalisation
+    # a per-expert bias added to the scores for the CHOICE only (the
+    # weights stay the raw scores): aux-loss-free balancing
+    correction_bias: bool = False
+    n_shared: int = 0                  # shared experts every token passes
+    # (first, count): the routed experts THIS holder has, of num_experts.
+    # Routing is over all num_experts; compute over the held ones, and a
+    # choice held elsewhere adds nothing here (its holder adds it)
+    held: tuple[int, int] | None = None
 
 
 def _gate_choices(gates: jnp.ndarray, top_k: int):
@@ -66,6 +88,52 @@ def _gate_choices(gates: jnp.ndarray, top_k: int):
     mean_gates = jnp.mean(gates, axis=0)
     aux = jnp.sum(frac_tokens * mean_gates) * e
     return top_vals, top_idx, aux
+
+
+def route(logits: jnp.ndarray, bias: jnp.ndarray | None, moe: MoEConfig):
+    """The router's choices from float32 ``logits [T, E]``: ``(weights
+    [T, k], experts [T, k])``.  Scores are a softmax or a sigmoid; the
+    correction ``bias`` moves the choice and not the weight; with groups,
+    only the ``topk_group`` groups with the best two-expert sums are
+    eligible; the weights are the SCORES at the chosen experts,
+    renormalised (``+ 1e-20``) and scaled."""
+    t, e = logits.shape
+    logits = logits.astype(jnp.float32)
+    if moe.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif moe.scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
+                         f"{moe.scoring!r}")
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if moe.n_group > 1:
+        per_group = choice.reshape(t, moe.n_group, e // moe.n_group)
+        group_score = jnp.sum(jax.lax.top_k(per_group, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, moe.topk_group)
+        group_ok = jnp.sum(jax.nn.one_hot(keep, moe.n_group,
+                                          dtype=jnp.int32), axis=1) > 0
+        choice = jnp.where(jnp.repeat(group_ok, e // moe.n_group, axis=1),
+                           choice, -jnp.inf)
+    _, experts = jax.lax.top_k(choice, moe.top_k)
+    w = jnp.take_along_axis(scores, experts, axis=1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * moe.routed_scale
+    return w, experts
+
+
+class GatedMLP(nn.Module):
+    """``down(silu(gate x) * up x)``, no biases."""
+
+    d_model: int
+    d_ff: int
+    dtype: jnp.dtype | None = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        h = nn.silu(dense(self.d_ff, "gate")(x)) * dense(self.d_ff, "up")(x)
+        return dense(self.d_model, "down")(h)
 
 
 def _top_k_routing(gates: jnp.ndarray, top_k: int, capacity: int):
@@ -176,9 +244,55 @@ class MoEMLP(nn.Module):
     d_ff: int
     moe: MoEConfig
     ep_axis: str | None = None
+    dtype: jnp.dtype | None = None     # compute dtype (None: the input's)
+
+    def _gated(self, x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+        """The served expert layer (``experts="gated_silu"``): the router
+        in float32 over all ``num_experts``, the gated grouped product
+        over the ``held`` experts, the shared expert.  Sows the tokens
+        each held expert was given into ``stats/expert_tokens`` (a no-op
+        unless the caller makes ``stats`` mutable)."""
+        from tpudist.ops.moe_dispatch import grouped_gated_mlp
+
+        moe = self.moe
+        e = moe.num_experts
+        dt = self.dtype or x.dtype
+        if self.ep_axis is not None:
+            raise ValueError(
+                "gated experts run single-shard: tell the layer the "
+                "experts it holds with MoEConfig.held")
+        first, count = moe.held or (0, e)
+        if not (0 <= first and first + count <= e and count > 0):
+            raise ValueError(f"held={moe.held} outside {e} experts")
+        logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                          name="router")(x)
+        bias = (self.param("router_bias", nn.initializers.zeros, (e,))
+                if moe.correction_bias else None)
+        weights, experts = route(logits, bias, moe)
+        shape_up = (count, self.d_model, self.d_ff)
+        init = nn.initializers.lecun_normal(batch_axis=0)
+        w_gate = self.param("w_gate", init, shape_up).astype(dt)
+        w_up = self.param("w_up", init, shape_up).astype(dt)
+        w_down = self.param(
+            "w_down", init, (count, self.d_ff, self.d_model)).astype(dt)
+        out, counts = grouped_gated_mlp(
+            x.astype(dt), w_gate, w_up, w_down, experts - first, weights,
+            num_experts=e)
+        self.sow("stats", "expert_tokens", counts,
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((count,), jnp.int32))
+        if moe.n_shared:
+            out = out + GatedMLP(self.d_model, moe.n_shared * self.d_ff,
+                                 dt, name="shared")(x)
+        return out, jnp.zeros((), jnp.float32)
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+        if self.moe.experts == "gated_silu":
+            return self._gated(x)
+        if self.moe.experts != "gelu":
+            raise ValueError(f"experts must be 'gelu' or 'gated_silu', got "
+                             f"{self.moe.experts!r}")
         t = x.shape[0]
         e = self.moe.num_experts
         capacity = max(

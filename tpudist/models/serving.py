@@ -220,6 +220,17 @@ def _shift_index_leaves(cache: Any, delta, names) -> Any:
     return walk(cache)
 
 
+def _kv_leaves(node: dict, prefix: str) -> list[str]:
+    """What a layer's attention keeps a token: the ``<suffix>`` of its
+    cache leaves ``<prefix>_<suffix>`` (``prefix`` one of ``cached``, the
+    dense buffers, ``paged``, the block pools, ``side``, the segment's
+    staging buffers).  ``["key", "value"]`` for MHA/GQA/MQA, ``["latent"]``
+    for latent attention: every place that moves cache rows iterates over
+    these and is indifferent to their number and width."""
+    return sorted(k[len(prefix) + 1:] for k in node
+                  if k.startswith(prefix + "_") and k != "side_index")
+
+
 def _bound_paged_walk(cache: Any, active) -> Any:
     """Entry of a segment, paged layout: a lane that is not active holds
     no request (released, finalised, or still prefilling through the
@@ -233,7 +244,7 @@ def _bound_paged_walk(cache: Any, active) -> Any:
         if not isinstance(node, dict):
             return node
         out = {k: walk(v) for k, v in node.items()}
-        if "paged_key" in out:
+        if "page_table" in out:
             out["cache_index"] = jnp.where(active, out["cache_index"], 0)
         return out
     return walk(cache)
@@ -606,6 +617,27 @@ class ServeLoop:
         if self.side:
             self.cache = self._with_side_buffers(self.cache)
         self._blank1 = _blank_cache(self._prefill_model, 1)  # prefill cache
+        if self.pool is not None and _kv_leaves(
+                self._paged_nodes(self.cache)[0], "paged") != ["key",
+                                                               "value"]:
+            # the loop itself moves whatever leaves a layer declares; the
+            # payloads that LEAVE it (handoff, migration, the host tier's
+            # spill) are still written as a K/V pair a layer
+            if role != "both" or preempt == "migrate":
+                raise ValueError(
+                    "KV handoff and migration payloads carry a key/value "
+                    "pair a layer; a cache of other leaves serves with "
+                    "role='both' and preempt='degrade'")
+            if self._tier is not None:
+                self._tier = None
+                self._prefix_cache.spill_hook = None
+        # expert layers (cfg.moe): the segment sums, step by step, the
+        # tokens each held expert was given and returns the sums as extra
+        # rows of the emit buffer it already returns
+        self._expert_blocks = [i for i in range(cfg.num_layers)
+                               if cfg.is_expert_layer(i)]
+        self._held = ((cfg.moe.held or (0, cfg.moe.num_experts))[1]
+                      if self._expert_blocks else 0)
         if decode_mode == "speculative":
             self.draft_cfg = draft_cfg
             self.draft_params = draft_params
@@ -695,6 +727,18 @@ class ServeLoop:
         # table a walk touches
         self._obs_pages_walked = obs.counter("serve/decode_pages_walked",
                                              unit="pages")
+        # expert layers: tokens the held experts were given over a drained
+        # segment's steps (all lanes, as lane_steps counts them), the
+        # busiest (layer, expert)'s part of that, and the (step, layer,
+        # expert) slots they were spread over.  expert_tokens over
+        # lane_steps x top_k x expert layers is the share of the routed
+        # work that lands here: held / num_experts under an even router
+        self._obs_expert_tokens = obs.counter("serve/expert_tokens",
+                                              unit="tokens")
+        self._obs_expert_tokens_max = obs.counter(
+            "serve/expert_tokens_max", unit="tokens")
+        self._obs_expert_slots = obs.counter("serve/expert_slots",
+                                             unit="slots")
         self._obs_segments = obs.counter("serve/segments", unit="segments")
         self._obs_queue = obs.gauge("serve/queue_depth", unit="reqs")
         self._obs_degraded = obs.gauge("serve/degraded", unit="bool")
@@ -835,24 +879,16 @@ class ServeLoop:
             if not isinstance(node, dict):
                 return node
             out = {k: walk(v) for k, v in node.items()}
-            if "cached_key" in out:
-                # the cache (and therefore the side buffers) is PACKED
-                # [B, S, Hkv*D] — see CausalSelfAttention._cached_attend
-                b, _, flat = out["cached_key"].shape
-                out["side_key"] = jnp.zeros(
-                    (b, self.side, flat), out["cached_key"].dtype)
-                out["side_value"] = jnp.zeros(
-                    (b, self.side, flat), out["cached_value"].dtype)
-                out["side_index"] = jnp.zeros((), jnp.int32)
-            elif "paged_key" in out:
-                # paged pool is [num_blocks, block, Hkv*D]; side buffers
-                # are per-SLOT, so their batch is self.B, not the pool's
-                flat = out["paged_key"].shape[2]
-                out["side_key"] = jnp.zeros(
-                    (self.B, self.side, flat), out["paged_key"].dtype)
-                out["side_value"] = jnp.zeros(
-                    (self.B, self.side, flat), out["paged_value"].dtype)
-                out["side_index"] = jnp.zeros((), jnp.int32)
+            # the cache (and therefore the side buffers) is PACKED
+            # [B, S, F] — see CausalSelfAttention._cached_attend; a paged
+            # pool is [num_blocks, block, F] and its side buffers are
+            # per-SLOT, so their batch is self.B, not the pool's
+            for prefix in ("cached", "paged"):
+                for name in _kv_leaves(out, prefix):
+                    main = out[f"{prefix}_{name}"]
+                    out[f"side_{name}"] = jnp.zeros(
+                        (self.B, self.side, main.shape[2]), main.dtype)
+                    out["side_index"] = jnp.zeros((), jnp.int32)
             return out
         return walk(cache)
 
@@ -899,8 +935,11 @@ class ServeLoop:
         def cond(carry):
             return (carry[0] < n_steps) & jnp.any(carry[3])
 
+        experts = self._expert_blocks
+
         def step(carry):
-            i, cache, tok, active, remaining, lived, corrupt, key, E = carry
+            (i, cache, tok, active, remaining, lived, corrupt, key, E,
+             X) = carry
             main_idx, side_idx = _index_leaves(cache)
             pos = main_idx if side_idx is None else main_idx + side_idx
             pos = jnp.minimum(pos, S - 1)
@@ -909,7 +948,12 @@ class ServeLoop:
             lived = lived + active.astype(jnp.int32)
             logits, mut = self.model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
-                positions=pos[:, None], mutable=["cache"])
+                positions=pos[:, None],
+                mutable=["cache", "stats"] if experts else ["cache"])
+            if experts:
+                X = X + jnp.stack([
+                    mut["stats"][f"block{b}"]["moe"]["expert_tokens"]
+                    for b in experts])
             last = logits[:, -1]
             last = jnp.where(poison, jnp.full_like(last, jnp.nan), last)
             # integrity guard: freeze (not emit) lanes whose logits are
@@ -930,17 +974,18 @@ class ServeLoop:
             active = active & ~hit_stop & (remaining > 0)
             tok = jnp.where(active, nxt, pad)
             return (i + 1, mut["cache"], tok, active, remaining, lived,
-                    corrupt, key, E)
+                    corrupt, key, E, X)
 
         lived0 = jnp.zeros((self.B,), jnp.int32)
         corrupt0 = jnp.zeros((self.B,), bool)
         E0 = jnp.full((self.B, self.steps), pad, jnp.int32)
+        X0 = jnp.zeros((len(experts), self._held), jnp.int32)
         cache = _bound_paged_walk(cache, active)
         (_, cache, tok, active, remaining, lived, corrupt, key,
-         E) = lax.while_loop(
+         E, X) = lax.while_loop(
             cond, step,
             (jnp.int32(0), cache, tok, active, remaining, lived0,
-             corrupt0, key, E0))
+             corrupt0, key, E0, X0))
         if self.side:
             # side -> main merge INSIDE the segment executable: one
             # dispatch per wave instead of two, and XLA can overlap
@@ -949,6 +994,13 @@ class ServeLoop:
         # column 0 carries the admission-deferred first tokens so ONE
         # host fetch resolves them together with the segment's emits
         emits = jnp.concatenate([first[:, None], E], axis=1)
+        if experts:
+            # [expert layers, held] counts ride home as whole extra rows
+            # below the lanes': the one fetch brings them
+            width = emits.shape[1]
+            flat = X.reshape(-1)
+            flat = jnp.pad(flat, (0, -flat.shape[0] % width))
+            emits = jnp.concatenate([emits, flat.reshape(-1, width)], axis=0)
         return cache, tok, active, remaining, key, emits, corrupt
 
     def _prefill_impl(self, params, prompt_padded, true_len, key,
@@ -981,7 +1033,7 @@ class ServeLoop:
                 if big.ndim == 1:      # cache_index vector <- true length
                     return big.at[slot].set(true_len)
                 return big.at[slot].set(small[0])
-            if "paged_key" in big:
+            if "page_table" in big:
                 return self._insert_paged_node(
                     big, small, slot, true_len, pages, write_block)
             return {k: (walk(v, small[k]) if k in small else v)
@@ -1001,13 +1053,12 @@ class ServeLoop:
         out = dict(big)
         bs = self.kv_block_size
         m = pages.shape[0]
-        n_pool = big["paged_key"].shape[0]
         covered = ((jnp.arange(m) * bs < true_len)
                    & (jnp.arange(m) >= write_block))
-        tgt = jnp.where(covered, pages, n_pool)
-        for name, src in (("paged_key", "cached_key"),
-                          ("paged_value", "cached_value")):
-            row = small[src][0]                       # dense [S, F]
+        for leaf in _kv_leaves(big, "paged"):
+            name = f"paged_{leaf}"
+            tgt = jnp.where(covered, pages, big[name].shape[0])
+            row = small[f"cached_{leaf}"][0]          # dense [S, F]
             pad = m * bs - row.shape[0]
             blocks = jnp.pad(row, ((0, pad), (0, 0))).reshape(m, bs, -1)
             out[name] = big[name].at[tgt].set(
@@ -1054,10 +1105,10 @@ class ServeLoop:
         def walk(big, small):
             if not isinstance(small, dict):
                 return small
-            if "cached_key" in small and "paged_key" in big:
+            if "page_table" in big:
                 out = dict(small)
-                for pname, dname in (("paged_key", "cached_key"),
-                                     ("paged_value", "cached_value")):
+                for leaf in _kv_leaves(big, "paged"):
+                    pname, dname = f"paged_{leaf}", f"cached_{leaf}"
                     rows = big[pname][pages]          # [M, bs, F]
                     flat = rows.reshape(-1, rows.shape[-1])
                     S = small[dname].shape[1]
@@ -1355,17 +1406,18 @@ class ServeLoop:
             if not isinstance(node, dict):
                 return node
             out = {k: walk(v) for k, v in node.items()}
-            if "paged_key" in out:
+            if "page_table" in out:
                 return self._merge_paged_node(out, lived)
-            if "side_key" in out:
+            if "side_index" in out:
                 idx = out["cache_index"]
-                S = out["cached_key"].shape[1]
-                cap = out["side_key"].shape[1]
+                leaves = _kv_leaves(out, "side")
+                S = out[f"cached_{leaves[0]}"].shape[1]
+                cap = out[f"side_{leaves[0]}"].shape[1]
                 p = jnp.arange(cap)
-                for name, side_name in (("cached_key", "side_key"),
-                                        ("cached_value", "side_value")):
+                for leaf in leaves:
+                    name = f"cached_{leaf}"
                     main = out[name]                 # packed [B, S, F]
-                    side = out[side_name]            # packed [B, cap, F]
+                    side = out[f"side_{leaf}"]       # packed [B, cap, F]
                     for r in range(B):
                         start = jnp.minimum(idx[r], S - cap)
                         sh = idx[r] - start          # 0 unless near S
@@ -1401,8 +1453,9 @@ class ServeLoop:
         tbl = out["page_table"]                    # [B, M]
         bs = self.kv_block_size
         S = self.cfg.max_seq_len
-        n_pool = out["paged_key"].shape[0]
-        cap = out["side_key"].shape[1]
+        leaves = _kv_leaves(out, "paged")
+        n_pool = out[f"paged_{leaves[0]}"].shape[0]
+        cap = out[f"side_{leaves[0]}"].shape[1]
         m = tbl.shape[1]
         t = jnp.arange(cap)[None, :]               # [1, cap]
         pos = idx[:, None] + t                     # [B, cap] logical
@@ -1411,9 +1464,9 @@ class ServeLoop:
         page = jnp.take_along_axis(tbl, blk, axis=1)
         page = jnp.where(live, page, n_pool).reshape(-1)
         off = (pos % bs).reshape(-1)
-        for name, side_name in (("paged_key", "side_key"),
-                                ("paged_value", "side_value")):
-            vals = out[side_name].astype(out[name].dtype)
+        for leaf in leaves:
+            name = f"paged_{leaf}"
+            vals = out[f"side_{leaf}"].astype(out[name].dtype)
             out[name] = out[name].at[page, off].set(
                 vals.reshape(-1, vals.shape[2]), mode="drop")
         out["cache_index"] = jnp.minimum(idx + lived, S)
@@ -1866,7 +1919,7 @@ class ServeLoop:
         def walk(node):
             if not isinstance(node, dict):
                 return
-            if "paged_key" in node:
+            if "page_table" in node:
                 out.append(node)
                 return
             for v in node.values():
@@ -2840,11 +2893,21 @@ class ServeLoop:
                 self._obs_decode_steps.inc(steps_run)
                 self._obs_lane_steps.inc(self.B * steps_run)
                 self._obs_pages_walked.inc(pages * steps_run)
+                routed = {}
+                if self._expert_blocks:
+                    n_cells = len(self._expert_blocks) * self._held
+                    counts = emits[self.B:].reshape(-1)[:n_cells]
+                    routed = {"expert_tokens": int(counts.sum()),
+                              "expert_tokens_max": int(counts.max())}
+                    self._obs_expert_tokens.inc(routed["expert_tokens"])
+                    self._obs_expert_tokens_max.inc(
+                        routed["expert_tokens_max"])
+                    self._obs_expert_slots.inc(steps_run * n_cells)
                 obs.tracer.complete(
                     "serve/segment_drain", t_fetched, time.perf_counter(),
                     seq=s_idx, steps=n_disp, steps_run=steps_run,
                     lanes=lanes, tokens=tokens, first_tokens=first_tokens,
-                    pages=pages)
+                    pages=pages, **routed)
             # zombie refund: every segment dispatched before the kill
             # (index < free_at) has drained once s_idx reaches
             # free_at - 1 — no stale merge can touch the blocks now

@@ -25,6 +25,7 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -39,13 +40,14 @@ AttentionFn = Callable[..., jnp.ndarray]
 
 def _masked_attend(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-    mask: Optional[jnp.ndarray],
+    mask: Optional[jnp.ndarray], scale: float | None = None,
 ) -> jnp.ndarray:
     """The one copy of the attention numerics every path shares: scaled
     f32-accumulated QKᵀ, finfo-min mask fill, f32 softmax, cast back.
     ``mask`` is boolean, broadcastable to [B, H, Sq, Sk] (True = attend)."""
     dtype = q.dtype
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     if mask is not None:
@@ -170,6 +172,47 @@ def _head_sharded_packed(decode_shard, q, k_all, v_all, n, window, h_kv):
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's rotary frequency blend (``rope_scaling`` of a published
+    config): frequencies above the ``beta_fast`` correction dim stay,
+    those below ``beta_slow``'s are divided by ``factor``, a linear ramp
+    between.  ``mscale_all_dim`` also enters the softmax scale."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention: queries through a ``q_lora_rank``
+    latent, keys and values through ONE ``kv_lora_rank`` latent plus one
+    rotary key of ``qk_rope_head_dim`` shared by all heads.  The cache
+    holds ``[latent | rotary key]`` a token, padded to a lane multiple."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """A cached row: latent, rotary key, zeros up to a multiple of 128
+        lanes.  On the TPU a 576-wide bf16 row occupies 640 lanes of tiled
+        memory anyway; stated so, the row's copies and the contraction
+        over it are lane-aligned, and the zeros add nothing to a score."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256
     num_layers: int = 2
@@ -204,6 +247,33 @@ class TransformerConfig:
     # plain decode latency; chunked verify forwards (speculative)
     # amortize the cost and keep the compile-size win.
     scan_layers: bool = False
+    # -- the block's vocabulary.  The defaults are the GPT-2 / GPTBigCode
+    # block this class began as (LayerNorm, a learned position table, a
+    # tanh-GELU MLP of mlp_ratio x embed_dim); every other value is read
+    # where the block is built, and nothing is keyed on a model's name.
+    norm: str = "layernorm"            # | "rmsnorm"
+    norm_eps: float = 1e-6
+    positions: str = "learned"         # | "rotary" (no position table)
+    rope_theta: float = 10000.0
+    rope_scaling: YarnScaling | None = None
+    # rotary width of a plain head (None = all of head_dim); MLA rotates
+    # its own qk_rope_head_dim slice
+    rope_dim: int | None = None
+    mlp: str = "gelu"                  # | "gated_silu": down(silu(gate) * up)
+    mlp_dim: int | None = None         # None = mlp_ratio * embed_dim
+    # latent attention instead of MHA/GQA (num_kv_heads is then unused)
+    mla: MLAConfig | None = None
+    # expert layers: a tpudist.models.moe.MoEConfig; the first
+    # ``first_k_dense`` layers keep the dense MLP
+    moe: Any = None
+    first_k_dense: int = 0
+
+    @property
+    def ffn_dim(self) -> int:
+        return self.mlp_dim or self.mlp_ratio * self.embed_dim
+
+    def is_expert_layer(self, i: int) -> bool:
+        return self.moe is not None and i >= self.first_k_dense
 
     @property
     def head_dim(self) -> int:
@@ -215,6 +285,120 @@ class TransformerConfig:
         kv = self.num_kv_heads or self.num_heads
         assert self.num_heads % kv == 0, (self.num_heads, kv)
         return kv
+
+
+def make_norm(cfg: TransformerConfig, name: str):
+    """The block's normalisation layer, by ``cfg.norm``."""
+    if cfg.norm == "layernorm":
+        return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                            name=name)
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                          name=name)
+    raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
+                     f"{cfg.norm!r}")
+
+
+def rope_inv_freq(dim: int, theta: float,
+                  scaling: YarnScaling | None) -> jnp.ndarray:
+    """``[dim // 2]`` rotary frequencies: ``theta^(-2i/dim)``, under YaRN
+    blended with the same divided by ``factor`` (a linear ramp between the
+    correction dims ``beta_fast`` / ``beta_slow`` give for the original
+    positions)."""
+    import math
+
+    half = dim // 2
+    extra = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    if scaling is None:
+        return extra
+
+    def correction_dim(rotations):
+        return (dim * math.log(scaling.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
+
+
+def _yarn_mscale(factor: float, m: float) -> float:
+    import math
+
+    return 1.0 if factor <= 1 or not m else 0.1 * m * math.log(factor) + 1.0
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
+               cfg: TransformerConfig) -> jnp.ndarray:
+    """Rotate the last axis of ``x [B, S, ..., dim]`` at ``positions
+    [B or 1, S]``.  HALF-SPLIT pairing: feature ``i`` pairs with
+    ``i + dim/2`` (what public implementations permute published
+    interleaved weights to).  Angles and the rotation in float32."""
+    dim = x.shape[-1]
+    sc = cfg.rope_scaling
+    ang = (positions.astype(jnp.float32)[..., None]
+           * rope_inv_freq(dim, cfg.rope_theta, sc))      # [B|1, S, dim/2]
+    mult = 1.0 if sc is None else (_yarn_mscale(sc.factor, sc.mscale)
+                                   / _yarn_mscale(sc.factor,
+                                                  sc.mscale_all_dim))
+    shape = ang.shape[:2] + (1,) * (x.ndim - 3) + (dim // 2,)
+    cos = (jnp.cos(ang) * mult).reshape(shape)
+    sin = (jnp.sin(ang) * mult).reshape(shape)
+    a = x[..., : dim // 2].astype(jnp.float32)
+    b = x[..., dim // 2:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
+def _flash_prefill(q, k_all, v_all, idx, *, window=None, scale=None,
+                   decode_shard=None):
+    """Chunk prefill through the flash forward kernel: queries at global
+    positions ``[idx, idx + s)`` against ``k_all`` / ``v_all [B, S, Hkv,
+    D]`` at ``q_offset=idx`` (its causal mask also silences the garbage in
+    not-yet-written rows; dead tiles are pruned).
+
+    Pads the query-row count so _auto_block lands on a Mosaic-lowerable
+    block: the LSE output's [1, 1, block_q] block needs block_q % 128 == 0
+    or block_q == s_pad, and q/out need the 8-row sublane tile.  Short
+    chunks round up to a power of two (block = whole chunk); long ones to
+    a multiple of 1024 so block_q is the measured-optimal 1024 (a prompt
+    like 7928 = 8·991 would otherwise get block_q = 8, which real-TPU
+    lowering rejects).  Padded rows are causally garbage but independent
+    of the real rows; sliced off below."""
+    from tpudist.ops.flash_attention import _auto_block, _flash_forward
+
+    s, rows = q.shape[1], k_all.shape[1]
+    if s <= 1024:
+        s_pad = max(8, 1 << (s - 1).bit_length())
+    else:
+        s_pad = -(-s // 1024) * 1024
+    q_in = q if s_pad == s else jnp.pad(
+        q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
+    block_k = _auto_block(rows)
+    if block_k < 8:  # the K side has the same sublane floor
+        raise ValueError(
+            f"decode_attention='flash' needs a power-of-two factor "
+            f">= 8 in max_seq_len (got {rows}); round "
+            f"max_seq_len up to a multiple of 8")
+    interp = jax.default_backend() == "cpu"
+    bq = _auto_block(s_pad)
+    if decode_shard is not None:
+        def local(qs, ks, vs, off):
+            out, _ = _flash_forward(
+                qs, ks, vs, True, bq, block_k, interp,
+                q_offset=off, window=window, scale=scale)
+            return out
+
+        out = _head_sharded(decode_shard, local, q_in, k_all, v_all, idx)
+        return out[:, :s]
+    out, _ = _flash_forward(
+        q_in, k_all, v_all, True, bq, block_k, interp,
+        q_offset=idx, window=window, scale=scale)
+    return out[:, :s]
 
 
 class CausalSelfAttention(nn.Module):
@@ -252,7 +436,8 @@ class CausalSelfAttention(nn.Module):
     kv_block_size: int = 0
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, *, causal: bool = True) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, *, causal: bool = True,
+                 positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         cfg = self.cfg
         b, s, _ = x.shape
         if cfg.kv_heads == cfg.num_heads:
@@ -268,6 +453,15 @@ class CausalSelfAttention(nn.Module):
                           dtype=cfg.compute_dtype, name="kv")(x)
             kv = kv.reshape(b, s, 2, cfg.kv_heads, cfg.head_dim)
             k, v = kv[:, :, 0], kv[:, :, 1]
+        if cfg.positions == "rotary":
+            # keys are cached AFTER the rotation, at their own positions
+            if positions is None:
+                positions = jnp.arange(s)[None, :]
+            rd = cfg.rope_dim or cfg.head_dim
+            q = jnp.concatenate(
+                [apply_rope(q[..., :rd], positions, cfg), q[..., rd:]], -1)
+            k = jnp.concatenate(
+                [apply_rope(k[..., :rd], positions, cfg), k[..., rd:]], -1)
         # cfg is the single source of truth for the sliding window: a
         # factory built with its OWN window (flash_attention_fn(window=W))
         # that disagrees is rejected — in BOTH branches, since the decode
@@ -641,47 +835,9 @@ class CausalSelfAttention(nn.Module):
         # (measured HLO: no cache all-gather), while a Pallas call cannot
         # be partitioned at all
         if self.decode_attention == "flash" and not seq_sharded:
-            from tpudist.ops.flash_attention import (
-                _auto_block, _flash_forward,
-            )
-
-            # Pad the query-row count so _auto_block lands on a Mosaic-
-            # lowerable block: the LSE output's [1, 1, block_q] block
-            # needs block_q % 128 == 0 or block_q == s_pad, and q/out
-            # need the 8-row sublane tile.  Short chunks round up to a
-            # power of two (block = whole chunk); long ones to a multiple
-            # of 1024 so block_q is the measured-optimal 1024 (a prompt
-            # like 7928 = 8·991 would otherwise get block_q = 8, which
-            # real-TPU lowering rejects).  Padded rows are causally
-            # garbage but independent of the real rows; sliced off below.
-            if s <= 1024:
-                s_pad = max(8, 1 << (s - 1).bit_length())
-            else:
-                s_pad = -(-s // 1024) * 1024
-            q_in = q if s_pad == s else jnp.pad(
-                q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
-            block_k = _auto_block(cfg.max_seq_len)
-            if block_k < 8:  # the K side has the same sublane floor
-                raise ValueError(
-                    f"decode_attention='flash' needs a power-of-two factor "
-                    f">= 8 in max_seq_len (got {cfg.max_seq_len}); round "
-                    f"max_seq_len up to a multiple of 8")
-            interp = jax.default_backend() == "cpu"
-            bq = _auto_block(s_pad)
-            if self.decode_shard is not None:
-                def local(qs, ks, vs, off):
-                    out, _ = _flash_forward(
-                        qs, ks, vs, True, bq, block_k, interp,
-                        q_offset=off, window=cfg.attention_window)
-                    return out
-
-                out = _head_sharded(self.decode_shard, local,
-                                    q_in, k_all, v_all, idx)
-                return out[:, :s]
-            out, _ = _flash_forward(
-                q_in, k_all, v_all, True, bq, block_k, interp,
-                q_offset=idx, window=cfg.attention_window)
-            return out[:, :s]
+            return _flash_prefill(q, k_all, v_all, idx,
+                                  window=cfg.attention_window,
+                                  decode_shard=self.decode_shard)
         q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
         k_pos = jnp.arange(cfg.max_seq_len)[None, :]          # [1, S]
         mask = k_pos <= q_pos
@@ -691,17 +847,266 @@ class CausalSelfAttention(nn.Module):
         return _masked_attend(q, k_all, v_all, mask[None, None])
 
 
+# rows of the latent cache the expanded prefill rebuilds keys and values
+# for: the first power-of-two multiple of this that covers the chunk's end
+_MLA_PREFILL_ROWS = 1024
+
+
+class _Kernel(nn.Module):
+    """A bare ``kernel`` parameter under a ``nn.Dense``-like name, for a
+    matrix that is applied in more than one form."""
+
+    shape: tuple
+    dtype: Any
+
+    @nn.compact
+    def __call__(self) -> jnp.ndarray:
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape).astype(self.dtype)
+
+
+class LatentSelfAttention(nn.Module):
+    """Multi-head latent attention (``cfg.mla``), two paths from one set of
+    weights.
+
+    * EXPANDED (training, and prefill through the batch-1 cache): keys and
+      values are rebuilt per head from the latent rows, ``k = [k_nope |
+      k_rope]`` with the one rotary key shared by all heads, and attention
+      is plain MHA with q/k ``qk_head_dim`` wide and v ``v_head_dim`` wide.
+    * ABSORBED (the serve loop's decode step over the paged cache): the
+      same mathematics reordered, ``q_abs = q_nope W_kvb^K`` per head, so
+      the cached row itself is the key and its latent part the value —
+      multi-query attention of all heads against one row a token,
+      ``o = (sum p c_kv) W_kvb^V``.  ``W_kvb`` is never applied per cached
+      position.
+
+    The cache holds ``[c_kv (after its norm) | k_rope (after the rotation)
+    | zeros]``, ``cfg.mla.cache_width`` numbers a token, under the leaves
+    ``cached_latent`` (dense, scalar index: prefill), ``paged_latent`` +
+    ``page_table`` (the serve loop's pool) and ``side_latent`` (its
+    segment-local staging buffer)."""
+
+    cfg: TransformerConfig
+    decode: bool = False
+    decode_attention: str = "dense"
+    serve_side_slots: int = 0
+    cache_layout: str = "dense"
+    kv_num_blocks: int = 0
+    kv_block_size: int = 0
+
+    @property
+    def softmax_scale(self) -> float:
+        cfg = self.cfg
+        scale = cfg.mla.qk_head_dim ** -0.5
+        if cfg.rope_scaling is not None:
+            m = _yarn_mscale(cfg.rope_scaling.factor,
+                             cfg.rope_scaling.mscale_all_dim)
+            scale *= m * m
+        return scale
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, *, causal: bool = True,
+                 positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        cfg, m = self.cfg, self.cfg.mla
+        b, s, _ = x.shape
+        h, nope, dv = cfg.num_heads, m.qk_nope_head_dim, m.v_head_dim
+        dt = cfg.compute_dtype
+        if cfg.attention_window is not None:
+            raise ValueError("latent attention has no sliding window")
+        if positions is None:
+            positions = jnp.arange(s)[None, :]
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
+        norm = functools.partial(nn.RMSNorm, epsilon=cfg.norm_eps, dtype=dt)
+        c_q = norm(name="q_norm")(dense(m.q_lora_rank, name="q_a")(x))
+        q = dense(h * m.qk_head_dim, name="q_b")(c_q).reshape(
+            b, s, h, m.qk_head_dim)
+        q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:],
+                                                   positions, cfg)
+        kv_a = dense(m.kv_lora_rank + m.qk_rope_head_dim, name="kv_a")(x)
+        c_kv = norm(name="kv_norm")(kv_a[..., : m.kv_lora_rank])
+        k_rope = apply_rope(kv_a[..., m.kv_lora_rank:], positions, cfg)
+        pad = m.cache_width - m.kv_lora_rank - m.qk_rope_head_dim
+        row = jnp.concatenate(
+            [c_kv, k_rope, jnp.zeros((b, s, pad), c_kv.dtype)], -1)
+        # [kv_lora, H, nope | v]: applied to latent rows (expanded) or to
+        # the queries and the attended latent (absorbed)
+        w_kvb = _Kernel((m.kv_lora_rank, h * (nope + dv)), dt,
+                        name="kv_b")().reshape(m.kv_lora_rank, h, nope + dv)
+
+        if not self.decode:
+            out = self._expanded(q_nope, q_rope, row, w_kvb, 0, causal)
+        elif self.cache_layout == "paged":
+            out = self._paged_absorbed(q_nope, q_rope, row, w_kvb)
+        elif self.cache_layout == "dense":
+            out = self._cached_expanded(q_nope, q_rope, row, w_kvb)
+        else:
+            raise ValueError(
+                f"cache_layout must be 'dense' or 'paged', got "
+                f"{self.cache_layout!r}")
+        return dense(cfg.embed_dim, name="proj")(out.reshape(b, s, h * dv))
+
+    def _expanded(self, q_nope, q_rope, rows, w_kvb, idx, causal=True):
+        """MHA of queries at positions ``idx + [0, s)`` over the latent
+        ``rows [B, R, W]``, keys and values rebuilt from them."""
+        cfg, m = self.cfg, self.cfg.mla
+        b, r, _ = rows.shape
+        h, nope = cfg.num_heads, m.qk_nope_head_dim
+        kv = jnp.einsum("brc,chd->brhd", rows[..., : m.kv_lora_rank], w_kvb)
+        k_rope = rows[..., m.kv_lora_rank:
+                      m.kv_lora_rank + m.qk_rope_head_dim]
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope[:, :, None, :],
+                              (b, r, h, m.qk_rope_head_dim))], -1)
+        v = kv[..., nope:]
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        if self.decode and self.decode_attention == "flash":
+            return _flash_prefill(q, k, v, idx, scale=self.softmax_scale)
+        mask = None
+        if causal:
+            q_pos = idx + jnp.arange(q.shape[1])[:, None]
+            mask = (jnp.arange(r)[None, :] <= q_pos)[None, None]
+        return _masked_attend(q, k, v, mask, scale=self.softmax_scale)
+
+    def _cached_expanded(self, q_nope, q_rope, row, w_kvb):
+        """Prefill (and scalar-index rollouts) through a dense latent
+        cache: the chunk's rows land at the write cursor and the queries
+        attend, expanded, over everything cached so far — rebuilt from the
+        first rows of the cache that cover the chunk's end, in steps of
+        ``_MLA_PREFILL_ROWS`` doubled, and not from all ``max_seq_len``."""
+        cfg = self.cfg
+        b, s = row.shape[:2]
+        S = cfg.max_seq_len
+        cached = self.variable(
+            "cache", "cached_latent", jnp.zeros,
+            (b, S, cfg.mla.cache_width), cfg.compute_dtype)
+        idx_var = self.variable(
+            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
+        idx = idx_var.value
+        if idx.ndim != 0:
+            raise ValueError(
+                "latent attention serves per-row positions through "
+                "cache_layout='paged' only")
+        rows = jax.lax.dynamic_update_slice(
+            cached.value, row.astype(cached.value.dtype), (0, idx, 0))
+        cached.value = rows
+        idx_var.value = idx + s
+        buckets = []
+        r = min(S, _MLA_PREFILL_ROWS)
+        while r < S:
+            if r >= s:
+                buckets.append(r)
+            r *= 2
+        buckets.append(S)
+        branches = [
+            lambda qn, qr, rw, w, i, n=n: self._expanded(
+                qn, qr, rw[:, :n], w, i)
+            for n in buckets]
+        if len(branches) == 1:
+            return branches[0](q_nope, q_rope, rows, w_kvb, idx)
+        which = sum((idx + s > n).astype(jnp.int32) for n in buckets[:-1])
+        return jax.lax.switch(which, branches, q_nope, q_rope, rows, w_kvb,
+                              idx)
+
+    def _paged_absorbed(self, q_nope, q_rope, row, w_kvb):
+        """One decode step against the PAGED latent cache (see
+        ``CausalSelfAttention._paged_attend`` for the pool, the page table
+        and the side buffer: the same arrangement with one leaf)."""
+        cfg, m = self.cfg, self.cfg.mla
+        b, s = row.shape[:2]
+        w = m.cache_width
+        nope, lat = m.qk_nope_head_dim, m.kv_lora_rank
+        bs_, nb = self.kv_block_size, self.kv_num_blocks
+        if bs_ < 1 or nb < 1:
+            raise ValueError(
+                "cache_layout='paged' needs kv_block_size and "
+                f"kv_num_blocks > 0 (got {bs_}, {nb})")
+        m_blocks = -(-cfg.max_seq_len // bs_)
+        pool = self.variable(
+            "cache", "paged_latent", jnp.zeros, (nb, bs_, w),
+            cfg.compute_dtype)
+        table = self.variable(
+            "cache", "page_table", jnp.zeros, (b, m_blocks), jnp.int32)
+        idx_var = self.variable(
+            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
+        idx = idx_var.value
+        if idx.ndim == 0:
+            if self.is_initializing():
+                return jnp.zeros(q_nope.shape[:3] + (m.v_head_dim,),
+                                 q_nope.dtype)
+            raise ValueError(
+                "the paged cache decodes through per-row vector "
+                "cache_index only (ServeLoop with cache_layout='paged')")
+        if s != 1:
+            raise NotImplementedError(
+                "the paged latent cache decodes one token a step")
+        if self.serve_side_slots <= 0:
+            raise ValueError(
+                "cache_layout='paged' requires serve_side_slots > 0")
+        cap = self.serve_side_slots
+        side = self.variable(
+            "cache", "side_latent", jnp.zeros, (b, cap, w),
+            cfg.compute_dtype)
+        side_idx = self.variable(
+            "cache", "side_index", lambda: jnp.zeros((), jnp.int32))
+        s_base = side_idx.value
+        side.value = jax.lax.dynamic_update_slice(
+            side.value, row.astype(side.value.dtype),
+            (0, jnp.minimum(s_base, cap - 1), 0))
+        side_idx.value = s_base + 1
+
+        # absorb W_kvb^K into the queries: [B, H, nope] x [lat, H, nope]
+        q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_kvb[..., :nope])
+        q_row = jnp.concatenate(
+            [q_abs, q_rope[:, 0],
+             jnp.zeros((b, cfg.num_heads, w - lat - m.qk_rope_head_dim),
+                       q_abs.dtype)], -1)                   # [B, H, W]
+        if self.decode_attention == "flash":
+            from tpudist.ops.flash_decode import paged_mla_decode
+
+            o_lat = paged_mla_decode(
+                q_row, pool.value, table.value, idx, d_v=lat,
+                scale=self.softmax_scale, side=side.value,
+                side_len=side_idx.value)
+        else:
+            # dense fallback (CPU / tests): gather the lane's pages and
+            # mask pool + side positions
+            from tpudist.ops.flash_decode import paged_gather_kv
+
+            main = paged_gather_kv(pool.value, table.value)
+            keys = jnp.concatenate([main, side.value], axis=1)
+            mask = jnp.concatenate(
+                [jnp.arange(main.shape[1])[None, :] < idx[:, None],
+                 jnp.broadcast_to(jnp.arange(cap)[None, :] < s_base + 1,
+                                  (b, cap))], axis=1)
+            logits = jnp.einsum(
+                "bhw,bkw->bhk", q_row, keys,
+                preferred_element_type=jnp.float32) * self.softmax_scale
+            logits = jnp.where(mask[:, None, :], logits,
+                               jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(logits, axis=-1).astype(keys.dtype)
+            o_lat = jnp.einsum("bhk,bkc->bhc", probs, keys[..., :lat])
+        out = jnp.einsum("bhc,chd->bhd", o_lat, w_kvb[..., nope:])
+        return out[:, None]
+
+
 class MLPBlock(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         cfg = self.cfg
-        h = nn.Dense(cfg.mlp_ratio * cfg.embed_dim, use_bias=False,
-                     dtype=cfg.compute_dtype, name="up")(x)
-        h = nn.gelu(h)
-        return nn.Dense(cfg.embed_dim, use_bias=False,
-                        dtype=cfg.compute_dtype, name="down")(h)
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=cfg.compute_dtype)
+        h = dense(cfg.ffn_dim, name="up")(x)
+        if cfg.mlp == "gelu":
+            h = nn.gelu(h)
+        elif cfg.mlp == "gated_silu":
+            h = nn.silu(dense(cfg.ffn_dim, name="gate")(x)) * h
+        else:
+            raise ValueError(f"mlp must be 'gelu' or 'gated_silu', got "
+                             f"{cfg.mlp!r}")
+        return dense(cfg.embed_dim, name="down")(h)
 
 
 class DecoderBlock(nn.Module):
@@ -715,22 +1120,42 @@ class DecoderBlock(nn.Module):
     kv_num_blocks: int = 0
     kv_block_size: int = 0
 
+    # an expert layer (cfg.moe) in place of the dense MLP
+    expert_layer: bool = False
+
     @nn.compact
-    def __call__(self, x: jnp.ndarray, causal: bool = True) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, causal: bool = True,
+                 positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         # NOTE: ``causal`` is positional (arg 2) so nn.remat can mark it
         # static (static_argnums) — keyword args would be traced.
-        h = nn.LayerNorm(dtype=self.cfg.compute_dtype, name="ln1")(x)
-        x = x + CausalSelfAttention(self.cfg, self.attention_fn,
-                                    decode=self.decode,
-                                    decode_attention=self.decode_attention,
-                                    decode_shard=self.decode_shard,
-                                    serve_side_slots=self.serve_side_slots,
-                                    cache_layout=self.cache_layout,
-                                    kv_num_blocks=self.kv_num_blocks,
-                                    kv_block_size=self.kv_block_size,
-                                    name="attn")(h, causal=causal)
-        h = nn.LayerNorm(dtype=self.cfg.compute_dtype, name="ln2")(x)
-        return x + MLPBlock(self.cfg, name="mlp")(h)
+        cfg = self.cfg
+        h = make_norm(cfg, "ln1")(x)
+        cache_kw = dict(decode=self.decode,
+                        decode_attention=self.decode_attention,
+                        serve_side_slots=self.serve_side_slots,
+                        cache_layout=self.cache_layout,
+                        kv_num_blocks=self.kv_num_blocks,
+                        kv_block_size=self.kv_block_size, name="attn")
+        if cfg.mla is not None:
+            if self.decode_shard is not None:
+                raise NotImplementedError(
+                    "latent attention has no sharded decode yet")
+            attn = LatentSelfAttention(cfg, **cache_kw)
+        else:
+            attn = CausalSelfAttention(cfg, self.attention_fn,
+                                       decode_shard=self.decode_shard,
+                                       **cache_kw)
+        x = x + attn(h, causal=causal, positions=positions)
+        h = make_norm(cfg, "ln2")(x)
+        if not self.expert_layer:
+            return x + MLPBlock(cfg, name="mlp")(h)
+        from tpudist.models.moe import MoEMLP
+
+        b, s, d = h.shape
+        y, _ = MoEMLP(d_model=d, d_ff=cfg.moe.d_ff or cfg.ffn_dim,
+                      moe=cfg.moe, dtype=cfg.compute_dtype,
+                      name="moe")(h.reshape(b * s, d))
+        return x + y.reshape(b, s, d)
 
 
 class _ScanBody(nn.Module):
@@ -810,14 +1235,24 @@ class TransformerLM(nn.Module):
             positions = jnp.arange(tokens.shape[1])[None, :]
         x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
                      dtype=cfg.compute_dtype, name="tok_embed")(tokens)
-        x = x + nn.Embed(cfg.max_seq_len, cfg.embed_dim,
-                         dtype=cfg.compute_dtype, name="pos_embed")(positions)
+        if cfg.positions == "learned":
+            x = x + nn.Embed(cfg.max_seq_len, cfg.embed_dim,
+                             dtype=cfg.compute_dtype,
+                             name="pos_embed")(positions)
+        elif cfg.positions != "rotary":
+            raise ValueError(f"positions must be 'learned' or 'rotary', "
+                             f"got {cfg.positions!r}")
         # remat: recompute each block's activations in backward instead of
         # storing them — the jax.checkpoint memory/FLOPs trade that makes
         # long-context training fit in HBM.  Default prevent_cse=True:
         # under plain jit XLA could otherwise CSE the recomputation back
         # into the stored forward and silently undo the memory savings.
         if cfg.scan_layers:
+            if cfg.moe is not None or cfg.positions == "rotary":
+                raise ValueError(
+                    "scan_layers stacks identical blocks that take no "
+                    "positions: expert layers and rotary positions need "
+                    "the unrolled layout")
             if self.serve_side_slots:
                 raise ValueError(
                     "serve_side_slots requires the unrolled layout "
@@ -847,8 +1282,9 @@ class TransformerLM(nn.Module):
                               cache_layout=self.cache_layout,
                               kv_num_blocks=self.kv_num_blocks,
                               kv_block_size=self.kv_block_size,
-                              name=f"block{i}")(x, causal)
-        x = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln_f")(x)
+                              expert_layer=cfg.is_expert_layer(i),
+                              name=f"block{i}")(x, causal, positions)
+        x = make_norm(cfg, "ln_f")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False,
                           dtype=cfg.compute_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)

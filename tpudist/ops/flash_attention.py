@@ -192,13 +192,16 @@ def _smem_spec():
 
 
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
-                   q_offset=0, k_offset=0, window=None):
+                   q_offset=0, k_offset=0, window=None, scale=None):
     """[B, S, H, D] in; internally runs on a fused [B·H, S, D] layout so
     every block's minor two dims are (seq_block, D) — the (8, 128)-tileable
     shape Mosaic requires (an [.., S, H, ..] block with a size-1 H slice is
     not lowerable on real TPUs).  ``q_offset``/``k_offset`` shift the causal
-    mask to global positions (ring attention)."""
+    mask to global positions (ring attention).  ``v`` may be narrower or
+    wider than ``q`` / ``k`` (latent attention: q/k 192, v 128): the output
+    has ``v``'s width.  ``scale`` defaults to ``D ** -0.5``."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     sk, h_kv = k.shape[1], k.shape[2]
     group = h // h_kv
     num_kb = sk // block_k
@@ -220,7 +223,8 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
         kv_idx = lambda g, i, j: (kv_head(g), j, 0)
 
     kernel = functools.partial(
-        _flash_kernel, scale=d ** -0.5, causal=causal,
+        _flash_kernel, scale=d ** -0.5 if scale is None else scale,
+        causal=causal,
         block_q=block_q, block_k=block_k, num_kb=span_k, window=window,
         prune=prune, total_kb=num_kb)
     out, lse = pl.pallas_call(
@@ -230,20 +234,20 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
             _smem_spec(),
             pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
+            pl.BlockSpec((1, block_k, dv), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda g, i, j: (g, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

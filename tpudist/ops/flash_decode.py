@@ -137,16 +137,17 @@ def _softmax_finalize(l_scr, acc_scr, o_ref, paired: bool):
     return l
 
 
-def _side_update(m_scr, l_scr, acc_scr, q, sk_ref, sv_ref, side_len,
+def _side_update(m_scr, l_scr, acc_scr, q, side_k, load_side_v, side_len,
                  scale: float):
     """The side buffer's rank update: its first ``side_len`` positions
-    join the same online softmax as the main cache."""
+    (key tile ``side_k``; ``load_side_v(side_k)`` gives the value tile once
+    the scores are made) join the same online softmax as the main cache."""
     s = jax.lax.dot_general(
-        q, sk_ref[0], (((1,), (1,)), ((), ())),
+        q, side_k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < side_len, s, -jnp.inf)
-    _softmax_update(m_scr, l_scr, acc_scr, s, None, sv_ref[0])
+    _softmax_update(m_scr, l_scr, acc_scr, s, None, load_side_v(side_k))
 
 
 def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
@@ -260,8 +261,8 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
         # update on tiles that are already resident)
         @pl.when(kj == num_kb - 1)
         def _side():
-            _side_update(m_scr, l_scr, acc_scr, q_tile(), sk_ref, sv_ref,
-                         meta_ref[0], scale)
+            _side_update(m_scr, l_scr, acc_scr, q_tile(), sk_ref[0],
+                         lambda _: sv_ref[0], meta_ref[0], scale)
 
     @pl.when(kj == num_kb - 1)
     def _finalize():
@@ -272,34 +273,50 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
             lse_ref[0, 0] = (m_scr[:] + jnp.log(l))[:, 0]
 
 
-def _paged_decode_kernel(meta_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
+def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                          block: int, pages_per_tile: int, m_blocks: int,
-                         lanes: int, r_kv: int, paired: bool, side: bool):
+                         lanes: int, r_kv: int, paired: bool, side: bool,
+                         d_v: int | None = None):
     """Online-softmax decode over ONE grid row (a lane's K/V-head chunk)
     of a paged cache, walking the lane's LIVE pages only.
 
     ``meta_ref`` is the scalar-prefetch vector ``[side_len, len_0 ..
-    len_{B-1}, table[0, 0] .. table[B-1, M-1]]``.  The pools stay in HBM
-    (``k_hbm`` / ``v_hbm``); the body fetches them itself, a TILE of
-    ``pages_per_tile`` pages at a time, into two VMEM slots: the next
-    tile's copies start before the current tile is computed, and the
-    first tile of the next grid row that holds a page starts during the
-    last tile of this one (``state_ref`` carries its slot across grid
-    rows), so a row's DMA latency hides behind its neighbour's work.  A
-    lane needs ``ceil(len / block)`` pages: no copy is started, waited
-    for or stepped over beyond that, and a lane of length 0 costs the
-    side-buffer update and the output write alone.  The arithmetic is
-    ``_decode_kernel``'s (f32 ``m`` / ``l`` / ``acc``, operands in their
-    own dtype into the MXU, ``k_pos < len`` masked on every tile)."""
+    len_{B-1}, table[0, 0] .. table[B-1, M-1]]``.  The pools stay in HBM;
+    the body fetches them itself, a TILE of ``pages_per_tile`` pages at a
+    time, into two VMEM slots: the next tile's copies start before the
+    current tile is computed, and the first tile of the next grid row that
+    holds a page starts during the last tile of this one (``state_ref``
+    carries its slot across grid rows), so a row's DMA latency hides behind
+    its neighbour's work.  A lane needs ``ceil(len / block)`` pages: no
+    copy is started, waited for or stepped over beyond that, and a lane of
+    length 0 costs the side-buffer update and the output write alone.  The
+    arithmetic is ``_decode_kernel``'s (f32 ``m`` / ``l`` / ``acc``,
+    operands in their own dtype into the MXU, ``k_pos < len`` masked on
+    every tile).
+
+    The walk serves two layouts.  ``d_v`` None: TWO pools (keys, values)
+    of one width, each with its side buffer (``paged_flash_decode``).
+    ``d_v`` set: ONE pool whose rows are the keys and whose first ``d_v``
+    columns are also the values, read once (``paged_mla_decode``: the
+    latent cache of multi-head latent attention)."""
+    n_pools = 2 if d_v is None else 1
+    pools, rest = rest[:n_pools], rest[n_pools:]
     if side:
-        sk_ref, sv_ref = rest[:2]
-        rest = rest[2:]
-    o_ref, k_buf, v_buf, sems, state_ref, m_scr, l_scr, acc_scr = rest[:8]
-    q_scr = rest[8] if paired else None
+        sides, rest = rest[:n_pools], rest[n_pools:]
+    o_ref, rest = rest[0], rest[1:]
+    bufs, rest = rest[:n_pools], rest[n_pools:]
+    sems, state_ref, m_scr, l_scr, acc_scr = rest[:5]
+    q_scr = rest[5] if paired else None
     g = pl.program_id(0)
     lane, r = g // r_kv, g % r_kv
     tile = pages_per_tile * block
-    d = k_buf.shape[-1]
+    d = bufs[0].shape[-1]
+
+    def values(keys, load_values):
+        """The value tile beside the key tile ``keys``: the second pool's
+        (loaded only now, after the scores), or the keys' own first
+        ``d_v`` columns."""
+        return load_values() if d_v is None else keys[:, :d_v]
 
     def lane_len(i):
         return meta_ref[1 + i]
@@ -308,7 +325,7 @@ def _paged_decode_kernel(meta_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
         return (lane_len(i) + block - 1) // block
 
     def tile_copies(i, r_, t, slot, fn):
-        """Apply ``fn`` (start or wait) to the K and V copy of every LIVE
+        """Apply ``fn`` (start or wait) to every pool's copy of every LIVE
         page of lane ``i``'s tile ``t`` — a loop and not an unrolled
         ladder of predicates: the body is traced and lowered once a call
         site, and a segment program holds one call a layer."""
@@ -321,7 +338,7 @@ def _paged_decode_kernel(meta_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
 
         def one_page(p, _):
             page = meta_ref[base + p]
-            for hbm, buf, kv in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+            for kv, (hbm, buf) in enumerate(zip(pools, bufs)):
                 fn(pltpu.make_async_copy(
                     hbm.at[page, :, chunk], buf.at[slot, p],
                     sems.at[kv, slot]))
@@ -355,7 +372,7 @@ def _paged_decode_kernel(meta_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
         state_ref[1] = 0
         # a slot's rows past the live pages of a tile are never copied
         # into: whatever VMEM held there would reach acc as 0 * garbage
-        v_buf[...] = jnp.zeros_like(v_buf)
+        bufs[-1][...] = jnp.zeros_like(bufs[-1])
 
     _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr)
 
@@ -393,20 +410,22 @@ def _paged_decode_kernel(meta_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
                     state_ref[1] = 1
 
             wait(lane, r, t, slot)
+            keys = bufs[0][slot].reshape(tile, d)
             s = jax.lax.dot_general(
-                q_tile(), k_buf[slot].reshape(tile, d),
-                (((1,), (1,)), ((), ())),
+                q_tile(), keys, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             k_pos = t * tile + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             s = jnp.where(k_pos < cache_len, s, -jnp.inf)
-            _softmax_update(m_scr, l_scr, acc_scr, s, None,
-                            v_buf[slot].reshape(tile, d))
+            _softmax_update(
+                m_scr, l_scr, acc_scr, s, None,
+                values(keys, lambda: bufs[-1][slot].reshape(tile, d)))
 
         jax.lax.fori_loop(0, n_tiles, one_tile, None)
 
     if side:
-        _side_update(m_scr, l_scr, acc_scr, q_tile(), sk_ref, sv_ref,
+        _side_update(m_scr, l_scr, acc_scr, q_tile(), sides[0][0],
+                     lambda keys: values(keys, lambda: sides[-1][0]),
                      meta_ref[0], scale)
     _softmax_finalize(l_scr, acc_scr, o_ref, paired)
 
@@ -900,14 +919,8 @@ def paged_flash_decode(
         if side_k.ndim != 3:
             raise ValueError(
                 "side buffers must be packed 3-D [B, cap, Hkv*D]")
-        cap = side_k.shape[1]
-        capp = max(8, -(-cap // 8) * 8)
-        if capp != cap:
-            pad = ((0, 0), (0, capp - cap), (0, 0))
-            side_k = jnp.pad(side_k, pad)
-            side_v = jnp.pad(side_v, pad)
-        side_k = side_k.astype(k_pool.dtype)
-        side_v = side_v.astype(v_pool.dtype)
+        side_k = _pad_side(side_k, k_pool.dtype)
+        side_v = _pad_side(side_v, v_pool.dtype)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
 
@@ -937,7 +950,6 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
         side_len.reshape(1),
         jnp.minimum(cache_len, m_blocks * block), table.reshape(-1)])
 
-    scale = d ** -0.5
     paired = h_kv % 2 == 0 and d * 2 <= 128 and not _DISABLE_PAIRING
     q4 = q.reshape(b, h_kv, g, d)
     q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
@@ -945,67 +957,177 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
         # pool pairing is free: adjacent KV heads are contiguous in the
         # packed minor dim, so a pair chunk is just a wider slice of it —
         # no reshape of the pool ever happens
-        rows, r_kv, d_eff = 2 * gp, h_kv // 2, 2 * d
+        r_kv = h_kv // 2
         q3 = q4.reshape(b * r_kv, 2, gp, d)
-        gp, d = rows, d_eff
     else:
         r_kv = h_kv
         q3 = q4.reshape(b * h_kv, gp, d)
+    out = _paged_call(
+        meta, q3, (k_pool, v_pool), (side_k, side_v) if side else None,
+        scale=d ** -0.5, lanes=b, r_kv=r_kv, paired=paired, d_v=None,
+        interpret=interpret, name="paged_flash_decode")
+    if paired:
+        o = out.reshape(b, r_kv * 2, gp, d)
+        return o[:, :, :g].reshape(b, 1, h, d)
+    return out.reshape(b, r_kv, gp, d)[:, :, :g].reshape(b, 1, h, d)
+
+
+def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
+                r_kv: int, paired: bool, d_v: int | None, interpret: bool,
+                name: str):
+    """The ``pallas_call`` both paged decode kernels share: one grid row a
+    (lane, K/V-head chunk), the pools left in HBM for the body's own
+    copies, ``q3 [rows, gp, d]`` (``[rows, 2, gp, d]`` paired) in and an
+    output of its shape (``d_v`` wide where the one pool's rows double as
+    values) out."""
+    block, m_blocks = pools[0].shape[1], (meta.shape[0] - 1 - lanes) // lanes
+    if paired:
+        gp, d = 2 * q3.shape[2], 2 * q3.shape[3]
+        row_spec = pl.BlockSpec((1, 2, gp // 2, d // 2),
+                                lambda g_, m: (g_, 0, 0, 0))
+        out_spec, out_shape = row_spec, q3.shape
+    else:
+        gp, d = q3.shape[1:]
+        # a grid row's queries in, its output out: the same block of both
+        row_spec = pl.BlockSpec((1, gp, d), lambda g_, m: (g_, 0, 0))
+        d_out = d if d_v is None else d_v
+        out_spec = pl.BlockSpec((1, gp, d_out), lambda g_, m: (g_, 0, 0))
+        out_shape = (q3.shape[0], gp, d_out)
     R = r_kv  # noqa: N806 — closed over by the index maps
     # a tile of about 1024 tokens: big enough that a grid row's fixed
     # cost and a copy's latency are small beside it, small enough that
-    # two slots of K and of V stay a small part of VMEM
+    # two slots of every pool stay a small part of VMEM
     pages_per_tile = max(1, min(m_blocks, 1024 // block))
-
-    # a grid row's queries in, its output out: the same block of both
-    if paired:
-        row_spec = pl.BlockSpec((1, 2, gp // 2, d // 2),
-                                lambda g_, m: (g_, 0, 0, 0))
-    else:
-        row_spec = pl.BlockSpec((1, gp, d), lambda g_, m: (g_, 0, 0))
     # the pools are left where they are (HBM): the kernel's own copies
     # fetch the pages a lane really holds, by the ids in meta
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    args = [meta, q3, k_pool, v_pool]
-    in_specs = [row_spec, pool_spec, pool_spec]
-    if side:
+    args = [meta, q3, *pools]
+    in_specs = [row_spec] + [pool_spec] * len(pools)
+    if sides is not None:
         side_spec = pl.BlockSpec(
-            (1, side_k.shape[1], d), lambda g_, m: (g_ // R, 0, g_ % R))
-        args += [side_k, side_v]
-        in_specs += [side_spec, side_spec]
+            (1, sides[0].shape[1], d), lambda g_, m: (g_ // R, 0, g_ % R))
+        args += list(sides)
+        in_specs += [side_spec] * len(sides)
 
-    tile_buf = pltpu.VMEM((2, pages_per_tile, block, d), k_pool.dtype)
-    out = pl.pallas_call(
+    tile_buf = pltpu.VMEM((2, pages_per_tile, block, d), pools[0].dtype)
+    acc_d = d if d_v is None else d_v
+    return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, scale=scale, block=block,
-            pages_per_tile=pages_per_tile, m_blocks=m_blocks, lanes=b,
-            r_kv=r_kv, paired=paired, side=side),
+            pages_per_tile=pages_per_tile, m_blocks=m_blocks, lanes=lanes,
+            r_kv=r_kv, paired=paired, side=sides is not None, d_v=d_v),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b * r_kv,),
+            grid=(lanes * r_kv,),
             in_specs=in_specs,
-            out_specs=row_spec,
-            scratch_shapes=[
-                tile_buf, tile_buf,                  # K, V: two slots
-                pltpu.SemaphoreType.DMA((2, 2)),     # [K/V, slot]
+            out_specs=out_spec,
+            scratch_shapes=[tile_buf] * len(pools) + [  # two slots a pool
+                pltpu.SemaphoreType.DMA((len(pools), 2)),  # [pool, slot]
                 pltpu.SMEM((2,), jnp.int32),         # cross-row prefetch
                 pltpu.VMEM((gp, 1), jnp.float32),
                 pltpu.VMEM((gp, 1), jnp.float32),
-                pltpu.VMEM((gp, d), jnp.float32),
-            ] + ([pltpu.VMEM((gp, d), q.dtype)] if paired else []),
+                pltpu.VMEM((gp, acc_d), jnp.float32),
+            ] + ([pltpu.VMEM((gp, d), q3.dtype)] if paired else []),
         ),
-        out_shape=jax.ShapeDtypeStruct(q3.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q3.dtype),
         # sequential: a row starts the next live row's first tile
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_flash_decode",
+        name=name,
     )(*args)
-    if paired:
-        d0 = d // 2
-        o = out.reshape(b, r_kv * 2, gp // 2, d0)
-        return o[:, :, :g].reshape(b, 1, h, d0)
-    return out.reshape(b, r_kv, gp, d)[:, :, :g].reshape(b, 1, h, d)
+
+
+def _pad_side(side, dtype):
+    """A side buffer's capacity rounded up to the sublane tile."""
+    cap = side.shape[1]
+    capp = max(8, -(-cap // 8) * 8)
+    if capp != cap:
+        side = jnp.pad(side, ((0, 0), (0, capp - cap), (0, 0)))
+    return side.astype(dtype)
+
+
+def paged_mla_decode(
+    q: jnp.ndarray,
+    pool: jnp.ndarray,
+    page_table: jnp.ndarray,
+    cache_len: jnp.ndarray,
+    *,
+    d_v: int,
+    scale: float,
+    side: jnp.ndarray | None = None,
+    side_len: jnp.ndarray | int = 0,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """One ABSORBED decode step of multi-head latent attention against a
+    paged latent cache: multi-query attention of ``H`` query heads against
+    ONE key row a token, whose first ``d_v`` columns are also the value.
+
+    The walk is :func:`paged_flash_decode`'s (live pages only, tiles of
+    about 1024 tokens, double-buffered copies, the next lane's first tile
+    prefetched, f32 ``m`` / ``l`` / ``acc``) over one pool read once; a
+    grid row is a lane, its ``[H, W]`` query block against a tile's
+    ``[1024, W]`` rows.
+
+    Args:
+      q: ``[B, H, W]`` absorbed queries (``q_nope W_kvb^K`` beside the
+        rotated ``q_rope``, zero in any padding columns).
+      pool: ``[num_blocks, block_size, W]`` latent rows ``[c_kv | k_rope |
+        padding]``; ``W`` a multiple of 128, padding columns ZERO (``d_v``
+        a multiple of 128 too wherever the chip's compiler is to slice
+        the values off lane-aligned).
+      page_table / cache_len: as :func:`paged_flash_decode`.
+      d_v: the latent width (the first ``d_v`` columns are the values).
+      scale: the softmax scale (the model's, not ``W ** -0.5``).
+      side / side_len: the segment-local staging buffer ``[B, cap, W]``.
+
+    Returns ``[B, H, d_v]`` (the caller applies ``W_kvb^V``).  In a trace
+    the kernel is ``paged_mla_decode`` with FOUR operands (meta, q, pool,
+    side) where ``paged_flash_decode`` has six."""
+    b, h, w = q.shape
+    if pool.ndim != 3 or pool.shape[2] != w:
+        raise ValueError(
+            f"the latent pool is [N, block, W={w}]; got {pool.shape}")
+    if w % 128 or not 0 < d_v <= w:
+        raise ValueError(
+            f"row width {w} must be a lane multiple (pad the row with zero "
+            f"columns) and hold the {d_v} value columns")
+    block = pool.shape[1]
+    if block < 8 or block % 8:
+        raise ValueError(
+            f"block_size must be a multiple of 8, got {block}")
+    cache_len = jnp.asarray(cache_len, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    if cache_len.shape != (b,) or table.ndim != 2 or table.shape[0] != b:
+        raise ValueError(
+            f"per-row cache_len [B={b}] and page_table [B, max_blocks] "
+            f"needed; got {cache_len.shape}, {table.shape}")
+    if side is not None:
+        side = _pad_side(side, pool.dtype)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _paged_mla_one(
+        q, pool, table, cache_len, jnp.asarray(side_len, jnp.int32), side,
+        d_v=int(d_v), scale=float(scale), interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("d_v", "scale", "interpret"))
+def _paged_mla_one(q, pool, table, cache_len, side_len, side, *, d_v: int,
+                   scale: float, interpret: bool):
+    """The validated call of :func:`paged_mla_decode`, under its own
+    ``jit`` for the reason :func:`_paged_decode_one` gives."""
+    b, h, w = q.shape
+    block, m_blocks = pool.shape[1], table.shape[1]
+    hp = -(-h // 8) * 8
+    meta = jnp.concatenate([
+        side_len.reshape(1),
+        jnp.minimum(cache_len, m_blocks * block), table.reshape(-1)])
+    q3 = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+    out = _paged_call(
+        meta, q3, (pool,), None if side is None else (side,), scale=scale,
+        lanes=b, r_kv=1, paired=False, d_v=d_v, interpret=interpret,
+        name="paged_mla_decode")
+    return out[:, :h]
 
 
 def quantize_kv(k: jnp.ndarray, v: jnp.ndarray):

@@ -104,6 +104,15 @@ def fused_moe_mlp(x: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray,
                            block_rows, interpret)
 
 
+def _block_experts(starts, bn: int, nb: int):
+    """The expert whose rows each of ``nb`` row blocks holds, from the
+    block-aligned group ``starts [E]``: the last group that starts at or
+    before the block (an empty group shares its successor's start)."""
+    block_ids = jnp.zeros((nb,), jnp.int32).at[
+        jnp.minimum(starts // bn, nb - 1)].add(1)
+    return jnp.clip(jnp.cumsum(block_ids) - 1, 0, starts.shape[0] - 1)
+
+
 def _fused_moe_fwd_only(x, w_up, w_down, top_idx, top_vals, block_rows,
                         interpret):
     t, d = x.shape
@@ -121,10 +130,7 @@ def _fused_moe_fwd_only(x, w_up, w_down, top_idx, top_vals, block_rows,
     xs = x[order // k]                                    # [NP, d] sorted rows
     gate = jnp.zeros((np_pad, 1), jnp.float32).at[pos, 0].set(
         top_vals.reshape(-1).astype(jnp.float32))         # pad slots: gate 0
-    # block -> expert id
-    block_ids = jnp.zeros((nb,), jnp.int32).at[
-        jnp.minimum(starts // bn, nb - 1)].add(1)
-    block_expert = jnp.clip(jnp.cumsum(block_ids) - 1, 0, e - 1)
+    block_expert = _block_experts(starts, bn, nb)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                            # block_expert
@@ -148,3 +154,139 @@ def _fused_moe_fwd_only(x, w_up, w_down, top_idx, top_vals, block_rows,
 
     # combine: gate already folded in-kernel — gather + sum over choices
     return jnp.sum(ys[pos].reshape(t, k, d), axis=1).astype(x.dtype)
+
+
+# -- gated grouped product: the expert layer of a served model --------------
+
+def _gg_kernel(meta_ref, x_ref, *refs, gated: bool, n_k: int):
+    """One grid step = one ``[bn, tk]`` x ``[tk, tn]`` product of one
+    expert's row block, accumulated over the ``k`` axis in f32 scratch;
+    the epilogue of the gated form is ``silu(gate) * up``."""
+    del meta_ref  # read by the index maps only
+    n_w = 2 if gated else 1
+    w_refs, o_ref, accs = refs[:n_w], refs[n_w], refs[n_w + 1:]
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _zero():
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
+
+    x = x_ref[...]
+    for w_ref, acc in zip(w_refs, accs):
+        acc[...] += jax.lax.dot_general(
+            x, w_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kk == n_k - 1)
+    def _store():
+        y = accs[0][...]
+        if gated:
+            y = jax.nn.silu(y) * accs[1][...]
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _tiles(k: int, n: int, itemsize: int, budget: int) -> tuple[int, int]:
+    """``(tk, tn)`` of a weight tile of at most about ``budget`` bytes:
+    whole rows first (a tile is then one contiguous slab of the expert's
+    matrix), halved while they stay multiples of 128."""
+    tn = n
+    while tn * 128 * itemsize > budget and tn % 256 == 0:
+        tn //= 2
+    tk = k
+    while tk * tn * itemsize > budget and tk % 256 == 0:
+        tk //= 2
+    return tk, tn
+
+
+def _grouped_matmul(xs, weights, block_expert, n_live, bn: int, *,
+                    gated: bool, interpret: bool, name: str):
+    """``xs [NP, K]`` (rows sorted by expert, every ``bn``-row block one
+    expert's) times ``weights[i] [E, K, N]`` -> ``[NP, N]``; with two
+    weights the gated form ``silu(xs @ w0) * (xs @ w1)``.  Only the first
+    ``n_live`` row blocks are visited (a DYNAMIC grid bound): the rest of
+    the output is never written and must never be read."""
+    np_pad, k = xs.shape
+    n = weights[0].shape[2]
+    tk, tn = _tiles(k, n, xs.dtype.itemsize, (2 if gated else 4) << 20)
+    n_k = k // tk
+    w_spec = pl.BlockSpec((1, tk, tn), lambda b, j, kk, m: (m[b], kk, j))
+    return pl.pallas_call(
+        functools.partial(_gg_kernel, gated=gated, n_k=n_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                        # block_expert
+            grid=(n_live, n // tn, n_k),
+            in_specs=[pl.BlockSpec((bn, tk), lambda b, j, kk, m: (b, kk))]
+            + [w_spec] * len(weights),
+            out_specs=pl.BlockSpec((bn, tn), lambda b, j, kk, m: (b, j)),
+            scratch_shapes=[pltpu.VMEM((bn, tn), jnp.float32)
+                            for _ in weights],
+        ),
+        out_shape=jax.ShapeDtypeStruct((np_pad, n), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            # two slots of every weight tile, the row block, the
+            # accumulators: under 20 MB at the budgets above
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret,
+        name=name,
+    )(block_expert, xs, *weights)
+
+
+def row_block(assignments: int, num_experts: int) -> int:
+    """Rows a block of the grouped product: twice the mean rows an expert
+    gets when ``assignments`` spread evenly over ``num_experts``, as a
+    power of two between 16 (a bf16 sublane tile) and 256.  A decode step
+    of 128 lanes x 8 choices over 256 experts gives 16, where padding every
+    group to 128 rows would multiply the rows by ten."""
+    mean = max(1, (2 * assignments) // max(num_experts, 1))
+    return min(256, max(16, 1 << (mean - 1).bit_length()))
+
+
+def grouped_gated_mlp(x: jnp.ndarray, w_gate: jnp.ndarray,
+                      w_up: jnp.ndarray, w_down: jnp.ndarray,
+                      local_idx: jnp.ndarray, weights: jnp.ndarray, *,
+                      num_experts: int | None = None,
+                      interpret: bool | None = None):
+    """The routed part of an expert layer over the experts HELD here:
+    ``sum_i w_i * down_e(silu(gate_e x) * up_e x)`` over a token's choices
+    ``e = local_idx[t, i]`` that fall in ``[0, E)``; a choice outside (an
+    expert another chip holds) adds nothing.  Sorted dispatch (the shared
+    counting sort) and two grouped Pallas products, ``moe_experts_gate_up``
+    and ``moe_experts_down``; no capacity, no token dropped.  The row
+    block follows from the number of assignments and ``num_experts`` (all
+    the router routes over; default ``E``), see :func:`row_block`.
+
+    ``x [T, d]``, ``w_gate / w_up [E, d, f]``, ``w_down [E, f, d]``,
+    ``local_idx / weights [T, k]``.  Returns ``(y [T, d], counts [E])``,
+    ``counts`` the tokens each held expert was given."""
+    from tpudist.models.moe import _counting_sort
+
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    t, d = x.shape
+    e = w_gate.shape[0]
+    k = local_idx.shape[1]
+    n = t * k
+    bn = row_block(n, num_experts or e)
+    held = (local_idx >= 0) & (local_idx < e)
+    # the choices held elsewhere sort into a last group no block visits
+    flat_e = jnp.where(held, local_idx, e).reshape(-1)
+    pos, order, sizes, starts, np_pad = _counting_sort(
+        flat_e, e + 1, block_rows=bn)
+    nb = np_pad // bn
+    n_live = starts[e] // bn
+    block_expert = _block_experts(starts[:e], bn, nb)
+    xs = x[order // k]                                  # [NP, d] sorted rows
+    h = _grouped_matmul(xs, (w_gate, w_up), block_expert, n_live, bn,
+                        gated=True, interpret=interpret,
+                        name="moe_experts_gate_up")
+    ys = _grouped_matmul(h, (w_down,), block_expert, n_live, bn,
+                         gated=False, interpret=interpret,
+                         name="moe_experts_down")
+    # a choice held elsewhere points past the visited blocks: masked, never
+    # multiplied (those rows are not written)
+    y = jnp.where(held[..., None], ys[pos].reshape(t, k, d), 0)
+    y = jnp.sum(y.astype(jnp.float32)
+                * weights[..., None].astype(jnp.float32), axis=1)
+    return y.astype(x.dtype), sizes[:e]
