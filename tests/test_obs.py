@@ -407,6 +407,36 @@ class TestGlobalRegistryWiring:
         assert lat["count"] >= 3
         assert snap["gauges"]["serve/queue_depth"]["value"] == 0
 
+    @pytest.mark.parametrize("layout", ["dense", "paged"])
+    def test_serving_counts_the_decode_kernels_rows(self, layout):
+        """``serve/decode_rows_computed`` and ``serve/decode_rows_live``
+        are registered by every loop; the paged layout ticks them (live
+        rows never more than computed ones), the dense layout leaves
+        them where they were."""
+        from tpudist.models.serving import Request, ServeLoop
+        from tpudist.models.transformer import TransformerConfig, TransformerLM
+
+        cfg = TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
+                                num_kv_heads=2, embed_dim=64, max_seq_len=96)
+        params = TransformerLM(cfg).init(
+            jax.random.key(0), np.zeros((1, 8), np.int32))["params"]
+        kw = (dict(decode_attention="dense") if layout == "dense" else
+              dict(cache_layout="paged", kv_block_size=16))
+        loop = ServeLoop(cfg, params, num_slots=2, steps_per_sync=5,
+                         prefill_chunk=8, **kw)
+        names = ("serve/decode_rows_computed", "serve/decode_rows_live")
+        before = [obs.counter(n).value() for n in names]
+        loop.run([Request(np.arange(1, 20, dtype=np.int32), 12, rid=i)
+                  for i in range(3)])
+        snap = obs.snapshot()["counters"]
+        assert all(n in snap for n in names)
+        computed, live = (obs.counter(n).value() - b
+                          for n, b in zip(names, before))
+        if layout == "dense":
+            assert computed == live == 0
+        else:
+            assert computed >= live > 0 and computed % 16 == 0
+
 
 # -- PR 2 satellites: span ring, publish staleness, merged prometheus,
 # -- utils-metrics dedupe, xla telemetry ------------------------------------

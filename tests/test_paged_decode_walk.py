@@ -1,5 +1,7 @@
 """``paged_flash_decode`` walks a lane's LIVE pages only, several pages a
-tile: every length that sits on an edge of the walk, against
+tile, and computes its live rows only (the tiles before a lane's last unmasked,
+the last in ONE masked update at the width its live pages need, the side
+buffer's rows in that same update): every length that sits on an edge of the walk, against
 ``paged_gather_kv`` + masked attention.
 
 Dead page-table entries point at pool block 0, which is NaN-filled here:
@@ -7,6 +9,8 @@ a dead page that reached the result would show.  The kernel runs under
 ``interpret`` (its own copies, semaphores and ``fori_loop`` included)."""
 
 import functools
+import importlib
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +18,7 @@ import numpy as np
 import pytest
 
 from tpudist.ops.flash_decode import (paged_flash_decode, paged_gather_kv,
-                                      paged_mla_decode)
+                                      paged_mla_decode, walk_rows)
 
 BLOCK, M_BLOCKS, CAP, HEADS = 128, 20, 8, 4
 P = 1024 // BLOCK                      # pages a tile, as the kernel derives
@@ -29,6 +33,12 @@ EDGES = {
     "block+1": BLOCK + 1, "pages_not_multiple_of_P": (P + 3) * BLOCK - 7,
     "exactly_P_pages": P * BLOCK, "P_pages+1": P * BLOCK + 1,
     "all_pages": M_BLOCKS * BLOCK,
+    "tile-1": P * BLOCK - 1,
+    # a full tile, then a last tile of k pages: its widths (k pages at
+    # "-1" and "+0", k + 1 at "+1"), and the mask's place one row either
+    # side of each page edge
+    **{f"tile+{k}pages{row:+d}": (P + k) * BLOCK + row
+       for k in (1, 2, 3, 4, 5, 7) for row in (-1, 0, 1)},
 }
 
 
@@ -73,20 +83,26 @@ def _reference(q, k_pool, v_pool, table, lens, h_kv, side_k, side_v,
     return (out / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30))[:, None]
 
 
+def _owned():
+    """Every lane's M_BLOCKS scattered pool blocks (block 0 is nobody's)."""
+    return 1 + np.random.default_rng(7).permutation(
+        LANES * M_BLOCKS).reshape(LANES, M_BLOCKS)
+
+
 @functools.cache
 def _setup(layout: str, side: str):
-    """Pools, buffers and the jitted call of one (layout, side): the
-    lengths and the table are arguments, so every case of it shares one
-    compile."""
+    """The two pools (numpy, block 0 NaN: the block dead entries name) and
+    the jitted call of one (layout, side): pools, table and lengths are
+    arguments, so every case of it shares one compile."""
     h_kv, d = LAYOUTS[layout]
     flat = h_kv * d
     ks = jax.random.split(jax.random.key(26), 5)
     n_pool = LANES * M_BLOCKS + 1
     q = jax.random.normal(ks[0], (LANES, 1, HEADS, d), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (n_pool, BLOCK, flat), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (n_pool, BLOCK, flat), jnp.float32)
-    k_pool = k_pool.at[0].set(jnp.nan)         # the block dead entries name
-    v_pool = v_pool.at[0].set(jnp.nan)
+    pools = [np.array(jax.random.normal(k, (n_pool, BLOCK, flat)))
+             for k in ks[1:3]]
+    for pool in pools:
+        pool[0] = np.nan
     side_len = SIDES[side]
     if side_len is None:
         side_k = side_v = None
@@ -95,7 +111,7 @@ def _setup(layout: str, side: str):
         side_v = jax.random.normal(ks[4], (LANES, CAP, flat), jnp.float32)
 
     @jax.jit
-    def both(table, lens):
+    def both(k_pool, v_pool, table, lens):
         got = paged_flash_decode(
             q, k_pool, v_pool, table, lens, packed_kv_heads=h_kv,
             side_k=side_k, side_v=side_v, side_len=side_len or 0,
@@ -104,7 +120,22 @@ def _setup(layout: str, side: str):
                           side_v, side_len or 0)
         return got, want
 
-    return both
+    return pools, both
+
+
+def _live_table(lens):
+    """Entries past a lane's live pages are DEAD and name the poisoned
+    block 0."""
+    pages = -(-lens // BLOCK)
+    return np.where(np.arange(M_BLOCKS)[None, :] < pages[:, None],
+                    _owned(), 0)
+
+
+def _check(got, want, what: str):
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all(), what
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    return got
 
 
 @pytest.mark.parametrize("side", SIDES)
@@ -112,17 +143,10 @@ def _setup(layout: str, side: str):
 @pytest.mark.parametrize("lengths", LENGTHS)
 def test_walk_matches_gather_reference(lengths, layout, side):
     lens = np.asarray(LENGTHS[lengths], np.int32)
-    pages = -(-lens // BLOCK)
-    # every lane owns M_BLOCKS scattered pool blocks; entries past its
-    # live pages are DEAD and name the poisoned block 0
-    owned = 1 + np.random.default_rng(7).permutation(
-        LANES * M_BLOCKS).reshape(LANES, M_BLOCKS)
-    table = np.where(np.arange(M_BLOCKS)[None, :] < pages[:, None], owned, 0)
-    got, want = _setup(layout, side)(jnp.asarray(table, jnp.int32),
-                                     jnp.asarray(lens))
-    got, want = np.asarray(got), np.asarray(want)
-    assert np.isfinite(got).all(), "a dead (poisoned) page reached the result"
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    pools, both = _setup(layout, side)
+    got = _check(*both(*pools, jnp.asarray(_live_table(lens), jnp.int32),
+                       jnp.asarray(lens)),
+                 "a dead (poisoned) page reached the result")
     if SIDES[side] in (None, 0):
         # nothing to attend: an empty lane's output is 0
         assert not got[lens == 0].any()
@@ -159,35 +183,124 @@ def _mla_setup(side: str):
     ks = jax.random.split(jax.random.key(27), 3)
     n_pool = LANES * M_BLOCKS + 1
     q = jax.random.normal(ks[0], (LANES, MLA_HEADS, MLA_W), jnp.float32)
-    pool = jax.random.normal(ks[1], (n_pool, BLOCK, MLA_W), jnp.float32)
-    pool = pool.at[0].set(jnp.nan)             # the block dead entries name
+    pool = np.array(jax.random.normal(ks[1], (n_pool, BLOCK, MLA_W)))
+    pool[0] = np.nan                           # the block dead entries name
     side_len = SIDES[side]
     buf = (None if side_len is None else
            jax.random.normal(ks[2], (LANES, CAP, MLA_W), jnp.float32))
 
     @jax.jit
-    def both(table, lens):
+    def both(pool, table, lens):
         got = paged_mla_decode(
             q, pool, table, lens, d_v=MLA_DV, scale=MLA_SCALE, side=buf,
             side_len=side_len or 0, interpret=True)
         return got, _mla_reference(q, pool, table, lens, buf, side_len or 0)
 
-    return both
+    return pool, both
 
 
 @pytest.mark.parametrize("side", SIDES)
 @pytest.mark.parametrize("lengths", LENGTHS)
 def test_latent_walk_matches_gather_reference(lengths, side):
     lens = np.asarray(LENGTHS[lengths], np.int32)
-    pages = -(-lens // BLOCK)
-    owned = 1 + np.random.default_rng(7).permutation(
-        LANES * M_BLOCKS).reshape(LANES, M_BLOCKS)
-    table = np.where(np.arange(M_BLOCKS)[None, :] < pages[:, None], owned, 0)
-    got, want = _mla_setup(side)(jnp.asarray(table, jnp.int32),
-                                 jnp.asarray(lens))
-    got, want = np.asarray(got), np.asarray(want)
+    pool, both = _mla_setup(side)
+    got = _check(*both(pool, jnp.asarray(_live_table(lens), jnp.int32),
+                       jnp.asarray(lens)),
+                 "a dead (poisoned) page reached the result")
     assert got.shape == (LANES, MLA_HEADS, MLA_DV)
-    assert np.isfinite(got).all(), "a dead (poisoned) page reached the result"
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     if SIDES[side] in (None, 0):
         assert not got[lens == 0].any()
+
+
+# -- rows beyond a length are not computed: poison them all -----------------
+
+# a batch whose lanes end at every width of the last tile, one row either
+# side of a page edge, beside the first batch's edges
+WIDTHS = [P * BLOCK - 1, (P + 1) * BLOCK - 1, (P + 2) * BLOCK + 1,
+          (P + 3) * BLOCK, (P + 4) * BLOCK - 1, (P + 5) * BLOCK + 1,
+          (P + 7) * BLOCK - 1, 2 * P * BLOCK + 1, 1, 0, 777]
+POISONED = {"mixed": MIXED, "widths": WIDTHS}
+
+
+def _poison(pool, lens):
+    """``pool`` with NaN in every row that no lane's length reaches: the
+    rows at and beyond each lane's length in ALL the pages it owns (its
+    last live page's tail and every page past it), beside block 0."""
+    pool = pool.copy()
+    for blocks, n in zip(_owned(), lens):
+        rows = pool[blocks].reshape(M_BLOCKS * BLOCK, -1)
+        rows[n:] = np.nan
+        pool[blocks] = rows.reshape(M_BLOCKS, BLOCK, -1)
+    return pool
+
+
+@pytest.mark.parametrize("side", SIDES)
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("lengths", POISONED)
+def test_no_row_beyond_a_length_is_computed(lengths, layout, side):
+    """The table names every page a lane owns, live or not, and the pools
+    hold NaN in every row at or beyond its length: a dead row that is
+    still computed (a weight of 0 on it is NaN in the MXU) shows."""
+    lens = np.asarray(POISONED[lengths], np.int32)
+    pools, both = _setup(layout, side)
+    _check(*both(*(_poison(p, lens) for p in pools),
+                 jnp.asarray(_owned(), jnp.int32), jnp.asarray(lens)),
+           "a dead (poisoned) row was computed")
+
+
+@pytest.mark.parametrize("side", SIDES)
+@pytest.mark.parametrize("lengths", POISONED)
+def test_no_latent_row_beyond_a_length_is_computed(lengths, side):
+    lens = np.asarray(POISONED[lengths], np.int32)
+    pool, both = _mla_setup(side)
+    _check(*both(_poison(pool, lens), jnp.asarray(_owned(), jnp.int32),
+                 jnp.asarray(lens)),
+           "a dead (poisoned) row was computed")
+
+
+# -- walk_rows is the host's count of what the kernel computes ---------------
+
+@pytest.mark.parametrize("length", [
+    (P + 2) * BLOCK + 1, 2 * P * BLOCK, 5 * BLOCK - 7],
+    ids=["tile+2pages+1", "two_tiles", "5pages-7"])
+@pytest.mark.parametrize("kernel", ["flash", "mla"])
+def test_walk_rows_is_what_the_kernel_computes(kernel, length):
+    """Every rank update of the walk goes through ``_softmax_update``: the
+    widths of the score tiles it was really given (a callback inside the
+    interpreted body, so an update under a ``pl.when`` that did not fire
+    is not counted) sum to ``walk_rows`` of the lane's length.  Poisoning
+    cannot count them: the rows of the last tile beyond the length are
+    computed and, by design, never show."""
+    fd = importlib.import_module("tpudist.ops.flash_decode")
+    update, widths = fd._softmax_update, []
+
+    def counted(m, l, acc, s, *rest, **kw):
+        jax.debug.callback(lambda n=s.shape[1]: widths.append(n))
+        update(m, l, acc, s, *rest, **kw)
+
+    lens = jnp.asarray([0, length, 0], jnp.int32)
+    table = jnp.asarray(1 + np.arange(3 * M_BLOCKS).reshape(3, M_BLOCKS),
+                        jnp.int32)
+    # the calls are jitted: neither an earlier trace may answer here nor
+    # this one, with its callback, later
+    jitted = fd._paged_mla_one if kernel == "mla" else fd._paged_decode_one
+    jitted.clear_cache()
+    try:
+        with mock.patch.object(fd, "_softmax_update", counted):
+            if kernel == "mla":
+                out = paged_mla_decode(
+                    jnp.ones((3, MLA_HEADS, MLA_W)),
+                    jnp.ones((3 * M_BLOCKS + 1, BLOCK, MLA_W)), table, lens,
+                    d_v=MLA_DV, scale=MLA_SCALE, interpret=True)
+            else:
+                pool = jnp.ones((3 * M_BLOCKS + 1, BLOCK, 16))
+                out = paged_flash_decode(
+                    jnp.ones((3, 1, HEADS, 16)), pool, pool, table, lens,
+                    packed_kv_heads=1, interpret=True)
+            jax.block_until_ready(out)
+            jax.effects_barrier()
+    finally:
+        jitted.clear_cache()
+    assert sum(widths) == walk_rows(length, BLOCK, P)
+    assert walk_rows(length, BLOCK, P) - length < BLOCK
+    assert walk_rows(0, BLOCK, P) == 0

@@ -197,6 +197,41 @@ def test_released_lane_walks_no_page(lm, chunked):
 
 
 @pytest.mark.parametrize("layout,chunked", CASES)
+def test_rows_computed_cover_the_live_rows(lm, layout, chunked):
+    """``rows`` is what the paged kernel's arithmetic covers for the lanes
+    live at a segment's dispatch (``walk_rows`` of each length: whole
+    steps of the last tile's width), ``rows_live`` the lengths themselves;
+    both counters tick ``x steps_run`` like the pages', and the dense
+    layout counts none."""
+    from tpudist.ops.flash_decode import paged_tile_pages, walk_rows
+
+    loop = make_loop(lm, layout, chunked)
+    computed = obs.counter("serve/decode_rows_computed")
+    live = obs.counter("serve/decode_rows_live")
+    before = computed.value(), live.value()
+    _, sp = run_traced(loop, requests([5, 19, 30, 9], [6, 3, 2 * STEPS, 1]))
+    drains = [e["args"] for e in sp["serve/segment_drain"]]
+    assert drains and all("rows" in a and "rows_live" in a for a in drains)
+    assert (computed.value() - before[0]
+            == sum(a["rows"] * a["steps_run"] for a in drains))
+    assert (live.value() - before[1]
+            == sum(a["rows_live"] * a["steps_run"] for a in drains))
+    if layout == "dense":
+        assert not any(a["rows"] or a["rows_live"] for a in drains)
+        return
+    block = loop.kv_block_size
+    per_tile = paged_tile_pages(block, loop.pool.max_blocks_per_slot)
+    granule = walk_rows(1, block, per_tile)  # the last tile's width step
+    assert walk_rows(granule + 1, block, per_tile) == 2 * granule
+    for a in drains:
+        assert a["rows"] >= a["rows_live"] and a["rows"] % granule == 0
+        assert a["rows"] >= a["pages"] * block
+        # less than a granule a lane is computed beyond its length
+        assert a["rows"] - a["rows_live"] < SLOTS * granule
+    assert any(a["rows_live"] > 0 for a in drains)
+
+
+@pytest.mark.parametrize("layout,chunked", CASES)
 def test_chunks_count_the_prompt(lm, layout, chunked):
     lengths = [1, CHUNK, CHUNK + 1, 3 * CHUNK + 5]
     done = make_loop(lm, layout, chunked).run(requests(lengths, [2] * 4))

@@ -394,6 +394,11 @@ class BlockPool:
         host's own count, in blocks — what a decode step walks for it."""
         return blocks_for(self._watermark[slot], self.block_size)
 
+    def covered_rows(self, slot: int) -> int:
+        """``slot``'s coverage watermark itself: the rows of its KV length
+        by the host's own count, the number ``covered_pages`` rounds up."""
+        return self._watermark[slot]
+
     def grow(self, slot: int, steps: int) -> None:
         """Advance ``slot``'s coverage by ``steps`` decode tokens (capped
         at its reservation), allocating from the reserved budget — this

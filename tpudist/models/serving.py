@@ -66,6 +66,7 @@ from tpudist.models.speculative import (
     _set_cache_index,
 )
 from tpudist.models.transformer import TransformerConfig, TransformerLM
+from tpudist.ops.flash_decode import paged_tile_pages, walk_rows
 
 # placeholder page row for the dense layout's admit signature (the insert
 # walk never reaches a paged node there)
@@ -727,6 +728,15 @@ class ServeLoop:
         # table a walk touches
         self._obs_pages_walked = obs.counter("serve/decode_pages_walked",
                                              unit="pages")
+        # the rows the kernel's arithmetic covered for those pages
+        # (ops.flash_decode.walk_rows of each live lane's length) and the
+        # live ones among them (the lengths), ticked the same way: live
+        # over computed is the share of the kernel's scores, exponentials
+        # and MXU passes that land on a row the softmax keeps
+        self._obs_rows_computed = obs.counter("serve/decode_rows_computed",
+                                              unit="rows")
+        self._obs_rows_live = obs.counter("serve/decode_rows_live",
+                                          unit="rows")
         # expert layers: tokens the held experts were given over a drained
         # segment's steps (all lanes, as lane_steps counts them), the
         # busiest (layer, expert)'s part of that, and the (step, layer,
@@ -2703,8 +2713,11 @@ class ServeLoop:
                            if st is not None and not st.get("zombie"))
                 k = (self._spec_k(live)
                      if self.decode_mode == "speculative" else 0)
-                pages = 0
+                pages = rows = rows_live = 0
                 if self.pool is not None:
+                    block = self.pool.block_size
+                    per_tile = paged_tile_pages(
+                        block, self.pool.max_blocks_per_slot)
                     # grow-on-decode-boundary: advance every live lane's
                     # page coverage by the segment's worst case (drawn
                     # from its admit-time reservation, so this cannot
@@ -2725,6 +2738,9 @@ class ServeLoop:
                             # decodes there yet, and their prompt
                             # coverage was allocated at admit
                             pages += self.pool.covered_pages(slot)
+                            held = self.pool.covered_rows(slot)
+                            rows += walk_rows(held, block, per_tile)
+                            rows_live += held
                             self.pool.grow(slot, n + k)
                     self._stamp_table()
             # the segment splits per-step keys and returns the advanced
@@ -2768,7 +2784,7 @@ class ServeLoop:
             except AttributeError:  # non-jax array (test doubles)
                 pass
             inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
-                             pages))
+                             (pages, rows, rows_live)))
             seq += 1
             self._obs_depth.set(len(inflight))
             # fault harness: a configured kill-after-K-segments SIGKILLs
@@ -2797,9 +2813,11 @@ class ServeLoop:
             layout: the pages under the live lanes' lengths when the
             segment was dispatched, by the pool's own count — what one
             call of the decode kernel walks; a lane frozen on the device
-            that the host has not drained yet is still counted)."""
+            that the host has not drained yet is still counted), with
+            ``rows`` (what the kernel's arithmetic covers for those lanes,
+            ``walk_rows`` of each length) and ``rows_live`` (the lengths)."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
-             t_disp, pages) = inflight.popleft()
+             t_disp, (pages, rows, rows_live)) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
                    and "seq" in st and st["seq"] <= s_idx
@@ -2893,6 +2911,8 @@ class ServeLoop:
                 self._obs_decode_steps.inc(steps_run)
                 self._obs_lane_steps.inc(self.B * steps_run)
                 self._obs_pages_walked.inc(pages * steps_run)
+                self._obs_rows_computed.inc(rows * steps_run)
+                self._obs_rows_live.inc(rows_live * steps_run)
                 routed = {}
                 if self._expert_blocks:
                     n_cells = len(self._expert_blocks) * self._held
@@ -2907,7 +2927,7 @@ class ServeLoop:
                     "serve/segment_drain", t_fetched, time.perf_counter(),
                     seq=s_idx, steps=n_disp, steps_run=steps_run,
                     lanes=lanes, tokens=tokens, first_tokens=first_tokens,
-                    pages=pages, **routed)
+                    pages=pages, rows=rows, rows_live=rows_live, **routed)
             # zombie refund: every segment dispatched before the kill
             # (index < free_at) has drained once s_idx reaches
             # free_at - 1 — no stale merge can touch the blocks now

@@ -64,18 +64,26 @@ _NEG_BIG = -1e30
 _DISABLE_PAIRING = env_flag("TPUDIST_DISABLE_HEAD_PAIRING")
 
 
-def _softmax_update(m_scr, l_scr, acc_scr, s, pv_scale, vb):
+def _softmax_update(m_scr, l_scr, acc_scr, s, pv_scale, vb, also=None):
     """One online-softmax rank update of the f32 ``m`` / ``l`` / ``acc``
     scratch from masked scores ``s`` and the value tile ``vb``
     (``pv_scale`` folds per-token V scales into the probability rows;
-    None for the bf16 path).  Shared by every decode kernel body."""
+    None for the bf16 path).  ``also``: a second ``(scores, values)`` pair
+    that joins the SAME update (one max, one rescale of ``acc``).  Shared
+    by every decode kernel body."""
     m = m_scr[:]
-    new_m = jnp.maximum(m, jnp.maximum(
-        jnp.max(s, axis=-1, keepdims=True), _NEG_BIG))
+    top = jnp.max(s, axis=-1, keepdims=True)
+    if also is not None:
+        top = jnp.maximum(top, jnp.max(also[0], axis=-1, keepdims=True))
+    new_m = jnp.maximum(m, jnp.maximum(top, _NEG_BIG))
     p = jnp.exp(s - new_m)
     corr = jnp.exp(m - new_m)
     m_scr[:] = new_m
-    l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    if also is not None:
+        p2 = jnp.exp(also[0] - new_m)
+        total = total + jnp.sum(p2, axis=-1, keepdims=True)
+    l_scr[:] = l_scr[:] * corr + total
     if pv_scale is not None:
         vs = pv_scale                            # [rows, bk]
         if vs.shape[0] == 2:
@@ -90,9 +98,14 @@ def _softmax_update(m_scr, l_scr, acc_scr, s, pv_scale, vb):
         pv = pv32.astype(jnp.bfloat16)
     else:
         pv = p.astype(vb.dtype)
-    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+    out = jax.lax.dot_general(
         pv, vb, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    if also is not None:
+        out = out + jax.lax.dot_general(
+            p2.astype(also[1].dtype), also[1], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    acc_scr[:] = acc_scr[:] * corr + out
 
 
 def _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr):
@@ -288,11 +301,26 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     holds a page starts during the last tile of this one (``state_ref``
     carries its slot across grid rows), so a row's DMA latency hides behind
     its neighbour's work.  A lane needs ``ceil(len / block)`` pages: no
-    copy is started, waited for or stepped over beyond that, and a lane of
-    length 0 costs the side-buffer update and the output write alone.  The
-    arithmetic is ``_decode_kernel``'s (f32 ``m`` / ``l`` / ``acc``,
-    operands in their own dtype into the MXU, ``k_pos < len`` masked on
-    every tile).
+    copy is started, waited for or stepped over beyond that.
+
+    The arithmetic follows the copies.  What a lane pays for, on a v5e, is
+    each RANK UPDATE (about 0.6 us of dependent latency: scores, max, exp,
+    sum, P.V, the ``acc`` rescale, whatever the width) and each PAGE it
+    computes (its two products at the MXU's rate); masks and selects ride
+    free beside them.  So a tile before the lane's last is full and takes
+    one unmasked update; the LAST tile takes one update at the width its
+    live pages need (in steps of :func:`_width_step` pages), masked at the
+    length, and the side buffer's rows join that same update instead of
+    one of their own; a lane of length 0 costs the side buffer's update
+    and the output write alone.  Per live row it is ``_decode_kernel``'s
+    arithmetic (f32 scores scaled in f32, f32 ``m`` / ``l`` / ``acc``,
+    operands in their own dtype into the MXU).
+
+    No row that was not copied is computed, and in the masked update every
+    value row at or beyond the length is set to 0 before the MXU sees it
+    (a weight of 0 on a row that is not finite would be NaN), so the tile
+    slots need no zero fill: what a slot's uncopied pages hold never
+    reaches a product.
 
     The walk serves two layouts.  ``d_v`` None: TWO pools (keys, values)
     of one width, each with its side buffer (``paged_flash_decode``).
@@ -309,7 +337,6 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     q_scr = rest[5] if paired else None
     g = pl.program_id(0)
     lane, r = g // r_kv, g % r_kv
-    tile = pages_per_tile * block
     d = bufs[0].shape[-1]
 
     def values(keys, load_values):
@@ -354,12 +381,13 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     def next_live_row():
         """The next grid row that holds a page: this lane's next chunk,
         else chunk 0 of the next lane whose length is not 0 (``lanes`` if
-        there is none)."""
-        nxt = jax.lax.fori_loop(
-            lane + 1, lanes,
-            lambda i, c: jnp.where(
-                jnp.logical_and(c == lanes, lane_len(i) > 0), i, c),
-            jnp.int32(lanes))
+        there is none).  The scan stops at the first such lane: it runs on
+        the scalar unit with nothing beside it, and a scan of every lane
+        left cost a row of 128 lanes half a microsecond."""
+        nxt = jax.lax.while_loop(
+            lambda i: jnp.logical_and(
+                i < lanes, lane_len(jnp.minimum(i, lanes - 1)) == 0),
+            lambda i: i + 1, lane + 1)
         if r_kv == 1:
             return nxt, 0
         more = r + 1 < r_kv
@@ -370,9 +398,6 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         # state = [slot of the pending first tile, 1 if it is in flight]
         state_ref[0] = 0
         state_ref[1] = 0
-        # a slot's rows past the live pages of a tile are never copied
-        # into: whatever VMEM held there would reach acc as 0 * garbage
-        bufs[-1][...] = jnp.zeros_like(bufs[-1])
 
     _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr)
 
@@ -380,7 +405,46 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         return q_scr[:] if paired else q_ref[0]
 
     cache_len = lane_len(lane)
-    n_tiles = (lane_pages(lane) + pages_per_tile - 1) // pages_per_tile
+    n_pages = lane_pages(lane)
+    n_tiles = (n_pages + pages_per_tile - 1) // pages_per_tile
+
+    def scores(keys, live):
+        """f32 scores of ``keys``' rows, -inf from row ``live`` on."""
+        s = jax.lax.dot_general(
+            q_tile(), keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if live is None:
+            return s
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        return jnp.where(col < live, s, -jnp.inf)
+
+    def side_tiles():
+        """The side buffer's ``(scores, values)``: its first ``side_len``
+        positions join the same online softmax as the main cache."""
+        keys = sides[0][0]
+        return scores(keys, meta_ref[0]), values(keys, lambda: sides[-1][0])
+
+    def attend(slot, n, live=None):
+        """One rank update over the first ``n`` pages of ``slot``.  ``live``
+        None: a tile before the last, every row under the length.  Else
+        the lane's last tile, ``live`` of its rows under the length: the
+        scores of the others are -inf, their values 0, and the side
+        buffer's rows join the update."""
+        rows = n * block
+
+        def load(buf, cleaned):
+            x = buf[slot, :n].reshape(rows, d)
+            if live is None or not cleaned:
+                return x
+            row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+            return jnp.where(row < live, x, jnp.zeros_like(x))
+
+        # one pool: its rows are the values too, so the keys are cleaned
+        keys = load(bufs[0], d_v is not None)
+        _softmax_update(
+            m_scr, l_scr, acc_scr, scores(keys, live), None,
+            values(keys, lambda: load(bufs[-1], True)),
+            also=side_tiles() if side and live is not None else None)
 
     @pl.when(n_tiles > 0)
     def _walk():
@@ -410,24 +474,65 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                     state_ref[1] = 1
 
             wait(lane, r, t, slot)
-            keys = bufs[0][slot].reshape(tile, d)
-            s = jax.lax.dot_general(
-                q_tile(), keys, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            k_pos = t * tile + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos < cache_len, s, -jnp.inf)
-            _softmax_update(
-                m_scr, l_scr, acc_scr, s, None,
-                values(keys, lambda: bufs[-1][slot].reshape(tile, d)))
+
+            @pl.when(t + 1 < n_tiles)
+            def _full_tile():
+                attend(slot, pages_per_tile)
+
+            @pl.when(t + 1 == n_tiles)
+            def _last_tile():
+                # one body a width: the widths are static shapes
+                held = n_pages - t * pages_per_tile
+                step = _width_step(pages_per_tile)
+                for lo in range(0, pages_per_tile, step):
+                    n = min(lo + step, pages_per_tile)
+
+                    @pl.when(jnp.logical_and(held > lo, held <= n))
+                    def _at_width(n=n):
+                        attend(slot, n,
+                               cache_len - t * pages_per_tile * block)
 
         jax.lax.fori_loop(0, n_tiles, one_tile, None)
 
     if side:
-        _side_update(m_scr, l_scr, acc_scr, q_tile(), sides[0][0],
-                     lambda keys: values(keys, lambda: sides[-1][0]),
-                     meta_ref[0], scale)
+        @pl.when(n_tiles == 0)
+        def _side_alone():
+            s, vals = side_tiles()
+            _softmax_update(m_scr, l_scr, acc_scr, s, None, vals)
+
     _softmax_finalize(l_scr, acc_scr, o_ref, paired)
+
+
+def _width_step(pages_per_tile: int) -> int:
+    """Pages between two widths the last tile of a paged walk can be
+    computed at: an eighth of a tile, rounded up (one page at the serving
+    cells' 8 pages a tile).  Each width is a body of its own, lowered once
+    a program."""
+    return -(-pages_per_tile // 8)
+
+
+def paged_tile_pages(block: int, m_blocks: int) -> int:
+    """Pages a tile of the paged walk holds: about 1024 tokens, big enough
+    that a rank update's fixed cost and a copy's latency are small beside
+    it, small enough that two slots of every pool stay a small part of
+    VMEM; never more than a lane's table row."""
+    return max(1, min(m_blocks, 1024 // block))
+
+
+def walk_rows(length: int, block: int, pages_per_tile: int) -> int:
+    """The cache rows :func:`_paged_decode_kernel`'s arithmetic covers for
+    ONE lane of ``length`` under pages of ``block`` rows, ``pages_per_tile``
+    (:func:`paged_tile_pages`) a tile: the tiles before the last whole, the
+    last at the width its live pages need.  The host's count of what a call
+    computes (``serve/decode_rows_computed``), held to the kernel by
+    ``tests/test_paged_decode_walk.py``."""
+    pages = -(-length // block)
+    if not pages:
+        return 0
+    before = (pages - 1) // pages_per_tile * pages_per_tile
+    step = _width_step(pages_per_tile)
+    width = min(-(-(pages - before) // step) * step, pages_per_tile)
+    return (before + width) * block
 
 
 def _pick_block_k(s: int, block_k: int) -> int:
@@ -994,10 +1099,7 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
         out_spec = pl.BlockSpec((1, gp, d_out), lambda g_, m: (g_, 0, 0))
         out_shape = (q3.shape[0], gp, d_out)
     R = r_kv  # noqa: N806 — closed over by the index maps
-    # a tile of about 1024 tokens: big enough that a grid row's fixed
-    # cost and a copy's latency are small beside it, small enough that
-    # two slots of every pool stay a small part of VMEM
-    pages_per_tile = max(1, min(m_blocks, 1024 // block))
+    pages_per_tile = paged_tile_pages(block, m_blocks)
     # the pools are left where they are (HBM): the kernel's own copies
     # fetch the pages a lane really holds, by the ids in meta
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
