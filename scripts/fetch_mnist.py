@@ -3,8 +3,8 @@
 The reference trains on torchvision MNIST to >=97% test accuracy
 (`/root/reference/pytorch_elastic/mnist_ddp_elastic.py:166-171`); this
 image has no bundled dataset, so real-MNIST parity is a gate that arms
-itself the moment data exists (``tests/test_real_mnist.py``,
-``bench.py: real_mnist``).  Run this script to make that happen:
+itself the moment data exists (``tests/test_real_mnist.py``).  Run this
+script to make that happen:
 
     python scripts/fetch_mnist.py [--dest data/MNIST/raw]
 
@@ -131,8 +131,7 @@ def main() -> int:
     dest = Path(args.dest)
     if fetch(dest):
         print(f"real MNIST ready in {dest} — the parity gate "
-              "(tests/test_real_mnist.py) and the bench.py real_mnist "
-              "line are now armed")
+              "(tests/test_real_mnist.py) is now armed")
         return 0
     print(
         "\nNo egress (or all mirrors unreachable).  To arm the real-MNIST\n"

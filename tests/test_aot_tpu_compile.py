@@ -410,7 +410,7 @@ def _compile_or_report(fn, *args) -> str:
 def test_report_fused_moe_mlp(v5e):
     from tpudist.ops.moe_dispatch import fused_moe_mlp
 
-    tokens, d, f, experts, top_k = 4096, 512, 2048, 8, 2  # bench.py on chip
+    tokens, d, f, experts, top_k = 4096, 512, 2048, 8, 2
     hlo = _compile_or_report(
         lambda x, wu, wd, i, g: fused_moe_mlp(x, wu, wd, i, g),
         _sds(v5e, (tokens, d)), _sds(v5e, (experts, d, f)),
@@ -422,7 +422,7 @@ def test_report_fused_moe_mlp(v5e):
 def test_report_fused_group_norm(v5e):
     from tpudist.ops.group_norm import group_norm_add_relu
 
-    # a ResNet50 stage-1 Bottleneck tail at bench.py's batch 128 @ 128 px
+    # a ResNet50 stage-1 Bottleneck tail at batch 128 @ 128 px
     x = _sds(v5e, (128, 32, 32, 256))
     c = _sds(v5e, (256,), jnp.float32)
 

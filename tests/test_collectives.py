@@ -255,6 +255,35 @@ class TestRingAllreduce:
 
         assert "coll/compress_ratio" in obs.snapshot()["gauges"]
 
+    def test_ring_fetches_within_bound_and_below_flat(self, server):
+        """The cost model: at world w the ring FETCHES at most
+        2(w-1)/w x the tree per rank where the flat gather fetches
+        (w-1) x, and bf16 on the wire halves the ring's again."""
+        world = 4
+        data = np.random.default_rng(5).standard_normal(
+            64 * 1024).astype(np.float32)          # 256 KiB
+
+        def fetched(rid, algo, compress):
+            def fn(rank, client):
+                coll = HostCollectives(
+                    client, rank, world, round_id=rid,
+                    config=CollectiveConfig(algorithm=algo,
+                                            compress=compress,
+                                            bucket_bytes=64 << 10))
+                coll.allreduce_sum({"g": data * (rank + 1)})
+                n = coll.bytes_fetched
+                coll.close()
+                return n
+
+            return max(_run_world(server, world, fn))
+
+        flat = fetched(210, "flat", "none")
+        ring = fetched(211, "ring", "none")
+        bf16 = fetched(212, "ring", "bf16")
+        assert ring <= 2 * (world - 1) / world * data.nbytes * 1.05
+        assert ring < flat, (ring, flat)
+        assert bf16 <= 0.55 * ring, (bf16, ring)
+
     def test_mixed_dtypes_stay_exact_under_compression(self, server):
         """Compression applies to float32 only: int64 / f64 / bool groups
         ride the wire raw and reduce exactly (the root-election score and
@@ -440,6 +469,37 @@ class TestAsyncHandles:
         np.testing.assert_array_equal(
             results[0][1]["t"], np.full(3, sum(range(world)), np.float32))
 
+    def test_overlapped_push_matches_sync_accumulation(self, server):
+        """Microbatch gradients pushed through ``OverlappedGradSync``
+        (allreduces in flight behind the next microbatch's compute)
+        accumulate to BITWISE the sum of the same allreduces made one
+        after the other."""
+        from tpudist.elastic.worker import OverlappedGradSync
+
+        world, microbatches = 2, 5
+        grad = np.random.default_rng(2).standard_normal(
+            3000).astype(np.float32)
+
+        def fn(rank, client):
+            coll = HostCollectives(client, rank, world, round_id=213,
+                                   config=_ring_cfg(bucket_bytes=4096))
+            trees = [{"g": grad * (rank + 1 + i)}
+                     for i in range(microbatches)]
+            total = None
+            for t in trees:
+                out = coll.allreduce_sum(t)
+                total = out if total is None else {"g": total["g"] + out["g"]}
+            sync = OverlappedGradSync(coll)
+            for t in trees:
+                sync.push(t)
+            overlapped = sync.reduce()
+            coll.close()
+            return total["g"].tobytes(), overlapped["g"].tobytes()
+
+        results = _run_world(server, world, fn)
+        assert all(a == b for a, b in results)
+        assert len({a for a, _ in results}) == 1
+
     def test_worker_thread_error_reraises_from_wait(self, server):
         """A PeerLost hit on the background worker must surface from
         wait() on the caller's thread, not vanish."""
@@ -543,26 +603,47 @@ class TestHierAllreduce:
         for out in results:
             np.testing.assert_array_equal(out["g"], want)
 
-    def test_cross_host_bytes_meet_host_bound(self, server):
-        """THE perf claim: each rank's cross-host wire traffic is
+    @pytest.mark.parametrize("world,hosts", [(4, 2), (8, 2), (8, 4)])
+    def test_cross_host_bytes_meet_host_bound(self, server, world, hosts):
+        """THE perf claim: a host's cross-host wire traffic is
         2(H-1)/H x tree size — a function of HOSTS, not chips (the flat
-        ring moves 2(w-1)/w x size per rank)."""
-        world, hosts, n = 4, 2, 2048
+        ring moves 2(w-1)/w x size per rank) — and compression applies
+        to that cross wire: bf16 about half of it, topk at most
+        2 x frac (an index and a value per survivor)."""
+        n, frac = 8192, 0.25
+        local = world // hosts
 
-        def fn(rank, client):
-            coll = HostCollectives(client, rank, world, round_id=41,
-                                   config=_hier_cfg(hosts=hosts))
-            coll.allreduce_sum({"g": np.ones(n, np.float32) * rank})
-            moved = coll.bytes_posted_cross + coll.bytes_fetched_cross
-            coll.close()
-            return moved
+        def per_host(rid, compress):
+            def fn(rank, client):
+                coll = HostCollectives(
+                    client, rank, world, round_id=rid,
+                    config=_hier_cfg(hosts=hosts, compress=compress,
+                                     topk_frac=frac))
+                coll.allreduce_sum(
+                    {"g": np.ones(n, np.float32) * (rank % 3 + 1)})
+                moved = (coll.bytes_posted_cross
+                         + coll.bytes_fetched_cross)
+                fetched = coll.bytes_fetched_cross
+                coll.close()
+                return moved, fetched
 
-        results = _run_world(server, world, fn)
+            results = _run_world(server, world, fn)
+            return results, max(
+                sum(results[h * local + j][1] for j in range(local))
+                for h in range(hosts))
+
+        base = 220 + 10 * world + 3 * hosts
+        results, dense = per_host(base, "none")
         bound = 2 * (hosts - 1) / hosts * (n * 4)
-        for moved in results:
+        for moved, _ in results:
             assert moved <= bound * 1.05, (moved, bound)
+        assert dense <= bound * 1.05, (dense, bound)
         # and it actually rode the cross wire (not degenerate zero)
-        assert max(results) > 0
+        assert dense > 0
+        _, bf16 = per_host(base + 1, "bf16")
+        _, topk = per_host(base + 2, "topk")
+        assert bf16 <= 0.55 * dense, (bf16, dense)
+        assert topk <= (2 * frac + 0.05) * dense, (topk, dense)
 
     def test_hier_falls_back_to_ring_when_hosts_dont_divide(self, server):
         """An elastic shrink to a non-divisible world must not wedge:
@@ -982,6 +1063,48 @@ class TestOverlappedGradSyncBucketed:
                 np.testing.assert_array_equal(
                     results[0][step][n],
                     np.full(50, world * i + 1, np.float32))
+
+
+    def test_bucketed_dp_grad_sync_is_the_full_batch_gradient(self, server):
+        """The dp gradient leg over the host plane: each rank's shard
+        gradient (weighted by its share of the batch) streamed leaf by
+        leaf in backward order sums to BITWISE the one-shot allreduce of
+        the same leaves, and to the gradient of the whole batch."""
+        from tpudist.elastic.worker import OverlappedGradSync
+
+        world = 2
+        rng = np.random.default_rng(4)
+        x = rng.standard_normal((8, 6)).astype(np.float32)
+        y = rng.standard_normal((8, 3)).astype(np.float32)
+        w = rng.standard_normal((6, 3)).astype(np.float32)
+        b = np.zeros(3, np.float32)
+
+        def grads(xs, ys):                 # d/d(w, b) of mean squared error
+            err = xs @ w + b - ys
+            return {"w": 2 * xs.T @ err / err.size,
+                    "b": 2 * err.sum(0) / err.size}
+
+        full = grads(x, y)
+        shards = [(x[:4], y[:4]), (x[4:], y[4:])]
+
+        def fn(rank, client):
+            coll = HostCollectives(client, rank, world, round_id=214,
+                                   config=_ring_cfg(bucket_bytes=256))
+            leaves = {k: (v * (len(shards[rank][0]) / len(x))).astype(
+                np.float32) for k, v in grads(*shards[rank]).items()}
+            one_shot = coll.allreduce_sum(leaves)
+            sync = OverlappedGradSync(coll, bucket_bytes=48)
+            for name in reversed(list(leaves)):
+                sync.grad_ready(name, leaves[name])
+            bucketed = sync.reduce()
+            coll.close()
+            return one_shot, bucketed
+
+        for one_shot, bucketed in _run_world(server, world, fn):
+            for name in full:
+                assert one_shot[name].tobytes() == bucketed[name].tobytes()
+                np.testing.assert_allclose(bucketed[name], full[name],
+                                           rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.slow

@@ -1,5 +1,5 @@
 """The DeepSeek-V3 block through the program against the plain reference
-(``tpudist/models/reference/deepseek_v3.py``), on the CPU at a small size
+(``benchmarks/reference/deepseek_v3.py``), on the CPU at a small size
 with every ratio of the published model kept: 4 heads, latents 96 / 64,
 nope 32, rope 16, v 32, 16 experts in 4 groups of which 2 are eligible, 4 a
 token, 4 held (experts 8-11: the seeded bias favours their group), a
@@ -23,7 +23,7 @@ from tpudist.models import (MLAConfig, MoEConfig, MoEMLP, Request, ServeLoop,
                             TransformerConfig, TransformerLM, YarnScaling)
 from tpudist.models import moe as moe_lib
 from tpudist.models.generate import _blank_cache
-from tpudist.models.reference import deepseek_v3 as ref
+from benchmarks.reference import deepseek_v3 as ref
 from tpudist.models.transformer import LatentSelfAttention
 
 VOCAB, EMBED, SEQ = 128, 256, 128

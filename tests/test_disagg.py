@@ -425,6 +425,57 @@ class TestTwoStageUnit:
         assert comps[0].reason == "length"
         assert seen_refs == [f"{ns}/kv/00000000"]
 
+    @pytest.mark.parametrize("with_ref", [True, False],
+                             ids=["pages-moved", "no-payload"])
+    def test_migrate_commit_is_a_stage_not_a_terminal(self, with_ref):
+        """Fast drain's router half: a draining replica answers with a
+        reason="migrate" commit.  With a payload ref the request goes
+        on to a peer as a decode-stage dispatch carrying the ref (one
+        journaled migration, no fallback); without one it reverts to an
+        ordinary prefill there and the fallback is counted.  Either way
+        exactly one terminal, nothing left in the journal."""
+        fc = FakeCoord()
+        ns = "mig1"
+        _register(fc, ns, "a", 0)
+        _register(fc, ns, "b", 1)
+        ref = f"{ns}/kv/00000000" if with_ref else None
+        seen = []
+
+        def on_set(key, value):
+            if key.startswith(f"{ns}/inbox/a/"):
+                req = _decode_request(value)
+                fc.kv.pop(key, None)
+                fc.kv[f"{ns}/draining/a"] = b"1"   # steered around from now
+                doc = {"key": req.rid, "tokens": [], "reason": "migrate",
+                       "replica": "a"}
+                if ref:
+                    doc["handoff_ref"] = ref
+                fc.kv[f"{ns}/done/{req.rid}"] = json.dumps(doc).encode()
+            elif key.startswith(f"{ns}/inbox/b/"):
+                req = _decode_request(value)
+                seen.append(req.kv_handoff)
+                fc.kv.pop(key, None)
+                fc.kv[f"{ns}/done/{req.rid}"] = json.dumps(
+                    {"key": req.rid,
+                     "tokens": [int(req.prompt[0]), int(req.prompt.size)],
+                     "reason": "length", "replica": "b"}).encode()
+
+        fc.on_set = on_set
+        m0 = _counter("router/migrations")
+        f0 = _counter("router/migration_fallbacks")
+        req = _requests(1)[0]
+        # 'a' is picked first: 'b' already carries load
+        fc.kv[f"{ns}/inbox/b/zz-busy"] = b"x"
+        comps = _router(fc, ns).run([req], timeout_s=10.0)
+        assert [(c.rid, c.reason) for c in comps] == [(req.rid, "length")]
+        assert comps[0].tokens.tolist() == [int(req.prompt[0]),
+                                            int(req.prompt.size)]
+        assert seen == [{"handoff_ref": ref} if with_ref else None]
+        assert _counter("router/migrations") - m0 == 1
+        assert _counter("router/migration_fallbacks") - f0 == \
+            (0 if with_ref else 1)
+        assert fc.keys(f"{ns}/journal/") == []
+
     def test_prefill_pool_empty_decode_stage_still_flows(self):
         """Stage pools are independent: with only a decode replica
         live, a fresh (prefill-stage) request waits un-dispatched

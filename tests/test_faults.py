@@ -381,6 +381,8 @@ class TestControlPlaneCrashKnobs:
         # a retry budget comfortably longer than the window (naps are
         # >= 20 ms each), so the gate deterministically outlives it
         client = CoordClient("127.0.0.1", server.port, retries=30)
+        stretches = obs.snapshot()["histograms"].get(
+            "coord/outage_s", {}).get("count", 0)
         faults.install(FaultPlan(coord_outage_at_s=0.0,
                                  coord_outage_s=0.15))
         try:
@@ -389,9 +391,12 @@ class TestControlPlaneCrashKnobs:
             assert faults.plan().injected["coord_outage"] >= 1
         finally:
             faults.reset()
-        b = obs.snapshot()["histograms"].get(
-            "coord/retry_backoff_s", {}).get("count", 0)
-        assert b >= 1
+        snap = obs.snapshot()
+        assert snap["histograms"].get(
+            "coord/retry_backoff_s", {}).get("count", 0) >= 1
+        # the store is reachable again and the stretch went on record
+        assert snap["gauges"]["coord/unavailable"]["value"] == 0
+        assert snap["histograms"]["coord/outage_s"]["count"] > stretches
 
 
 class TestIntegrityKnobs:
@@ -427,7 +432,7 @@ class TestIntegrityKnobs:
         """'2:2': payloads 2 and 4 get ONE bit flipped past the frame
         header (so the CHECKSUM, not a parse error, is what catches
         it); the cap then disarms the injection — the transient shape
-        whose reinstatement path the quarantine bench drives."""
+        whose reinstatement path the fleet test drives."""
         from tpudist.runtime import wire
 
         plan = FaultPlan(flip_wire_bits="2:2")

@@ -222,6 +222,41 @@ class TestServeTier:
         assert loop.pool.free_blocks == loop.pool.num_blocks
         assert loop.tier_drained() is True
 
+    def test_tier_multiplies_reusable_prefix_capacity(self, params):
+        """Four tenants' 3-block prefixes asked round-robin through a
+        7-block pool: each tenant's pages are evicted before its next
+        ask.  With the host tier the evicted pages stay reusable — at
+        least twice the cached prefix blocks for the same pool, and
+        hits where the pool alone finds fewer — with the same tokens
+        for every request, none lost, both levels drained."""
+        prompts = [_prompt(300 + t, 52) for t in range(4)]
+        reqs = [(f"r{rnd}t{t}", np.concatenate(
+                    [prompts[t], _prompt(400 + 4 * rnd + t, 2 + rnd)]))
+                for rnd in range(3) for t in range(4)]
+
+        def arm(tier_bytes):
+            loop = _tier_loop(params, tier_bytes, kv_num_blocks=7)
+            got = {}
+            for rid, prompt in reqs:
+                for c in loop.run([Request(prompt, 6, rid=rid)]):
+                    got[c.rid] = tuple(c.tokens.tolist())
+            cached = len(loop._prefix_cache._entries) + (
+                len(loop._tier) if loop._tier is not None else 0)
+            hits = loop.prefix_stats["hits"]
+            loop.flush_prefix_cache()
+            loop.pool.check()
+            assert loop.pool.free_blocks == loop.pool.num_blocks
+            assert loop.tier_drained() in (None, True)
+            return got, cached, hits
+
+        plain, plain_cached, plain_hits = arm(0)
+        tiered, tiered_cached, tiered_hits = arm(8 << 20)
+        assert sorted(tiered) == sorted(rid for rid, _ in reqs)  # none lost
+        assert tiered == plain
+        assert tiered_cached >= 2 * plain_cached, (tiered_cached,
+                                                   plain_cached)
+        assert tiered_hits > plain_hits, (tiered_hits, plain_hits)
+
     def test_env_zero_disables_tier(self, params):
         loop = _tier_loop(params, 0, kv_num_blocks=7)
         assert loop._tier is None and loop.tier_drained() is None
@@ -251,6 +286,10 @@ class TestPrefixExportInstall:
         [c] = peer.run([Request(prompt, 8, rid="q")])
         np.testing.assert_array_equal(c.tokens, _want(params, prompt, 8))
         assert peer.prefix_stats["hits"] - hits0 == 1  # adopted, not re-prefilled
+        for loop in (owner, peer):
+            loop.flush_prefix_cache()
+            assert loop.pool.used_blocks == 0
+            assert loop.tier_drained() is True
 
     def test_export_continues_into_tier(self, params):
         """An owner whose pages spilled must still export them: the

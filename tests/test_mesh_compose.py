@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from tpudist import obs
-from tpudist.parallel import mesh_bench
 from tpudist.parallel.mesh import (
     MeshSpec,
     make_composed_state,
@@ -22,6 +21,7 @@ from tpudist.parallel.mesh import (
 from tpudist.parallel.pipeline import (
     interleave_params,
     make_1f1b_pipeline_train_step,
+    make_stacked_pipeline_train_step,
     stacked_state_specs,
     state_specs_like,
 )
@@ -29,65 +29,248 @@ from tpudist.train.state import TrainState
 
 
 # ---------------------------------------------------------------------------
+# set-up shared by the matrix and the grow/shrink test
+# ---------------------------------------------------------------------------
+
+def _assert_bitwise(got, want):
+    """Two pytrees of arrays, leaf for leaf, byte for byte."""
+    la, lb = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(la) == len(lb)
+    for a, b in zip(la, lb):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.tobytes() == b.tobytes(), (
+            f"max_abs_diff {np.max(np.abs(a.astype(np.float64) - b))}")
+
+
+def _run(step, state, batch, steps=2):
+    metrics = None
+    for _ in range(steps):
+        state, metrics = step(state, *batch)
+    jax.block_until_ready((state, metrics))
+    return state, metrics
+
+
+def _reports_flops(step, state, batch):
+    """The composed step's ``.lower`` delegate yields cost_analysis FLOPs
+    (the Trainer's ``xla/step_tflops`` / ``xla/mfu`` feed)."""
+    from tpudist.obs import xla as obs_xla
+
+    return obs_xla.cost_flops(step.lower(state, *batch)) is not None
+
+
+def _lm_setup(num_layers=1):
+    from tpudist.models import TransformerConfig, TransformerLM
+    from tpudist.ops.losses import cross_entropy
+
+    cfg = TransformerConfig(vocab_size=32, num_layers=num_layers,
+                            num_heads=2, embed_dim=16, max_seq_len=8)
+    model = TransformerLM(cfg)
+    rng = np.random.default_rng(0)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 8)), jnp.int32)
+    targets = jnp.roll(tokens, -1, axis=1)
+    params = model.init(jax.random.key(0), tokens)["params"]
+
+    def loss_fn(p, batch, rng):
+        toks, tgts = batch
+        logits = model.apply({"params": p}, toks)
+        return cross_entropy(logits.reshape(-1, logits.shape[-1]),
+                             tgts.reshape(-1)), {}
+
+    return cfg, model, params, loss_fn, (tokens, targets)
+
+
+def _check_gspmd_combo(spec, ref_axes, ref_specs_fn, ref_data_axes,
+                       model, params, loss_fn, batch):
+    """One GSPMD point of the matrix: two steps of the composed step
+    against the single-strategy step assembled from the same blocks."""
+    from jax.sharding import NamedSharding
+
+    from tpudist.parallel.tensor_parallel import (
+        make_spmd_train_step, shard_tree,
+    )
+    from tpudist.runtime.mesh import make_mesh
+
+    devs = jax.devices()[: spec.n_devices]
+    tx = optax.sgd(0.1)
+
+    ref_mesh = make_mesh(ref_axes, devs)
+    ref_specs = ref_specs_fn(ref_mesh)
+    ref_state = TrainState.create(
+        model.apply, shard_tree(params, ref_mesh, ref_specs), tx)
+    ref_step = make_spmd_train_step(loss_fn, ref_mesh, ref_specs,
+                                    donate=False)
+    ref_batch = jax.tree.map(
+        lambda x: jax.device_put(
+            x, NamedSharding(ref_mesh, P(ref_data_axes))), batch)
+    ref_state, ref_metrics = _run(ref_step, ref_state, ref_batch)
+
+    mesh = spec.build(devs)
+    step = make_composed_train_step(spec, mesh, loss_fn, params=params,
+                                    donate=False)
+    state, _ = make_composed_state(model.apply, params, tx, spec, mesh)
+    cbatch = shard_composed_batch(batch, mesh, spec)
+    state, metrics = _run(step, state, cbatch)
+
+    _assert_bitwise((metrics["loss"], state.params),
+                    (ref_metrics["loss"], ref_state.params))
+    assert _reports_flops(step, state, cbatch)
+
+
+# ---------------------------------------------------------------------------
 # composition matrix: each combo vs its single-strategy reference
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 class TestCompositionMatrix:
-    """The bench's matrix rows, asserted in-tree: tests and bench share one
-    implementation (mesh_bench) so CI's JSONL gate and the suite can't
-    drift.  Slow-marked (the rows compile 2 programs each); the fast tier
-    still covers composition via the grow/shrink, trainer, and pp tests
-    below, and CI's mesh-smoke job gates the same rows from the bench
-    JSONL on every push."""
+    """Six points of the dp x fsdp x tp x pp x ep space, each BITWISE its
+    single-strategy entry point at equal global batch, each reporting
+    FLOPs through ``.lower``.  The GSPMD and expert points are
+    slow-marked (each compiles two LM programs); the two pipeline points
+    run in the fast tier."""
 
+    @pytest.mark.slow
     def test_gspmd_combos_bitwise(self, devices8):
         from tpudist.parallel.fsdp import fsdp_specs
         from tpudist.parallel.tensor_parallel import (
             spec_tree_from_rules, transformer_tp_rules,
         )
 
-        cfg, model, params, loss_fn, batch = mesh_bench._lm_setup()
-        rows = [
-            mesh_bench._gspmd_row(
-                "dp2_tp2",
-                MeshSpec(dp=2, tp=2,
-                         rules=tuple(transformer_tp_rules("tp"))),
-                {"data": 2, "model": 2},
-                lambda m: spec_tree_from_rules(
-                    params, transformer_tp_rules("model")),
-                "data", model, params, loss_fn, batch),
-            mesh_bench._gspmd_row(
-                "fsdp2_tp2",
-                MeshSpec(fsdp=2, tp=2,
-                         rules=tuple(transformer_tp_rules("tp"))),
-                {"fsdp": 2, "model": 2},
-                lambda m: fsdp_specs(params, m, axis="fsdp",
-                                     tp_rules=transformer_tp_rules("model")),
-                "fsdp", model, params, loss_fn, batch),
-            mesh_bench._gspmd_row(
-                "dp2_fsdp2_tp2",
-                MeshSpec(dp=2, fsdp=2, tp=2,
-                         rules=tuple(transformer_tp_rules("tp"))),
-                {"data": 2, "fsdp": 2, "model": 2},
-                lambda m: fsdp_specs(params, m, axis="fsdp",
-                                     tp_rules=transformer_tp_rules("model")),
-                ("data", "fsdp"), model, params, loss_fn, batch),
-        ]
-        for row in rows:
-            assert row["exact_match"], row
-            assert row["mfu_reported"], row
+        cfg, model, params, loss_fn, batch = _lm_setup()
+        tp_rules = tuple(transformer_tp_rules("tp"))
+
+        def fsdp_tp(m):
+            return fsdp_specs(params, m, axis="fsdp",
+                              tp_rules=transformer_tp_rules("model"))
+
+        _check_gspmd_combo(                                   # dp2_tp2
+            MeshSpec(dp=2, tp=2, rules=tp_rules),
+            {"data": 2, "model": 2},
+            lambda m: spec_tree_from_rules(
+                params, transformer_tp_rules("model")),
+            "data", model, params, loss_fn, batch)
+        _check_gspmd_combo(                                   # fsdp2_tp2
+            MeshSpec(fsdp=2, tp=2, rules=tp_rules),
+            {"fsdp": 2, "model": 2}, fsdp_tp,
+            "fsdp", model, params, loss_fn, batch)
+        _check_gspmd_combo(                                   # dp2_fsdp2_tp2
+            MeshSpec(dp=2, fsdp=2, tp=2, rules=tp_rules),
+            {"data": 2, "fsdp": 2, "model": 2}, fsdp_tp,
+            ("data", "fsdp"), model, params, loss_fn, batch)
 
     def test_pipeline_combos_bitwise(self, devices8):
-        for row in mesh_bench._pipeline_rows():
-            assert row["exact_match"], row
-            assert row["mfu_reported"], row
-            assert 0 < row["bubble_fraction"] < 1, row
+        """dp x pp (1F1B) and dp x pp x tp (stacked schedule, Megatron
+        block) against the direct pipeline entry points."""
+        from tpudist.parallel.common import id_fwd_psum_bwd, psum_fwd_id_bwd
+        from tpudist.runtime.mesh import make_mesh
 
+        rng = np.random.default_rng(0)
+        M, d, ff, Pp = 4, 8, 16, 2
+        tx = optax.sgd(0.1)
+
+        def mse(out, y):
+            return jnp.mean((out - y) ** 2)
+
+        batch = (jnp.asarray(rng.standard_normal((16, d)), jnp.float32),
+                 jnp.asarray(rng.standard_normal((16, d)), jnp.float32))
+
+        # -- dp2 x pp2: homogeneous tanh blocks through 1F1B
+        params = {
+            "w": jnp.asarray(rng.standard_normal((Pp, d, d)) * 0.3,
+                             jnp.float32),
+            "b": jnp.zeros((Pp, d), jnp.float32),
+        }
+
+        def block(p, a):
+            return jnp.tanh(a @ p["w"] + p["b"])
+
+        devs = jax.devices()[:4]
+        ref_state = TrainState.create(None, params, tx)
+        ref_step = make_1f1b_pipeline_train_step(
+            block, mse, make_mesh({"data": 2, "stage": Pp}, devs), M,
+            ref_state, donate=False)
+        ref_state, ref_metrics = _run(ref_step, ref_state, batch)
+
+        spec = MeshSpec(dp=2, pp=Pp, num_microbatches=M)
+        state = TrainState.create(None, params, tx)
+        step = make_composed_train_step(
+            spec, spec.build(devs), block_fn=block, stage_loss_fn=mse,
+            state_example=state, donate=False)
+        state, metrics = _run(step, state, batch)
+        _assert_bitwise((metrics["loss"], state.params),
+                        (ref_metrics["loss"], ref_state.params))
+        assert _reports_flops(step, state, batch)
+        assert 0 < step.bubble_fraction < 1
+
+        # -- dp2 x pp2 x tp2: stacked schedule, Megatron MLP block
+        params3 = {
+            "up": jnp.asarray(rng.standard_normal((Pp, d, ff)) * 0.3,
+                              jnp.float32),
+            "down": jnp.asarray(rng.standard_normal((Pp, ff, d)) * 0.3,
+                                jnp.float32),
+        }
+
+        def tp_block(axis):
+            def fn(p, a):
+                a = id_fwd_psum_bwd(a, axis)
+                h = jnp.tanh(a @ p["up"])
+                return psum_fwd_id_bwd(h @ p["down"], axis)
+            return fn
+
+        devs8 = jax.devices()[:8]
+        ref_mesh = make_mesh({"data": 2, "stage": Pp, "model": 2}, devs8)
+        ref_state = TrainState.create(None, params3, tx)
+        ref_specs = state_specs_like(
+            ref_state, {"up": P("stage", None, "model"),
+                        "down": P("stage", "model", None)})
+        ref_step = make_stacked_pipeline_train_step(
+            tp_block("model"), mse, ref_mesh, M, ref_state,
+            state_specs=ref_specs, grad_sync_axes=("model",), donate=False)
+        ref_state, ref_metrics = _run(ref_step, ref_state, batch)
+
+        spec = MeshSpec(dp=2, pp=Pp, tp=2, num_microbatches=M)
+        state = TrainState.create(None, params3, tx)
+        specs = state_specs_like(
+            state, {"up": P("pp", None, "tp"), "down": P("pp", "tp", None)})
+        step = make_composed_train_step(
+            spec, spec.build(devs8), block_fn=tp_block("tp"),
+            stage_loss_fn=mse, state_example=state, state_specs=specs,
+            grad_sync_axes=("tp",), donate=False)
+        state, metrics = _run(step, state, batch)
+        _assert_bitwise((metrics["loss"], state.params),
+                        (ref_metrics["loss"], ref_state.params))
+        assert _reports_flops(step, state, batch)
+        assert 0 < step.bubble_fraction < 1
+
+    @pytest.mark.slow
     def test_ep_combo_bitwise(self, devices8):
-        row = mesh_bench._ep_row()
-        assert row["exact_match"], row
-        assert row["mfu_reported"], row
+        from tpudist.models import (
+            MoEConfig, MoETransformerLM, TransformerConfig,
+        )
+        from tpudist.ops.losses import cross_entropy
+        from tpudist.parallel.expert_parallel import moe_ep_rules
+        from tpudist.parallel.tensor_parallel import spec_tree_from_rules
+
+        cfg = TransformerConfig(vocab_size=32, num_layers=1, num_heads=2,
+                                embed_dim=16, max_seq_len=8)
+        model = MoETransformerLM(cfg, MoEConfig(num_experts=2, top_k=1,
+                                                capacity_factor=4.0))
+        tokens = jnp.asarray(
+            np.random.default_rng(0).integers(0, 32, (8, 8)), jnp.int32)
+        params = model.init(jax.random.key(0), tokens)["params"]
+
+        def loss_fn(p, batch, rng):
+            (toks,) = batch
+            logits, aux = model.apply({"params": p}, toks)
+            ce = cross_entropy(logits[:, :-1].reshape(-1, cfg.vocab_size),
+                               toks[:, 1:].reshape(-1))
+            return ce + aux, {}
+
+        # the reference keeps the expert rules on their native axis name;
+        # the composed step uses the same rules over "ep"
+        _check_gspmd_combo(
+            MeshSpec(dp=2, ep=2, rules=tuple(moe_ep_rules("ep"))),
+            {"data": 2, "expert": 2},
+            lambda m: spec_tree_from_rules(params, moe_ep_rules("expert")),
+            "data", model, params, loss_fn, (tokens,))
 
 
 # ---------------------------------------------------------------------------
@@ -182,7 +365,7 @@ def test_meshspec_grow_shrink_recompile(devices8):
     + re-compile, not a silent layout corruption."""
     from tpudist.parallel.tensor_parallel import transformer_tp_rules
 
-    cfg, model, params, loss_fn, batch = mesh_bench._lm_setup()
+    cfg, model, params, loss_fn, batch = _lm_setup()
     tx = optax.sgd(0.1)
 
     ref_state = TrainState.create(model.apply, params, tx)

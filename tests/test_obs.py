@@ -315,23 +315,6 @@ class TestExporters:
         assert by_name["lat/p50"] == 2.0
         assert by_name["lat/count"] == 3
 
-    def test_bench_emit_goes_through_exporter(self, capsys):
-        import bench
-
-        n0 = len(bench._EMITTED)
-        bench._emit("smoke_metric", 1.5, "s", None, extra=2)
-        out = capsys.readouterr().out.strip().splitlines()[-1]
-        obj = json.loads(out)
-        core = {k: obj[k] for k in ("metric", "value", "unit",
-                                    "vs_baseline", "extra")}
-        assert core == {"metric": "smoke_metric", "value": 1.5, "unit": "s",
-                        "vs_baseline": None, "extra": 2}
-        # every row carries provenance (caller-supplied keys win)
-        assert obj["bench_schema"] == bench._BENCH_SCHEMA
-        assert set(obj) >= {"git_sha", "seed", "bench"}
-        assert bench._EMITTED[n0:] == [obj]
-        del bench._EMITTED[n0:]
-
     def test_prometheus_text_round_trip(self):
         r = _registry()
         r.counter("train/steps", unit="steps").inc(42)

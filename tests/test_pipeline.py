@@ -781,6 +781,16 @@ class TestCanonicalInterleavedSchedule:
                     # the comparable plain span is plain * V chunk ticks
                     assert inter < plain * V, (P_, M, V, inter, plain * V)
 
+    def test_acceptance_point_bubble(self):
+        """P=4 stages, M=16 micro-batches, V=4 chunks a stage: the
+        schedule that executes idles at most 8% of its ticks."""
+        from tpudist.parallel.pipeline import _one_f_one_b_schedule
+
+        P_, M, V = 4, 16, 4
+        sched = _one_f_one_b_schedule(P_, M, V)
+        bubble = (sched.T - 2 * V * M) / sched.T
+        assert 0 < bubble <= 0.08, (sched.T, bubble)
+
     def test_canonical_order_structure(self):
         from tpudist.parallel.pipeline import _canonical_interleaved_order
 

@@ -280,6 +280,7 @@ class TestServeExactness:
             np.testing.assert_array_equal(a[rid], b[rid],
                                           err_msg=f"rid={rid}")
         assert chunked.pool.free_blocks == chunked.pool.num_blocks
+        assert oneshot.pool.free_blocks == oneshot.pool.num_blocks
 
     def test_intertoken_samples_recorded(self, params):
         loop = ServeLoop(CFG, params, num_slots=2, steps_per_sync=4,

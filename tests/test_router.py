@@ -503,6 +503,77 @@ class TestControlPlaneUnit:
         from tpudist import obs
         assert obs.snapshot()["gauges"]["router/degraded"]["value"] == 1.0
 
+    @pytest.mark.parametrize("canary_ok", [False, True],
+                             ids=["corrupt-canary", "clean-canary"])
+    def test_roll_structural_canary_gates_the_commit(self, canary_ok):
+        """Blue-green rollout: a warmed, heartbeating green pool that
+        answers its canary WRONG rolls back with blue untouched (pool
+        key never shifted, no blue replica stopped); an exact canary
+        commits — the pool key shifts to green and blue drains."""
+        from tpudist.models.serving import Completion, Request
+
+        fc = FakeCoord()
+        ns = "bluegreen"
+        self._reg_only(fc, ns, "b0", 0)
+        fc.live_set.add(f"{ns}:b0")
+        expect = np.array([5, 6, 7], np.int32)
+
+        class Proc:                       # a green worker's Popen
+            returncode = None
+
+            def poll(self):
+                return self.returncode
+
+            def wait(self, timeout=None):
+                return self.returncode
+
+        proc = Proc()
+
+        def spawn():
+            self._reg_only(fc, ns, "g0", 1, pool="green")
+            fc.live_set.add(f"{ns}:g0")
+            return [proc]
+
+        def on_set(key, value):
+            if key.startswith(f"{ns}/inbox/g0/canary-"):
+                k = key.rsplit("/", 1)[1]
+                tokens = expect if canary_ok else expect + 1
+                fc.kv[f"{ns}/done/{k}"] = _encode_completion(
+                    "g0", Completion(rid=k, prompt=np.zeros(1, np.int32),
+                                     tokens=tokens, reason="length"))
+            elif key.startswith(f"{ns}/stop/"):
+                # a stopped replica exits and its lease lapses
+                fc.live_set.discard(f"{ns}:{key.rsplit('/', 1)[1]}")
+                proc.returncode = 0
+
+        fc.on_set = on_set
+        router = Router(fc, namespace=ns, use_health=False)
+        rb0 = _counter("router/rollbacks")
+        rolls0 = _counter("router/structural_rolls")
+        out = router.roll_structural(
+            spawn, 1, canary=Request(np.arange(5, dtype=np.int32), 3,
+                                     rid="probe"),
+            expect_tokens=expect, warmup_timeout_s=5.0,
+            canary_timeout_s=5.0, drain_timeout_s=5.0)
+        assert out["blue"] == ["b0"] and out["procs"] == [proc]
+        if canary_ok:
+            assert out["ok"] is True and out["stage"] == "done"
+            assert out["green"] == ["g0"]
+            assert out["blue_drained"] is True
+            assert fc.kv[f"{ns}/pool"] == b"green"
+            assert f"{ns}:b0" not in fc.live_set       # blue stopped
+            assert f"{ns}:g0" in fc.live_set
+            assert _counter("router/structural_rolls") - rolls0 == 1
+            assert _counter("router/rollbacks") - rb0 == 0
+        else:
+            assert out["ok"] is False and out["stage"] == "canary"
+            assert "mismatch" in out["reason"]
+            assert f"{ns}/pool" not in fc.kv            # traffic never shifted
+            assert f"{ns}:b0" in fc.live_set            # blue untouched
+            assert f"{ns}/replica/g0" not in fc.kv      # green swept
+            assert _counter("router/rollbacks") - rb0 == 1
+            assert _counter("router/structural_rolls") - rolls0 == 0
+
     def test_alloc_replica_indices_chain(self):
         """Concurrent scale-ups must never collide on replica indices:
         allocation is an atomic add-chain, and seeding only advances
@@ -630,6 +701,7 @@ class TestFleetE2E:
         server, client = _coord_pair()
         ns = "kill-fleet"
         obs.events.clear()   # this process's ring: router-side events
+        obs.slo.clear()      # and its SLO windows
         procs = launch_local_fleet(
             f"127.0.0.1:{server.port}", 2, namespace=ns,
             replica_args=["--cache-layout", "paged",
@@ -651,6 +723,12 @@ class TestFleetE2E:
                                      {}).get("value", 0))
         assert deaths >= 1 and redispatched >= 1
         assert procs[1].returncode == -9  # SIGKILL, not a clean exit
+        # one terminal decision a request, each fed to the SLO windows
+        # the burn rate is read from
+        assert (after["router/decisions/completed"]["value"]
+                - before.get("router/decisions/completed",
+                             {}).get("value", 0)) == 6
+        assert sum(obs.slo.counts(max(obs.slo.windows))) == 6
         # redispatched greedy output is token-identical to an
         # uninterrupted single-loop run over the same weights
         want = self._reference(6)
@@ -789,13 +867,31 @@ class TestFleetE2E:
     def test_two_replicas_share_load_no_faults(self):
         """Happy path: both replicas serve, output exact-matches the
         local reference, both exit cleanly with drained pools."""
+        from tpudist import obs
+        from tpudist.obs.aggregate import collect, merge_snapshots
+        from tpudist.obs.registry import hist_quantile
+
         server, client = _coord_pair()
         ns = "happy-fleet"
         procs = launch_local_fleet(
             f"127.0.0.1:{server.port}", 2, namespace=ns,
             replica_args=["--cache-layout", "paged",
                           "--kv-block-size", "16", "--ttl", "1.0"])
+        before = {n: _counter(n) for n in
+                  ("router/replica_deaths", "router/redispatched",
+                   "router/handoffs")}
         comps = self._route(client, procs, 4, namespace=ns)
+        # a calm unified fleet: nobody died, nothing moved
+        assert {n: _counter(n) - v for n, v in before.items()} == {
+            "router/replica_deaths": 0, "router/redispatched": 0,
+            "router/handoffs": 0}
+        # the fleet-merged queue-wait histogram the router's SLO
+        # admission reads outlives the replicas' exit
+        wait_h = merge_snapshots(collect(client, f"{ns}/metrics"))[
+            "histograms"]["serve/queue_wait_s"]
+        assert wait_h["count"] >= 4
+        assert (hist_quantile(wait_h, 0.99)
+                >= hist_quantile(wait_h, 0.5) >= 0)
         assert sorted(c.rid for c in comps) == [f"q{i}" for i in range(4)]
         want = self._reference(4)
         for c in comps:

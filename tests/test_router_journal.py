@@ -294,6 +294,60 @@ class TestCrashRecoverProperty:
         assert _counter("router/recoveries") - r0 == kills
 
 
+class TestRouteModeFailover:
+    def test_crashed_route_then_recover_writes_each_result_once(
+            self, tmp_path, monkeypatch):
+        """The router's own command line (``--route --requests F
+        --results OUT`` and then ``--route --recover --results OUT``):
+        the first pass dies mid-run, the second rebuilds from the
+        journal and from what OUT already holds — every request ends
+        with exactly one result line (none lost, none twice), tokens as
+        the replica produced them, journal compacted."""
+        import argparse
+
+        from tpudist.runtime import router as router_mod
+
+        fc = FakeCoord()
+        ns = "routecli"
+        _register(fc, ns, "a", 0)
+        _instant_replica(fc, ns)
+        monkeypatch.setattr(router_mod, "CoordClient",
+                            lambda host, port: fc)
+        reqs = _requests(8)
+        req_file = tmp_path / "requests.json"
+        req_file.write_text(json.dumps(
+            [{"prompt": r.prompt.astype(int).tolist(),
+              "max_new_tokens": int(r.max_new_tokens), "rid": r.rid}
+             for r in reqs]))
+        results = tmp_path / "results.jsonl"
+
+        def args(**kw):
+            return argparse.Namespace(
+                coord="127.0.0.1:1", namespace=ns, poll_s=0.001,
+                lost_after=5.0, timeout=30.0, results=str(results),
+                requests=str(req_file), recover=False, **kw)
+
+        faults.install(FaultPlan(router_kill_after_polls=2,
+                                 router_kill_raise=True))
+        with pytest.raises(RouterKilled):
+            router_mod._run_route_mode(args())
+        assert fc.keys(f"{ns}/journal/")        # the crash left work open
+        faults.reset()
+        rec = args()
+        rec.recover = True
+        router_mod._run_route_mode(rec)
+
+        lines = [json.loads(ln)
+                 for ln in results.read_text().splitlines() if ln.strip()]
+        assert sorted(d["rid"] for d in lines) == \
+            sorted(r.rid for r in reqs)         # none lost, none twice
+        want = {r.rid: [int(r.prompt[0]), int(r.prompt.size)]
+                for r in reqs}
+        assert all(d["tokens"] == want[d["rid"]] for d in lines)
+        assert fc.keys(f"{ns}/journal/") == []
+        assert fc.keys(f"{ns}/done/") == []
+
+
 class _BrownoutCoord(FakeCoord):
     """FakeCoord that is unreachable while ``outage`` is set; the
     outage lifts itself after ``blind_max`` refused ops."""

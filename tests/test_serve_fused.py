@@ -72,22 +72,35 @@ class TestFusedExactMatch:
             assert loop.pool.used_blocks == 0
             loop.pool.check()
 
-    def test_fewer_dispatches(self, params):
-        """The amortization itself: a fused segment serves the whole
-        batch's tokens in ~tokens/steps_per_sync host dispatches."""
-        loop = ServeLoop(CFG, params, num_slots=2, prefill_chunk=16,
-                         stop_tokens=(1,), auto_unstack=False,
-                         steps_per_sync=8, pipeline_depth=2,
-                         decode_attention="flash")
-        # the obs counter is registry-global (shared across loops in
-        # this process) — diff around the run
-        before = loop._obs_dispatches.value()
-        comps = loop.run(_reqs())
-        n_tokens = sum(len(c.tokens) for c in comps)
-        n_disp = loop._obs_dispatches.value() - before
-        # 4 requests x 12 tokens through 2 slots at N=8: a handful of
-        # dispatches (admission waves add a few), never one per token
-        assert n_disp <= n_tokens / 4, (n_disp, n_tokens)
+    @pytest.mark.parametrize("n_fused,budget,bound,fewer_than_single", [
+        (8, 12, 1 / 4, None),       # short answers: admission waves weigh
+        (16, 48, 1 / 16 + 0.02, 8.0)], ids=["n8-short", "n16-long"])
+    def test_fewer_dispatches(self, params, n_fused, budget, bound,
+                              fewer_than_single):
+        """The amortization itself: an N-step segment serves the batch's
+        tokens in a handful of host dispatches (admission waves add a
+        few), never one per token — at N=16 and 48-token answers at most
+        1/N + 0.02 a token, and at least 8x fewer than the single-token
+        loop pays for the same requests."""
+        def dispatches_per_token(steps_per_sync):
+            loop = ServeLoop(CFG, params, num_slots=2, prefill_chunk=16,
+                             auto_unstack=False,
+                             steps_per_sync=steps_per_sync,
+                             pipeline_depth=2, decode_attention="flash")
+            # the obs counter is registry-global (shared across loops in
+            # this process) — diff around the run
+            before = loop._obs_dispatches.value()
+            comps = loop.run([Request(prompt=_prompt(i, 5 + 3 * i),
+                                      max_new_tokens=budget, rid=i)
+                              for i in range(4)])
+            n_tokens = sum(len(c.tokens) for c in comps)
+            assert n_tokens == 4 * budget
+            return (loop._obs_dispatches.value() - before) / n_tokens
+
+        fused = dispatches_per_token(n_fused)
+        assert fused <= bound, fused
+        if fewer_than_single:
+            assert dispatches_per_token(1) / fused >= fewer_than_single
 
     def test_mid_segment_eos(self, params, reference):
         """Requests whose stop token lands mid-segment (not at an N

@@ -162,16 +162,23 @@ class TestPipelinedDispatch:
 
     def test_pipelined_exact_match_mixed_workload(self, params):
         """The staleness contract must not cost a token: the pipelined
-        loop (depths 2 and 3) is byte-identical — tokens, finish reasons,
-        finish ORDER — to the synchronous loop (depth 1) on a mixed
-        prompt-length / stop-token workload with queueing and mid-flight
-        slot reuse.  Same instance across depths: shared executables, so
-        any divergence is host-scheduling, not numerics."""
+        loop is byte-identical to the synchronous loop (depth 1) on a
+        mixed prompt-length / stop-token workload with queueing and
+        mid-flight slot reuse.  At the default depth 2 that holds for
+        tokens, finish reasons and finish ORDER; at depth 3 a freed lane
+        is seen two segments late, so a queued request may be admitted
+        (and finish) later than in the synchronous run — its tokens and
+        reason may not change.  Same instance across depths: shared
+        executables, so any divergence is host-scheduling, not
+        numerics."""
         reqs = [Request(_prompt(10 + i, 3 + 5 * i), 25, rid=i)
                 for i in range(6)]
+        # tokens the seeded model emits in some of these streams and not
+        # in others (rid 3 meets neither); the assert below says so if a
+        # jax release changes the draw
         loop = ServeLoop(CFG, params, num_slots=2, steps_per_sync=4,
                          decode_attention="flash", prefill_chunk=8,
-                         stop_tokens=(7, 13))
+                         stop_tokens=(6, 15))
 
         def sig(comps):
             return [(c.rid, tuple(c.tokens.tolist()), c.reason)
@@ -182,9 +189,11 @@ class TestPipelinedDispatch:
         # the workload exercises BOTH finish paths under pipelining
         assert {r for _, _, r in sync} == {"stop", "length"}
         assert sorted(r for r, _, _ in sync) == list(range(6))
-        for depth in (2, 3):
-            loop.pipeline_depth = depth
-            assert sig(loop.run(reqs)) == sync, f"depth {depth} diverged"
+        loop.pipeline_depth = 2
+        assert sig(loop.run(reqs)) == sync, "depth 2 diverged"
+        loop.pipeline_depth = 3
+        assert sorted(sig(loop.run(reqs))) == sorted(sync), \
+            "depth 3 diverged"
 
     def test_default_depth_is_pipelined(self, params):
         loop = ServeLoop(CFG, params, num_slots=1)

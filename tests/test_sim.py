@@ -387,7 +387,7 @@ class TestEnvelopeChecker:
         assert any("only 3" in line for line in report)
 
     def test_scenario_rows_skips_noise(self, tmp_path):
-        path = tmp_path / "bench.jsonl"
+        path = tmp_path / "rows.jsonl"
         path.write_text("\n".join([
             "some log line",
             json.dumps({"metric": "serve/throughput", "value": 1.0}),
@@ -614,14 +614,49 @@ class TestFleetSimChaos:
         assert row["envelope_ok"], row["violations"]
 
 
+# a dedicated test already runs these four (steady_state and
+# coord_brownout in test_alerts.py, the other two below)
+_HELD_ELSEWHERE = {"steady_state", "coord_brownout", "silent_corruption",
+                   "priority_saturation"}
+# what a scenario must show beyond its own envelope
+_BUILTIN_EXTRA = {
+    "cold_prefix_tenants": {"global_hit_rate": lambda v: v >= 0.8,
+                            "tier_hit_blocks": lambda v: v > 0,
+                            "alerts_fired":
+                                lambda v: v == ["TierHeadroomLow"]},
+    "replica_death_storm": {"replica_deaths": lambda v: v == 2,
+                            "scale_ups": lambda v: v >= 1,
+                            "alerts_fired": lambda v: "ReplicaLost" in v},
+    "router_failover": {"router_recoveries": lambda v: v >= 1},
+}
+
+
+class TestBuiltinMatrix:
+    @pytest.mark.parametrize("name", sorted(set(BUILTIN) - _HELD_ELSEWHERE))
+    def test_builtin_scenario_meets_its_envelope(self, name):
+        """Every named scenario, through the real router and autoscaler
+        on the virtual clock, stays inside its own SLO envelope — checked
+        from the row's raw fields, as ``python -m tpudist.sim.envelope``
+        does, not from the flag the simulator set — loses no request and
+        fires exactly the alerts its envelope allows."""
+        from tpudist.sim.simulator import FleetSim
+
+        row = FleetSim(builtin(name)).run()
+        assert check_row(row) == [], row["violations"]
+        assert row["envelope_ok"] is True
+        assert row["lost_requests"] == 0
+        for field, ok in _BUILTIN_EXTRA.get(name, {}).items():
+            assert ok(row[field]), (field, row[field])
+
+
 @pytest.mark.skipif(not os.path.exists(FIXTURE),
                     reason="recorded live-run fixture missing")
 class TestSimReplayAgreement:
     """The acceptance check, offline: replaying the checked-in recorded
     live run (a 1-replica fleet breaching a millisecond wait target)
     must reproduce the autoscaler's scale-up decision sequence within
-    one poll of the first breach — bench.py's sim_replay gate, pinned
-    to a fixture so it regresses loudly without needing a live fleet."""
+    one poll of the first breach — pinned to a fixture so it regresses
+    loudly without needing a live fleet."""
 
     @staticmethod
     def _first_up_rel(decision_log, action_seq, target_wait_s):

@@ -54,7 +54,7 @@ from tpudist import obs
 
 __all__ = ["HostTier", "tier_budget_from_env", "DEFAULT_TIER_BYTES"]
 
-# 64 MiB default: plenty for the test/bench models, obviously tunable
+# 64 MiB default: plenty for the test models, obviously tunable
 # for real fleets via TPUDIST_KV_HOST_TIER_BYTES (0 disables the tier)
 DEFAULT_TIER_BYTES = 64 * 1024 * 1024
 

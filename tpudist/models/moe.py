@@ -41,8 +41,7 @@ class MoEConfig:
     # bounded, drops overflow tokens; the formulation EP's all-to-all
     # transports).  "ragged": sorted dispatch + jax.lax.ragged_dot grouped
     # matmuls — no [T, E, C] einsums (which at small E cost MORE FLOPs
-    # than the experts themselves: measured 6.5× overhead in bench.py),
-    # no capacity, no token dropping.  "fused": the ragged layout through
+    # than the experts themselves), no capacity, no token dropping.  "fused": the ragged layout through
     # the Pallas grouped-matmul kernel (tpudist.ops.moe_dispatch) — both
     # expert matmuls in one kernel, the [T·k, f] intermediate resident in
     # VMEM.  Both non-einsum paths are single-shard only (ep_axis needs

@@ -28,10 +28,9 @@ scheduler, shaped for TPU/XLA rather than for a GPU runtime:
   freezes inside the segment), the host finalizes completions and reuses
   the slot.
 
-The bench criterion (``bench.py: serve_loop``): tokens/s/slot at 8k
-context with MIXED prompt lengths within ~15% of the fixed-batch
-rollout, which is the cost of the request layer — the decode step is the
-same kernels either way.
+What this loop costs on the chip is measured by the benchmark's serving
+cells (``python3 benchmarks/run.py --workload sc3b_code_steady``;
+PERF.md section 5 has where the time goes).
 """
 
 from __future__ import annotations
@@ -461,7 +460,7 @@ class ServeLoop:
         self.params = params
         self.B = num_slots
         self.steps = steps_per_sync
-        # mutable on purpose: benches flip the SAME instance between
+        # mutable on purpose: a test flips the SAME instance between
         # synchronous (1) and pipelined runs, so both share executables
         self.pipeline_depth = pipeline_depth
         self.prefill_chunk = prefill_chunk
@@ -582,13 +581,13 @@ class ServeLoop:
         # Request.prefix_hash), LRU-bounded — the replica's published
         # affinity summary (see prefix_summary)
         self._affinity_recent: dict[int, None] = {}
-        # cumulative host-side tallies benches read as deltas (obs
+        # cumulative host-side tallies callers read as deltas (obs
         # counters also tick; this avoids registry round trips)
         self.prefix_stats = {"requests": 0, "hits": 0, "hit_tokens": 0,
                              "prompt_tokens": 0, "prefill_tokens": 0}
         # per-run (gap_seconds_per_token, tokens) samples, one per
-        # drained decode segment — benches compute p99 inter-token
-        # latency from these (reset at every run())
+        # drained decode segment — the benchmark's gap_p90_ms is
+        # computed from these (reset at every run())
         self.intertoken_samples: list[tuple[float, int]] = []
         self._last_drain_t: float | None = None
         self.model = TransformerLM(cfg, decode=True,
@@ -643,8 +642,8 @@ class ServeLoop:
             self.draft_cfg = draft_cfg
             self.draft_params = draft_params
             # the draft decodes DENSE per-row: verify chunks and single
-            # steps both go through the banded-mask path, so the CPU
-            # bench pays ONE masked matmul per draft step, and its cache
+            # steps both go through the banded-mask path, so a draft
+            # step pays ONE masked matmul, and its cache
             # is num_slots x draft_seq_len — small by construction
             self.draft_model = TransformerLM(draft_cfg, decode=True,
                                              decode_attention="dense")
@@ -806,14 +805,14 @@ class ServeLoop:
         self._segment = jax.jit(self._segment_impl,
                                 donate_argnums=(1, 2, 3, 4, 6))
         # params is a jit ARGUMENT (a closure capture would lower the
-        # whole parameter tree into the traced program — the
-        # duplicated-constants hazard bench.py documents — and would pin
+        # whole parameter tree into the traced program as duplicated
+        # constants — and would pin
         # first-trace weights if self.params is ever rebound)
         self._admit_dev = jax.jit(self._admit_dev_impl,
                                   donate_argnums=(1, 2, 3, 4, 5),
                                   static_argnames=("true_chunk",))
-        # standalone prefill, used by benchmarks to price admission's
-        # device work without touching live state
+        # standalone prefill: admission's device work without touching
+        # live state
         self._prefill_one = jax.jit(self._prefill_impl,
                                     static_argnames=("true_chunk",))
         if cache_layout == "paged":
@@ -1553,7 +1552,7 @@ class ServeLoop:
         """Drop every cached prefix (idle blocks return to the free
         list) AND every host-tier entry.  Called automatically at
         weight hot-swaps — spilled KV is exactly as stale as resident
-        KV — and by benches before asserting fully drained pool and
+        KV — and by callers before asserting fully drained pool and
         tier."""
         if self._prefix_cache is not None:
             self._prefix_cache.flush()
@@ -1763,7 +1762,7 @@ class ServeLoop:
             raise
 
     def tier_drained(self) -> bool | None:
-        """Tier invariants + emptiness — the exit-report / bench drain
+        """Tier invariants + emptiness — the exit report's drain
         gate (``None`` when no tier exists).  Runs the cross-structure
         check: no hash simultaneously tiered and HBM-resident."""
         if self._tier is None:
@@ -2465,7 +2464,7 @@ class ServeLoop:
                     with obs.span("serve/admit", slot=slot,
                                   rid=_span_rid(req.rid)):
                         st = slot_state[slot] = self._admit(slot, req)
-                    # stamped here, not in _admit: benches wrap
+                    # stamped here, not in _admit: a caller may wrap
                     # loop._admit, and the stamp must cover the wrapper.
                     # A chunked admission gets its seq stamp (and its
                     # prefill_done) at the FINISH dispatch

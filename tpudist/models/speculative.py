@@ -246,10 +246,9 @@ def speculative_generate(
         # steps, where the stacked layout costs ~4×.  The TARGET's layout
         # is PRESERVED: it only ever runs chunk verifies, which amortize
         # the stacked-cache slicing, so a scanned target keeps its
-        # depth-independent compile size at ~no step-time cost — the
-        # configuration bench.py uses.  The sharded entry
-        # points normalize BOTH unconditionally (their sharding rules
-        # need per-layer names).
+        # depth-independent compile size at ~no step-time cost.  The
+        # sharded entry points normalize BOTH unconditionally (their
+        # sharding rules need per-layer names).
         from tpudist.models.generate import serving_layout
 
         draft_cfg, draft_params = serving_layout(draft_cfg, draft_params)

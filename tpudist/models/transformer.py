@@ -582,10 +582,9 @@ class CausalSelfAttention(nn.Module):
         With ``serve_side_slots > 0`` (the ServeLoop configuration) the
         write goes to a SEGMENT-LOCAL side buffer at a SCALAR in-segment
         index — XLA keeps scalar dynamic_update_slice chains in place,
-        while per-row-indexed main-cache writes measured +0.35 ms/step on
-        the 8-layer 8k bench model (neither batched scatters nor
-        per-row-index DUS chains stay in place inside the full segment
-        graph).  Attention then runs as ONE fused flash-decode call over
+        while per-row-indexed main-cache writes do not (neither batched
+        scatters nor per-row-index DUS chains stay in place inside the
+        full segment graph).  Attention then runs as ONE fused flash-decode call over
         the frozen main cache (per-row lengths) plus the side buffer's
         live positions (:meth:`_serve_attend_sided`); the ServeLoop
         scatters side → main once per segment (amortized to ~nothing).
@@ -661,10 +660,9 @@ class CausalSelfAttention(nn.Module):
         Attention runs as ONE fused kernel call: the flash-decode kernel
         streams the frozen main cache at each row's own length and then
         attends the side buffer's live positions as a trailing grid step
-        of the SAME online softmax (``flash_decode(side_k=...)``) — the
-        separate dense side attend + explicit log-sum-exp merge this
-        method used through round 4 measured +0.15–0.2 ms/step on the
-        8-layer 8k bench model."""
+        of the SAME online softmax (``flash_decode(side_k=...)``), with
+        no separate dense side attend and no explicit log-sum-exp
+        merge."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
         cap = self.serve_side_slots

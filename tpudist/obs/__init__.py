@@ -11,7 +11,7 @@ The subsystem every layer reports through (see docs/OBSERVABILITY.md):
   the XProf trace from :func:`tpudist.utils.metrics.maybe_profile`.
 * :mod:`tpudist.obs.aggregate` — workers publish snapshots through the
   coord KV store; rank 0 merges them into a cluster view.
-* :mod:`tpudist.obs.export` — bench-schema JSONL, Prometheus text, and a
+* :mod:`tpudist.obs.export` — metric-row JSONL, Prometheus text, and a
   stdlib-only HTTP ``/metrics`` + ``/healthz`` endpoint.
 * :mod:`tpudist.obs.health` — rank-0 straggler/staleness classification
   over the published snapshots, with hysteresis.

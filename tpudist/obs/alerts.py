@@ -28,9 +28,9 @@ Two rule sets ship with the repo:
   read fired alerts through the same interface instead of bespoke
   threshold probes.
 
-:func:`rules_hash` gives a stable short hash of a loaded rule set; the
-bench stamps it onto every JSONL row so trajectory comparisons detect
-silent rule drift.
+:func:`rules_hash` gives a stable short hash of a loaded rule set; every
+scenario row of :mod:`tpudist.sim` carries it, so a comparison of two
+runs detects silent rule drift.
 """
 
 from __future__ import annotations
@@ -183,8 +183,8 @@ def load_rules(docs: Iterable[dict] | str) -> tuple[AlertRule, ...]:
 
 
 def rules_hash(rules: Iterable[AlertRule]) -> str:
-    """Stable short hash of a rule set (order-insensitive): bench rows
-    carry it so silent rule drift shows up in trajectory diffs."""
+    """Stable short hash of a rule set (order-insensitive): scenario rows
+    carry it so silent rule drift shows up when two runs are compared."""
     canon = json.dumps(sorted((r.to_dict() for r in rules),
                               key=lambda d: d["name"]),
                        sort_keys=True, separators=(",", ":"))

@@ -1,11 +1,11 @@
-"""Exporters: bench-schema JSONL, Prometheus text format, HTTP /metrics.
+"""Exporters: metric-row JSONL, Prometheus text format, HTTP /metrics.
 
 Three renderings of the same registry snapshot:
 
 * :func:`jsonl_line` / :func:`snapshot_to_jsonl` — one JSON object per
-  line in the exact ``bench.py`` schema (``{"metric", "value", "unit",
-  "vs_baseline", ...}``, insertion order preserved) so BENCH_*.json
-  parsers keep working when bench emits through the registry.
+  line, ``{"metric", "value", "unit", "vs_baseline", ...}`` with
+  insertion order preserved: the row :mod:`tpudist.sim` emits per
+  scenario and :mod:`tpudist.sim.envelope` reads.
 * :func:`to_prometheus` — Prometheus text exposition format 0.0.4.
   Log-bucket histograms become classic cumulative ``le`` histograms
   whose upper bounds are the bucket upper edges ``growth**(idx+1)``.
@@ -26,19 +26,18 @@ __all__ = ["jsonl_line", "snapshot_to_jsonl", "to_prometheus",
            "MetricsServer"]
 
 
-# -- JSONL (the bench.py wire schema) ---------------------------------------
+# -- JSONL (one metric row a line) ------------------------------------------
 
 def jsonl_line(metric: str, value, unit: str, vs_baseline=None,
                **extra) -> str:
-    """One bench-schema line.  Key order is load-bearing: existing
-    BENCH_*.json tooling reads these positionally-ish and the recap
-    printer re-dumps them verbatim."""
+    """One metric row.  ``metric``, ``value``, ``unit`` and
+    ``vs_baseline`` come first, in that order; ``extra`` keys follow."""
     return json.dumps({"metric": metric, "value": value, "unit": unit,
                        "vs_baseline": vs_baseline, **extra})
 
 
 def snapshot_to_jsonl(snapshot: dict, **extra) -> list[str]:
-    """Render a registry (or merged cluster) snapshot as bench-schema
+    """Render a registry (or merged cluster) snapshot as metric-row
     lines: counters/gauges one line each, histograms one line per summary
     stat (count/mean/p50/p90/p99/...)."""
     lines: list[str] = []

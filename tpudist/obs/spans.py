@@ -98,7 +98,7 @@ class SpanTracer:
     def __init__(self, max_events: int = 100_000,
                  fence: bool | None = None) -> None:
         self.max_events = max_events
-        # None -> env-controlled so tests/benches can fence without code
+        # None -> env-controlled so a test or a run can fence without code
         self.fence = env_flag("TPUDIST_OBS_FENCE") if fence is None else fence
         self.dropped = 0
         self._events: collections.deque[dict] = collections.deque(

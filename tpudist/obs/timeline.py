@@ -1,8 +1,8 @@
 """Request-timeline reconstruction: ``python -m tpudist.obs.timeline``.
 
 Loads a fleet event log — the merged ``tpudist.events/1`` document
-(:func:`tpudist.obs.events.merge_events` output, e.g. the file the
-``serve_fleet`` bench writes), a ``tpudist.postmortem/1`` crash bundle
+(:func:`tpudist.obs.events.merge_events` output written with
+:func:`tpudist.obs.atomic_write_json`), a ``tpudist.postmortem/1`` crash bundle
 (whose ``request_events`` tail this tool understands), or a raw
 published ring snapshot — and reconstructs each request's causal
 history: one time-ordered timeline per trace id, spanning every

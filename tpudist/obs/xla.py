@@ -18,9 +18,8 @@ without three signals this module feeds into the obs registry:
   degrading to nothing on backends that report no stats (CPU).
 * **Cost/MFU telemetry** — ``cost_analysis()``-derived FLOPs per
   compiled step feeding live ``xla/step_tflops`` and ``xla/mfu`` gauges
-  against the chip's known bf16 peak.  ``bench.py`` and
-  ``scripts/resnet_mfu_sweep.py`` read :func:`peak_tflops` / :func:`mfu`
-  from here instead of keeping their own peak tables.
+  against the chip's known bf16 peak (:func:`peak_tflops` /
+  :func:`mfu`).
 
 Everything degrades to a no-op without jax or without a backend — the
 obs layer must stay importable everywhere.
@@ -44,8 +43,7 @@ __all__ = [
     "update_memory_gauges",
 ]
 
-# bf16 peak TFLOP/s per chip, by jax device_kind (moved here from
-# bench.py so the live MFU gauge and the benches share one table)
+# bf16 peak TFLOP/s per chip, by jax device_kind
 PEAK_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,   # v5e

@@ -551,7 +551,7 @@ class Autoscaler:
 
     def poll(self) -> dict:
         """Observe -> decide -> act, once.  Returns the decision record
-        (tests assert on it; the bench logs it)."""
+        (tests assert on it)."""
         faults.autoscale_poll()
         self._obs_polls.inc()
         try:

@@ -544,8 +544,7 @@ class HostCollectives:
         ``bounds(n)`` / ``reduce_scatter(vec)`` / ``all_gather(shard,
         n)`` and span exactly this rank's host group (``local_world ==
         world // config.hosts``).  When absent, the intra phases ride
-        the coord store (the simulated-ICI path the tests and bench
-        drive) — lossless accumulation-dtype bytes either way.
+        the coord store (the simulated-ICI path the tests drive) — lossless accumulation-dtype bytes either way.
 
     Error-feedback state (``compress="topk"``) is OWNED by the instance
     and keyed by bucket: dropped gradient mass re-enters this rank's
@@ -584,7 +583,7 @@ class HostCollectives:
         self.intra = intra
         self._op = 0
         self._posted: dict[int, list[str]] = {}  # op -> keys (for GC)
-        self.bytes_posted = 0     # per-instance wire accounting (bench/tests
+        self.bytes_posted = 0     # per-instance wire accounting (tests
         self.bytes_fetched = 0    # read these; obs counters are global)
         self.bytes_posted_cross = 0   # hier: cross-host ring bytes only —
         self.bytes_fetched_cross = 0  # the wire the 2(H-1)/H bound is about

@@ -254,8 +254,8 @@ class IciKVTransport(KVTransport):
     """Fast path: payloads move by reference through an in-process
     registry, page arrays optionally ``device_put`` onto the decode
     side's device — the intra-host shape of device-to-device migration.
-    Share ONE instance between the prefill and decode loops (the bench's
-    colocated-fleet mode); a cross-host fleet uses the coord path or a
+    Share ONE instance between the prefill and decode loops (a
+    colocated fleet); a cross-host fleet uses the coord path or a
     formed ICI world behind this same interface."""
 
     def __init__(self, *, device=None) -> None:

@@ -254,7 +254,7 @@ class FaultPlan:
         self.coord_outage_s = float(coord_outage_s)
         # wire corruption spec "N" (every Nth payload, forever) or
         # "N:M" (every Nth, but stop after M flips — the transient
-        # corruption shape whose reinstatement path the bench drives)
+        # corruption shape whose reinstatement path the fleet test drives)
         self.flip_wire_every: int | None = None
         self.flip_wire_max: int | None = None
         if flip_wire_bits is not None:

@@ -55,7 +55,7 @@ __all__ = ["QuarantineConfig", "GoldenProbe", "QuarantineManager"]
 class QuarantineConfig:
     """Strike/probe/reinstate policy.
 
-    Defaults are tuned for the tiny-fleet benches and tests; a real
+    Defaults are tuned for the tiny fleets of the tests; a real
     deployment would stretch the windows by the same factor as its
     heartbeat TTLs.
     """

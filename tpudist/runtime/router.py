@@ -393,7 +393,7 @@ class ReplicaWorker:
                                      namespace=f"{namespace}/metrics",
                                      interval_s=publish_interval_s)
         # request-event ring publisher: per-replica lifecycle events flow
-        # to {ns}/events/{rank}; rank 0 (or the bench) merges them into
+        # to {ns}/events/{rank}; rank 0 (or the caller) merges them into
         # the fleet-wide timeline.  rid -> TraceContext for requests this
         # replica picked up, so the done-commit event can be recorded
         # without widening the completion wire format.
@@ -1161,7 +1161,7 @@ class Router:
         # per-reason terminal-decision counters: how each request LEFT
         # the router (completed normally, shed at admission, timed out,
         # failed past max_redispatch) plus the non-terminal re-route.
-        # Surfaced by loads()' fleet view and the bench JSONL.
+        # Surfaced by loads()' fleet view.
         self._obs_decisions = {
             reason: obs.counter(
                 f"router/decisions/{reason}", unit="reqs",
@@ -2559,7 +2559,7 @@ class Router:
                 "blue_drained": blue_drained}
 
 
-# -- fleet process helpers (tests, bench, example, CI) ---------------------
+# -- fleet process helpers (tests, the example) -----------------------------
 
 def build_tiny_lm(vocab: int = 64, layers: int = 2, heads: int = 4,
                   kv_heads: int = 2, embed: int = 64, seq_len: int = 96,
@@ -2695,7 +2695,7 @@ def launch_local_fleet(coord_addr: str, n: int, *,
                        env_overrides: dict[int, dict] | None = None,
                        platform: str = "cpu") -> list[subprocess.Popen]:
     """Spawn ``n`` replica worker subprocesses on this host (tests,
-    bench, CI, the example).  ``env_overrides[i]`` adds env vars to
+    the example).  ``env_overrides[i]`` adds env vars to
     replica ``i`` — the fault-injection knobs go in this way, so a kill
     schedule hits exactly the replica the scenario names.  Also seeds
     the fleet's replica-index add-chain past ``n`` so later

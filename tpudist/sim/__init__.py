@@ -12,10 +12,11 @@ Scenario diversity as a regression suite (see docs/OBSERVABILITY.md):
   ``tpudist.events/1`` document, so an incident replays as a scenario.
 * :mod:`tpudist.sim.simulator` — :class:`FleetSim` runs the REAL
   router + autoscaler code against a virtual clock and simulated
-  replicas, emitting the same decision counters and bench-JSONL
+  replicas, emitting the same decision counters and metric-row
   summary schema as a live run, orders of magnitude faster.
-* :mod:`tpudist.sim.envelope` — the shared envelope checker the CI
-  scenario-matrix job gates on (``python -m tpudist.sim.envelope``).
+* :mod:`tpudist.sim.envelope` — the shared envelope checker
+  (``python -m tpudist.sim.envelope``; ``tests/test_sim.py`` holds
+  every builtin scenario to it).
 
 ``python -m tpudist.sim --all --check`` runs the builtin matrix
 offline and exits nonzero on any envelope violation.

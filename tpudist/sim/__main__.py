@@ -14,7 +14,7 @@ Examples::
     # replay a recorded tpudist.events/1 trace through the simulator
     python -m tpudist.sim --replay trace.json
 
-Rows are bench-schema JSONL (``metric``/``value``/``unit`` first, the
+Rows are metric-row JSONL (``metric``/``value``/``unit`` first, the
 scenario summary as extra keys) — the same schema a live run emits, so
 :mod:`tpudist.sim.envelope` gates both identically.
 """

@@ -1,19 +1,18 @@
-"""The shared SLO-envelope checker CI gates scenario runs on.
+"""The shared SLO-envelope checker scenario runs are gated on.
 
 Both execution paths — live replay and offline simulation — emit one
-bench-JSONL row per scenario (``"metric": "scenario/{name}"`` with the
-summary fields as extra keys).  This module is the one place that
-decides whether such a row is inside its envelope, so the live bench,
-the offline matrix, and the CI job cannot drift apart on what "green"
-means.
+metric-row JSONL line per scenario (``"metric": "scenario/{name}"`` with
+the summary fields as extra keys).  This module is the one place that
+decides whether such a row is inside its envelope, so a live run, the
+offline matrix and the tests (``tests/test_sim.py``) cannot drift apart
+on what "green" means.
 
 Deliberately light: imports only :mod:`tpudist.sim.scenario` (pure
-stdlib), so the CI gate can run it without jax/flax installed —
-the same discipline as ``bench.py``'s heredoc asserts.
+stdlib), so it runs without jax/flax installed.
 
 CLI::
 
-    python -m tpudist.sim.envelope BENCH.jsonl --min-scenarios 5
+    python -m tpudist.sim.envelope ROWS.jsonl --min-scenarios 5
 
 exits nonzero when any scenario row violates its envelope, a builtin
 scenario is missing, or fewer than ``--min-scenarios`` rows are found.
@@ -29,8 +28,8 @@ __all__ = ["scenario_rows", "check_row", "check_rows", "main"]
 
 
 def scenario_rows(path: str) -> list[dict]:
-    """The ``scenario/*`` rows of a bench-JSONL file (non-JSON lines —
-    log noise around the bench output — are skipped)."""
+    """The ``scenario/*`` rows of a JSONL file (non-JSON lines — log
+    noise around the rows — are skipped)."""
     rows: list[dict] = []
     with open(path) as f:
         for line in f:
@@ -102,9 +101,9 @@ def main(argv=None) -> int:
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="Gate a bench-JSONL file on per-scenario SLO "
-                    "envelopes")
-    ap.add_argument("jsonl", help="bench JSONL file (scenario/* rows)")
+        description="Gate a JSONL file of scenario rows on per-scenario "
+                    "SLO envelopes")
+    ap.add_argument("jsonl", help="JSONL file (scenario/* rows)")
     ap.add_argument("--min-scenarios", type=int, default=5)
     ap.add_argument("--no-require-builtin", action="store_true",
                     help="don't demand every builtin scenario be present")

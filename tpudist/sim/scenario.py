@@ -12,7 +12,7 @@ execution paths:
   ``Router.run(requests, arrivals=...)``) — real replicas, real chaos;
 * the OFFLINE simulator (:class:`tpudist.sim.simulator.FleetSim`) —
   the real router/autoscaler policy code against simulated replicas,
-  seconds instead of chaos-bench minutes.
+  seconds instead of the minutes a live chaos run takes.
 
 Specs are plain dicts (JSON-shaped) parsed by
 :meth:`ScenarioSpec.from_dict`, which REJECTS unknown keys — a typo'd
@@ -223,7 +223,7 @@ _FLEET_DEFAULTS: dict[str, Any] = {
 @dataclass(frozen=True)
 class Envelope:
     """The per-scenario SLO gate, asserted against the summary row a
-    run emits (live bench or offline simulator — same schema).
+    run emits (live run or offline simulator — same schema).
 
     ``None`` bounds are unchecked.  ``decisions`` bounds the router's
     terminal decision counters: ``{"shed": {"min": 1, "max": 10}}``."""

@@ -24,10 +24,11 @@ against:
 
 Because the policy code is shared, a simulated run emits the same
 decision counters, the same autoscaler ``decision_log``, and a summary
-row in the same bench-JSONL schema as a live run — which is what makes
-scenario envelopes (:class:`tpudist.sim.scenario.Envelope`) meaningful
-as CI gates, and what the sim-vs-live agreement check in ``bench.py``
-leans on.
+row in the same metric-row JSONL schema as a live run — which is what
+makes scenario envelopes (:class:`tpudist.sim.scenario.Envelope`)
+meaningful as gates, and what the replay of a recorded live run
+(``tests/test_sim.py``, ``tests/data/sim_replay_fixture.json``) leans
+on.
 """
 
 from __future__ import annotations
@@ -686,7 +687,7 @@ class FleetSim:
     """One offline scenario run (see module docstring).
 
     ``FleetSim(spec).run()`` returns the scenario summary row —
-    the bench-JSONL payload the :class:`~tpudist.sim.scenario.Envelope`
+    the metric-row payload the :class:`~tpudist.sim.scenario.Envelope`
     checks — with ``envelope_ok`` / ``violations`` already folded in."""
 
     def __init__(self, spec: ScenarioSpec, *,
@@ -887,7 +888,7 @@ class FleetSim:
 
     def run(self, *, timeout_s: float | None = None) -> dict:
         """Replay the workload through the real router; returns the
-        scenario summary row (bench-JSONL schema, envelope-checked)."""
+        scenario summary row (metric-row schema, envelope-checked)."""
         # process-global SLO window: scrub the previous scenario's
         # observations so this run's burn gauges start clean
         obs.slo.clear()
@@ -1078,7 +1079,7 @@ class FleetSim:
         # alert accounting (ISSUE 17): every rule that reached firing at
         # any point in the run, plus the hash of the rule set it fired
         # under — the envelope's must_fire/must_not_fire checks read
-        # these, and bench rows carry the hash for provenance
+        # these, and the row carries the hash for provenance
         row["alerts_fired"] = sorted(self.alerts.fired_names)
         row["alert_rules_hash"] = self.alerts.rules_hash
         violations = spec.envelope.check(row)

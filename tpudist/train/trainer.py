@@ -129,8 +129,8 @@ class Trainer:
                 "Trainer's epoch/eval/snapshot loop assumes a "
                 "(state, inputs, labels) step; pipeline (pp > 1) training "
                 "uses stage-stacked params and a schedule-specific batch "
-                "layout — drive make_composed_train_step directly (see "
-                "tpudist/parallel/mesh_bench.py)")
+                "layout — drive tpudist.parallel.mesh."
+                "make_composed_train_step directly")
         self.mesh = mesh
         self.train_loader = train_loader
         self.test_loader = test_loader

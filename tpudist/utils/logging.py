@@ -57,10 +57,3 @@ def get_logger(name: str = "tpudist") -> logging.Logger:
         _configured = True
     return logger
 
-
-def coordinator_only(logger: logging.Logger) -> logging.Logger:
-    """Silence INFO output on non-coordinator processes (call after the
-    backend is up, e.g. from a trainer) to avoid N-way duplicated logs."""
-    if _process_index_if_initialized() != 0:
-        logger.setLevel(logging.WARNING)
-    return logger
