@@ -757,6 +757,11 @@ class ServeLoop:
         # that brought the first token to the host, and to the completion
         self._obs_ttft = obs.histogram("serve/ttft_s", unit="s")
         self._obs_latency = obs.histogram("serve/request_latency", unit="s")
+        # segments dispatched between a request's admission and the first
+        # segment that carries it: what its lane stood filled and not
+        # decoding (ints the loop holds; 0 for a one-chunk prompt)
+        self._obs_admit_segments = obs.histogram("serve/admit_segments",
+                                                 unit="segments")
         # enqueue -> admit: how long requests sit behind busy lanes (and,
         # paged, behind a full block pool).  Sliding-window so the SLO
         # gate and the autoscaler react to the LAST minute, not the
@@ -1058,20 +1063,37 @@ class ServeLoop:
         (shared-prefix blocks owned by the cache) — target the
         (out-of-range) index ``num_blocks`` and are DROPPED — only this
         admission's own allocated pages are written, so no live or
-        cached block of another owner can be hit."""
+        cached block of another owner can be hit.
+
+        Two scatters a leaf, the second under a ``cond``: the chip runs
+        a scatter's updates one after another, landed or dropped, so
+        the blocks two prefill chunks cover go first and the rest of
+        the page row (``max_seq_len / block`` entries) is walked only
+        for a prompt that reaches it.  The finish rides in the
+        iteration of the admission's last chunk, between two decode
+        segments, and most prompts are a chunk or two."""
         out = dict(big)
         bs = self.kv_block_size
         m = pages.shape[0]
         covered = ((jnp.arange(m) * bs < true_len)
                    & (jnp.arange(m) >= write_block))
+        head = min(m, max(1, 2 * self.prefill_chunk // bs))
         for leaf in _kv_leaves(big, "paged"):
             name = f"paged_{leaf}"
             tgt = jnp.where(covered, pages, big[name].shape[0])
             row = small[f"cached_{leaf}"][0]          # dense [S, F]
             pad = m * bs - row.shape[0]
-            blocks = jnp.pad(row, ((0, pad), (0, 0))).reshape(m, bs, -1)
-            out[name] = big[name].at[tgt].set(
-                blocks.astype(big[name].dtype), mode="drop")
+            blocks = (jnp.pad(row, ((0, pad), (0, 0))).reshape(m, bs, -1)
+                      .astype(big[name].dtype))
+            pool = big[name].at[tgt[:head]].set(blocks[:head], mode="drop")
+            if head < m:
+                # traced here and now, so the closure sees this leaf
+                pool = lax.cond(
+                    true_len > head * bs,
+                    lambda p: p.at[tgt[head:]].set(
+                        blocks[head:], mode="drop"),
+                    lambda p: p, pool)
+            out[name] = pool
         out["page_table"] = big["page_table"].at[slot].set(pages)
         out["cache_index"] = big["cache_index"].at[slot].set(true_len)
         return out
@@ -1782,8 +1804,9 @@ class ServeLoop:
         admission allocates (and prefix-aliases) pool blocks, stages a
         batch-1 prefill cache, and returns a slot state carrying a
         ``prefill`` phase — the run loop dispatches one prompt chunk
-        per iteration between decode segments and finishes with the
-        insert + lane stamps (see ``advance_admissions``)."""
+        per iteration between decode segments, and the finish (insert +
+        lane stamps) in the iteration of the last chunk (see
+        ``advance_admissions``)."""
         self._validate(req)
         prompt = np.asarray(req.prompt, np.int32)
         L = int(prompt.size)
@@ -1867,15 +1890,25 @@ class ServeLoop:
           the covered prefix entirely (positions below ``suffix_start``
           inside the first chunk are recomputed to identical bytes).
 
-        The run loop pops one ``(off, width)`` per iteration."""
+        The run loop pops one ``(off, width)`` per iteration; the one
+        that empties the worklist takes the finish dispatch with it."""
         max_new = int(req.max_new_tokens)
         suffix_start = 0
         write_block = 0
         shared_n = 0
+        cache1 = self._blank1
         if self._prefix_cache is not None:
             blocks, suffix_start, cow = self._prefix_plan(prompt, L)
             shared_n = len(blocks)
             self.pool.admit(slot, L, max_new, shared=blocks)
+            if suffix_start:
+                # gathered THROUGH the shared blocks, so before the COW
+                # split: the split's new block is empty until the finish
+                # insert, and a chunk narrower than a block recomputes
+                # only part of it
+                cache1 = self._gather_prefix(
+                    self.cache, self._blank1,
+                    jnp.asarray(self.pool.table[slot]))
             if cow:
                 self.pool.cow_write(slot, len(blocks) - 1)
             # registration is DEFERRED to the finish dispatch: the
@@ -1891,13 +1924,8 @@ class ServeLoop:
             self.pool.admit(slot, L, max_new)
         self.prefix_stats["prefill_tokens"] += L - suffix_start
         self._obs_prefill_tokens.inc(L - suffix_start)
-        if self.pool is not None:
-            pages = jnp.asarray(self.pool.table[slot])
-            cache1 = (self._gather_prefix(self.cache, self._blank1, pages)
-                      if suffix_start else self._blank1)
-        else:
-            pages = _NO_PAGES
-            cache1 = self._blank1
+        pages = (jnp.asarray(self.pool.table[slot])
+                 if self.pool is not None else _NO_PAGES)
         C = min(self.prefill_chunk, self.cfg.max_seq_len)
         Lp = min(-(-L // C) * C, self.cfg.max_seq_len)
         padded = np.full((1, Lp), self.pad_token, np.int32)
@@ -2267,6 +2295,8 @@ class ServeLoop:
                 "serve/request", t_q, now, rid=_span_rid(req.rid),
                 slot=slot, prompt_len=int(np.asarray(req.prompt).size),
                 chunks=chunks, tokens=timing.tokens, reason=reason,
+                admit_seq=stamps.get("admit_seq"),
+                decode_seq=stamps.get("decode_seq"),
                 **{k: None if t is None else t - t_q
                    for k, t in inner.items()})
             comp = Completion(
@@ -2383,6 +2413,17 @@ class ServeLoop:
                 else:
                     finalize(slot, "timeout")
 
+        def join_decode(st: dict) -> None:
+            """The lane's tokens first surface in the NEXT dispatched
+            segment (index ``seq``): the stamp gates its drain, rides
+            on the ``serve/request`` span as ``decode_seq`` next to
+            ``admit_seq``, and their difference (the segments the lane
+            stood filled and not decoding) ticks
+            ``serve/admit_segments``."""
+            st["seq"] = st["stamps"]["decode_seq"] = seq
+            self._obs_admit_segments.record(
+                seq - st["stamps"]["admit_seq"])
+
         def admit_free() -> None:
             """Expire queued deadlines, then fill free lanes from the
             queue; a new admission's tokens first surface in the NEXT
@@ -2471,10 +2512,11 @@ class ServeLoop:
                     # (advance_admissions) — its tokens cannot surface
                     # before that segment.
                     st["stamps"] = {"enqueue": t_q,
-                                    "admit": time.perf_counter()}
+                                    "admit": time.perf_counter(),
+                                    "admit_seq": seq}
                     if "prefill" not in st:
                         st["stamps"]["prefill_done"] = st["stamps"]["admit"]
-                        st["seq"] = seq
+                        join_decode(st)
                     self._obs_requests.inc()
                     obs.recorder.record(
                         "serve_admit", slot=slot, seq=seq,
@@ -2547,12 +2589,16 @@ class ServeLoop:
             pass.  Each chunk is an async dispatch into the lane's
             transient batch-1 cache (same chunk grid as the one-shot
             ``_prefill``, so the KV and logits are bitwise identical).
-            When the worklist empties, the FINISH dispatch scatters the
-            batch-1 cache into the paged table (suffix blocks only —
-            shared prefix blocks are read in place), selects the first
-            token from the final chunk's logits, stamps the lane
-            active, and the slot joins decode with its drain gated on
-            the NEXT segment."""
+            The FINISH dispatch rides in the SAME iteration as the
+            lane's last chunk: it needs nothing but that chunk's
+            outputs, which are device futures already in hand, and the
+            device runs dispatches in order.  It scatters the batch-1
+            cache into the paged table (suffix blocks only — shared
+            prefix blocks are read in place), selects the first token
+            from the final chunk's logits, stamps the lane active, and
+            the slot joins decode with its drain gated on the segment
+            ``dispatch()`` chains next, in this iteration: a lane never
+            stands filled and idle for an iteration of its own."""
             freed_by_handoff: list[int] = []
             for slot in range(self.B):
                 st = slot_state[slot]
@@ -2564,7 +2610,7 @@ class ServeLoop:
                     toks = pf["padded"][:, off:off + w]
                     with obs.span("serve/prefill_chunk", slot=slot,
                                   rid=_span_rid(st["req"].rid), off=off,
-                                  width=w):
+                                  width=w, seq=seq):
                         pf["cache1"], pf["logits"] = self._prefill_chunk(
                             self.params, pf["cache1"], toks,
                             np.int32(off), chunk=w)
@@ -2572,10 +2618,11 @@ class ServeLoop:
                     pf["off_last"] = off
                     tev("prefill_chunk", st["req"], slot=slot,
                         off=off, width=w, left=len(pf["chunks"]))
-                    continue
+                    if pf["chunks"]:
+                        continue   # ONE chunk a lane per iteration
                 self._key, pk = jax.random.split(self._key)
                 with obs.span("serve/admit_finish", slot=slot,
-                              rid=_span_rid(st["req"].rid)):
+                              rid=_span_rid(st["req"].rid), seq=seq):
                     (self.cache, self._tok, self._active,
                      self._remaining, self._first) = self._admit_finish(
                         self.cache, self._tok, self._active,
@@ -2628,8 +2675,7 @@ class ServeLoop:
                     freed_by_handoff.append(slot)
                     continue
                 del st["prefill"]
-                # tokens first surface in the NEXT dispatched segment
-                st["seq"] = seq
+                join_decode(st)
             if freed_by_handoff:
                 # a prefill-role loop has no decode dispatches, so
                 # nothing else would refill a lane freed by export —
