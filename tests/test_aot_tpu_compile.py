@@ -355,6 +355,158 @@ def test_latent_expert_model_names_its_kernels(v5e):
                                   *_segment_args(v5e, loop))) == 4
 
 
+# The Mellum 2 cell (mellum2_agent_mixed): 40 lanes, 32 query heads on 4
+# K/V heads of 128, block 128, 64 table entries, side 16, a window of 1024
+# in a pool of its own (400 blocks); 16 held experts of 2304 x 896 of 64.
+WIN_LANES, WIN_HEADS, WIN_KV, WIN_D, WIN_W, WIN_STEPS = 40, 32, 4, 128, 1024, 16
+
+
+def test_paged_window_decode_at_the_cells_shapes(v5e):
+    """The windowed walk for the chip: its own name, the plain kernel's
+    six operands and one output, so that readers by name tell the two
+    apart and no reader by shape counts it twice."""
+    flat = WIN_KV * WIN_D
+    q = _sds(v5e, (WIN_LANES, 1, WIN_HEADS, WIN_D))
+    pool = _sds(v5e, (400, BLOCK, flat))
+    table = _sds(v5e, (WIN_LANES, SEQ // BLOCK), jnp.int32)
+    lens = _sds(v5e, (WIN_LANES,), jnp.int32)
+    buf = _sds(v5e, (WIN_LANES, WIN_STEPS, flat))
+
+    def call(window):
+        return _compile(
+            lambda q, k, v, t, n, sk, sv, sl: paged_flash_decode(
+                q, k, v, t, n, packed_kv_heads=WIN_KV, side_k=sk,
+                side_v=sv, side_len=sl, window=window),
+            q, pool, pool, table, lens, buf, buf, _sds(v5e, (), jnp.int32))
+
+    from benchmarks.layer_metrics.paged_decode_us_per_call import KERNEL
+    from benchmarks.layer_metrics.window_decode_us_per_call import (
+        KERNEL as WINDOW_KERNEL)
+
+    for window, name, mine, other in (
+            (WIN_W, "paged_window_decode", WINDOW_KERNEL, KERNEL),
+            (None, "paged_flash_decode", KERNEL, WINDOW_KERNEL)):
+        hlo = call(window).replace("ROOT %", "%")
+        assert _kernel_calls(hlo) == 1
+        op = _custom_call(hlo, name)
+        assert op["pallas"] and op["operands"] == 6
+        assert len(op["outputs"]) == 1
+        (line,) = [l.strip() for l in hlo.splitlines()
+                   if re.match(rf"\s*%{name}[.\d]* = ", l)]
+        assert mine.match(line) and not other.match(line)
+
+
+def test_window_none_lowers_as_before_the_window(v5e):
+    """``window=None`` is the program of PR 30, instruction for instruction:
+    the kernel's lowered text does not depend on the argument being passed,
+    and differs from the windowed one."""
+    lanes, heads, kv_heads, d, entries, n_blocks = PAGED_SHAPES["cell_sc3b"]
+    flat = kv_heads * d
+    args = (_sds(v5e, (lanes, 1, heads, d)), _sds(v5e, (n_blocks, BLOCK, flat)),
+            _sds(v5e, (n_blocks, BLOCK, flat)),
+            _sds(v5e, (lanes, entries), jnp.int32),
+            _sds(v5e, (lanes,), jnp.int32), _sds(v5e, (lanes, STEPS, flat)),
+            _sds(v5e, (lanes, STEPS, flat)), _sds(v5e, (), jnp.int32))
+
+    def text(**kw):
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            return jax.jit(
+                lambda q, k, v, t, n, sk, sv, sl: paged_flash_decode(
+                    q, k, v, t, n, packed_kv_heads=kv_heads, side_k=sk,
+                    side_v=sv, side_len=sl, **kw)).lower(*args).as_text()
+
+    plain = text()
+    assert text(window=None) == plain
+    assert text(window=1024) != plain
+
+
+def test_rolling_prefill_flash_forward_at_the_cells_shapes(v5e):
+    """A windowed layer's prefill chunk: 512 queries at a dynamic offset
+    against the rolling buffer of 1024 + 512 rows at a dynamic base."""
+    kv = _sds(v5e, (1, WIN_W + CHUNK, WIN_KV, WIN_D))
+    hlo = _compile(
+        lambda q, k, v, off, base: _flash_forward(
+            q, k, v, True, CHUNK, 512, False, q_offset=off, k_offset=base,
+            window=WIN_W)[0],
+        _sds(v5e, (1, CHUNK, WIN_HEADS, WIN_D)), kv, kv,
+        _sds(v5e, (), jnp.int32), _sds(v5e, (), jnp.int32))
+    assert _kernel_calls(hlo) == 1
+
+
+@pytest.mark.parametrize("tokens", [WIN_LANES, CHUNK],
+                         ids=["decode_step", "prefill_chunk"])
+def test_grouped_expert_product_at_2304_by_896(v5e, tokens):
+    """16 held experts of 2304 x 896, 8 choices a token over 64: a decode
+    step of 40 lanes (row block 16) and a prefill chunk of 512 (128)."""
+    from tpudist.ops.moe_dispatch import (_tiles, grouped_gated_mlp,
+                                          row_block)
+
+    d, f, held, top_k, experts = 2304, 896, 16, 8, 64
+    assert row_block(WIN_LANES * top_k, experts) == 16
+    assert row_block(CHUNK * top_k, experts) == 128
+    # whole rows of an expert's matrix, the contraction in two halves
+    assert _tiles(d, f, 2, 2 << 20) == (1152, 896)
+    assert _tiles(f, d, 2, 4 << 20) == (896, 2304)
+    hlo = _compile(
+        lambda x, wg, wu, wd, i, w: grouped_gated_mlp(
+            x, wg, wu, wd, i, w, num_experts=experts),
+        _sds(v5e, (tokens, d)), _sds(v5e, (held, d, f)),
+        _sds(v5e, (held, d, f)), _sds(v5e, (held, f, d)),
+        _sds(v5e, (tokens, top_k), jnp.int32),
+        _sds(v5e, (tokens, top_k), jnp.float32))
+    assert _kernel_calls(hlo) == 2
+
+
+def test_mixed_window_model_names_its_kernels(v5e):
+    """A model with sliding-window and full layers, grouped queries at a
+    stated head width and expert layers (Mellum 2's block at a small
+    size): the segment's kernels and the prefill chunk's, by the names a
+    trace shows, and the finish program for both block groups."""
+    from tpudist.models import MoEConfig, YarnScaling
+
+    moe = MoEConfig(num_experts=16, top_k=4, experts="gated_silu", d_ff=128,
+                    scoring="softmax", held=(0, 4))
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=4, num_heads=8, num_kv_heads=2,
+        head_size=128, embed_dim=512, max_seq_len=4096,
+        compute_dtype=jnp.bfloat16, norm="rmsnorm", positions="rotary",
+        rope_theta=500000.0,
+        rope_scaling=YarnScaling(16.0, 8192, 32.0, 1.0, 1.0, 0.0),
+        window_rope_scaling=None, layer_windows=(1024, 1024, 1024, None),
+        mlp="gated_silu", mlp_dim=128, moe=moe)
+    loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),
+                     num_slots=SLOTS, steps_per_sync=WIN_STEPS,
+                     decode_attention="flash", prefill_chunk=CHUNK,
+                     cache_layout="paged", kv_block_size=BLOCK)
+    assert loop.kv_window_blocks == SLOTS * 10
+    seg = _on(v5e, (loop.params, loop.cache, loop._tok, loop._active,
+                    loop._remaining, loop._first, loop._key,
+                    jnp.int32(WIN_STEPS), jnp.bool_(False)))
+    assert _kernel_scopes(loop._segment, *seg) == {
+        "paged_window_decode", "paged_flash_decode", "moe_experts_gate_up",
+        "moe_experts_down"}
+    assert _kernel_calls(_compile(loop._segment, *seg)) == 4 + 2 * 4
+    # a windowed layer's batch-1 cache is the window and a chunk
+    assert loop._blank1["block0"]["attn"]["cached_key"].shape == (
+        1, 1024 + CHUNK, 256)
+    assert loop._blank1["block3"]["attn"]["cached_key"].shape == (
+        1, 4096, 256)
+    chunk = _on(v5e, (loop.params, loop._blank1,
+                      jnp.zeros((1, CHUNK), jnp.int32), jnp.int32(0),
+                      jnp.int32(0)))
+    assert _kernel_scopes(loop._prefill_chunk, *chunk, chunk=CHUNK) == {
+        "flash_fwd", "moe_experts_gate_up", "moe_experts_down"}
+    assert _kernel_calls(_compile(loop._prefill_chunk, *chunk,
+                                  chunk=CHUNK)) == 4 + 2 * 4
+    pages = (jnp.zeros((32,), jnp.int32), jnp.zeros((32,), jnp.int32))
+    finish = _on(v5e, (loop.cache, loop._tok, loop._active, loop._remaining,
+                       loop._first, loop._blank1,
+                       jnp.zeros((1, 1, 1024), jnp.float32), jnp.int32(0),
+                       jnp.int32(5), jnp.int32(0), jnp.int32(5), pages,
+                       jnp.int32(0), loop._key))
+    _compile(loop._admit_finish, *finish)
+
+
 def test_dense_layout_segment_names_its_kernel(v5e):
     cfg = _cfg(4, 1)
     loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),

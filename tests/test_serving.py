@@ -295,9 +295,13 @@ class TestPagedCache:
             ServeLoop(CFG, params, num_slots=1, cache_layout="paged",
                       kv_block_size=12)
         import dataclasses
-        wcfg = dataclasses.replace(CFG, attention_window=32)
-        with pytest.raises(ValueError, match="sliding-window"):
-            ServeLoop(wcfg, params, num_slots=1, cache_layout="paged")
+        # a windowed model serves paged since PR 31 (its own block group;
+        # tests/test_window_serving.py); a segment's staged tokens must
+        # fit in the window
+        wcfg = dataclasses.replace(CFG, attention_window=16)
+        with pytest.raises(ValueError, match="fit in the window"):
+            ServeLoop(wcfg, params, num_slots=1, cache_layout="paged",
+                      steps_per_sync=32)
         # a request whose reservation can NEVER fit the pool fails fast
         loop = ServeLoop(CFG, params, num_slots=1, cache_layout="paged",
                          kv_block_size=16, kv_num_blocks=2)

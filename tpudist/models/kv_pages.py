@@ -62,6 +62,18 @@ Cached-but-idle blocks (pinned, refcount 0) are RECLAIMABLE capacity:
 blocks only, and :attr:`used_blocks` excludes them — so admission
 control, the drain check, and the autoscaler all see truthful pressure.
 
+Block GROUPS (PR 31).  A model whose layers are not all of one kind
+keeps them in pools of different needs: a full-attention layer holds every
+token of a request, a sliding-window layer the last ``window`` of them
+whatever the length.  The pool above is the FULL group, and behaves as it
+always did, to the block; :class:`WindowGroup` is the window layers' group
+beside it, with its own capacity, free list, table, reservation rule
+(what covers a window and a segment, never a request's whole length) and
+``used_blocks``, and it RELEASES a lane's blocks that fell wholly below
+the window before each segment.  ``BlockPool(window=...)`` builds it; the
+pool's ``can_admit`` / ``admit`` / ``grow`` / ``free_slot`` / ``check``
+cover both groups, so the serve loop asks one object.
+
 The device half lives in :mod:`tpudist.models.transformer`
 (``CausalSelfAttention._paged_attend``) and
 :func:`tpudist.ops.flash_decode.paged_flash_decode`.
@@ -119,6 +131,186 @@ def request_prefix_hash(tokens: Sequence[int]) -> int:
     return _hash_bytes(np.asarray(tokens, np.int32).tobytes())
 
 
+def span_blocks(rows: int, block_size: int) -> int:
+    """The most blocks ``rows`` consecutive positions touch, at the worst
+    alignment."""
+    return max(rows, 0) if rows < 2 else (rows - 2) // block_size + 2
+
+
+class WindowGroup:
+    """The block group of a model's SLIDING-WINDOW layers: a lane holds the
+    blocks that cover ``[len - window + 1, len + steps)`` and nothing
+    below, whatever its length.
+
+    * **reservation**: ``min(blocks of the request's whole length,
+      lane_blocks)``, where ``lane_blocks`` is what a window and a segment
+      of ``steps`` tokens touch at the worst alignment.  Held for the
+      request's life: released blocks stay promised to the lane, so its
+      growth can never fail (the same promise the full group makes).
+    * **admit**: the blocks that cover the prompt's last ``window - 1``
+      rows (what the first decode step sees; the finish insert writes
+      exactly those).
+    * **grow** (before each dispatched segment, with the lane's length
+      ``held`` at its start and its coverage ``target`` after): blocks
+      wholly below row ``held + 1 - window`` are RELEASED to the free list
+      (``released`` counts them), then blocks are drawn to cover
+      ``target``.  A released block may be handed to another lane at once:
+      the device runs dispatches in order, so a segment already in flight
+      reads it before its next owner's insert or merge writes it, and a
+      windowed decode never reads a page id below its window.
+    * a lane's ``table`` row holds its blocks at their logical indices and
+      0 (a valid pool index) everywhere else.
+    """
+
+    def __init__(self, num_blocks: int | None, block_size: int,
+                 num_slots: int, max_seq_len: int, window: int,
+                 steps: int) -> None:
+        """``num_blocks`` None: every lane's ``lane_blocks``, a group
+        that never refuses an admission."""
+        if window < 1 or steps < 1:
+            raise ValueError(
+                f"window and steps must be >= 1, got {window}, {steps}")
+        self.window, self.steps = int(window), int(steps)
+        self.block_size = int(block_size)
+        self.max_seq_len = int(max_seq_len)
+        self.lane_blocks = min(
+            span_blocks(self.window - 1 + self.steps, block_size),
+            blocks_for(max_seq_len, block_size))
+        if num_blocks is None:
+            num_blocks = num_slots * self.lane_blocks
+        if num_blocks < self.lane_blocks:
+            raise ValueError(
+                f"the window group needs at least one lane's "
+                f"{self.lane_blocks} blocks, got {num_blocks}")
+        self.num_blocks = int(num_blocks)
+        self._free: list[int] = list(range(self.num_blocks - 1, -1, -1))
+        # a lane's blocks at logical indices _lo .. _lo + len - 1
+        self._blocks: list[list[int]] = [[] for _ in range(num_slots)]
+        self._lo = [0] * num_slots
+        self._need = [0] * num_slots          # the lane's reservation
+        self._reserved_total = 0              # promised, not yet drawn
+        self.released = 0                     # lifetime, blocks
+        self.table = np.zeros(
+            (num_slots, blocks_for(max_seq_len, block_size)), np.int32)
+        self._obs_used = obs.gauge("serve/kv_window_blocks_used",
+                                   unit="blocks")
+        self._obs_free = obs.gauge("serve/kv_window_blocks_free",
+                                   unit="blocks")
+        self._obs_released = obs.counter("serve/kv_window_blocks_released",
+                                         unit="blocks")
+        self._publish()
+
+    @property
+    def free_blocks(self) -> int:
+        return len(self._free) - self._reserved_total
+
+    @property
+    def used_blocks(self) -> int:
+        return self.num_blocks - len(self._free)
+
+    def _publish(self) -> None:
+        self._obs_used.set(self.used_blocks)
+        self._obs_free.set(len(self._free))
+
+    def request_blocks(self, prompt_len: int, max_new_tokens: int) -> int:
+        total = min(prompt_len + max_new_tokens, self.max_seq_len)
+        return min(blocks_for(total, self.block_size), self.lane_blocks)
+
+    def can_admit(self, prompt_len: int, max_new_tokens: int) -> bool:
+        return (self.request_blocks(prompt_len, max_new_tokens)
+                <= self.free_blocks)
+
+    def first_block(self, held: int) -> int:
+        """The block that holds the first row a query at position ``held``
+        sees: everything below it is outside the window for good."""
+        return max(held + 1 - self.window, 0) // self.block_size
+
+    def _cover(self, slot: int, lo: int, count: int) -> None:
+        """Hold blocks ``lo .. count - 1``: release below, draw above."""
+        blks = self._blocks[slot]
+        drop = min(max(lo - self._lo[slot], 0), len(blks))
+        if drop:
+            self.table[slot, self._lo[slot]:self._lo[slot] + drop] = 0
+            self._free.extend(reversed(blks[:drop]))
+            del blks[:drop]
+            self._lo[slot] += drop
+            self._reserved_total += drop
+            self.released += drop
+            self._obs_released.inc(drop)
+        if not blks:
+            self._lo[slot] = lo
+        while self._lo[slot] + len(blks) < count:
+            if not self._free:
+                raise RuntimeError("window block group exhausted")
+            blk = self._free.pop()
+            self.table[slot, self._lo[slot] + len(blks)] = blk
+            blks.append(blk)
+            self._reserved_total -= 1
+        if len(blks) > self._need[slot]:
+            raise AssertionError(
+                f"slot {slot} holds {len(blks)} window blocks over its "
+                f"reservation {self._need[slot]}")
+
+    def admit(self, slot: int, prompt_len: int, max_new_tokens: int) -> None:
+        if self._blocks[slot] or self._need[slot]:
+            raise RuntimeError(f"slot {slot} still holds window blocks")
+        need = self.request_blocks(prompt_len, max_new_tokens)
+        if need > self.free_blocks:
+            raise RuntimeError(
+                f"admit of {need} window blocks exceeds free "
+                f"{self.free_blocks} (call can_admit first)")
+        self._need[slot] = need
+        self._reserved_total += need
+        self._cover(slot, self.first_block(prompt_len),
+                    blocks_for(prompt_len, self.block_size))
+        self._publish()
+
+    def grow(self, slot: int, held: int, target: int) -> None:
+        self._cover(slot, self.first_block(held),
+                    blocks_for(target, self.block_size))
+        self._publish()
+
+    def free_slot(self, slot: int) -> None:
+        blks = self._blocks[slot]
+        self._reserved_total -= self._need[slot] - len(blks)
+        self._free.extend(reversed(blks))
+        blks.clear()
+        self.table[slot, :] = 0
+        self._lo[slot] = 0
+        self._need[slot] = 0
+        self._publish()
+
+    def check(self) -> None:
+        held = [b for blks in self._blocks for b in blks]
+        if len(held) != len(set(held)):
+            raise AssertionError("a window block is held twice")
+        free = set(self._free)
+        if len(free) != len(self._free):
+            raise AssertionError("duplicate blocks on the window free list")
+        if free & set(held):
+            raise AssertionError(
+                f"window blocks both free and held: {free & set(held)}")
+        if len(free) + len(held) != self.num_blocks:
+            raise AssertionError("leaked window blocks: held + free != pool")
+        promised = sum(n - len(b) for n, b in zip(self._need, self._blocks))
+        if promised != self._reserved_total or not (
+                0 <= self._reserved_total <= len(self._free)):
+            raise AssertionError(
+                f"window reservation {self._reserved_total} (counted "
+                f"{promised}) outside the free list's {len(self._free)}")
+        for slot, blks in enumerate(self._blocks):
+            if len(blks) > self.lane_blocks:
+                raise AssertionError(
+                    f"slot {slot} holds {len(blks)} window blocks, more "
+                    f"than a lane's {self.lane_blocks}")
+            want = np.zeros_like(self.table[slot])
+            want[self._lo[slot]:self._lo[slot] + len(blks)] = blks
+            if not np.array_equal(want, self.table[slot]):
+                raise AssertionError(
+                    f"slot {slot}'s window table row drifted from its "
+                    f"blocks")
+
+
 class BlockPool:
     """Host-side allocator for the paged KV cache.
 
@@ -129,6 +321,13 @@ class BlockPool:
         needs the 8-row sublane tile).
       num_slots: decode lanes (page-table rows).
       max_seq_len: model context; bounds ``max_blocks_per_slot``.
+      window / window_blocks / window_steps: a model with sliding-window
+        layers: their width, the capacity of their block group (default:
+        every lane's ``lane_blocks``, so that group never refuses an
+        admission) and the most tokens a segment adds.  Builds
+        :attr:`window_group`; everything above is then the FULL layers'
+        group.  Prefix aliasing and KV export are the full group's alone
+        and are refused with a window group.
 
     The page table (:attr:`table`) is a ``[num_slots,
     max_blocks_per_slot]`` int32 array; rows are filled left-to-right
@@ -139,7 +338,9 @@ class BlockPool:
     """
 
     def __init__(self, num_blocks: int, block_size: int, num_slots: int,
-                 max_seq_len: int) -> None:
+                 max_seq_len: int, *, window: int | None = None,
+                 window_blocks: int | None = None,
+                 window_steps: int = 1) -> None:
         if block_size < 8 or block_size % 8:
             raise ValueError(
                 f"block_size must be a positive multiple of 8, got "
@@ -180,6 +381,11 @@ class BlockPool:
         self._obs_free = obs.gauge("serve/kv_blocks_free", unit="blocks")
         self._obs_frag = obs.gauge("serve/kv_frag", unit="fraction")
         self._obs_cow = obs.counter("serve/cow_splits", unit="blocks")
+        self.window_group: WindowGroup | None = None
+        if window is not None:
+            self.window_group = WindowGroup(
+                window_blocks, block_size, num_slots, max_seq_len, window,
+                window_steps)
         self._publish()
 
     # -- accounting --------------------------------------------------------
@@ -288,6 +494,8 @@ class BlockPool:
                 raise AssertionError(
                     f"in-migration blocks of slot {slot} unreferenced: "
                     f"{bad}")
+        if self.window_group is not None:
+            self.window_group.check()
 
     # -- allocation --------------------------------------------------------
 
@@ -302,7 +510,10 @@ class BlockPool:
         is the extra private block a copy-on-write split will draw
         immediately after admit (full-prompt cache hits)."""
         return (self.request_blocks(prompt_len, max_new_tokens)
-                - shared + cow <= self.free_blocks)
+                - shared + cow <= self.free_blocks
+                and (self.window_group is None
+                     or self.window_group.can_admit(prompt_len,
+                                                    max_new_tokens)))
 
     def _take_block(self) -> int:
         if not self._free and not (
@@ -323,6 +534,12 @@ class BlockPool:
                                "free_slot it before re-admitting")
         total = self.request_blocks(prompt_len, max_new_tokens)
         now = blocks_for(prompt_len, self.block_size)
+        if self.window_group is not None:
+            if shared:
+                raise RuntimeError(
+                    "prefix aliasing is the full group's alone: a pool "
+                    "with a window group shares nothing")
+            self.window_group.admit(slot, prompt_len, max_new_tokens)
         if len(shared) > now:
             raise ValueError(
                 f"{len(shared)} shared blocks exceed the prompt's "
@@ -407,6 +624,8 @@ class BlockPool:
             raise RuntimeError(
                 f"grow on slot {slot} while its KV is in migration")
         target = min(self._watermark[slot] + steps, self._cap[slot])
+        if self.window_group is not None:
+            self.window_group.grow(slot, self._watermark[slot], target)
         need = blocks_for(target, self.block_size)
         have = len(self._slot_blocks[slot])
         if need > have:
@@ -447,6 +666,8 @@ class BlockPool:
         self._cap[slot] = 0
         self._shared_upto[slot] = 0
         self._prompt_len[slot] = 0
+        if self.window_group is not None:
+            self.window_group.free_slot(slot)
         self._publish()
 
     # -- KV migration (disaggregated prefill/decode) ----------------------
@@ -463,6 +684,10 @@ class BlockPool:
         free list.  The caller reads the device pages named by
         ``blocks`` while the freeze holds."""
         blks = self._slot_blocks[slot]
+        if self.window_group is not None:
+            raise RuntimeError(
+                "KV export carries one block list a slot: a pool with a "
+                "window group exports nothing")
         if not blks:
             raise RuntimeError(f"export_slot on empty slot {slot}")
         if slot in self._migrating:

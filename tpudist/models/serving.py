@@ -56,7 +56,12 @@ from tpudist.models.generate import (
     _stop_array,
     serving_layout,
 )
-from tpudist.models.kv_pages import BlockPool, PrefixCache, chain_hashes
+from tpudist.models.kv_pages import (
+    BlockPool,
+    PrefixCache,
+    chain_hashes,
+    span_blocks,
+)
 from tpudist.models.kv_tier import HostTier, tier_budget_from_env
 from tpudist.models.speculative import (
     AdaptiveDraftPolicy,
@@ -284,6 +289,15 @@ class ServeLoop:
         sizes the pool to full dense capacity
         (``num_slots × ceil(max_seq_len / block_size)``); the HBM win
         comes from passing the capacity the workload actually needs.
+      A model with sliding-window layers (``cfg.layer_windows`` or
+        ``attention_window``) served paged keeps them in a block group of
+        their own beside ``kv_num_blocks`` (the full-attention layers'):
+        a lane holds there what covers its last ``window`` tokens and a
+        segment, and releases what fell below.  That group is sized to
+        every lane's worst case (``kv_window_blocks``, read-only), so it
+        never refuses an admission.  ``docs/DESIGN.md`` ("Window and full
+        layers in one paged cache") lists what such a model cannot be
+        combined with; each is refused here with the reason.
       pipeline_depth: compiled segments in flight before the host blocks
         on a fetch.  2 (the default) dispatches segment ``k+1`` as soon
         as ``k`` returns — the carry chains on device — and then fetches
@@ -341,6 +355,18 @@ class ServeLoop:
         can no longer stall every in-flight request's inter-token
         latency for its whole prefill.  The chunk partition is the SAME
         grid the one-shot path uses, so output stays token-identical.
+      max_prefill_lanes: the most lanes in chunked admission at once
+        (``None``: no bound).  A lane in admission holds a batch-1
+        prefill cache (``max_seq_len`` rows in every full-attention
+        layer) from its first chunk to its finish, so a burst of long
+        prompts (a cold start filling every lane) holds ``lanes x
+        chunks`` of them at once; with the bound, a request whose turn
+        comes while that many lanes prefill WAITS in the queue (FIFO,
+        ``serve/queue_wait_s``) for a finish.  Bounds memory, never
+        results.  Every lane in admission runs one chunk between two
+        decode segments, so it is also the prefill an iteration may
+        carry (``max_prefill_lanes x prefill_chunk`` tokens): the
+        bound on the inter-token gap's tail.
       prefix_sharing: copy-on-write prefix page sharing (paged layout +
         chunked prefill only; silently off otherwise).  A host-side
         :class:`~tpudist.models.kv_pages.PrefixCache` maps rolling
@@ -396,6 +422,7 @@ class ServeLoop:
         num_draft: int | str = "adaptive",
         spec_ladder: Sequence[int] = (2, 4, 8),
         chunked_prefill: bool = True,
+        max_prefill_lanes: int | None = None,
         prefix_sharing: bool = True,
         role: str = "both",
         preempt: str = "degrade",
@@ -420,10 +447,37 @@ class ServeLoop:
             raise ValueError(
                 f"cache_layout must be 'dense' or 'paged', got "
                 f"{cache_layout!r}")
-        if cache_layout == "paged" and cfg.attention_window is not None:
-            raise ValueError(
-                "cache_layout='paged' has no sliding-window trim yet; "
-                "serve windowed models with the dense layout")
+        # a layer's window, by its name in the cache tree; the one width
+        # of the windowed layers (None: the model has none)
+        self._window_of = {f"block{i}": w
+                           for i, w in enumerate(cfg.windows)}
+        widths = {w for w in cfg.windows if w is not None}
+        self._window = min(widths) if widths else None
+        windowed_paged = cache_layout == "paged" and bool(widths)
+        if windowed_paged:
+            # what a cache of two block groups cannot do yet, refused
+            # here with the reason (docs/DESIGN.md has the list)
+            if len(widths) > 1:
+                raise ValueError(
+                    f"the paged cache keeps ONE window block group: the "
+                    f"windowed layers must share a width, got "
+                    f"{sorted(widths)}")
+            if decode_mode != "plain":
+                raise ValueError(
+                    "a model with sliding-window layers serves paged "
+                    "under decode_mode='plain' only: a verify chunk "
+                    "would need the windowed kernel at several queries "
+                    "and a rollback of released blocks")
+            if role != "both" or preempt == "migrate":
+                raise ValueError(
+                    "KV handoff and migration payloads carry one block "
+                    "list a slot; a cache with a window block group "
+                    "serves with role='both' and preempt='degrade'")
+            if self._window < steps_per_sync:
+                raise ValueError(
+                    f"a segment's {steps_per_sync} staged tokens must "
+                    f"fit in the window ({self._window}): lower "
+                    "steps_per_sync")
         if role not in ("both", "prefill", "decode"):
             raise ValueError(
                 f"role must be 'both', 'prefill', or 'decode', got "
@@ -468,7 +522,8 @@ class ServeLoop:
         self._stop = _stop_array(stop_tokens)
         self._stop_set = (set(np.asarray(self._stop).tolist())
                           if self._stop is not None else set())
-        if decode_attention == "flash" and cfg.attention_window is not None:
+        if (decode_attention == "flash" and self._window is not None
+                and cache_layout != "paged"):
             import warnings
 
             warnings.warn(
@@ -539,7 +594,7 @@ class ServeLoop:
         # K+1-token verify chunk past the steps_per_sync-1 already kept.
         self.side = (steps_per_sync + self._k_max
                      if (decode_attention == "flash"
-                         and cfg.attention_window is None)
+                         and self._window is None)
                      or cache_layout == "paged" else 0)
         self.cache_layout = cache_layout
         if cache_layout == "paged":
@@ -548,20 +603,33 @@ class ServeLoop:
                   if kv_num_blocks is None else int(kv_num_blocks))
             self.kv_block_size, self.kv_num_blocks = bs_, nb
             # the host half: free list, per-slot block lists, and the
-            # page table the compiled carry consumes (stamped at dispatch)
-            self.pool = BlockPool(nb, bs_, num_slots, cfg.max_seq_len)
+            # page table the compiled carry consumes (stamped at
+            # dispatch); with windowed layers, their block group beside
+            self.pool = BlockPool(
+                nb, bs_, num_slots, cfg.max_seq_len, window=self._window,
+                window_steps=steps_per_sync)
         else:
             self.kv_block_size = self.kv_num_blocks = 0
             self.pool = None
+        wg = self.pool.window_group if self.pool is not None else None
+        self.kv_window_blocks = wg.num_blocks if wg is not None else 0
         # chunked-interleaved prefill: plain decode only (the
         # speculative admit fuses a draft prefill into the same dispatch
         # and keeps the one-shot path); prefix sharing additionally
         # needs the paged layout — shared blocks live in the pool
         self.chunked = bool(chunked_prefill) and decode_mode == "plain"
+        if max_prefill_lanes is not None and max_prefill_lanes < 1:
+            raise ValueError(
+                f"max_prefill_lanes must be >= 1, got {max_prefill_lanes}")
+        self.max_prefill_lanes = max_prefill_lanes
+        # (a cache with a window group shares nothing: a hit's boundary
+        # would have to bring the window group's blocks before it, which
+        # the finished request released long ago; off, as with the dense
+        # layout)
         self._prefix_cache = (
             PrefixCache(self.pool)
             if prefix_sharing and self.chunked and self.pool is not None
-            else None)
+            and wg is None else None)
         # the weights version the loop's CURRENT params correspond to;
         # stamps tier entries and pull-mode exports so KV computed
         # under one version can never be adopted under another (the
@@ -595,16 +663,23 @@ class ServeLoop:
                                    serve_side_slots=self.side,
                                    cache_layout=cache_layout,
                                    kv_num_blocks=self.kv_num_blocks,
-                                   kv_block_size=self.kv_block_size)
+                                   kv_block_size=self.kv_block_size,
+                                   kv_window_blocks=self.kv_window_blocks)
         # admission prefill ALWAYS runs dense: it fills a fresh batch-1
         # scalar-index cache (contiguous chunked writes) and the insert
         # scatters that row into pages — prefilling straight into the
         # pool would need per-chunk page-table plumbing for zero gain
         # (the batch-1 cache is transient)
+        # a windowed layer's batch-1 cache follows its kind too: the
+        # window and the chunk in hand (CausalSelfAttention.
+        # _rolling_prefill), not max_seq_len rows
         self._prefill_model = (
             TransformerLM(cfg, decode=True,
                           decode_attention=decode_attention,
-                          serve_side_slots=self.side)
+                          serve_side_slots=self.side,
+                          prefill_window_rows=(
+                              min(prefill_chunk, cfg.max_seq_len)
+                              if wg is not None else 0))
             if cache_layout == "paged" else self.model)
         # the slot cache: blank, with VECTOR index leaves (one position
         # per slot) — this is what routes attention through the per-row
@@ -736,6 +811,12 @@ class ServeLoop:
                                               unit="rows")
         self._obs_rows_live = obs.counter("serve/decode_rows_live",
                                           unit="rows")
+        # the same two of the WINDOW layers' calls (a layer): walk_rows
+        # with the window, and min(length, window)
+        self._obs_rows_window = obs.counter(
+            "serve/decode_rows_window_computed", unit="rows")
+        self._obs_rows_window_live = obs.counter(
+            "serve/decode_rows_window_live", unit="rows")
         # expert layers: tokens the held experts were given over a drained
         # segment's steps (all lanes, as lane_steps counts them), the
         # busiest (layer, expert)'s part of that, and the (step, layer,
@@ -911,14 +992,24 @@ class ServeLoop:
         Each layer gets a FRESH device array: the segment donates the
         whole cache, and one buffer shared across every layer's
         ``page_table`` leaf would be donated more than once."""
-        tbl = self.pool.table
+        wg = self.pool.window_group
+        # a SNAPSHOT of the window group's table: the host zeroes a live
+        # lane's released entries in place while an earlier segment may
+        # not have run yet, and on the CPU backend a device array can
+        # alias the numpy buffer it was made from.  (The full group's
+        # entries of a live lane never change, so its table goes as ever.)
+        window_tbl = None if wg is None else wg.table.copy()
 
-        def walk(node):
+        def walk(node, window=None):
             if not isinstance(node, dict):
                 return node
-            out = {k: walk(v) for k, v in node.items()}
+            out = {k: walk(v, self._window_of.get(k, window))
+                   for k, v in node.items()}
             if "page_table" in out:
-                out["page_table"] = jnp.asarray(tbl)
+                # a layer's own group's table
+                out["page_table"] = jnp.asarray(
+                    self.pool.table if window is None or wg is None
+                    else window_tbl)
             return out
 
         self.cache = walk(self.cache)
@@ -1032,7 +1123,7 @@ class ServeLoop:
         return cache, first
 
     def _insert_impl(self, cache, cache1, slot, true_len, pages,
-                     write_block=0):
+                     write_block=0, off_last=0):
         """Scatter the prefilled batch-1 cache into slot ``slot`` —
         matched BY NAME because the slot cache carries side buffers the
         prefill cache does not (they are left untouched: side_index is 0
@@ -1041,16 +1132,25 @@ class ServeLoop:
         row is re-blocked into the slot's pages.  ``write_block`` skips
         the scatter below that block index — a shared-prefix admission
         must not rewrite blocks other slots alias (its page row still
-        maps them; only the suffix's private blocks take writes)."""
-        def walk(big, small):
+        maps them; only the suffix's private blocks take writes).  With a
+        window block group ``pages`` is the pair (full group's row, window
+        group's row) and ``off_last`` the offset of the prompt's last
+        prefill chunk, which places the windowed layers' rolling rows."""
+        def walk(big, small, window=None):
             if not isinstance(big, dict):
                 if big.ndim == 1:      # cache_index vector <- true length
                     return big.at[slot].set(true_len)
                 return big.at[slot].set(small[0])
             if "page_table" in big:
+                if isinstance(pages, tuple) and window is not None:
+                    return self._insert_window_node(
+                        big, small, slot, true_len, pages[1], off_last)
                 return self._insert_paged_node(
-                    big, small, slot, true_len, pages, write_block)
-            return {k: (walk(v, small[k]) if k in small else v)
+                    big, small, slot, true_len,
+                    pages[0] if isinstance(pages, tuple) else pages,
+                    write_block)
+            return {k: (walk(v, small[k], self._window_of.get(k, window))
+                        if k in small else v)
                     for k, v in big.items()}
         return walk(cache, cache1)
 
@@ -1098,6 +1198,37 @@ class ServeLoop:
         out["cache_index"] = big["cache_index"].at[slot].set(true_len)
         return out
 
+    def _insert_window_node(self, big, small, slot, true_len, pages,
+                            off_last):
+        """A WINDOWED layer's insert: its batch-1 cache is the rolling
+        buffer ``_rolling_prefill`` left (row ``i`` holds position
+        ``max(0, off_last - window) + i``) and its group holds the blocks
+        from the one with row ``true_len + 1 - window`` (the first row the
+        first decode step sees) to the prompt's last: those, at most what
+        ``window - 1`` rows touch, are gathered a block at a time and
+        scattered to the group's pages; an index past the prompt drops.
+        Rows of the first block that lie before the buffer's base are
+        whatever the clip finds: they are below the window for good."""
+        out = dict(big)
+        bs, w = self.kv_block_size, self._window
+        base = jnp.maximum(off_last - w, 0)
+        n_w = max(1, span_blocks(w - 1, bs))
+        j = jnp.maximum(true_len + 1 - w, 0) // bs + jnp.arange(n_w)
+        m = pages.shape[0]
+        pos = j[:, None] * bs + jnp.arange(bs)[None, :]       # [n_w, bs]
+        for leaf in _kv_leaves(big, "paged"):
+            name = f"paged_{leaf}"
+            row = small[f"cached_{leaf}"][0]                  # [rows, F]
+            tgt = jnp.where(j * bs < true_len,
+                            pages[jnp.minimum(j, m - 1)],
+                            big[name].shape[0])
+            blocks = row[jnp.clip(pos - base, 0, row.shape[0] - 1)]
+            out[name] = big[name].at[tgt].set(
+                blocks.astype(big[name].dtype), mode="drop")
+        out["page_table"] = big["page_table"].at[slot].set(pages)
+        out["cache_index"] = big["cache_index"].at[slot].set(true_len)
+        return out
+
     def _admit_dev_impl(self, params, cache, tok, active, remaining,
                         first_buf, prompt_padded, true_len, slot, max_new,
                         pages, key, *, true_chunk):
@@ -1112,7 +1243,10 @@ class ServeLoop:
         dispatch time only, not the prefill's round trip)."""
         cache1, first = self._prefill_impl(
             params, prompt_padded, true_len, key, true_chunk=true_chunk)
-        cache = self._insert_impl(cache, cache1, slot, true_len, pages)
+        width = prompt_padded.shape[1]
+        chunk = min(true_chunk, width)
+        cache = self._insert_impl(cache, cache1, slot, true_len, pages,
+                                  off_last=(width - 1) // chunk * chunk)
         tok = tok.at[slot].set(first)
         act = max_new > 1
         if self._stop is not None:
@@ -1153,18 +1287,26 @@ class ServeLoop:
                     for k, v in small.items()}
         return walk(cache, blank1)
 
-    def _prefill_chunk_impl(self, params, cache1, toks, off, *, chunk):
+    def _prefill_chunk_impl(self, params, cache1, toks, off, row=None, *,
+                            chunk):
         """ONE prompt chunk through the scalar-index prefill path:
         write cursor forced to ``off`` (dynamic — every chunk of a given
         width shares one executable), positions ``off + [0, chunk)``.
         The chunk grid matches :func:`_prefill`'s exactly (same widths
         at the same offsets), so the per-chunk dispatches produce
         bitwise the same cache and logits as the fused one-shot path —
-        chunking changes WHEN prefill work runs, never its result."""
+        chunking changes WHEN prefill work runs, never its result.
+
+        ``row`` (dynamic): hand back that ONE row of the chunk's logits,
+        ``[1, 1, V]``, the only one the finish reads, so that a lane in
+        admission does not hold ``[1, chunk, V]`` float32 from its last
+        chunk to its finish; None: all of them."""
         cache1 = _set_cache_index(cache1, off)
         logits, mut = self._prefill_model.apply(
             {"params": params, "cache": cache1}, toks,
             positions=off + jnp.arange(chunk)[None, :], mutable=["cache"])
+        if row is not None:
+            logits = lax.dynamic_slice_in_dim(logits, row, 1, axis=1)
         return mut["cache"], logits
 
     def _admit_finish_impl(self, cache, tok, active, remaining, first_buf,
@@ -1174,12 +1316,14 @@ class ServeLoop:
         prefilled batch-1 cache into the slot (skipping shared blocks
         below ``write_block``), sample the deferred first token from the
         LAST chunk's logits (position ``true_len - 1`` lives at row
-        ``true_len - 1 - off`` of that chunk), stamp the lane."""
+        ``true_len - 1 - off`` of that chunk; a chunk program that was
+        told the row hands back that row alone), stamp the lane."""
         cache1 = _set_cache_index(cache1, true_len)
         cache = self._insert_impl(cache, cache1, slot, true_len, pages,
-                                  write_block=write_block)
-        last = lax.dynamic_index_in_dim(
-            logits[0], true_len - 1 - off, keepdims=False)
+                                  write_block=write_block, off_last=off)
+        last = (logits[0, 0] if logits.shape[1] == 1
+                else lax.dynamic_index_in_dim(
+                    logits[0], true_len - 1 - off, keepdims=False))
         first = self._select(last[None, :], key)[0].astype(jnp.int32)
         tok = tok.at[slot].set(first)
         act = max_new > 1
@@ -1842,7 +1986,7 @@ class ServeLoop:
             # required: the pipelined host learns stops a segment late
             # and keeps growing blindly until the finalize lands)
             self.pool.admit(slot, L, int(req.max_new_tokens))
-            pages = jnp.asarray(self.pool.table[slot])
+            pages = self._slot_pages(slot)
         else:
             pages = _NO_PAGES
         chunk = min(self.prefill_chunk, self.cfg.max_seq_len)
@@ -1871,6 +2015,14 @@ class ServeLoop:
                 true_chunk=chunk)
         return {"req": req, "tokens": [], "pending_first": True,
                 "chunks": -(-Lp // chunk)}
+
+    def _slot_pages(self, slot: int):
+        """``slot``'s page row as the insert takes it: the full group's,
+        or the pair (full, window) where the pool has a window group."""
+        full = jnp.asarray(self.pool.table[slot])
+        wg = self.pool.window_group
+        return full if wg is None else (
+            full, jnp.asarray(wg.table[slot].copy()))
 
     def _admit_start(self, slot: int, req: Request, prompt: np.ndarray,
                      L: int) -> dict:
@@ -1924,7 +2076,7 @@ class ServeLoop:
             self.pool.admit(slot, L, max_new)
         self.prefix_stats["prefill_tokens"] += L - suffix_start
         self._obs_prefill_tokens.inc(L - suffix_start)
-        pages = (jnp.asarray(self.pool.table[slot])
+        pages = (self._slot_pages(slot)
                  if self.pool is not None else _NO_PAGES)
         C = min(self.prefill_chunk, self.cfg.max_seq_len)
         Lp = min(-(-L // C) * C, self.cfg.max_seq_len)
@@ -2453,8 +2605,15 @@ class ServeLoop:
                             version=self._pending_swap.get("version"))
                 self._obs_queue.set(len(pending))
                 return
+            prefilling = sum(1 for st in slot_state
+                             if st is not None and "prefill" in st)
             for slot in range(self.B):
                 if slot_state[slot] is None and pending:
+                    if (self.max_prefill_lanes is not None
+                            and prefilling >= self.max_prefill_lanes):
+                        # what lanes in admission hold is bounded: the
+                        # head waits for a finish (FIFO, as for blocks)
+                        break
                     if self.preempt == "migrate":
                         # priority-first admission: the best waiting
                         # class jumps the queue (FIFO within a class);
@@ -2517,6 +2676,8 @@ class ServeLoop:
                     if "prefill" not in st:
                         st["stamps"]["prefill_done"] = st["stamps"]["admit"]
                         join_decode(st)
+                    else:
+                        prefilling += 1
                     self._obs_requests.inc()
                     obs.recorder.record(
                         "serve_admit", slot=slot, seq=seq,
@@ -2608,12 +2769,15 @@ class ServeLoop:
                 if pf["chunks"]:
                     off, w = pf["chunks"].pop(0)
                     toks = pf["padded"][:, off:off + w]
+                    # the one row of logits the finish reads (any row
+                    # of a chunk that is not the prompt's last)
+                    row = min(max(pf["L"] - 1 - off, 0), w - 1)
                     with obs.span("serve/prefill_chunk", slot=slot,
                                   rid=_span_rid(st["req"].rid), off=off,
                                   width=w, seq=seq):
                         pf["cache1"], pf["logits"] = self._prefill_chunk(
                             self.params, pf["cache1"], toks,
-                            np.int32(off), chunk=w)
+                            np.int32(off), np.int32(row), chunk=w)
                     st["chunks"] += 1
                     pf["off_last"] = off
                     tev("prefill_chunk", st["req"], slot=slot,
@@ -2759,7 +2923,11 @@ class ServeLoop:
                 k = (self._spec_k(live)
                      if self.decode_mode == "speculative" else 0)
                 pages = rows = rows_live = 0
+                windowed = None
                 if self.pool is not None:
+                    wg = self.pool.window_group
+                    rows_w = rows_w_live = 0
+                    released = wg.released if wg is not None else 0
                     block = self.pool.block_size
                     per_tile = paged_tile_pages(
                         block, self.pool.max_blocks_per_slot)
@@ -2786,7 +2954,16 @@ class ServeLoop:
                             held = self.pool.covered_rows(slot)
                             rows += walk_rows(held, block, per_tile)
                             rows_live += held
+                            if wg is not None:
+                                rows_w += walk_rows(held, block, per_tile,
+                                                    wg.window)
+                                rows_w_live += min(held, wg.window)
                             self.pool.grow(slot, n + k)
+                    if wg is not None:
+                        # the window layers' walk (a layer) and the
+                        # blocks this segment's growth let go of
+                        windowed = (rows_w, rows_w_live,
+                                    wg.released - released)
                     self._stamp_table()
             # the segment splits per-step keys and returns the advanced
             # key — no per-wave host-side split dispatch needed
@@ -2829,7 +3006,7 @@ class ServeLoop:
             except AttributeError:  # non-jax array (test doubles)
                 pass
             inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
-                             (pages, rows, rows_live)))
+                             (pages, rows, rows_live, windowed)))
             seq += 1
             self._obs_depth.set(len(inflight))
             # fault harness: a configured kill-after-K-segments SIGKILLs
@@ -2860,9 +3037,15 @@ class ServeLoop:
             call of the decode kernel walks; a lane frozen on the device
             that the host has not drained yet is still counted), with
             ``rows`` (what the kernel's arithmetic covers for those lanes,
-            ``walk_rows`` of each length) and ``rows_live`` (the lengths)."""
+            ``walk_rows`` of each length) and ``rows_live`` (the lengths).
+            A model with sliding-window layers adds ``rows_window`` /
+            ``rows_window_live`` (the same two of a WINDOW layer's call:
+            ``walk_rows`` with the window, ``min(length, window)``; ``rows``
+            and ``rows_live`` stay the full layers') and ``blocks_released``
+            (what the window group let go of when the segment was planned)."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
-             t_disp, (pages, rows, rows_live)) = inflight.popleft()
+             t_disp, (pages, rows, rows_live,
+                      windowed)) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
                    and "seq" in st and st["seq"] <= s_idx
@@ -2959,11 +3142,20 @@ class ServeLoop:
                 self._obs_rows_computed.inc(rows * steps_run)
                 self._obs_rows_live.inc(rows_live * steps_run)
                 routed = {}
+                if windowed is not None:
+                    # the window layers' calls, ticked like the full
+                    # layers' rows; blocks_released is the segment's own
+                    rows_w, rows_w_live, released = windowed
+                    self._obs_rows_window.inc(rows_w * steps_run)
+                    self._obs_rows_window_live.inc(rows_w_live * steps_run)
+                    routed = {"rows_window": rows_w,
+                              "rows_window_live": rows_w_live,
+                              "blocks_released": released}
                 if self._expert_blocks:
                     n_cells = len(self._expert_blocks) * self._held
                     counts = emits[self.B:].reshape(-1)[:n_cells]
-                    routed = {"expert_tokens": int(counts.sum()),
-                              "expert_tokens_max": int(counts.max())}
+                    routed.update(expert_tokens=int(counts.sum()),
+                                  expert_tokens_max=int(counts.max()))
                     self._obs_expert_tokens.inc(routed["expert_tokens"])
                     self._obs_expert_tokens_max.inc(
                         routed["expert_tokens_max"])

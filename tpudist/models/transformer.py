@@ -227,7 +227,16 @@ class TransformerConfig:
     # Sliding-window attention width (None = full causal attention).
     # The single source of truth: the training path passes it to the
     # attention_fn and the decode cache mask applies the same band.
+    # This is the spelling for "every layer"; ``layer_windows`` states a
+    # window a layer.
     attention_window: int | None = None
+    # One entry a layer: that layer's sliding-window width, None for full
+    # causal attention (None here: every layer takes attention_window).
+    # Read through ``layer_window(i)``.
+    layer_windows: tuple | None = None
+    # A head's width where it is not embed_dim // num_heads (None: that
+    # quotient).  Read through ``head_dim``.
+    head_size: int | None = None
     # Compile the layer stack as ONE lax.scan over stacked parameters
     # instead of a Python loop (the maxtext-style "scan over layers").
     # The traced program holds one block body regardless of depth, so
@@ -256,6 +265,11 @@ class TransformerConfig:
     positions: str = "learned"         # | "rotary" (no position table)
     rope_theta: float = 10000.0
     rope_scaling: YarnScaling | None = None
+    # the windowed layers' rotary scaling where it is not the full
+    # layers' ("as_full": rope_scaling for every layer; None: plain
+    # frequencies on the windowed layers).  Read through
+    # ``layer_rope_scaling(i)``.
+    window_rope_scaling: Any = "as_full"
     # rotary width of a plain head (None = all of head_dim); MLA rotates
     # its own qk_rope_head_dim slice
     rope_dim: int | None = None
@@ -277,8 +291,38 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         assert self.embed_dim % self.num_heads == 0
         return self.embed_dim // self.num_heads
+
+    @property
+    def attn_dim(self) -> int:
+        """Width of the heads side by side: what ``proj`` takes."""
+        return self.num_heads * self.head_dim
+
+    def layer_window(self, i: int | None) -> int | None:
+        """Layer ``i``'s sliding-window width (None: full attention);
+        ``i`` None (a module built on its own, a scanned stack): every
+        layer's, ``attention_window``."""
+        if self.layer_windows is None or i is None:
+            return self.attention_window
+        if len(self.layer_windows) != self.num_layers:
+            raise ValueError(
+                f"layer_windows has {len(self.layer_windows)} entries for "
+                f"{self.num_layers} layers")
+        return self.layer_windows[i]
+
+    @property
+    def windows(self) -> tuple:
+        """``layer_window`` of every layer."""
+        return tuple(self.layer_window(i) for i in range(self.num_layers))
+
+    def layer_rope_scaling(self, i: int | None) -> YarnScaling | None:
+        if (self.layer_window(i) is not None
+                and self.window_rope_scaling != "as_full"):
+            return self.window_rope_scaling
+        return self.rope_scaling
 
     @property
     def kv_heads(self) -> int:
@@ -333,13 +377,15 @@ def _yarn_mscale(factor: float, m: float) -> float:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               cfg: TransformerConfig) -> jnp.ndarray:
+               cfg: TransformerConfig, scaling: Any = "cfg") -> jnp.ndarray:
     """Rotate the last axis of ``x [B, S, ..., dim]`` at ``positions
     [B or 1, S]``.  HALF-SPLIT pairing: feature ``i`` pairs with
     ``i + dim/2`` (what public implementations permute published
-    interleaved weights to).  Angles and the rotation in float32."""
+    interleaved weights to).  Angles and the rotation in float32.
+    ``scaling``: the layer's own (``cfg.layer_rope_scaling``) where layers
+    differ; by default ``cfg.rope_scaling``."""
     dim = x.shape[-1]
-    sc = cfg.rope_scaling
+    sc = cfg.rope_scaling if isinstance(scaling, str) else scaling
     ang = (positions.astype(jnp.float32)[..., None]
            * rope_inv_freq(dim, cfg.rope_theta, sc))      # [B|1, S, dim/2]
     mult = 1.0 if sc is None else (_yarn_mscale(sc.factor, sc.mscale)
@@ -355,11 +401,12 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 
 
 def _flash_prefill(q, k_all, v_all, idx, *, window=None, scale=None,
-                   decode_shard=None):
+                   decode_shard=None, k_offset=0):
     """Chunk prefill through the flash forward kernel: queries at global
     positions ``[idx, idx + s)`` against ``k_all`` / ``v_all [B, S, Hkv,
     D]`` at ``q_offset=idx`` (its causal mask also silences the garbage in
-    not-yet-written rows; dead tiles are pruned).
+    not-yet-written rows; dead tiles are pruned).  ``k_offset``: the global
+    position of ``k_all``'s row 0 (a rolling buffer's base).
 
     Pads the query-row count so _auto_block lands on a Mosaic-lowerable
     block: the LSE output's [1, 1, block_q] block needs block_q % 128 == 0
@@ -397,7 +444,7 @@ def _flash_prefill(q, k_all, v_all, idx, *, window=None, scale=None,
         return out[:, :s]
     out, _ = _flash_forward(
         q_in, k_all, v_all, True, bq, block_k, interp,
-        q_offset=idx, window=window, scale=scale)
+        q_offset=idx, k_offset=k_offset, window=window, scale=scale)
     return out[:, :s]
 
 
@@ -434,6 +481,18 @@ class CausalSelfAttention(nn.Module):
     cache_layout: str = "dense"
     kv_num_blocks: int = 0
     kv_block_size: int = 0
+    # this layer's index in the stack: its window and rotary scaling are
+    # cfg.layer_window(layer) / cfg.layer_rope_scaling(layer)
+    layer: int | None = None
+    # > 0 (the serve loop's batch-1 prefill model): a WINDOWED layer's
+    # dense scalar-index cache is a rolling buffer of window +
+    # prefill_window_rows rows, fed chunks of prefill_window_rows tokens
+    # in order (see _rolling_prefill); 0: max_seq_len rows as ever
+    prefill_window_rows: int = 0
+
+    @property
+    def window(self) -> int | None:
+        return self.cfg.layer_window(self.layer)
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, *, causal: bool = True,
@@ -441,12 +500,12 @@ class CausalSelfAttention(nn.Module):
         cfg = self.cfg
         b, s, _ = x.shape
         if cfg.kv_heads == cfg.num_heads:
-            qkv = nn.Dense(3 * cfg.embed_dim, use_bias=False,
+            qkv = nn.Dense(3 * cfg.attn_dim, use_bias=False,
                            dtype=cfg.compute_dtype, name="qkv")(x)
             qkv = qkv.reshape(b, s, 3, cfg.num_heads, cfg.head_dim)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:  # GQA: separate projections, K/V at the grouped head count
-            q = nn.Dense(cfg.embed_dim, use_bias=False,
+            q = nn.Dense(cfg.attn_dim, use_bias=False,
                          dtype=cfg.compute_dtype, name="q")(x)
             q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
             kv = nn.Dense(2 * cfg.kv_heads * cfg.head_dim, use_bias=False,
@@ -458,20 +517,23 @@ class CausalSelfAttention(nn.Module):
             if positions is None:
                 positions = jnp.arange(s)[None, :]
             rd = cfg.rope_dim or cfg.head_dim
+            sc = cfg.layer_rope_scaling(self.layer)
             q = jnp.concatenate(
-                [apply_rope(q[..., :rd], positions, cfg), q[..., rd:]], -1)
+                [apply_rope(q[..., :rd], positions, cfg, sc),
+                 q[..., rd:]], -1)
             k = jnp.concatenate(
-                [apply_rope(k[..., :rd], positions, cfg), k[..., rd:]], -1)
+                [apply_rope(k[..., :rd], positions, cfg, sc),
+                 k[..., rd:]], -1)
         # cfg is the single source of truth for the sliding window: a
         # factory built with its OWN window (flash_attention_fn(window=W))
         # that disagrees is rejected — in BOTH branches, since the decode
         # cache masks from cfg alone and would otherwise silently discard
         # the factory's window.
         fw = getattr(self.attention_fn, "factory_window", None)
-        if fw is not None and fw != cfg.attention_window:
+        if fw is not None and fw != self.window:
             raise ValueError(
                 f"attention_fn was built with window={fw} but "
-                f"cfg.attention_window={cfg.attention_window}; set the "
+                f"cfg.attention_window={self.window}; set the "
                 "window on TransformerConfig (the single source of "
                 "truth) or make the two agree")
         if self.decode:
@@ -482,8 +544,8 @@ class CausalSelfAttention(nn.Module):
             # and a fn that doesn't accept the kwarg fails loudly instead
             # of training full-attention against a windowed decode cache.
             out = self.attention_fn(q, k, v, causal=causal,
-                                    window=cfg.attention_window)
-        out = out.reshape(b, s, cfg.embed_dim)
+                                    window=self.window)
+        out = out.reshape(b, s, cfg.attn_dim)
         return nn.Dense(cfg.embed_dim, use_bias=False,
                         dtype=cfg.compute_dtype, name="proj")(out)
 
@@ -518,6 +580,8 @@ class CausalSelfAttention(nn.Module):
         # lane-multiple minor dim keeps every consumer relayout-free;
         # per-head views are reshaped where semantics need them.
         flat = h_kv * d
+        if self.prefill_window_rows and self.window is not None:
+            return self._rolling_prefill(q, k, v)
         cached_k = self.variable(
             "cache", "cached_key", jnp.zeros,
             (b, cfg.max_seq_len, flat), cfg.compute_dtype)
@@ -560,19 +624,82 @@ class CausalSelfAttention(nn.Module):
                 if _shard_kind(self.decode_shard) in ("seq", "heads_seq"):
                     return _seq_sharded_decode(
                         self.decode_shard, q, k_all, v_all, idx + 1,
-                        cfg.attention_window, h_kv)
+                        self.window, h_kv)
                 return _head_sharded_packed(
                     self.decode_shard, q, k_all, v_all, idx + 1,
-                    cfg.attention_window, h_kv)
+                    self.window, h_kv)
             return flash_decode(q, k_all, v_all, idx + 1,
-                                window=cfg.attention_window,
+                                window=self.window,
                                 packed_kv_heads=h_kv)
         mask = jnp.arange(cfg.max_seq_len) <= idx            # causal: ≤ self
-        if cfg.attention_window is not None:  # sliding window: last W only
+        if self.window is not None:  # sliding window: last W only
             mask = mask & (
-                idx - jnp.arange(cfg.max_seq_len) < cfg.attention_window)
+                idx - jnp.arange(cfg.max_seq_len) < self.window)
         k4, v4 = repeat_kv(q, view4(k_all), view4(v_all))
         return _masked_attend(q, k4, v4, mask[None, None, None, :])
+
+    def _rolling_prefill(self, q, k, v):
+        """Chunk prefill of a WINDOWED layer through a rolling buffer of
+        ``window + prefill_window_rows`` rows instead of ``max_seq_len``:
+        a prefilling lane of the serve loop then holds, in this layer,
+        the window and the chunk in hand and not the whole context.
+
+        The layout is a function of the write cursor alone (the serve
+        loop forces the cursor to a chunk's offset, so nothing else can
+        carry it): chunks arrive IN ORDER, each ``prefill_window_rows``
+        wide but the last, and at entry with the cursor at ``idx`` row
+        ``i`` holds position ``base(idx - chunk) + i``, ``base(x) =
+        max(0, x - window)``: what the chunk before left.  The buffer is
+        moved down to ``base(idx)``, the chunk lands at row ``idx -
+        base(idx)`` (at most ``window``), and the queries attend at
+        ``k_offset=base(idx)``.  Nothing moves AFTER the chunk, so the
+        last chunk's padded tail never pushes a live row out: the rows a
+        decode step needs after a prompt of ``L`` tokens, ``[L - window +
+        1, L)``, are all there (``serving._insert_window_node`` reads them
+        at ``base(offset of the last chunk)``)."""
+        cfg = self.cfg
+        b, s, h_kv, d = k.shape
+        w, c = self.window, self.prefill_window_rows
+        rows, flat = w + c, h_kv * d
+        if s > c:
+            raise ValueError(
+                f"a windowed layer's prefill buffer takes chunks of at "
+                f"most prefill_window_rows={c} tokens, got {s}")
+        cached_k = self.variable(
+            "cache", "cached_key", jnp.zeros, (b, rows, flat),
+            cfg.compute_dtype)
+        cached_v = self.variable(
+            "cache", "cached_value", jnp.zeros, (b, rows, flat),
+            cfg.compute_dtype)
+        idx_var = self.variable(
+            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
+        idx = idx_var.value
+        if idx.ndim != 0:
+            raise ValueError(
+                "the rolling prefill buffer is the batch-1 scalar-index "
+                "cache of the serve loop's admission")
+        base = jnp.maximum(idx - w, 0)
+        shift = base - jnp.maximum(idx - c - w, 0)
+
+        def put(var, new):
+            buf = jnp.pad(var.value, ((0, 0), (0, c), (0, 0)))
+            buf = jax.lax.dynamic_slice(buf, (0, shift, 0), (b, rows, flat))
+            return jax.lax.dynamic_update_slice(
+                buf, new.reshape(b, s, flat).astype(buf.dtype),
+                (0, idx - base, 0))
+
+        k_all, v_all = put(cached_k, k), put(cached_v, v)
+        cached_k.value, cached_v.value = k_all, v_all
+        idx_var.value = idx + s
+        k4 = k_all.reshape(b, rows, h_kv, d)
+        v4 = v_all.reshape(b, rows, h_kv, d)
+        if self.decode_attention == "flash":
+            return _flash_prefill(q, k4, v4, idx, window=w, k_offset=base)
+        q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
+        k_pos = base + jnp.arange(rows)[None, :]              # [1, rows]
+        mask = (k_pos <= q_pos) & (q_pos - k_pos < w)
+        k4, v4 = repeat_kv(q, k4, v4)
+        return _masked_attend(q, k4, v4, mask[None, None])
 
     def _serve_attend(self, q, k, v, cached_k, cached_v, idx_var):
         """One decode step with PER-ROW cache positions: row ``r``'s K/V
@@ -624,7 +751,7 @@ class CausalSelfAttention(nn.Module):
 
         n = idx + 1  # [B] valid lengths including the current token
         if (s == 1 and self.decode_attention == "flash"
-                and cfg.attention_window is None):
+                and self.window is None):
             from tpudist.ops.flash_decode import flash_decode
 
             return flash_decode(q, k_all, v_all, n,
@@ -639,9 +766,9 @@ class CausalSelfAttention(nn.Module):
         positions = jnp.arange(cfg.max_seq_len)[None, None, :]  # [1,1,S]
         q_pos = idx[:, None] + jnp.arange(s)[None, :]           # [B, s]
         mask = positions < (q_pos + 1)[:, :, None]              # [B,s,S]
-        if cfg.attention_window is not None:
+        if self.window is not None:
             mask = mask & (q_pos[:, :, None] - positions
-                           < cfg.attention_window)
+                           < self.window)
         k4 = k_all.reshape(b, cfg.max_seq_len, h_kv, d)
         v4 = v_all.reshape(b, cfg.max_seq_len, h_kv, d)
         k_rep, v_rep = repeat_kv(q, k4, v4)
@@ -756,10 +883,14 @@ class CausalSelfAttention(nn.Module):
             raise NotImplementedError(
                 "sharded decode over the paged cache is not wired yet; "
                 "serve paged through the replicated path")
-        if cfg.attention_window is not None:
+        window = self.window
+        if window is not None and (s != 1
+                                   or self.serve_side_slots > window):
             raise ValueError(
-                "the paged cache has no sliding-window trim yet; use "
-                "cache_layout='dense' for windowed models")
+                "a windowed layer decodes one token a step over the paged "
+                "cache, with a side buffer no longer than its window "
+                f"(got s={s}, serve_side_slots={self.serve_side_slots}, "
+                f"window={window})")
         if self.serve_side_slots <= 0:
             raise ValueError(
                 "cache_layout='paged' requires serve_side_slots > 0 "
@@ -790,7 +921,8 @@ class CausalSelfAttention(nn.Module):
             return paged_flash_decode(
                 q, paged_k.value, paged_v.value, table.value, idx,
                 packed_kv_heads=h_kv, side_k=side_k.value,
-                side_v=side_v.value, side_len=side_idx.value)
+                side_v=side_v.value, side_len=side_idx.value,
+                window=window)
         # dense fallback: gather the slot's pages into a contiguous view
         # (one full-logical-cache copy per step — fine on CPU, the reason
         # the kernel exists on TPU) and mask main + side positions;
@@ -801,9 +933,16 @@ class CausalSelfAttention(nn.Module):
         k_main = paged_gather_kv(paged_k.value, table.value)
         v_main = paged_gather_kv(paged_v.value, table.value)
         s_all = k_main.shape[1]
-        mask_main = jnp.broadcast_to(
-            (jnp.arange(s_all)[None, :] < idx[:, None])[:, None],
-            (b, s, s_all))                                     # [B, s, S']
+        live_main = jnp.arange(s_all)[None, :] < idx[:, None]
+        if window is not None:
+            # the query sits at idx + s_base: the side rows are all inside
+            # its window (cap <= window), the pool's from idx + s_base -
+            # window + 1 on
+            live_main = live_main & (
+                jnp.arange(s_all)[None, :]
+                > (idx + s_base - window)[:, None])
+        mask_main = jnp.broadcast_to(live_main[:, None],
+                                     (b, s, s_all))            # [B, s, S']
         mask_side = jnp.broadcast_to(
             jnp.arange(cap)[None, None, :]
             < s_base + jnp.arange(s)[None, :, None] + 1,
@@ -834,13 +973,13 @@ class CausalSelfAttention(nn.Module):
         # be partitioned at all
         if self.decode_attention == "flash" and not seq_sharded:
             return _flash_prefill(q, k_all, v_all, idx,
-                                  window=cfg.attention_window,
+                                  window=self.window,
                                   decode_shard=self.decode_shard)
         q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
         k_pos = jnp.arange(cfg.max_seq_len)[None, :]          # [1, S]
         mask = k_pos <= q_pos
-        if cfg.attention_window is not None:
-            mask = mask & (q_pos - k_pos < cfg.attention_window)
+        if self.window is not None:
+            mask = mask & (q_pos - k_pos < self.window)
         k_all, v_all = repeat_kv(q, k_all, v_all)
         return _masked_attend(q, k_all, v_all, mask[None, None])
 
@@ -1120,6 +1259,9 @@ class DecoderBlock(nn.Module):
 
     # an expert layer (cfg.moe) in place of the dense MLP
     expert_layer: bool = False
+    # see CausalSelfAttention
+    layer: int | None = None
+    prefill_window_rows: int = 0
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, causal: bool = True,
@@ -1138,11 +1280,14 @@ class DecoderBlock(nn.Module):
             if self.decode_shard is not None:
                 raise NotImplementedError(
                     "latent attention has no sharded decode yet")
+            if any(w is not None for w in cfg.windows):
+                raise ValueError("latent attention has no sliding window")
             attn = LatentSelfAttention(cfg, **cache_kw)
         else:
-            attn = CausalSelfAttention(cfg, self.attention_fn,
-                                       decode_shard=self.decode_shard,
-                                       **cache_kw)
+            attn = CausalSelfAttention(
+                cfg, self.attention_fn, decode_shard=self.decode_shard,
+                layer=self.layer,
+                prefill_window_rows=self.prefill_window_rows, **cache_kw)
         x = x + attn(h, causal=causal, positions=positions)
         h = make_norm(cfg, "ln2")(x)
         if not self.expert_layer:
@@ -1219,6 +1364,11 @@ class TransformerLM(nn.Module):
     cache_layout: str = "dense"
     kv_num_blocks: int = 0
     kv_block_size: int = 0
+    # the paged pool of a WINDOWED layer (cfg.layer_window(i) not None)
+    # where it is not kv_num_blocks: the serve loop's window block group
+    kv_window_blocks: int = 0
+    # see CausalSelfAttention
+    prefill_window_rows: int = 0
 
     @nn.compact
     def __call__(
@@ -1246,11 +1396,13 @@ class TransformerLM(nn.Module):
         # under plain jit XLA could otherwise CSE the recomputation back
         # into the stored forward and silently undo the memory savings.
         if cfg.scan_layers:
-            if cfg.moe is not None or cfg.positions == "rotary":
+            if (cfg.moe is not None or cfg.positions == "rotary"
+                    or len(set(cfg.windows)) > 1):
                 raise ValueError(
                     "scan_layers stacks identical blocks that take no "
-                    "positions: expert layers and rotary positions need "
-                    "the unrolled layout")
+                    "positions: expert layers, rotary positions and "
+                    "windows that differ by layer need the unrolled "
+                    "layout")
             if self.serve_side_slots:
                 raise ValueError(
                     "serve_side_slots requires the unrolled layout "
@@ -1273,14 +1425,24 @@ class TransformerLM(nn.Module):
             block_cls = (nn.remat(DecoderBlock, static_argnums=(2,))
                          if self.remat else DecoderBlock)
             for i in range(cfg.num_layers):
+                windowed = cfg.layer_window(i) is not None
                 x = block_cls(cfg, self.attention_fn, decode=self.decode,
                               decode_attention=self.decode_attention,
                               decode_shard=self.decode_shard,
                               serve_side_slots=self.serve_side_slots,
                               cache_layout=self.cache_layout,
-                              kv_num_blocks=self.kv_num_blocks,
+                              kv_num_blocks=(
+                                  self.kv_window_blocks
+                                  if windowed and self.kv_window_blocks
+                                  else self.kv_num_blocks),
                               kv_block_size=self.kv_block_size,
                               expert_layer=cfg.is_expert_layer(i),
+                              # only a model whose layers differ tells
+                              # them apart: every other stack is built of
+                              # the blocks it always was
+                              layer=(i if cfg.layer_windows is not None
+                                     else None),
+                              prefill_window_rows=self.prefill_window_rows,
                               name=f"block{i}")(x, causal, positions)
         x = make_norm(cfg, "ln_f")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False,

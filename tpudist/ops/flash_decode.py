@@ -289,7 +289,7 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
 def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                          block: int, pages_per_tile: int, m_blocks: int,
                          lanes: int, r_kv: int, paired: bool, side: bool,
-                         d_v: int | None = None):
+                         d_v: int | None = None, window: int | None = None):
     """Online-softmax decode over ONE grid row (a lane's K/V-head chunk)
     of a paged cache, walking the lane's LIVE pages only.
 
@@ -326,7 +326,23 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     of one width, each with its side buffer (``paged_flash_decode``).
     ``d_v`` set: ONE pool whose rows are the keys and whose first ``d_v``
     columns are also the values, read once (``paged_mla_decode``: the
-    latent cache of multi-head latent attention)."""
+    latent cache of multi-head latent attention).
+
+    ``window`` (static; None is the program above, instruction for
+    instruction): a SLIDING-WINDOW layer.  The query sits at position
+    ``len + side_len - 1`` and sees the cache rows from ``lo = len +
+    side_len - window`` on, so the walk STARTS at the page that holds
+    ``lo`` (``first_page``): tiles are counted from there, pages below it
+    are neither copied nor computed (the host may have released them: a
+    page id below the window is never read), the row's first tile masks
+    its rows under ``lo`` (copied, finite: their scores are -inf and
+    their values need no cleaning), and the first-tile prefetch of this
+    row and of the next live row aim there.  A lane shorter than the
+    window starts at page 0 and walks as without one; the side buffer's
+    rows (never more than the window) join the last tile's update as
+    before.  :func:`walk_rows` counts the same."""
+    if window is not None and d_v is not None:
+        raise ValueError("the one-pool (latent) walk has no window")
     n_pools = 2 if d_v is None else 1
     pools, rest = rest[:n_pools], rest[n_pools:]
     if side:
@@ -348,8 +364,17 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     def lane_len(i):
         return meta_ref[1 + i]
 
+    def first_row(i):
+        """The first cache row lane ``i``'s query sees (window only)."""
+        return jnp.maximum(lane_len(i) + meta_ref[0] - window, 0)
+
+    def first_page(i):
+        return 0 if window is None else first_row(i) // block
+
     def lane_pages(i):
-        return (lane_len(i) + block - 1) // block
+        """Pages lane ``i``'s walk covers: those under its length, from
+        the window's first on."""
+        return (lane_len(i) + block - 1) // block - first_page(i)
 
     def tile_copies(i, r_, t, slot, fn):
         """Apply ``fn`` (start or wait) to every pool's copy of every LIVE
@@ -358,7 +383,8 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         site, and a segment program holds one call a layer."""
         live = jnp.minimum(lane_pages(i) - t * pages_per_tile,
                            pages_per_tile)
-        base = 1 + lanes + i * m_blocks + t * pages_per_tile
+        base = (1 + lanes + i * m_blocks + first_page(i)
+                + t * pages_per_tile)
         # grid row's chunk of the packed minor dim (all of it at r_kv 1)
         chunk = (slice(None) if r_kv == 1
                  else pl.ds(pl.multiple_of(r_ * d, d), d))
@@ -404,19 +430,27 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     def q_tile():
         return q_scr[:] if paired else q_ref[0]
 
-    cache_len = lane_len(lane)
     n_pages = lane_pages(lane)
     n_tiles = (n_pages + pages_per_tile - 1) // pages_per_tile
+    # rows of the walk, counted from its first page: the lane's length
+    # there, and (window) the first row the query sees
+    walk_row0 = first_page(lane) * block
+    cache_len = lane_len(lane) - walk_row0
+    first_live = None if window is None else first_row(lane) - walk_row0
 
-    def scores(keys, live):
-        """f32 scores of ``keys``' rows, -inf from row ``live`` on."""
+    def scores(keys, live, below=None):
+        """f32 scores of ``keys``' rows, -inf from row ``live`` on and
+        (a window's first tile) under row ``below``."""
         s = jax.lax.dot_general(
             q_tile(), keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if live is None:
+        if live is None and below is None:
             return s
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        return jnp.where(col < live, s, -jnp.inf)
+        keep = None if live is None else col < live
+        if below is not None:
+            keep = col >= below if keep is None else keep & (col >= below)
+        return jnp.where(keep, s, -jnp.inf)
 
     def side_tiles():
         """The side buffer's ``(scores, values)``: its first ``side_len``
@@ -424,12 +458,13 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         keys = sides[0][0]
         return scores(keys, meta_ref[0]), values(keys, lambda: sides[-1][0])
 
-    def attend(slot, n, live=None):
+    def attend(slot, n, live=None, below=None):
         """One rank update over the first ``n`` pages of ``slot``.  ``live``
         None: a tile before the last, every row under the length.  Else
         the lane's last tile, ``live`` of its rows under the length: the
         scores of the others are -inf, their values 0, and the side
-        buffer's rows join the update."""
+        buffer's rows join the update.  ``below`` (window): the tile's
+        rows under it lie before the window."""
         rows = n * block
 
         def load(buf, cleaned):
@@ -442,7 +477,7 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         # one pool: its rows are the values too, so the keys are cleaned
         keys = load(bufs[0], d_v is not None)
         _softmax_update(
-            m_scr, l_scr, acc_scr, scores(keys, live), None,
+            m_scr, l_scr, acc_scr, scores(keys, live, below), None,
             values(keys, lambda: load(bufs[-1], True)),
             also=side_tiles() if side and live is not None else None)
 
@@ -474,10 +509,14 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                     state_ref[1] = 1
 
             wait(lane, r, t, slot)
+            # a window's lower edge lies in the walk's first tile: at or
+            # under 0 in every later one
+            below = (None if window is None
+                     else first_live - t * pages_per_tile * block)
 
             @pl.when(t + 1 < n_tiles)
             def _full_tile():
-                attend(slot, pages_per_tile)
+                attend(slot, pages_per_tile, below=below)
 
             @pl.when(t + 1 == n_tiles)
             def _last_tile():
@@ -490,7 +529,8 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                     @pl.when(jnp.logical_and(held > lo, held <= n))
                     def _at_width(n=n):
                         attend(slot, n,
-                               cache_len - t * pages_per_tile * block)
+                               cache_len - t * pages_per_tile * block,
+                               below)
 
         jax.lax.fori_loop(0, n_tiles, one_tile, None)
 
@@ -519,14 +559,21 @@ def paged_tile_pages(block: int, m_blocks: int) -> int:
     return max(1, min(m_blocks, 1024 // block))
 
 
-def walk_rows(length: int, block: int, pages_per_tile: int) -> int:
+def walk_rows(length: int, block: int, pages_per_tile: int,
+              window: int | None = None, side_len: int = 1) -> int:
     """The cache rows :func:`_paged_decode_kernel`'s arithmetic covers for
     ONE lane of ``length`` under pages of ``block`` rows, ``pages_per_tile``
     (:func:`paged_tile_pages`) a tile: the tiles before the last whole, the
-    last at the width its live pages need.  The host's count of what a call
-    computes (``serve/decode_rows_computed``), held to the kernel by
-    ``tests/test_paged_decode_walk.py``."""
+    last at the width its live pages need.  With a ``window`` the walk
+    starts at the page that holds row ``length + side_len - window``
+    (``side_len`` counts the step's own token: 1 at a segment's first
+    step).  The host's count of what a call computes
+    (``serve/decode_rows_computed``, ``serve/decode_rows_window_computed``),
+    held to the kernel by ``tests/test_paged_decode_walk.py`` and
+    ``tests/test_window_decode.py``."""
     pages = -(-length // block)
+    if window is not None:
+        pages -= max(length + side_len - window, 0) // block
     if not pages:
         return 0
     before = (pages - 1) // pages_per_tile * pages_per_tile
@@ -932,6 +979,7 @@ def paged_flash_decode(
     side_v: jnp.ndarray | None = None,
     side_len: jnp.ndarray | int = 0,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """One decode step of attention against a PAGED KV cache.
 
@@ -970,6 +1018,13 @@ def paged_flash_decode(
       side_k / side_v / side_len: the serve loop's segment-local staging
         buffers (``[B, cap, Hkv*D]`` packed), attended after the paged
         cache in the same online softmax — as on :func:`flash_decode`.
+      window: a sliding-window layer's width (static).  The query is the
+        row ``cache_len + side_len - 1`` (the step's token is the side
+        buffer's last live row) and sees ``window`` rows, itself
+        included; the walk starts at the page that holds the first of
+        them, and page-table entries below it are never read.  Needs the
+        side buffers and one query a call.  In a trace the kernel is
+        ``paged_window_decode``.
 
     MULTI-QUERY DECODE (``q`` with ``s > 1``): the speculative verify
     chunk — the ``page_table`` already covers the segment's pre-reserved
@@ -982,6 +1037,11 @@ def paged_flash_decode(
     Returns ``[B, s, H, D]``.
     """
     b, s_q, h, d = q.shape
+    if window is not None and (s_q != 1 or side_k is None or window < 1):
+        raise ValueError(
+            "a windowed paged decode takes one query a call with the "
+            "side buffers (the query's position is cache_len + side_len "
+            "- 1) and a window >= 1")
     if s_q > 1:
         if side_k is None:
             raise ValueError(
@@ -1032,12 +1092,13 @@ def paged_flash_decode(
     return _paged_decode_one(
         q, k_pool, v_pool, table, cache_len,
         jnp.asarray(side_len, jnp.int32), side_k, side_v, h_kv=h_kv,
-        interpret=bool(interpret))
+        interpret=bool(interpret), window=window)
 
 
-@functools.partial(jax.jit, static_argnames=("h_kv", "interpret"))
+@functools.partial(jax.jit, static_argnames=("h_kv", "interpret", "window"))
 def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
-                      side_k, side_v, *, h_kv: int, interpret: bool):
+                      side_k, side_v, *, h_kv: int, interpret: bool,
+                      window: int | None = None):
     """The validated single-query call of :func:`paged_flash_decode`.
     Under its own ``jit``: a segment program calls it once a layer with
     the same shapes, and the kernel body is then traced and lowered once
@@ -1070,7 +1131,9 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
     out = _paged_call(
         meta, q3, (k_pool, v_pool), (side_k, side_v) if side else None,
         scale=d ** -0.5, lanes=b, r_kv=r_kv, paired=paired, d_v=None,
-        interpret=interpret, name="paged_flash_decode")
+        interpret=interpret, window=window,
+        name="paged_flash_decode" if window is None
+        else "paged_window_decode")
     if paired:
         o = out.reshape(b, r_kv * 2, gp, d)
         return o[:, :, :g].reshape(b, 1, h, d)
@@ -1079,7 +1142,7 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
 
 def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
                 r_kv: int, paired: bool, d_v: int | None, interpret: bool,
-                name: str):
+                name: str, window: int | None = None):
     """The ``pallas_call`` both paged decode kernels share: one grid row a
     (lane, K/V-head chunk), the pools left in HBM for the body's own
     copies, ``q3 [rows, gp, d]`` (``[rows, 2, gp, d]`` paired) in and an
@@ -1117,7 +1180,8 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
         functools.partial(
             _paged_decode_kernel, scale=scale, block=block,
             pages_per_tile=pages_per_tile, m_blocks=m_blocks, lanes=lanes,
-            r_kv=r_kv, paired=paired, side=sides is not None, d_v=d_v),
+            r_kv=r_kv, paired=paired, side=sides is not None, d_v=d_v,
+            window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(lanes * r_kv,),
