@@ -361,12 +361,21 @@ def test_latent_expert_model_names_its_kernels(v5e):
 WIN_LANES, WIN_HEADS, WIN_KV, WIN_D, WIN_W, WIN_STEPS = 40, 32, 4, 128, 1024, 16
 
 
-def test_paged_window_decode_at_the_cells_shapes(v5e):
+@pytest.mark.parametrize("kv_heads", [WIN_KV, 2 * WIN_KV],
+                         ids=["cell", "8_kv_heads_two_rows_a_lane"])
+def test_paged_window_decode_at_the_cells_shapes(v5e, kv_heads):
     """The windowed walk for the chip: its own name, the plain kernel's
     six operands and one output, so that readers by name tell the two
-    apart and no reader by shape counts it twice."""
-    flat = WIN_KV * WIN_D
-    q = _sds(v5e, (WIN_LANES, 1, WIN_HEADS, WIN_D))
+    apart and no reader by shape counts it twice.  A lane's four K/V heads
+    share a grid row (4 MiB of tile slots: ``_TILE_SLOT_BYTES``); eight are
+    over that budget and take two rows of four: what VMEM refuses of
+    either shows here and not on the chip."""
+    from tpudist.ops.flash_decode import paged_grid_rows
+
+    assert paged_grid_rows(WIN_LANES, kv_heads, WIN_D, BLOCK, SEQ // BLOCK
+                           ) == WIN_LANES * kv_heads // WIN_KV
+    flat = kv_heads * WIN_D
+    q = _sds(v5e, (WIN_LANES, 1, WIN_HEADS * kv_heads // WIN_KV, WIN_D))
     pool = _sds(v5e, (400, BLOCK, flat))
     table = _sds(v5e, (WIN_LANES, SEQ // BLOCK), jnp.int32)
     lens = _sds(v5e, (WIN_LANES,), jnp.int32)
@@ -375,7 +384,7 @@ def test_paged_window_decode_at_the_cells_shapes(v5e):
     def call(window):
         return _compile(
             lambda q, k, v, t, n, sk, sv, sl: paged_flash_decode(
-                q, k, v, t, n, packed_kv_heads=WIN_KV, side_k=sk,
+                q, k, v, t, n, packed_kv_heads=kv_heads, side_k=sk,
                 side_v=sv, side_len=sl, window=window),
             q, pool, pool, table, lens, buf, buf, _sds(v5e, (), jnp.int32))
 

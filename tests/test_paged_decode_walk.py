@@ -18,7 +18,8 @@ import numpy as np
 import pytest
 
 from tpudist.ops.flash_decode import (paged_flash_decode, paged_gather_kv,
-                                      paged_mla_decode, walk_rows)
+                                      paged_grid_rows, paged_mla_decode,
+                                      walk_rows)
 
 BLOCK, M_BLOCKS, CAP, HEADS = 128, 20, 8, 4
 P = 1024 // BLOCK                      # pages a tile, as the kernel derives
@@ -49,9 +50,29 @@ def _alone(n: int) -> list[int]:
 
 
 LENGTHS = {"mixed": MIXED, **{k: _alone(v) for k, v in EDGES.items()}}
-# (kv heads, head_dim): one K/V head; a head-paired layout (d * 2 <= 128);
-# two K/V heads of 128, each grid row its own chunk of the packed minor dim
-LAYOUTS = {"kv1": (1, 16), "kv2_paired": (2, 16), "kv2_d128": (2, 128)}
+F32, BF16 = jnp.float32, jnp.bfloat16
+# (kv heads, head_dim, query heads, dtype of q and the pools) -> grid rows a
+# lane.  One K/V head; a head-paired layout (d * 2 <= 128); then the layouts
+# whose K/V heads SHARE a lane's grid row (its page copies, its rank
+# updates): two heads of 128; four of 128 in bf16 (the Mellum cell's row);
+# two pair chunks of d = 64 (four heads in one block-diagonal query); and
+# the lanes whose tile slots pass the VMEM budget and take several rows,
+# each a group of heads: four heads of 128 in float32 (2 rows of 2), eight
+# of 128 in bf16 (2 rows of 4)
+LAYOUTS = {"kv1": (1, 16, 4, F32), "kv2_paired": (2, 16, 4, F32),
+           "kv2_d128": (2, 128, 4, F32),
+           "kv4_d128_bf16": (4, 128, 8, BF16),
+           "kv4_d64_paired": (4, 64, 8, F32),
+           "kv4_d128": (4, 128, 8, F32), "kv8_d128_bf16": (8, 128, 16, BF16)}
+ROWS_A_LANE = {"kv1": 1, "kv2_paired": 1, "kv2_d128": 1, "kv4_d128_bf16": 1,
+               "kv4_d64_paired": 1, "kv4_d128": 2, "kv8_d128_bf16": 2}
+# the layouts of this PR run the lengths that tell a folded row from a row a
+# head: ragged lanes with empty ones between, every lane empty, a last tile
+# of one live row, a last tile of three pages and a row, a lane's whole table
+FOLDED = ("kv4_d128_bf16", "kv4_d64_paired", "kv4_d128", "kv8_d128_bf16")
+FOLDED_LENGTHS = ("mixed", "len0", "P_pages+1", "tile+3pages+1", "all_pages")
+CASES = [(n, layout) for layout in LAYOUTS
+         for n in (FOLDED_LENGTHS if layout in FOLDED else LENGTHS)]
 SIDES = {"noside": None, "side0": 0, "side5": 5}
 
 
@@ -94,12 +115,12 @@ def _setup(layout: str, side: str):
     """The two pools (numpy, block 0 NaN: the block dead entries name) and
     the jitted call of one (layout, side): pools, table and lengths are
     arguments, so every case of it shares one compile."""
-    h_kv, d = LAYOUTS[layout]
+    h_kv, d, heads, dtype = LAYOUTS[layout]
     flat = h_kv * d
     ks = jax.random.split(jax.random.key(26), 5)
     n_pool = LANES * M_BLOCKS + 1
-    q = jax.random.normal(ks[0], (LANES, 1, HEADS, d), jnp.float32)
-    pools = [np.array(jax.random.normal(k, (n_pool, BLOCK, flat)))
+    q = jax.random.normal(ks[0], (LANES, 1, heads, d), dtype)
+    pools = [np.array(jax.random.normal(k, (n_pool, BLOCK, flat), dtype))
              for k in ks[1:3]]
     for pool in pools:
         pool[0] = np.nan
@@ -107,8 +128,8 @@ def _setup(layout: str, side: str):
     if side_len is None:
         side_k = side_v = None
     else:
-        side_k = jax.random.normal(ks[3], (LANES, CAP, flat), jnp.float32)
-        side_v = jax.random.normal(ks[4], (LANES, CAP, flat), jnp.float32)
+        side_k = jax.random.normal(ks[3], (LANES, CAP, flat), dtype)
+        side_v = jax.random.normal(ks[4], (LANES, CAP, flat), dtype)
 
     @jax.jit
     def both(k_pool, v_pool, table, lens):
@@ -116,9 +137,11 @@ def _setup(layout: str, side: str):
             q, k_pool, v_pool, table, lens, packed_kv_heads=h_kv,
             side_k=side_k, side_v=side_v, side_len=side_len or 0,
             interpret=True)
-        want = _reference(q, k_pool, v_pool, table, lens, h_kv, side_k,
-                          side_v, side_len or 0)
-        return got, want
+        # the reference in float32 from the same (bf16) values
+        up = lambda x: None if x is None else x.astype(F32)  # noqa: E731
+        want = _reference(up(q), up(k_pool), up(v_pool), table, lens, h_kv,
+                          up(side_k), up(side_v), side_len or 0)
+        return got.astype(F32), want
 
     return pools, both
 
@@ -131,22 +154,24 @@ def _live_table(lens):
                     _owned(), 0)
 
 
-def _check(got, want, what: str):
+def _check(got, want, what: str, layout: str = "kv1"):
     got, want = np.asarray(got), np.asarray(want)
     assert np.isfinite(got).all(), what
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # bf16: the probabilities go into the MXU in the pools' dtype (2^-9 of a
+    # weight each); a head that read another head's columns is off by ~1
+    tol = 2e-5 if LAYOUTS[layout][3] == F32 else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
     return got
 
 
 @pytest.mark.parametrize("side", SIDES)
-@pytest.mark.parametrize("layout", LAYOUTS)
-@pytest.mark.parametrize("lengths", LENGTHS)
+@pytest.mark.parametrize("lengths,layout", CASES)
 def test_walk_matches_gather_reference(lengths, layout, side):
     lens = np.asarray(LENGTHS[lengths], np.int32)
     pools, both = _setup(layout, side)
     got = _check(*both(*pools, jnp.asarray(_live_table(lens), jnp.int32),
                        jnp.asarray(lens)),
-                 "a dead (poisoned) page reached the result")
+                 "a dead (poisoned) page reached the result", layout)
     if SIDES[side] in (None, 0):
         # nothing to attend: an empty lane's output is 0
         assert not got[lens == 0].any()
@@ -245,7 +270,7 @@ def test_no_row_beyond_a_length_is_computed(lengths, layout, side):
     pools, both = _setup(layout, side)
     _check(*both(*(_poison(p, lens) for p in pools),
                  jnp.asarray(_owned(), jnp.int32), jnp.asarray(lens)),
-           "a dead (poisoned) row was computed")
+           "a dead (poisoned) row was computed", layout)
 
 
 @pytest.mark.parametrize("side", SIDES)
@@ -304,3 +329,62 @@ def test_walk_rows_is_what_the_kernel_computes(kernel, length):
     assert sum(widths) == walk_rows(length, BLOCK, P)
     assert walk_rows(length, BLOCK, P) - length < BLOCK
     assert walk_rows(0, BLOCK, P) == 0
+
+
+# -- a grid row is a lane: paged_grid_rows is the call's grid and the host's
+# count (serve/decode_grid_rows) -------------------------------------------
+
+def _pallas_grids(jaxpr) -> list[tuple]:
+    """The ``grid=`` of every ``pallas_call`` under ``jaxpr``."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+# the serving cells' calls (lanes, K/V heads, head width, pools, bytes an
+# element; block 128, 64 table entries): Mellum 2 and the 3B a row a lane,
+# DeepSeek's latent pool likewise; over the VMEM budget, rows of head groups
+@pytest.mark.parametrize("shape,rows", [
+    ((40, 4, 128, 2, 2), 40), ((24, 1, 128, 2, 2), 24),
+    ((128, 1, 640, 1, 2), 128), ((40, 8, 128, 2, 2), 2 * 40),
+    ((40, 4, 128, 2, 4), 2 * 40), ((40, 16, 128, 2, 2), 4 * 40),
+    # one pair of 64 is one chunk already; two pairs share the row; a chunk
+    # narrower than a 128-lane tile keeps a row of its own
+    ((4, 2, 64, 2, 2), 4), ((4, 4, 64, 2, 2), 4), ((4, 3, 16, 2, 2), 3 * 4)],
+    ids=["mellum2", "sc3b", "dsv3_latent", "8_heads", "4_heads_f32",
+         "16_heads", "one_pair", "two_pairs", "narrow"])
+def test_grid_rows_at_the_cells_shapes(shape, rows):
+    lanes, h_kv, d, pools, itemsize = shape
+    assert paged_grid_rows(lanes, h_kv, d, 128, 64, pools=pools,
+                           itemsize=itemsize) == rows
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_the_call_takes_its_grid_from_the_helper(layout):
+    h_kv, d, heads, dtype = LAYOUTS[layout]
+    pool = jax.ShapeDtypeStruct((9, BLOCK, h_kv * d), dtype)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_flash_decode, packed_kv_heads=h_kv, interpret=True))(
+        jax.ShapeDtypeStruct((LANES, 1, heads, d), dtype), pool, pool,
+        jax.ShapeDtypeStruct((LANES, M_BLOCKS), jnp.int32),
+        jax.ShapeDtypeStruct((LANES,), jnp.int32))
+    rows = paged_grid_rows(LANES, h_kv, d, BLOCK, M_BLOCKS,
+                           itemsize=jnp.dtype(dtype).itemsize)
+    assert _pallas_grids(jaxpr.jaxpr) == [(rows,)]
+    assert rows == ROWS_A_LANE[layout] * LANES
+
+
+def test_the_latent_call_takes_its_grid_from_the_helper():
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_mla_decode, d_v=MLA_DV, scale=MLA_SCALE, interpret=True))(
+        jax.ShapeDtypeStruct((LANES, MLA_HEADS, MLA_W), F32),
+        jax.ShapeDtypeStruct((9, BLOCK, MLA_W), F32),
+        jax.ShapeDtypeStruct((LANES, M_BLOCKS), jnp.int32),
+        jax.ShapeDtypeStruct((LANES,), jnp.int32))
+    assert _pallas_grids(jaxpr.jaxpr) == [(paged_grid_rows(
+        LANES, 1, MLA_W, BLOCK, M_BLOCKS, pools=1, itemsize=4),)] == [
+        (LANES,)]

@@ -231,6 +231,59 @@ def test_rows_computed_cover_the_live_rows(lm, layout, chunked):
     assert any(a["rows_live"] > 0 for a in drains)
 
 
+# (heads, K/V heads, embed, grid rows a lane): one K/V head; two of 128 that share a lane's
+# grid row; three of 16, too narrow to share one (a row each)
+GRID_MODELS = {"one_kv_head": (2, 1, 32, 1),
+               "two_kv_heads_of_128": (2, 2, 256, 1),
+               "three_narrow_heads": (3, 3, 48, 3)}
+
+
+@pytest.mark.parametrize("model", GRID_MODELS)
+def test_grid_rows_are_the_calls_grid(model):
+    """``serve/decode_grid_rows`` is what the paged kernel's calls put on
+    the grid: ``grid_rows`` of every ``serve/segment_drain`` is
+    ``paged_grid_rows`` of the loop's shapes (the helper the call takes its
+    ``grid=`` from, ``tests/test_paged_decode_walk.py``), and the counter
+    ticks it ``x attention layers x steps_run``; a lane costs ONE row a call
+    where its K/V heads share one."""
+    from tpudist.ops.flash_decode import paged_grid_rows
+
+    heads, kv_heads, embed, rows_a_lane = GRID_MODELS[model]
+    layers = 2
+    cfg = TransformerConfig(vocab_size=VOCAB, num_layers=layers,
+                            num_heads=heads, num_kv_heads=kv_heads,
+                            embed_dim=embed, max_seq_len=SEQ)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), np.zeros((1, 8), np.int32))["params"]
+    loop = make_loop((cfg, params), "paged", True)
+    counter = obs.counter("serve/decode_grid_rows")
+    lane_steps = obs.counter("serve/lane_steps")
+    before = counter.value(), lane_steps.value()
+    _, sp = run_traced(loop, requests([5, 19, 30, 9], [6, 3, 2 * STEPS, 1]))
+    drains = [e["args"] for e in sp["serve/segment_drain"]]
+    rows = paged_grid_rows(
+        SLOTS, kv_heads, embed // heads, loop.kv_block_size,
+        loop.pool.max_blocks_per_slot, itemsize=4)
+    assert rows == rows_a_lane * SLOTS
+    assert drains and all(a["grid_rows"] == rows for a in drains)
+    ticked = counter.value() - before[0]
+    assert ticked == sum(rows * layers * a["steps_run"] for a in drains) > 0
+    assert ticked == rows_a_lane * layers * (lane_steps.value() - before[1])
+
+
+def test_no_kernel_no_grid_rows(lm):
+    """The dense layout and the paged layout's gather fallback call no paged
+    kernel: the span says 0 and the counter stays."""
+    counter = obs.counter("serve/decode_grid_rows")
+    before = counter.value()
+    for loop in (make_loop(lm, "dense", True),
+                 make_loop(lm, "paged", True, decode_attention="dense")):
+        _, sp = run_traced(loop, requests([5, 9], [6, 3]))
+        assert all(e["args"]["grid_rows"] == 0
+                   for e in sp["serve/segment_drain"])
+    assert counter.value() == before
+
+
 @pytest.mark.parametrize("layout,chunked", CASES)
 def test_chunks_count_the_prompt(lm, layout, chunked):
     lengths = [1, CHUNK, CHUNK + 1, 3 * CHUNK + 5]

@@ -70,7 +70,8 @@ from tpudist.models.speculative import (
     _set_cache_index,
 )
 from tpudist.models.transformer import TransformerConfig, TransformerLM
-from tpudist.ops.flash_decode import paged_tile_pages, walk_rows
+from tpudist.ops.flash_decode import (paged_grid_rows, paged_tile_pages,
+                                      walk_rows)
 
 # placeholder page row for the dense layout's admit signature (the insert
 # walk never reaches a paged node there)
@@ -706,6 +707,22 @@ class ServeLoop:
             if self._tier is not None:
                 self._tier = None
                 self._prefix_cache.spill_hook = None
+        # what ONE decode step's paged kernel calls put on the grid: the
+        # rows of a call (paged_grid_rows, which the call itself takes its
+        # grid= from: a lane's K/V heads share a row where their tiles fit
+        # VMEM) times the attention layers, each one call a step; 0 where
+        # no kernel runs (the dense layout, the gather fallback)
+        self._grid_rows = self._attn_layers = 0
+        if self.pool is not None and decode_attention == "flash":
+            nodes = self._paged_nodes(self.cache)
+            leaves = _kv_leaves(nodes[0], "paged")
+            pool0 = nodes[0]["paged_" + leaves[0]]
+            h_kv = cfg.kv_heads if leaves == ["key", "value"] else 1
+            self._grid_rows = paged_grid_rows(
+                num_slots, h_kv, pool0.shape[2] // h_kv, self.kv_block_size,
+                self.pool.max_blocks_per_slot, pools=len(leaves),
+                itemsize=pool0.dtype.itemsize)
+            self._attn_layers = len(nodes)
         # expert layers (cfg.moe): the segment sums, step by step, the
         # tokens each held expert was given and returns the sums as extra
         # rows of the emit buffer it already returns
@@ -817,6 +834,13 @@ class ServeLoop:
             "serve/decode_rows_window_computed", unit="rows")
         self._obs_rows_window_live = obs.counter(
             "serve/decode_rows_window_live", unit="rows")
+        # the grid rows the paged kernel's calls took: rows a call x
+        # attention layers x the steps a drained segment ran.  Over
+        # lane_steps x layers it is the rows a lane costs a call: 1 where
+        # a lane's K/V heads share a row, their number where each has its
+        # own
+        self._obs_grid_rows = obs.counter("serve/decode_grid_rows",
+                                          unit="rows")
         # expert layers: tokens the held experts were given over a drained
         # segment's steps (all lanes, as lane_steps counts them), the
         # busiest (layer, expert)'s part of that, and the (step, layer,
@@ -3037,7 +3061,9 @@ class ServeLoop:
             call of the decode kernel walks; a lane frozen on the device
             that the host has not drained yet is still counted), with
             ``rows`` (what the kernel's arithmetic covers for those lanes,
-            ``walk_rows`` of each length) and ``rows_live`` (the lengths).
+            ``walk_rows`` of each length), ``rows_live`` (the lengths) and
+            ``grid_rows`` (the grid rows of one call of the paged kernel,
+            ``ops.flash_decode.paged_grid_rows`` of the loop's shapes).
             A model with sliding-window layers adds ``rows_window`` /
             ``rows_window_live`` (the same two of a WINDOW layer's call:
             ``walk_rows`` with the window, ``min(length, window)``; ``rows``
@@ -3141,6 +3167,8 @@ class ServeLoop:
                 self._obs_pages_walked.inc(pages * steps_run)
                 self._obs_rows_computed.inc(rows * steps_run)
                 self._obs_rows_live.inc(rows_live * steps_run)
+                self._obs_grid_rows.inc(
+                    self._grid_rows * self._attn_layers * steps_run)
                 routed = {}
                 if windowed is not None:
                     # the window layers' calls, ticked like the full
@@ -3164,7 +3192,8 @@ class ServeLoop:
                     "serve/segment_drain", t_fetched, time.perf_counter(),
                     seq=s_idx, steps=n_disp, steps_run=steps_run,
                     lanes=lanes, tokens=tokens, first_tokens=first_tokens,
-                    pages=pages, rows=rows, rows_live=rows_live, **routed)
+                    pages=pages, rows=rows, rows_live=rows_live,
+                    grid_rows=self._grid_rows, **routed)
             # zombie refund: every segment dispatched before the kill
             # (index < free_at) has drained once s_idx reaches
             # free_at - 1 — no stale merge can touch the blocks now

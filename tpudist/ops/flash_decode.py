@@ -22,9 +22,10 @@ speedup.  Positions beyond the cache index, or older than the window,
 mask to -inf as before.
 
 The PAGED kernel (:func:`paged_flash_decode`) shares the softmax update
-and has its own body: grid ``(B·H_kv,)``, the pools left in HBM, and a
-loop inside the body over the pages a lane really holds, fetched a tile
-of several pages at a time by its own double-buffered copies.
+and has its own body: grid ``(B,)``, a row a lane with all its K/V heads
+(:func:`paged_grid_rows`), the pools left in HBM, and a loop inside the
+body over the pages a lane really holds, fetched whole, a tile of several
+pages at a time, by its own double-buffered copies.
 
 Guideline (pre-PR 1 capture, not re-measured): ``head_dim < 128`` underfills
 the 128-lane tile width of the K/V blocks (measured: half DMA
@@ -62,6 +63,9 @@ _NEG_BIG = -1e30
 # import: jit caches are not keyed on env vars, so a mid-process flip
 # would silently re-time the cached paired executable.
 _DISABLE_PAIRING = env_flag("TPUDIST_DISABLE_HEAD_PAIRING")
+# where in a paired row's [1, 2, gp, d] query and output blocks each
+# member's tile sits
+_PAIR_SLOTS = ((0, 0), (0, 1))
 
 
 def _softmax_update(m_scr, l_scr, acc_scr, s, pv_scale, vb, also=None):
@@ -108,45 +112,47 @@ def _softmax_update(m_scr, l_scr, acc_scr, s, pv_scale, vb, also=None):
     acc_scr[:] = acc_scr[:] * corr + out
 
 
-def _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr):
-    """Reset the online-softmax scratch for a new grid row and, for the
-    head-paired layout (``q_scr`` not None), build its query tile."""
+def _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr, slots):
+    """Reset the online-softmax scratch for a new grid row and, where
+    several heads share the row's update (``slots`` not None: the
+    head-paired layout, a paged lane's folded K/V heads), build their
+    block-diagonal query tile in ``q_scr`` from the heads' ``[gp, d]``
+    queries, at ``slots`` of the row's query block."""
     m_scr[:] = jnp.full_like(m_scr, _NEG_BIG)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
-    if q_scr is not None:
-        # block-diagonal [2gp, 2d] from the two [gp, d] members:
-        # rows [0, gp) carry member 0's queries in lanes [0, d),
-        # rows [gp, 2gp) member 1's in lanes [d, 2d) — the zero
-        # half annihilates the other member in the single 2d
+    if slots is not None:
+        # block-diagonal [n·gp, n·d] from the n [gp, d] heads: rows
+        # [j·gp, (j+1)·gp) carry head j's queries in lanes [j·d, (j+1)·d)
+        # — the zeros annihilate the other heads in the single n·d
         # contraction.  Built ONCE per grid row into scratch: the
         # lane-offset concatenates are not free under Mosaic, and
         # rebuilding them every K step measured ~2x on the whole
         # kernel at B=8
-        q0, q1 = q_ref[0, 0], q_ref[0, 1]
-        z = jnp.zeros_like(q0)
+        heads = [q_ref[at] for at in slots]
+        z = jnp.zeros_like(heads[0])
         q_scr[:] = jnp.concatenate(
-            [jnp.concatenate([q0, z], axis=1),
-             jnp.concatenate([z, q1], axis=1)], axis=0)
+            [jnp.concatenate([q if i == j else z
+                              for i in range(len(heads))], axis=1)
+             for j, q in enumerate(heads)], axis=0)
 
 
-def _softmax_finalize(l_scr, acc_scr, o_ref, paired: bool):
+def _softmax_finalize(l_scr, acc_scr, o_ref, slots=None):
     """Write ``acc / l`` to the grid row's output block; returns the
-    clamped ``l`` (the log-sum-exp needs it)."""
+    clamped ``l`` (the log-sum-exp needs it).  ``slots`` (a block-diagonal
+    row): where in ``o_ref`` each head's output goes."""
     l = jnp.maximum(l_scr[:], 1e-30)
     o = (acc_scr[:] / l).astype(o_ref.dtype)
-    if paired:
-        # UNPACK in kernel: member m's output lives in rows
-        # [m·gp, (m+1)·gp) × lanes [m·d, (m+1)·d) of the block-
-        # diagonal result — write each member's tile to its own
-        # [gp, d] output slot, so XLA sees the natural layout and
-        # pays no per-token lane-half slicing/stacking
-        half_r = o.shape[0] // 2
-        half_d = o.shape[1] // 2
-        o_ref[0, 0] = o[:half_r, :half_d]
-        o_ref[0, 1] = o[half_r:, half_d:]
-    else:
+    if slots is None:
         o_ref[0] = o
+        return l
+    # UNPACK in kernel: head j's output lives in rows [j·gp, (j+1)·gp) ×
+    # lanes [j·d, (j+1)·d) of the block-diagonal result — write each
+    # head's tile to its own [gp, d] output slot, so XLA sees the natural
+    # layout and pays no per-token lane slicing/stacking
+    gp, d = o.shape[0] // len(slots), o.shape[1] // len(slots)
+    for j, at in enumerate(slots):
+        o_ref[at] = o[j * gp:(j + 1) * gp, j * d:(j + 1) * d]
     return l
 
 
@@ -213,6 +219,7 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
+    slots = _PAIR_SLOTS if paired_q else None
     kj = pl.program_id(1)
     if rows_per_batch is None:
         cache_len = meta_ref[0]
@@ -228,7 +235,7 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
     @pl.when(kj == 0)
     def _init():
         _softmax_init(m_scr, l_scr, acc_scr, q_ref,
-                      q_scr if paired_q else None)
+                      q_scr if paired_q else None, slots)
 
     def q_tile():
         return q_scr[:] if paired_q else q_ref[0]    # [gp, D]
@@ -279,7 +286,7 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
 
     @pl.when(kj == num_kb - 1)
     def _finalize():
-        l = _softmax_finalize(l_scr, acc_scr, o_ref, paired_q)
+        l = _softmax_finalize(l_scr, acc_scr, o_ref, slots)
         if with_lse:
             # log-sum-exp of this shard's scores: the merge key for
             # sequence-parallel decode (out = Σ out_i·exp(lse_i − LSE))
@@ -288,10 +295,26 @@ def _decode_kernel(meta_ref, q_ref, k_ref, *rest, scale: float,
 
 def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                          block: int, pages_per_tile: int, m_blocks: int,
-                         lanes: int, r_kv: int, paired: bool, side: bool,
-                         d_v: int | None = None, window: int | None = None):
-    """Online-softmax decode over ONE grid row (a lane's K/V-head chunk)
-    of a paged cache, walking the lane's LIVE pages only.
+                         lanes: int, groups: int, slots: tuple | None,
+                         side: bool, d_v: int | None = None,
+                         window: int | None = None):
+    """Online-softmax decode over ONE grid row of a paged cache: a LANE
+    and all its K/V heads, walking the lane's live pages only.
+
+    The K/V heads of a lane walk the same pages, so they share a row: it
+    fetches each live page ONCE, whole (``[block, h_kv * d]``, contiguous
+    in the pool), and one rank update a tile serves every head, through
+    the block-diagonal query the head-paired layout already builds
+    (``slots``: where in the row's ``[heads, .., gp, d]`` query and output
+    blocks each head sits; its queries meet zeros in the other heads'
+    columns, so within a head the sums are what a row of its own gives).
+    ``slots`` None: one head, the query block as it is.  A lane whose
+    heads' tile slots do not fit VMEM takes ``groups`` rows, each a group
+    of heads and this same program (:func:`paged_grid_rows`).  VMEM at the
+    widest cell (4 K/V heads of 128 in bf16, tiles of 8 pages of 128
+    rows): 2 slots x 1 MiB a pool = 4 MiB for both pools, beside the
+    masked copy of each pool's last tile (1 MiB each), f32 scores of
+    ``[32, 1024]`` and the ``[32, 512]`` query, ``m`` / ``l`` / ``acc``.
 
     ``meta_ref`` is the scalar-prefetch vector ``[side_len, len_0 ..
     len_{B-1}, table[0, 0] .. table[B-1, M-1]]``.  The pools stay in HBM;
@@ -350,9 +373,9 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     o_ref, rest = rest[0], rest[1:]
     bufs, rest = rest[:n_pools], rest[n_pools:]
     sems, state_ref, m_scr, l_scr, acc_scr = rest[:5]
-    q_scr = rest[5] if paired else None
+    q_scr = rest[5] if slots is not None else None
     g = pl.program_id(0)
-    lane, r = g // r_kv, g % r_kv
+    lane, r = g // groups, g % groups
     d = bufs[0].shape[-1]
 
     def values(keys, load_values):
@@ -385,8 +408,9 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                            pages_per_tile)
         base = (1 + lanes + i * m_blocks + first_page(i)
                 + t * pages_per_tile)
-        # grid row's chunk of the packed minor dim (all of it at r_kv 1)
-        chunk = (slice(None) if r_kv == 1
+        # the grid row's heads in the packed minor dim (all of it where
+        # a lane is one row: the page whole, one contiguous copy)
+        chunk = (slice(None) if groups == 1
                  else pl.ds(pl.multiple_of(r_ * d, d), d))
 
         def one_page(p, _):
@@ -405,18 +429,19 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         tile_copies(i, r_, t, slot, lambda c: c.wait())
 
     def next_live_row():
-        """The next grid row that holds a page: this lane's next chunk,
-        else chunk 0 of the next lane whose length is not 0 (``lanes`` if
-        there is none).  The scan stops at the first such lane: it runs on
-        the scalar unit with nothing beside it, and a scan of every lane
-        left cost a row of 128 lanes half a microsecond."""
+        """The next grid row that holds a page: this lane's next group of
+        heads (a lane of several rows), else the first row of the next
+        lane whose length is not 0 (``lanes`` if there is none).  The scan
+        stops at the first such lane: it runs on the scalar unit with
+        nothing beside it, and a scan of every lane left cost a row of 128
+        lanes half a microsecond."""
         nxt = jax.lax.while_loop(
             lambda i: jnp.logical_and(
                 i < lanes, lane_len(jnp.minimum(i, lanes - 1)) == 0),
             lambda i: i + 1, lane + 1)
-        if r_kv == 1:
+        if groups == 1:
             return nxt, 0
-        more = r + 1 < r_kv
+        more = r + 1 < groups
         return jnp.where(more, lane, nxt), jnp.where(more, r + 1, 0)
 
     @pl.when(g == 0)
@@ -425,10 +450,10 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         state_ref[0] = 0
         state_ref[1] = 0
 
-    _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr)
+    _softmax_init(m_scr, l_scr, acc_scr, q_ref, q_scr, slots)
 
     def q_tile():
-        return q_scr[:] if paired else q_ref[0]
+        return q_ref[0] if slots is None else q_scr[:]
 
     n_pages = lane_pages(lane)
     n_tiles = (n_pages + pages_per_tile - 1) // pages_per_tile
@@ -540,7 +565,7 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
             s, vals = side_tiles()
             _softmax_update(m_scr, l_scr, acc_scr, s, None, vals)
 
-    _softmax_finalize(l_scr, acc_scr, o_ref, paired)
+    _softmax_finalize(l_scr, acc_scr, o_ref, slots)
 
 
 def _width_step(pages_per_tile: int) -> int:
@@ -557,6 +582,50 @@ def paged_tile_pages(block: int, m_blocks: int) -> int:
     it, small enough that two slots of every pool stay a small part of
     VMEM; never more than a lane's table row."""
     return max(1, min(m_blocks, 1024 // block))
+
+
+# VMEM the tile slots of one paged call may take (every pool, both slots):
+# a quarter of the 16 MiB a v5e kernel gets by default, since the walk's
+# temporaries come on top of them (a masked copy of each pool's last tile,
+# half a pool's slots; the f32 scores).  The widest cell sits on it (4 K/V
+# heads of 128 in bf16, 8 pages of 128 rows a tile: 2 pools x 2 slots x
+# 1 MiB); tests/test_aot_tpu_compile.py compiles that call, and one of
+# twice its heads (two rows a lane), for a described v5e
+_TILE_SLOT_BYTES = 4 << 20
+
+
+def _pairs(h_kv: int, d: int) -> bool:
+    """Whether adjacent K/V heads share a chunk of the packed minor dim
+    (the head-paired layout of narrow heads)."""
+    return h_kv % 2 == 0 and d * 2 <= 128 and not _DISABLE_PAIRING
+
+
+def paged_grid_rows(lanes: int, h_kv: int, d: int, block: int,
+                    m_blocks: int, *, pools: int = 2,
+                    itemsize: int = 2) -> int:
+    """Grid rows of ONE paged decode call over ``lanes`` lanes of ``h_kv``
+    K/V heads of ``d`` (the latent pool: one head of the row's width,
+    ``pools=1``), pages of ``block`` rows, ``m_blocks`` table entries a
+    lane, pools of ``itemsize`` bytes an element.
+
+    A grid row is a lane: the lane's K/V-head chunks (a head; a pair of
+    narrow heads) share its page copies and its rank updates.  Chunks a
+    row = the largest divisor of the lane's chunks whose tile slots (every
+    pool, two slots, :func:`paged_tile_pages` pages) fit
+    ``_TILE_SLOT_BYTES``; a lane whose chunks do not all fit takes several
+    rows, each a group of them, and a chunk that is not a whole number of
+    128-lane tiles keeps a row of its own (its slice of a fetched page
+    would not be lane-aligned).  Follows from shapes alone.  Both the
+    call's ``grid=`` and the host's count (``serve/decode_grid_rows``)."""
+    chunks, width = (h_kv // 2, 2 * d) if _pairs(h_kv, d) else (h_kv, d)
+    slot = (pools * 2 * paged_tile_pages(block, m_blocks) * block * width
+            * itemsize)
+    fold = 1
+    if width % 128 == 0:
+        fold = max((n for n in range(1, chunks + 1)
+                    if chunks % n == 0 and n * slot <= _TILE_SLOT_BYTES),
+                   default=1)
+    return lanes * (chunks // fold)
 
 
 def walk_rows(length: int, block: int, pages_per_tile: int,
@@ -806,7 +875,7 @@ def _flash_decode_impl(q, k_cache, k_scale, v_cache, v_scale, cache_len,
     # lane half, so folding member m's scale into half-m score/prob rows
     # is exact.
     scale = d ** -0.5
-    paired = h_kv % 2 == 0 and d * 2 <= 128 and not _DISABLE_PAIRING
+    paired = _pairs(h_kv, d)
     q4 = q.reshape(b, h_kv, g, d)                    # [B, Hkv, g, d]
     q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     if paired:
@@ -1116,7 +1185,7 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
         side_len.reshape(1),
         jnp.minimum(cache_len, m_blocks * block), table.reshape(-1)])
 
-    paired = h_kv % 2 == 0 and d * 2 <= 128 and not _DISABLE_PAIRING
+    paired = _pairs(h_kv, d)
     q4 = q.reshape(b, h_kv, g, d)
     q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     if paired:
@@ -1144,24 +1213,36 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
                 r_kv: int, paired: bool, d_v: int | None, interpret: bool,
                 name: str, window: int | None = None):
     """The ``pallas_call`` both paged decode kernels share: one grid row a
-    (lane, K/V-head chunk), the pools left in HBM for the body's own
-    copies, ``q3 [rows, gp, d]`` (``[rows, 2, gp, d]`` paired) in and an
+    lane and all its ``r_kv`` K/V-head chunks where their tile slots fit
+    (:func:`paged_grid_rows`; several rows a lane, each a group of
+    chunks, where not), the pools left in HBM for the body's own copies,
+    ``q3 [lanes * r_kv, gp, d]`` (``[.., 2, gp, d]`` paired) in and an
     output of its shape (``d_v`` wide where the one pool's rows double as
     values) out."""
     block, m_blocks = pools[0].shape[1], (meta.shape[0] - 1 - lanes) // lanes
-    if paired:
-        gp, d = 2 * q3.shape[2], 2 * q3.shape[3]
-        row_spec = pl.BlockSpec((1, 2, gp // 2, d // 2),
-                                lambda g_, m: (g_, 0, 0, 0))
-        out_spec, out_shape = row_spec, q3.shape
+    members = 2 if paired else 1
+    gp, d_head = q3.shape[-2:]
+    rows = paged_grid_rows(
+        lanes, r_kv * members, d_head, block, m_blocks, pools=len(pools),
+        itemsize=pools[0].dtype.itemsize)
+    groups = rows // lanes
+    chunks = r_kv // groups                # of the lane's r_kv, a grid row
+    # where each head of a row sits in its query and output blocks (None:
+    # the one head is the block), and the row's columns of a page
+    slots = (tuple((j, m) for j in range(chunks) for m in range(2))
+             if paired else
+             tuple((j,) for j in range(chunks)) if chunks > 1 else None)
+    n = chunks * members
+    d = n * d_head
+    # a grid row's queries in, its output out: the same block of both
+    row_spec = pl.BlockSpec((chunks, *q3.shape[1:]),
+                            lambda g_, m: (g_,) + (0,) * (q3.ndim - 1))
+    if d_v is None:
+        out_spec, out_shape, acc_d = row_spec, q3.shape, d
     else:
-        gp, d = q3.shape[1:]
-        # a grid row's queries in, its output out: the same block of both
-        row_spec = pl.BlockSpec((1, gp, d), lambda g_, m: (g_, 0, 0))
-        d_out = d if d_v is None else d_v
-        out_spec = pl.BlockSpec((1, gp, d_out), lambda g_, m: (g_, 0, 0))
-        out_shape = (q3.shape[0], gp, d_out)
-    R = r_kv  # noqa: N806 — closed over by the index maps
+        out_spec = pl.BlockSpec((1, gp, d_v), lambda g_, m: (g_, 0, 0))
+        out_shape, acc_d = (q3.shape[0], gp, d_v), d_v
+    G = groups  # noqa: N806 — closed over by the index maps
     pages_per_tile = paged_tile_pages(block, m_blocks)
     # the pools are left where they are (HBM): the kernel's own copies
     # fetch the pages a lane really holds, by the ids in meta
@@ -1170,30 +1251,29 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
     in_specs = [row_spec] + [pool_spec] * len(pools)
     if sides is not None:
         side_spec = pl.BlockSpec(
-            (1, sides[0].shape[1], d), lambda g_, m: (g_ // R, 0, g_ % R))
+            (1, sides[0].shape[1], d), lambda g_, m: (g_ // G, 0, g_ % G))
         args += list(sides)
         in_specs += [side_spec] * len(sides)
 
     tile_buf = pltpu.VMEM((2, pages_per_tile, block, d), pools[0].dtype)
-    acc_d = d if d_v is None else d_v
     return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, scale=scale, block=block,
             pages_per_tile=pages_per_tile, m_blocks=m_blocks, lanes=lanes,
-            r_kv=r_kv, paired=paired, side=sides is not None, d_v=d_v,
+            groups=groups, slots=slots, side=sides is not None, d_v=d_v,
             window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(lanes * r_kv,),
+            grid=(rows,),
             in_specs=in_specs,
             out_specs=out_spec,
             scratch_shapes=[tile_buf] * len(pools) + [  # two slots a pool
                 pltpu.SemaphoreType.DMA((len(pools), 2)),  # [pool, slot]
                 pltpu.SMEM((2,), jnp.int32),         # cross-row prefetch
-                pltpu.VMEM((gp, 1), jnp.float32),
-                pltpu.VMEM((gp, 1), jnp.float32),
-                pltpu.VMEM((gp, acc_d), jnp.float32),
-            ] + ([pltpu.VMEM((gp, d), q3.dtype)] if paired else []),
+                pltpu.VMEM((n * gp, 1), jnp.float32),
+                pltpu.VMEM((n * gp, 1), jnp.float32),
+                pltpu.VMEM((n * gp, acc_d), jnp.float32),
+            ] + ([pltpu.VMEM((n * gp, d), q3.dtype)] if n > 1 else []),
         ),
         out_shape=jax.ShapeDtypeStruct(out_shape, q3.dtype),
         # sequential: a row starts the next live row's first tile
