@@ -516,6 +516,221 @@ def test_mixed_window_model_names_its_kernels(v5e):
     _compile(loop._admit_finish, *finish)
 
 
+# The Keye-VL 2.0 cell (keye2_longdoc_mixed): 16 lanes, 256 table entries of
+# 128 rows, a pool of 2900 blocks, side 16; 32 query heads on 4 K/V heads of
+# 128; an indexer of 16 heads of 64 (a key stored 128 wide) that keeps 2048
+# rows; prefill chunks of 2048 queries over a batch-1 cache of 32768 rows.
+IDX_LANES, IDX_HEADS, IDX_WIDTH, IDX_TOPK = 16, 16, 128, 2048
+IDX_ENTRIES, IDX_BLOCKS, IDX_CHUNK, IDX_SIDE = 256, 2900, 2048, 16
+IDX_Q, IDX_KV, IDX_D = 32, 4, 128
+
+
+def _named_call(hlo: str, name: str, pattern) -> dict:
+    (line,) = [l.strip() for l in hlo.splitlines()
+               if re.match(rf"\s*(ROOT )?%{name}[.\d]* = ", l)]
+    assert pattern.match(line.removeprefix("ROOT "))
+    return _custom_call(hlo.replace("ROOT %", "%"), name)
+
+
+@pytest.mark.parametrize("path", ["decode_step", "prefill_chunk"])
+def test_paged_index_scores_at_the_cells_shapes(v5e, path):
+    """A decode step: a grid row a lane, each its own pages.  A prefill
+    chunk: 16 queries a grid row over ONE table row (the batch-1 cache's
+    32768 rows seen as pages)."""
+    from benchmarks.layer_metrics import _index_spans
+    from tpudist.ops.flash_decode import (index_queries_per_row,
+                                          paged_index_scores)
+
+    if path == "decode_step":
+        t, rows, table, blocks = IDX_LANES, IDX_LANES, IDX_LANES, IDX_BLOCKS
+    else:
+        tq = index_queries_per_row(IDX_CHUNK, IDX_HEADS, IDX_ENTRIES * BLOCK)
+        assert tq == 16
+        t, rows, table, blocks = IDX_CHUNK, IDX_CHUNK // tq, 1, IDX_ENTRIES
+    hlo = _compile(
+        paged_index_scores, _sds(v5e, (t, IDX_HEADS, IDX_WIDTH)),
+        _sds(v5e, (t, IDX_HEADS), jnp.float32),
+        _sds(v5e, (blocks, BLOCK, IDX_WIDTH)),
+        _sds(v5e, (table, IDX_ENTRIES), jnp.int32),
+        _sds(v5e, (rows,), jnp.int32))
+    assert _kernel_calls(hlo) == 1
+    op = _named_call(hlo, "paged_index_scores", _index_spans.SCORES)
+    assert op["pallas"] and op["operands"] == 4      # meta, q, w, pool
+    assert op["outputs"] == (
+        f"f32[{rows},{IDX_ENTRIES * BLOCK // 1024},{t // rows},1024]",)
+
+
+def test_a_page_of_64_wide_index_keys_is_refused(v5e):
+    """Why the cached index key is 128 wide (``index_cache_width``): the
+    chip's compiler refuses the copy of a page narrower than a tile."""
+    from tpudist.ops.flash_decode import paged_index_scores
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(
+            paged_index_scores, _sds(v5e, (IDX_LANES, IDX_HEADS, 64)),
+            _sds(v5e, (IDX_LANES, IDX_HEADS), jnp.float32),
+            _sds(v5e, (IDX_BLOCKS, BLOCK, 64)),
+            _sds(v5e, (IDX_LANES, IDX_ENTRIES), jnp.int32),
+            _sds(v5e, (IDX_LANES,), jnp.int32))
+
+
+def test_sparse_gqa_attend_at_the_cells_shapes(v5e):
+    """The chosen rows of both pools gathered by flat row id (the staged
+    rows out of the side buffers) and attended as pages of the gathered
+    buffers, on the shared walk under its own name."""
+    from benchmarks.layer_metrics import _index_spans
+    from benchmarks.layer_metrics.paged_decode_us_per_call import KERNEL
+    from tpudist.ops.flash_decode import sparse_gqa_attend
+
+    flat = IDX_KV * IDX_D
+    hlo = _compile(
+        lambda q, k, v, i, c, sk, sv: sparse_gqa_attend(
+            q, k, v, i, c, packed_kv_heads=IDX_KV, side_k=sk, side_v=sv),
+        _sds(v5e, (IDX_LANES, IDX_Q, IDX_D)),
+        _sds(v5e, (IDX_BLOCKS * BLOCK, flat)),
+        _sds(v5e, (IDX_BLOCKS * BLOCK, flat)),
+        _sds(v5e, (IDX_LANES, IDX_TOPK), jnp.int32),
+        _sds(v5e, (IDX_LANES,), jnp.int32),
+        _sds(v5e, (IDX_LANES, IDX_SIDE, flat)),
+        _sds(v5e, (IDX_LANES, IDX_SIDE, flat)))
+    assert _kernel_calls(hlo) == 1
+    op = _named_call(hlo, "sparse_gqa_attend", _index_spans.ATTEND)
+    assert op["pallas"] and op["operands"] == 4   # meta, q, K rows, V rows
+    assert op["outputs"] == (
+        f"bf16[{IDX_LANES * IDX_KV},{IDX_Q // IDX_KV},{IDX_D}]",)
+    assert not any(KERNEL.match(l.strip()) for l in hlo.splitlines())
+
+
+def test_flash_chosen_rows_at_the_cells_shapes(v5e):
+    """A sparse chunk's attention: 2048 queries at a dynamic offset over
+    the batch-1 cache's 32768 rows under an int8 mask a (query, row)."""
+    from benchmarks.layer_metrics import _index_spans
+    from tpudist.ops.flash_attention import flash_chosen_rows
+
+    kv = _sds(v5e, (1, IDX_ENTRIES * BLOCK, IDX_KV, IDX_D))
+    hlo = _compile(
+        lambda q, k, v, m, o: flash_chosen_rows(q, k, v, m, o),
+        _sds(v5e, (1, IDX_CHUNK, IDX_Q, IDX_D)), kv, kv,
+        _sds(v5e, (IDX_CHUNK, IDX_ENTRIES * BLOCK), jnp.int8),
+        _sds(v5e, (), jnp.int32))
+    assert _kernel_calls(hlo) == 1
+    op = _named_call(hlo, "sparse_gqa_prefill", _index_spans.CHUNK_ATTEND)
+    assert op["pallas"] and op["operands"] == 5   # offset, q, k, v, mask
+
+
+@pytest.mark.parametrize("path", ["decode_step", "prefill_chunk"])
+def test_index_select_at_the_cells_shapes(v5e, path):
+    """The decode step's selection over the table's reach and the staged
+    rows (positions), a chunk's over the cache's rows (a mask, its passes
+    under a loop that follows the rows): passes and products, no sort of
+    the row."""
+    from tpudist.ops.flash_decode import index_select, index_select_mask
+
+    if path == "decode_step":
+        scores = _sds(v5e, (IDX_LANES, IDX_ENTRIES * BLOCK + IDX_SIDE),
+                      jnp.float32)
+        hlo = _compile(lambda s: index_select(s, IDX_TOPK), scores)
+        # what it replaces is one
+        assert re.search(r"\bsort\(", _compile(
+            lambda s: jax.lax.top_k(s, IDX_TOPK)[1], scores))
+    else:
+        hlo = _compile(
+            lambda s, n: index_select_mask(s, IDX_TOPK, rows=n),
+            _sds(v5e, (IDX_CHUNK, IDX_ENTRIES * BLOCK), jnp.float32),
+            _sds(v5e, (), jnp.int32))
+    assert not re.search(r"\bsort\(", hlo) and _kernel_calls(hlo) == 0
+
+
+def _indexer_loop(**over):
+    from tpudist.models import MoEConfig
+
+    moe = MoEConfig(num_experts=16, top_k=4, experts="gated_silu", d_ff=128,
+                    scoring="softmax", held=(0, 4))
+    sizes = dict(index_heads=16, index_head_dim=64, index_topk=1024)
+    sizes.update(over)
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=2, num_heads=8, num_kv_heads=2,
+        head_size=128, embed_dim=512, max_seq_len=4096,
+        compute_dtype=jnp.bfloat16, norm="rmsnorm", positions="rotary",
+        rope_theta=1e7, mlp="gated_silu", mlp_dim=128, moe=moe,
+        qk_norm=True, **sizes)
+    return ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),
+                     num_slots=SLOTS, steps_per_sync=WIN_STEPS,
+                     decode_attention="flash", prefill_chunk=1024,
+                     cache_layout="paged", kv_block_size=BLOCK)
+
+
+def _indexer_programs(v5e, loop):
+    seg = _on(v5e, (loop.params, loop.cache, loop._tok, loop._active,
+                    loop._remaining, loop._first, loop._key,
+                    jnp.int32(WIN_STEPS), jnp.bool_(False)))
+    chunk = _on(v5e, (loop.params, loop._blank1,
+                      jnp.zeros((1, 1024), jnp.int32), jnp.int32(0),
+                      jnp.int32(0)))
+    return seg, chunk
+
+
+def test_indexer_model_names_its_kernels(v5e):
+    """Grouped queries with a q/k norm and an indexer that keeps 1024 of up
+    to 4096 rows (Keye-VL 2.0's block at a small size): the segment holds
+    the dense kernel (no lane past 1024 rows) and the routines of the
+    chosen rows; the prefill chunk the dense flash pass (a prompt's first
+    chunk) and the sparse one; the finish moves three leaves a layer."""
+    loop = _indexer_loop()
+    seg, chunk = _indexer_programs(v5e, loop)
+    experts = {"moe_experts_gate_up", "moe_experts_down"}
+    assert _kernel_scopes(loop._segment, *seg) == (
+        {"paged_flash_decode", "paged_index_scores", "sparse_gqa_attend"}
+        | experts)
+    assert _kernel_scopes(loop._prefill_chunk, *chunk, chunk=1024) == (
+        {"flash_fwd", "paged_index_scores", "sparse_gqa_prefill"} | experts)
+    # two layers of three attention kernels and two expert kernels
+    assert _kernel_calls(_compile(loop._segment, *seg)) == 10
+    node = loop.cache["block0"]["attn"]
+    assert node["paged_ikey"].shape[1:] == (BLOCK, 128)
+    assert loop._blank1["block0"]["attn"]["cached_ikey"].shape == (
+        1, 4096, 128)
+    pages = jnp.zeros((32,), jnp.int32)
+    finish = _on(v5e, (loop.cache, loop._tok, loop._active, loop._remaining,
+                       loop._first, loop._blank1,
+                       jnp.zeros((1, 1, 1024), jnp.float32), jnp.int32(0),
+                       jnp.int32(5), jnp.int32(0), jnp.int32(5), pages,
+                       jnp.int32(0), loop._key))
+    _compile(loop._admit_finish, *finish)
+
+
+def test_a_model_without_an_indexer_lowers_as_before(v5e):
+    """``index_topk=None`` is the program of PR 32: stating the three
+    indexer sizes as None changes no line of the lowered segment or chunk,
+    neither holds the selection's scope or a conditional on the lanes'
+    lengths, and an indexer changes both."""
+    def texts(**kw_sizes):
+        debug = kw_sizes.pop("debug_info", False)
+        loop = _indexer_loop(**kw_sizes)
+        seg, chunk = _indexer_programs(v5e, loop)
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            both = (loop._segment.lower(*seg).as_text(debug_info=debug),
+                    loop._prefill_chunk.lower(*chunk, chunk=1024).as_text(
+                        debug_info=debug))
+        # a kernel's serialized body carries source lines and a counter
+        return tuple(re.sub(r'backend_config = "[^"]*"', "", t)
+                     for t in both)
+
+    none = dict(index_heads=None, index_head_dim=None, index_topk=None)
+    plain = texts(**none)
+    for text in plain:
+        assert "stablehlo.case" not in text
+        assert "paged_index_scores" not in text
+    with_indexer = texts()
+    assert all("stablehlo.case" in t for t in with_indexer)
+    # the scope as a name stack has it (``.../index_select/...``): the bare
+    # word also names a frame of the tracebacks that jaxprs cached by an
+    # indexer traced earlier in the process carry into later programs
+    for mine, theirs in zip(texts(debug_info=True, **none),
+                            texts(debug_info=True)):
+        assert "/index_select/" not in mine and "/index_select/" in theirs
+
+
 def test_dense_layout_segment_names_its_kernel(v5e):
     cfg = _cfg(4, 1)
     loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),

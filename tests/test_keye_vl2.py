@@ -1,0 +1,591 @@
+"""The Keye-VL 2.0 language block (grouped-query attention with a per-head
+q/k norm and a learned indexer, an expert FFN in every layer) through the
+program against the plain reference (``benchmarks/reference/keye_vl2.py``),
+on the CPU at a small size with the published model's shape kept: 4 query
+heads on 2 K/V heads of a stated width (4 x 16 on a 48-wide model), an
+indexer of 8 heads of 16 with ONE index key a token that keeps 16 rows, far
+under the contexts used (up to 72), so that the selection bites everywhere;
+a softmax router over 8 experts of which 4 are held, 2 a token, seeded
+weights.
+
+Float32 compute: the program and the reference then agree to rounding, and
+choose the same rows except where two index scores lie closer than
+rounding, which the seeds below do not hit.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import keye_vl2 as ref
+from tpudist import obs
+from tpudist.models import (MoEConfig, MoEMLP, Request, ServeLoop,
+                            TransformerConfig, TransformerLM)
+from tpudist.models.generate import _blank_cache
+from tpudist.models.serving import _index_leaves, _kv_leaves
+from tpudist.ops.flash_attention import flash_chosen_rows
+from tpudist.ops.flash_decode import (index_queries_per_row, index_select,
+                                      index_select_mask, paged_index_scores,
+                                      sparse_gqa_attend)
+
+VOCAB, EMBED, SEQ, TOPK = 97, 48, 128, 16
+DIMS = ref.Dims(
+    vocab=VOCAB, layers=3, embed=EMBED, heads=4, kv_heads=2, head_dim=16,
+    expert_ff=32, experts=8, top_k=2, held=(0, 4), index_heads=8,
+    index_dim=16, index_topk=TOPK, rope_theta=10000.0)
+# logits are O(1) at these widths; float32 against float32-HIGHEST differs
+# in the 6th digit
+LOGIT_TOL = 2e-4
+
+
+def _moe(held=DIMS.held, experts=DIMS.experts) -> MoEConfig:
+    return MoEConfig(num_experts=experts, top_k=DIMS.top_k,
+                     experts="gated_silu", d_ff=DIMS.expert_ff,
+                     scoring="softmax", held=held)
+
+
+def _cfg(topk=TOPK, **over) -> TransformerConfig:
+    base = dict(
+        vocab_size=VOCAB, num_layers=DIMS.layers, num_heads=DIMS.heads,
+        num_kv_heads=DIMS.kv_heads, head_size=DIMS.head_dim,
+        embed_dim=EMBED, max_seq_len=SEQ, compute_dtype=jnp.float32,
+        norm="rmsnorm", positions="rotary", rope_theta=DIMS.rope_theta,
+        mlp="gated_silu", mlp_dim=DIMS.expert_ff, moe=_moe(), qk_norm=True,
+        index_heads=DIMS.index_heads, index_head_dim=DIMS.index_dim,
+        index_topk=topk)
+    base.update(over)
+    return TransformerConfig(**base)
+
+
+def _plain_cfg() -> TransformerConfig:
+    """The same model without an indexer."""
+    return _cfg(topk=None, index_heads=None, index_head_dim=None)
+
+
+@functools.cache
+def _params():
+    """Seeded leaves; the norms' scales and the index key norm's bias are
+    drawn too, so that each is exercised."""
+    tree = TransformerLM(_cfg()).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    keys = iter(jax.random.split(jax.random.key(6), 64))
+
+    def draw(path, leaf):
+        name = "/".join(str(getattr(p, "key", p)) for p in path)
+        if "q_norm" in name or "k_norm/scale" in name:
+            return 1.0 + 0.2 * jax.random.normal(next(keys), leaf.shape)
+        if "idx_k_norm/bias" in name:
+            return 0.1 * jax.random.normal(next(keys), leaf.shape)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(draw, tree)
+
+
+def _plain_params():
+    return {
+        name: ({**node, "attn": {k: v for k, v in node["attn"].items()
+                                 if not k.startswith("idx_")}}
+               if name.startswith("block") else node)
+        for name, node in _params().items()}
+
+
+# -- (a) the program against the reference ------------------------------------
+
+def test_one_shot_forward_matches_reference():
+    toks = jax.random.randint(jax.random.key(1), (1, 72), 0, VOCAB)
+    got = TransformerLM(_cfg()).apply({"params": _params()}, toks)[0]
+    want = ref.Forward(DIMS).logits(_params(), toks[0])
+    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    attn = _params()["block0"]["attn"]
+    assert attn["idx_q"]["kernel"].shape == (EMBED, 8 * 16)
+    assert attn["idx_k"]["kernel"].shape == (EMBED, 16)
+    assert attn["idx_w"]["kernel"].shape == (EMBED, 8)
+    assert attn["q_norm"]["scale"].shape == (16,)
+    assert set(attn["idx_k_norm"]) == {"scale", "bias"}
+
+
+def test_the_selection_bites():
+    """The same sequence with the selection skipped, and with the wrong
+    rows, gives other logits: the comparison above is of the choice."""
+    toks = jax.random.randint(jax.random.key(1), (72,), 0, VOCAB)
+    want = np.asarray(ref.Forward(DIMS).logits(_params(), toks))
+    for select in ("all", "recent"):
+        other = np.asarray(ref.Forward(DIMS, select=select).logits(
+            _params(), toks))
+        np.testing.assert_allclose(other[:TOPK], want[:TOPK],
+                                   atol=LOGIT_TOL, rtol=0)
+        assert np.abs(other[TOPK:] - want[TOPK:]).max() > 100 * LOGIT_TOL
+
+
+def test_the_q_k_norm_is_in_the_forward():
+    """Without the per-head norm on q and k the logits are another
+    model's."""
+    toks = jax.random.randint(jax.random.key(1), (1, 24), 0, VOCAB)
+    with_norm = TransformerLM(_cfg()).apply({"params": _params()}, toks)
+    bare = {
+        name: ({**node, "attn": {k: v for k, v in node["attn"].items()
+                                 if k not in ("q_norm", "k_norm")}}
+               if name.startswith("block") else node)
+        for name, node in _params().items()}
+    without = TransformerLM(_cfg(qk_norm=False)).apply({"params": bare},
+                                                       toks)
+    assert float(jnp.abs(with_norm - without).max()) > 100 * LOGIT_TOL
+
+
+@pytest.mark.parametrize("decode_attention", ["dense", "flash"])
+@pytest.mark.parametrize("chunk", [16, 24], ids=["chunk16", "chunk24"])
+def test_chunked_prefill_logits_match_reference(chunk, decode_attention):
+    """Prefill through the batch-1 cache: a chunk that ends at or under
+    ``index_topk`` is the dense program, every later one scores, selects
+    and attends the chosen rows (``flash``: the kernels under interpret;
+    ``dense``: the mask)."""
+    toks = jax.random.randint(jax.random.key(2), (1, 72), 0, VOCAB)
+    model = TransformerLM(_cfg(), decode=True,
+                          decode_attention=decode_attention)
+    cache, got = _blank_cache(model, 1), []
+    for lo in range(0, 72, chunk):
+        piece = toks[:, lo: lo + chunk]
+        logits, mut = model.apply(
+            {"params": _params(), "cache": cache}, piece,
+            positions=jnp.arange(lo, lo + piece.shape[1])[None, :],
+            mutable=["cache"])
+        cache = mut["cache"]
+        got.append(logits[0])
+    want = ref.Forward(DIMS).logits(_params(), toks[0])
+    np.testing.assert_allclose(np.concatenate(got), want, atol=LOGIT_TOL,
+                               rtol=0)
+
+
+def test_scalar_index_rollout_step_matches_reference():
+    """A batch-1 rollout's one-token steps after a prefill (the dense
+    scalar-index cache): the chosen rows by a mask."""
+    toks = jax.random.randint(jax.random.key(4), (1, 40), 0, VOCAB)
+    model = TransformerLM(_cfg(), decode=True)
+    cache, got = _blank_cache(model, 1), []
+    for lo, hi in [(0, 32)] + [(i, i + 1) for i in range(32, 40)]:
+        logits, mut = model.apply(
+            {"params": _params(), "cache": cache}, toks[:, lo:hi],
+            positions=jnp.arange(lo, hi)[None, :], mutable=["cache"])
+        cache = mut["cache"]
+        got.append(logits[0])
+    want = ref.Forward(DIMS).logits(_params(), toks[0])
+    np.testing.assert_allclose(np.concatenate(got), want, atol=LOGIT_TOL,
+                               rtol=0)
+
+
+REQUESTS = [(61, 9), (5, 14), (20, 6), (33, 11), (17, 10)]
+
+
+def _serve(cfg, params, decode_attention, watch=None):
+    loop = ServeLoop(cfg, params, num_slots=3, cache_layout="paged",
+                     kv_block_size=16, kv_num_blocks=32, prefill_chunk=16,
+                     steps_per_sync=4, decode_attention=decode_attention)
+    rng = np.random.default_rng(0)
+    reqs = [Request(rng.integers(0, VOCAB, n).astype(np.int32), m,
+                    rid=f"r{i}") for i, (n, m) in enumerate(REQUESTS)]
+    done = []
+
+    def source():
+        if watch is not None:
+            watch(loop)
+        out, reqs[:] = reqs[:2], reqs[2:]
+        return out if out or len(done) < len(REQUESTS) else None
+
+    loop.run(source=source, sink=done.append)
+    assert loop.pool.used_blocks == 0
+    loop.pool.check()
+    return done
+
+
+def _worst_gap(done, params, dims=DIMS):
+    fw = ref.Forward(dims)
+    worst = 0.0
+    for c in done:
+        assert c.reason == "length"
+        served = np.asarray(c.tokens)
+        seq = np.concatenate([np.asarray(c.prompt), served[:-1]])
+        logits = np.asarray(fw.logits(params, jnp.asarray(seq),
+                                      len(c.prompt) - 1))
+        worst = max(worst, float(
+            (logits.max(-1) - logits[np.arange(len(served)), served]).max()))
+    return worst
+
+
+@pytest.mark.parametrize("decode_attention", ["dense", "flash"])
+def test_serve_loop_matches_reference(decode_attention):
+    """ServeLoop end to end: chunked prefill with chosen rows, the paged
+    cache of three leaves, and the decode step's scores -> selection ->
+    attention over the chosen rows (``flash``: ``paged_index_scores`` and
+    ``sparse_gqa_attend`` under interpret), lanes under and over
+    ``index_topk`` in one step.  The served token is the reference's
+    arg-max at every position."""
+    done = _serve(_cfg(), _params(), decode_attention)
+    assert len(done) == len(REQUESTS)
+    assert _worst_gap(done, _params()) <= LOGIT_TOL
+
+
+def test_serve_loop_that_skips_the_selection_fails_the_same_tolerance():
+    """The control: a program that attends every row (the same weights,
+    ``index_topk`` beyond every context) is not what the reference
+    computes."""
+    done = _serve(_cfg(topk=SEQ + 8), _params(), "dense")
+    assert _worst_gap(done, _params()) > 50 * LOGIT_TOL
+
+
+@pytest.mark.parametrize("decode_attention", ["dense", "flash"])
+def test_context_at_or_under_topk_gives_the_plain_models_tokens(
+        decode_attention):
+    """With ``index_topk`` no context reaches, every row is chosen: the
+    served tokens are those of the same model WITHOUT an indexer, and a
+    one-shot forward of ``index_topk`` tokens gives its logits."""
+    mine = _serve(_cfg(topk=SEQ + 8), _params(), decode_attention)
+    theirs = {c.rid: c.tokens for c in _serve(
+        _plain_cfg(), _plain_params(), decode_attention)}
+    assert len(mine) == len(REQUESTS) and all(
+        np.array_equal(c.tokens, theirs[c.rid]) for c in mine)
+    toks = jax.random.randint(jax.random.key(3), (1, TOPK), 0, VOCAB)
+    np.testing.assert_allclose(
+        TransformerLM(_cfg()).apply({"params": _params()}, toks),
+        TransformerLM(_plain_cfg()).apply({"params": _plain_params()},
+                                          toks), atol=1e-6, rtol=0)
+
+
+# -- (b) the routines against jax.numpy ---------------------------------------
+
+def _scores(q, w, keys, seen):
+    sc = jnp.einsum("thd,trd->thr", q, keys)
+    sc = (jnp.maximum(sc, 0) * w[:, :, None]).sum(1)
+    return jnp.where(jnp.arange(keys.shape[1])[None] < seen[:, None], sc,
+                     -jnp.inf)
+
+
+@pytest.mark.parametrize("lens", [(0, 37, 300), (320, 1, 129)],
+                         ids=["empty_short_long", "full_one_tile_edge"])
+def test_index_scores_of_a_decode_step(lens):
+    rng = np.random.default_rng(0)
+    b, h, d, bs, m, n = 3, 8, 16, 8, 40, 64
+    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(b, h)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(n, bs, d)), jnp.float32)
+    table = jnp.asarray(rng.integers(0, n, (b, m)), jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    got = paged_index_scores(q, w, pool, table, lens, interpret=True)
+    want = _scores(q, w, pool[table].reshape(b, m * bs, d), lens)
+    assert np.array_equal(np.isfinite(got), np.isfinite(want))
+    np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
+                               np.where(np.isfinite(want), want, 0),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("queries,idx", [(16, 40), (8, 0), (12, 100)])
+def test_index_scores_of_a_prefill_chunk(queries, idx):
+    """Queries by the grid row over ONE table row, each with its own
+    limit."""
+    rng = np.random.default_rng(1)
+    h, d, bs, rows = 8, 16, 8, 128
+    q = jnp.asarray(rng.normal(size=(queries, h, d)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(queries, h)), jnp.float32)
+    keys = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32)
+    tq = index_queries_per_row(queries, h, rows)
+    seen = idx + (jnp.arange(queries // tq) + 1) * tq
+    got = paged_index_scores(q, w, keys.reshape(rows // bs, bs, d),
+                             jnp.arange(rows // bs)[None], seen,
+                             interpret=True)
+    want = _scores(q, w, jnp.broadcast_to(keys, (queries, rows, d)),
+                   idx + jnp.arange(queries) + 1)
+    assert tq > 1 and np.array_equal(np.isfinite(got), np.isfinite(want))
+    np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
+                               np.where(np.isfinite(want), want, 0),
+                               atol=1e-5)
+
+
+def test_queries_a_grid_row_follow_the_output_block():
+    # the cell's chunk: 2048 queries of 16 heads over 32768 rows
+    assert index_queries_per_row(2048, 16, 32768) == 16
+    assert index_queries_per_row(2048, 64, 32768) == 8
+    assert index_queries_per_row(2048, 16, 1 << 20) == 1
+    assert index_queries_per_row(7, 16, 128) == 1
+
+
+@pytest.mark.parametrize("h_kv,d", [(2, 128), (4, 128), (2, 16)],
+                         ids=["kv2_d128", "kv4_d128", "kv2_d16_paired"])
+def test_sparse_attend_over_given_rows(h_kv, d):
+    rng = np.random.default_rng(2)
+    t, g, k, n, cap = 5, 2, 16, 200, 4
+    h, flat = h_kv * g, h_kv * d
+    q = jnp.asarray(rng.normal(size=(t, h, d)), jnp.float32)
+    k_src = jnp.asarray(rng.normal(size=(n, flat)), jnp.float32)
+    v_src = jnp.asarray(rng.normal(size=(n, flat)), jnp.float32)
+    side_k = jnp.asarray(rng.normal(size=(t, cap, flat)), jnp.float32)
+    side_v = jnp.asarray(rng.normal(size=(t, cap, flat)), jnp.float32)
+    count = np.asarray([1, 5, 16, 9, 16], np.int32)
+    # a query's first ``count`` ids ascend, so its staged rows (ids from
+    # n on) come last among them; what follows is ignored
+    ids = rng.integers(0, n + cap, (t, k))
+    for i, c in enumerate(count):
+        ids[i, :c] = np.sort(rng.choice(
+            np.arange(n - 6, n + cap), size=c, replace=False)
+            if c <= 10 else rng.choice(n + cap, size=c, replace=False))
+    ids, count = jnp.asarray(ids, jnp.int32), jnp.asarray(count)
+    got = sparse_gqa_attend(q, k_src, v_src, ids, count,
+                            packed_kv_heads=h_kv, side_k=side_k,
+                            side_v=side_v, interpret=True)
+
+    def rows(src, side):
+        out = jnp.where(
+            (ids >= n)[..., None],
+            jnp.take_along_axis(
+                side, jnp.clip(ids - n, 0, cap - 1)[..., None], 1),
+            src[jnp.minimum(ids, n - 1)])
+        return jnp.repeat(out.reshape(t, k, h_kv, d), g, axis=2)
+
+    logits = jnp.einsum("thd,tkhd->thk", q, rows(k_src, side_k)) * d ** -0.5
+    logits = jnp.where(jnp.arange(k)[None, None] < count[:, None, None],
+                       logits, -jnp.inf)
+    want = jnp.einsum("thk,tkhd->thd", jax.nn.softmax(logits, -1),
+                      rows(v_src, side_v))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("offset,rows", [(0, 64), (32, 64), (96, 128)])
+def test_flash_pass_over_a_mask(offset, rows):
+    """``flash_chosen_rows``: a chunk's queries at an offset over the rows
+    a mask names, against a masked softmax; rows after a query are not
+    attended whatever the mask says."""
+    rng = np.random.default_rng(5)
+    s, h, h_kv, d = 32, 4, 2, 16
+    q = jnp.asarray(rng.normal(size=(1, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, rows, h_kv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, rows, h_kv, d)), jnp.float32)
+    mask = rng.random((s, rows)) < 0.3
+    mask[np.arange(s), np.minimum(offset + np.arange(s), rows - 1)] = True
+    got = flash_chosen_rows(q, k, v, jnp.asarray(mask, jnp.int8), offset,
+                            block_q=16, block_k=16, interpret=True)
+    seen = mask & (np.arange(rows)[None] <= offset + np.arange(s)[:, None])
+    kk, vv = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * d ** -0.5
+    logits = jnp.where(seen[None, None], logits, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1), vv)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_equal_scores_go_to_the_lower_position():
+    sc = jnp.asarray([[1., 3., 3., -jnp.inf, 2., 3., 3., 0.],
+                      [5., 5., 5., 5., 5., 5., 5., 5.],
+                      [-jnp.inf, 1., -jnp.inf, 1., 1., -jnp.inf, 0., 0.]])
+    got = np.asarray(index_select(sc, 4))
+    assert got.tolist() == [[1, 2, 5, 6], [0, 1, 2, 3], [1, 3, 4, 6]]
+    assert got.tolist() == np.sort(jax.lax.top_k(sc, 4)[1], axis=1).tolist()
+    mask = np.asarray(index_select_mask(sc, 4))
+    assert [np.flatnonzero(m).tolist() for m in mask] == got.tolist()
+    # fewer than k scores above -inf: they come first, and alone in a mask
+    few = jnp.asarray([[-jnp.inf, 2., -jnp.inf, 1., -jnp.inf, -jnp.inf,
+                        -jnp.inf, -jnp.inf]])
+    assert np.asarray(index_select(few, 4))[0, :2].tolist() == [1, 3]
+    assert np.flatnonzero(index_select_mask(few, 4)[0]).tolist() == [1, 3]
+
+
+@pytest.mark.parametrize("shape", [(3, 300, 16), (256, 1000, 64),
+                                   (5, 40, 16), (4, 70, 64),
+                                   (6, 8192, 32)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_threshold_selection_is_top_k(shape):
+    """Scores in steps of a quarter (ties everywhere), rows of every
+    length, one row all equal: the same set as ``jax.lax.top_k``, in
+    ascending order; the mask names the same set, and passes that follow
+    the rows (a prefill chunk's) give the same mask."""
+    t, r, k = shape
+    rng = np.random.default_rng(3)
+    sc = np.round(rng.normal(size=(t, r)).astype(np.float32) * 4) / 4
+    live = rng.integers(1, r + 1, t)
+    sc = np.where(np.arange(r)[None] < live[:, None], sc, -np.inf)
+    sc[2] = np.where(np.isfinite(sc[2]), 0.5, -np.inf)
+    k = min(k, r)
+    want = np.asarray(jax.lax.top_k(jnp.asarray(sc), k)[1])
+    got = np.asarray(index_select(jnp.asarray(sc), k))
+    mask = np.asarray(index_select_mask(jnp.asarray(sc), k))
+    followed = np.asarray(jax.jit(
+        lambda s, n: index_select_mask(s, k, rows=n))(
+        jnp.asarray(sc), jnp.int32(live.max())))
+    assert np.array_equal(mask, followed)
+    for i in range(t):
+        n = min(int(np.isfinite(sc[i]).sum()), k)
+        assert sorted(want[i, :n].tolist()) == got[i, :n].tolist()
+        assert np.flatnonzero(mask[i]).tolist() == got[i, :n].tolist()
+
+
+@pytest.mark.parametrize("tied_rows", [0, 3, 8, 9],
+                         ids=lambda n: f"{n}-rows-tie")
+def test_a_chunks_few_tied_rows_are_cut_apart(tied_rows):
+    """A chunk's rows (64 and more) of float scores of which a few tie at
+    the k-th value, as a deep chunk's do: up to ``_TIE_ROWS`` of them are
+    cut in a search of their own, more send every row through it; the
+    mask is ``jax.lax.top_k``'s set either way, with passes that follow
+    the rows too."""
+    t, r, k = 96, 512, 32
+    rng = np.random.default_rng(tied_rows)
+    sc = rng.normal(size=(t, r)).astype(np.float32)
+    live = rng.integers(k + 8, r + 1, t)
+    sc = np.where(np.arange(r)[None] < live[:, None], sc, -np.inf)
+    for i in rng.choice(t, tied_rows, replace=False):
+        # the k-th value three more times, on both sides of its column
+        order = np.argsort(-sc[i], kind="stable")
+        kth = order[k - 1]
+        spare = [c for c in order[k + 4:k + 40] if c != kth][:3]
+        sc[i, spare] = sc[i, kth]
+    want = np.asarray(jax.lax.top_k(jnp.asarray(sc), k)[1])
+    mask = np.asarray(index_select_mask(jnp.asarray(sc), k))
+    followed = np.asarray(jax.jit(
+        lambda s, n: index_select_mask(s, k, rows=n))(
+        jnp.asarray(sc), jnp.int32(live.max())))
+    assert np.array_equal(mask, followed)
+    for i in range(t):
+        assert np.flatnonzero(mask[i]).tolist() == sorted(want[i].tolist())
+
+
+# -- (c) a block of three leaves of different width ----------------------------
+
+def test_three_leaves_ride_admission_side_buffer_and_release():
+    """The rows a live lane holds in ``paged_ikey`` (128 wide: 16 numbers
+    and zeros), ``paged_key`` and ``paged_value`` (32 wide), read back through its page
+    table after its prompt was inserted and segments merged their staged
+    rows, are the rows a one-chunk prefill of the same tokens computes;
+    ``check()`` holds at every poll, and every block comes back."""
+    snaps = []
+
+    def watch(loop):
+        loop.pool.check()
+        # copies: the next segment donates the cache
+        snaps.append((
+            {"block1": jax.tree.map(jnp.copy, loop.cache["block1"])},
+            loop.pool.table.copy()))
+
+    done = _serve(_cfg(), _params(), "flash", watch)
+    first = next(c for c in done if c.rid == "r0")
+    seq = np.concatenate([first.prompt, first.tokens])
+    model = TransformerLM(_cfg(), decode=True)
+    _, mut = model.apply(
+        {"params": _params(), "cache": _blank_cache(model, 1)},
+        jnp.asarray(seq[None]), mutable=["cache"])
+    want = mut["cache"]["block1"]["attn"]
+    checked = 0
+    for cache, table in snaps:
+        node = cache["block1"]["attn"]
+        assert _kv_leaves(node, "paged") == ["ikey", "key", "value"]
+        assert _kv_leaves(node, "side") == ["ikey", "key", "value"]
+        assert "side_index" in node
+        held = int(_index_leaves(cache)[0][0])
+        if held <= len(first.prompt):
+            continue
+        for leaf, width in (("ikey", 128), ("key", 32),
+                            ("value", 32)):
+            pool = np.asarray(node[f"paged_{leaf}"])
+            assert pool.shape[-1] == width
+            rows = pool[table[0]].reshape(-1, width)[:held]
+            if not np.allclose(rows[:8, :4], np.asarray(
+                    want[f"cached_{leaf}"])[0, :8, :4], atol=2e-5):
+                break           # lane 0 holds another request by now
+            np.testing.assert_allclose(
+                rows, np.asarray(want[f"cached_{leaf}"])[0, :held],
+                atol=2e-5)
+            if leaf == "ikey":
+                assert not rows[:, DIMS.index_dim:].any()
+                assert rows[:, :DIMS.index_dim].any()
+        else:
+            checked += 1
+    assert checked
+
+
+def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer():
+    """The guide's share test at toy size: the expert layer with all 8
+    experts against the sum of its 4 shares of 2 held experts each (the
+    router over all 8 in every share)."""
+    x = jax.random.normal(jax.random.key(7), (12, EMBED))
+    whole = MoEMLP(d_model=EMBED, d_ff=DIMS.expert_ff, moe=_moe(held=None),
+                   dtype=jnp.float32)
+    full = whole.init(jax.random.key(8), x)["params"]
+    want, _ = whole.apply({"params": full}, x)
+    total = 0.0
+    for first in range(0, 8, 2):
+        part = MoEMLP(d_model=EMBED, d_ff=DIMS.expert_ff,
+                      moe=_moe(held=(first, 2)), dtype=jnp.float32)
+        mine = {**full, **{k: full[k][first: first + 2]
+                           for k in ("w_gate", "w_up", "w_down")}}
+        total = total + part.apply({"params": mine}, x)[0]
+    np.testing.assert_allclose(total, want, atol=1e-5)
+
+
+# -- (d) what it refuses, and what it counts ------------------------------------
+
+def test_refusals_at_construction():
+    kw = dict(num_slots=2, kv_block_size=16)
+    with pytest.raises(ValueError, match="indexer.*one token a lane"):
+        ServeLoop(_cfg(), _params(), cache_layout="paged",
+                  decode_mode="speculative", draft_cfg=_cfg(),
+                  draft_params=_params(), **kw)
+    with pytest.raises(ValueError, match="indexer.*cache_layout='paged'"):
+        ServeLoop(_cfg(), _params(), cache_layout="dense", **kw)
+    with pytest.raises(ValueError, match="an indexer's key beside K and V"):
+        ServeLoop(_cfg(), _params(), cache_layout="paged",
+                  preempt="migrate", **kw)
+    with pytest.raises(ValueError, match="an indexer's key beside K and V"):
+        ServeLoop(_cfg(), _params(), cache_layout="paged", role="prefill",
+                  **kw)
+    with pytest.raises(ValueError, match="together"):
+        _cfg(index_heads=None)
+    with pytest.raises(ValueError, match="multiples of 8"):
+        _cfg(topk=12)
+    with pytest.raises(ValueError, match="no sliding window"):
+        _cfg(attention_window=8)
+    with pytest.raises(ValueError, match="causal attention only"):
+        TransformerLM(_cfg()).apply({"params": _params()},
+                                    jnp.zeros((1, 8), jnp.int32),
+                                    causal=False)
+
+
+def test_prefix_sharing_and_the_host_tier_are_off(monkeypatch):
+    monkeypatch.setenv("TPUDIST_KV_HOST_TIER_BYTES", str(1 << 20))
+    loop = ServeLoop(_cfg(), _params(), num_slots=2, cache_layout="paged",
+                     kv_block_size=16, prefix_sharing=True)
+    assert loop._prefix_cache is None and loop._tier is None
+    plain = ServeLoop(_plain_cfg(), _plain_params(), num_slots=2,
+                      cache_layout="paged", kv_block_size=16,
+                      prefix_sharing=True)
+    assert plain._prefix_cache is not None and plain._tier is not None
+
+
+def test_segments_and_chunks_say_what_was_scored_and_chosen():
+    names = ("index_rows_selected", "index_rows_scored", "decode_rows_live")
+    before = [obs.counter(f"serve/{n}").value() for n in names]
+    obs.tracer.clear()
+    _serve(_cfg(), _params(), "dense")
+    chosen, scored, live = (obs.counter(f"serve/{n}").value() - b
+                            for n, b in zip(names, before))
+    assert 0 < chosen < scored == live
+    drains = [e["args"] for e in obs.tracer.events()
+              if e["name"] == "serve/segment_drain"]
+    assert drains and all(
+        a["rows_selected"] <= min(a["rows_scored"], 3 * TOPK)
+        and a["rows_scored"] == a["rows_live"] for a in drains)
+    assert any(a["rows_selected"] < a["rows_scored"] for a in drains)
+    chunks = [e["args"] for e in obs.tracer.events()
+              if e["name"] == "serve/prefill_chunk"]
+    # a chunk is sparse once it ends beyond row index_topk
+    assert chunks and all(
+        a["sparse"] == (a["off"] + a["width"] > TOPK) for a in chunks)
+    assert {a["sparse"] for a in chunks} == {True, False}
+
+
+def test_a_model_without_an_indexer_says_neither():
+    obs.tracer.clear()
+    _serve(_plain_cfg(), _plain_params(), "dense")
+    assert all("rows_selected" not in e["args"]
+               and "rows_scored" not in e["args"]
+               and "sparse" not in e["args"]
+               for e in obs.tracer.events()
+               if e["name"] in ("serve/segment_drain",
+                                "serve/prefill_chunk"))

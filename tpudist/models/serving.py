@@ -231,8 +231,12 @@ def _kv_leaves(node: dict, prefix: str) -> list[str]:
     cache leaves ``<prefix>_<suffix>`` (``prefix`` one of ``cached``, the
     dense buffers, ``paged``, the block pools, ``side``, the segment's
     staging buffers).  ``["key", "value"]`` for MHA/GQA/MQA, ``["latent"]``
-    for latent attention: every place that moves cache rows iterates over
-    these and is indifferent to their number and width."""
+    for latent attention, ``["ikey", "key", "value"]`` for grouped-query
+    attention with an indexer (its index key a token beside K and V): every
+    place that moves cache rows iterates over these and is indifferent to
+    their number and width.  No leaf's suffix may be ``index``: the
+    staging buffer's cursor is the leaf ``side_index``, which shares the
+    prefix and is left out here BY NAME (hence ``side_ikey``)."""
     return sorted(k[len(prefix) + 1:] for k in node
                   if k.startswith(prefix + "_") and k != "side_index")
 
@@ -542,6 +546,21 @@ class ServeLoop:
                 f"decode_mode must be 'plain' or 'speculative', got "
                 f"{decode_mode!r}")
         self.decode_mode = decode_mode
+        # rows a query attends at most where an indexer chooses them
+        # (None: every row under its length)
+        self._index_topk = cfg.index_topk
+        if self._index_topk is not None:
+            if decode_mode == "speculative":
+                raise ValueError(
+                    "a model with an indexer decodes one token a lane a "
+                    "step (its scores, selection and attention over the "
+                    "chosen rows have no verify-chunk form): "
+                    "decode_mode='plain'")
+            if cache_layout != "paged":
+                raise ValueError(
+                    "a model with an indexer serves through "
+                    "cache_layout='paged': the dense layout's per-row "
+                    "decode has no index scores or selection")
         if decode_mode == "speculative":
             if draft_cfg is None or draft_params is None:
                 raise ValueError(
@@ -626,11 +645,14 @@ class ServeLoop:
         # (a cache with a window group shares nothing: a hit's boundary
         # would have to bring the window group's blocks before it, which
         # the finished request released long ago; off, as with the dense
-        # layout)
+        # layout.  A model with an indexer shares nothing either: a hit
+        # starts the suffix's first chunk at a block boundary inside a
+        # chunk, and no test holds a chunk's selection there, nor three
+        # leaves through the gathered prefix)
         self._prefix_cache = (
             PrefixCache(self.pool)
             if prefix_sharing and self.chunked and self.pool is not None
-            and wg is None else None)
+            and wg is None and self._index_topk is None else None)
         # the weights version the loop's CURRENT params correspond to;
         # stamps tier entries and pull-mode exports so KV computed
         # under one version can never be adopted under another (the
@@ -702,7 +724,8 @@ class ServeLoop:
             if role != "both" or preempt == "migrate":
                 raise ValueError(
                     "KV handoff and migration payloads carry a key/value "
-                    "pair a layer; a cache of other leaves serves with "
+                    "pair a layer; a cache of other leaves (a latent row; "
+                    "an indexer's key beside K and V) serves with "
                     "role='both' and preempt='degrade'")
             if self._tier is not None:
                 self._tier = None
@@ -716,11 +739,15 @@ class ServeLoop:
         if self.pool is not None and decode_attention == "flash":
             nodes = self._paged_nodes(self.cache)
             leaves = _kv_leaves(nodes[0], "paged")
-            pool0 = nodes[0]["paged_" + leaves[0]]
-            h_kv = cfg.kv_heads if leaves == ["key", "value"] else 1
+            # the pools the attention kernel walks: a K/V pair (an
+            # indexer's keys have a walk of their own), or the latent rows
+            pair = {"key", "value"} <= set(leaves)
+            walked = ["key", "value"] if pair else leaves
+            pool0 = nodes[0]["paged_" + walked[0]]
+            h_kv = cfg.kv_heads if pair else 1
             self._grid_rows = paged_grid_rows(
                 num_slots, h_kv, pool0.shape[2] // h_kv, self.kv_block_size,
-                self.pool.max_blocks_per_slot, pools=len(leaves),
+                self.pool.max_blocks_per_slot, pools=len(walked),
                 itemsize=pool0.dtype.itemsize)
             self._attn_layers = len(nodes)
         # expert layers (cfg.moe): the segment sums, step by step, the
@@ -828,6 +855,13 @@ class ServeLoop:
                                               unit="rows")
         self._obs_rows_live = obs.counter("serve/decode_rows_live",
                                           unit="rows")
+        # a model with an indexer: the index keys a decode step's scores
+        # read (the live lanes' lengths) and the rows its attention reads
+        # (min(length, index_topk) a lane), a layer, ticked the same way
+        self._obs_rows_scored = obs.counter("serve/index_rows_scored",
+                                            unit="rows")
+        self._obs_rows_selected = obs.counter("serve/index_rows_selected",
+                                              unit="rows")
         # the same two of the WINDOW layers' calls (a layer): walk_rows
         # with the window, and min(length, window)
         self._obs_rows_window = obs.counter(
@@ -2796,9 +2830,13 @@ class ServeLoop:
                     # the one row of logits the finish reads (any row
                     # of a chunk that is not the prompt's last)
                     row = min(max(pf["L"] - 1 - off, 0), w - 1)
+                    # with an indexer: whether the chunk attends chosen
+                    # rows (CausalSelfAttention._prefill_attend's rule)
+                    chosen = ({} if self._index_topk is None else
+                              {"sparse": off + w > self._index_topk})
                     with obs.span("serve/prefill_chunk", slot=slot,
                                   rid=_span_rid(st["req"].rid), off=off,
-                                  width=w, seq=seq):
+                                  width=w, seq=seq, **chosen):
                         pf["cache1"], pf["logits"] = self._prefill_chunk(
                             self.params, pf["cache1"], toks,
                             np.int32(off), np.int32(row), chunk=w)
@@ -2946,7 +2984,7 @@ class ServeLoop:
                            if st is not None and not st.get("zombie"))
                 k = (self._spec_k(live)
                      if self.decode_mode == "speculative" else 0)
-                pages = rows = rows_live = 0
+                pages = rows = rows_live = rows_selected = 0
                 windowed = None
                 if self.pool is not None:
                     wg = self.pool.window_group
@@ -2978,6 +3016,8 @@ class ServeLoop:
                             held = self.pool.covered_rows(slot)
                             rows += walk_rows(held, block, per_tile)
                             rows_live += held
+                            if self._index_topk is not None:
+                                rows_selected += min(held, self._index_topk)
                             if wg is not None:
                                 rows_w += walk_rows(held, block, per_tile,
                                                     wg.window)
@@ -3030,7 +3070,8 @@ class ServeLoop:
             except AttributeError:  # non-jax array (test doubles)
                 pass
             inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
-                             (pages, rows, rows_live, windowed)))
+                             (pages, rows, rows_live, rows_selected,
+                              windowed)))
             seq += 1
             self._obs_depth.set(len(inflight))
             # fault harness: a configured kill-after-K-segments SIGKILLs
@@ -3068,9 +3109,13 @@ class ServeLoop:
             ``rows_window_live`` (the same two of a WINDOW layer's call:
             ``walk_rows`` with the window, ``min(length, window)``; ``rows``
             and ``rows_live`` stay the full layers') and ``blocks_released``
-            (what the window group let go of when the segment was planned)."""
+            (what the window group let go of when the segment was planned).
+            A model with an indexer adds ``rows_scored`` (the index keys its
+            scores read, a layer: the live lanes' lengths) and
+            ``rows_selected`` (the rows its attention reads:
+            ``min(length, index_topk)`` a lane)."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
-             t_disp, (pages, rows, rows_live,
+             t_disp, (pages, rows, rows_live, rows_selected,
                       windowed)) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
@@ -3179,6 +3224,11 @@ class ServeLoop:
                     routed = {"rows_window": rows_w,
                               "rows_window_live": rows_w_live,
                               "blocks_released": released}
+                if self._index_topk is not None:
+                    self._obs_rows_scored.inc(rows_live * steps_run)
+                    self._obs_rows_selected.inc(rows_selected * steps_run)
+                    routed.update(rows_scored=rows_live,
+                                  rows_selected=rows_selected)
                 if self._expert_blocks:
                     n_cells = len(self._expert_blocks) * self._held
                     counts = emits[self.B:].reshape(-1)[:n_cells]

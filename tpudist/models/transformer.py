@@ -281,6 +281,49 @@ class TransformerConfig:
     # ``first_k_dense`` layers keep the dense MLP
     moe: Any = None
     first_k_dense: int = 0
+    # a per-head RMSNorm (one scale of head_dim, shared by the heads) on
+    # the queries and the keys before the rotation
+    qk_norm: bool = False
+    # a learned indexer (sparse attention over grouped-query K/V):
+    # ``index_heads`` index queries of ``index_head_dim`` a token, ONE index
+    # key a token (cached beside K and V), a float32 weight a head; a query
+    # attends only the ``index_topk`` rows of the highest ``sum_h w_h
+    # relu(q_h . k)``.  All three None: no indexer, the program as it was.
+    index_heads: int | None = None
+    index_head_dim: int | None = None
+    index_topk: int | None = None
+
+    def __post_init__(self):
+        sizes = (self.index_heads, self.index_head_dim, self.index_topk)
+        if any(v is None for v in sizes) and any(v is not None
+                                                 for v in sizes):
+            raise ValueError(
+                "an indexer states index_heads, index_head_dim and "
+                f"index_topk together; got {sizes}")
+        if self.index_topk is not None:
+            if (self.index_topk % 8 or self.index_heads % 8
+                    or self.index_head_dim % 2):
+                raise ValueError(
+                    "index_topk and index_heads are multiples of 8 (the "
+                    "chosen rows are attended as pages, the heads fill "
+                    "sublanes) and an index head rotates in pairs; got "
+                    f"{sizes}")
+            if (self.mla is not None or self.positions != "rotary"
+                    or any(w is not None for w in self.windows)):
+                raise ValueError(
+                    "an indexer chooses rows for grouped-query attention "
+                    "under rotary positions, every layer over its whole "
+                    "context: no latent attention, no sliding window")
+
+    @property
+    def index_cache_width(self) -> int:
+        """A cached index key: ``index_head_dim`` numbers and zeros up to
+        a multiple of 128 lanes.  On the TPU a 64-wide bf16 row occupies
+        128 lanes of tiled memory anyway (and the chip's compiler refuses
+        a page copy narrower than a tile); stated so, the row's copies and
+        the contraction over it are lane-aligned, and the zeros add nothing
+        to a score."""
+        return -(-self.index_head_dim // 128) * 128
 
     @property
     def ffn_dim(self) -> int:
@@ -448,7 +491,50 @@ def _flash_prefill(q, k_all, v_all, idx, *, window=None, scale=None,
     return out[:, :s]
 
 
+def _index_scores(q_i, w_i, ikeys):
+    """A learned indexer's scores by plain jax.numpy: ``q_i [B, S, Hi, Di]``
+    and ``w_i [B, S, Hi]`` float32 against ``ikeys [B, R, Di]`` -> ``sum_h
+    w_h relu(q_h . k)``, ``[B, S, R]`` float32."""
+    sc = jnp.einsum("bshd,brd->bshr", q_i, ikeys.astype(q_i.dtype),
+                    preferred_element_type=jnp.float32)
+    return jnp.einsum("bshr,bsh->bsr", jnp.maximum(sc, 0.0),
+                      w_i.astype(jnp.float32))
+
+
+def _index_choice(q_i, w_i, ikeys, valid, k: int):
+    """The rows a learned indexer chooses, as a mask, by plain jax.numpy:
+    ``q_i [B, S, Hi, Di]`` and ``w_i [B, S, Hi]`` float32 against ``ikeys
+    [B, R, Di]``; ``valid [B, S, R]`` says which rows a query sees.  True
+    for its ``min(k, rows seen)`` rows of the highest ``sum_h w_h relu(q_h
+    . k)`` (float32; equal scores to the lower row).  Holds ``[B, S, Hi,
+    R]`` float32: the one-shot forward, the dense fallbacks and the tests'
+    comparison, not the serving path."""
+    b, s, r = valid.shape
+    sc = jnp.where(valid, _index_scores(q_i, w_i, ikeys), -jnp.inf)
+    ids = jax.lax.top_k(sc, min(k, r))[1]
+    chosen = jnp.zeros((b, s, r), bool).at[
+        jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+        ids].set(True)
+    return chosen & valid
+
+
 class CausalSelfAttention(nn.Module):
+    """Multi-head / grouped-query attention with the serve loop's caches.
+
+    With an INDEXER (``cfg.index_topk``) a query attends only the
+    ``index_topk`` rows its index scores put first.  A token then keeps a
+    third row, its index key (``index_head_dim`` numbers after its
+    LayerNorm and its rotation, zeros up to ``cfg.index_cache_width``),
+    under ``cached_ikey`` / ``paged_ikey`` /
+    ``side_ikey`` beside the K and V leaves.  Both cached paths then run
+    scores -> selection -> attention over the chosen rows
+    (``ops.flash_decode.paged_index_scores``, ``index_select_mask`` /
+    ``index_select``, ``sparse_gqa_attend``; a prefill chunk through
+    ``ops.flash_attention.flash_chosen_rows``): the decode step when some
+    lane holds more than ``index_topk`` rows, a prefill chunk when it ends
+    beyond row ``index_topk``; below that every row is chosen and the
+    program is the one without an indexer."""
+
     cfg: TransformerConfig
     attention_fn: AttentionFn = sdpa
     decode: bool = False
@@ -512,6 +598,11 @@ class CausalSelfAttention(nn.Module):
                           dtype=cfg.compute_dtype, name="kv")(x)
             kv = kv.reshape(b, s, 2, cfg.kv_heads, cfg.head_dim)
             k, v = kv[:, :, 0], kv[:, :, 1]
+        if cfg.qk_norm:
+            q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                           name="k_norm")(k)
         if cfg.positions == "rotary":
             # keys are cached AFTER the rotation, at their own positions
             if positions is None:
@@ -536,8 +627,41 @@ class CausalSelfAttention(nn.Module):
                 f"cfg.attention_window={self.window}; set the "
                 "window on TransformerConfig (the single source of "
                 "truth) or make the two agree")
+        index = None
+        if cfg.index_topk is not None:
+            if not causal:
+                raise ValueError("an indexer chooses among the rows before "
+                                 "a query: causal attention only")
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            # index queries and the one index key a token, every feature
+            # rotated at the token's position by plain frequencies
+            q_i = apply_rope(
+                nn.Dense(hi * di, use_bias=False, dtype=cfg.compute_dtype,
+                         name="idx_q")(x).reshape(b, s, hi, di),
+                positions, cfg, None)
+            k_i = apply_rope(
+                nn.LayerNorm(epsilon=1e-6, dtype=cfg.compute_dtype,
+                             name="idx_k_norm")(
+                    nn.Dense(di, use_bias=False, dtype=cfg.compute_dtype,
+                             name="idx_k")(x)),
+                positions, cfg, None)
+            w_i = nn.Dense(hi, use_bias=False, dtype=jnp.float32,
+                           param_dtype=jnp.float32, name="idx_w")(
+                x) * (hi ** -0.5 * di ** -0.5)
+            index = (q_i, w_i, k_i)
         if self.decode:
-            out = self._cached_attend(q, k, v)
+            if index is not None:
+                # the cached key's width (index_cache_width): zero columns
+                pad = [(0, 0)] * 3 + [(0, cfg.index_cache_width - di)]
+                index = (jnp.pad(q_i, pad), w_i, jnp.pad(k_i, pad[1:]))
+            out = self._cached_attend(q, k, v, index)
+        elif index is not None and s > cfg.index_topk:
+            # the one-shot forward: the chosen rows as a mask
+            mask = _index_choice(
+                q_i, w_i, k_i, jnp.broadcast_to(
+                    jnp.tril(jnp.ones((s, s), bool)), (b, s, s)),
+                cfg.index_topk)
+            out = _masked_attend(q, *repeat_kv(q, k, v), mask[:, None])
         else:
             # window passed unconditionally (None = full causal) so the
             # training path can never diverge from the decode cache mask,
@@ -549,7 +673,7 @@ class CausalSelfAttention(nn.Module):
         return nn.Dense(cfg.embed_dim, use_bias=False,
                         dtype=cfg.compute_dtype, name="proj")(out)
 
-    def _cached_attend(self, q, k, v):
+    def _cached_attend(self, q, k, v, index=None):
         """Decoding against a KV cache of ``max_seq_len`` slots (the
         standard flax ``cache`` collection pattern): fixed-shape buffers +
         ``dynamic_update_slice`` keep the whole autoregressive loop
@@ -567,7 +691,7 @@ class CausalSelfAttention(nn.Module):
             # the paged layout never materializes the dense buffers —
             # that absence IS the capacity win, so branch before the
             # cached_key/cached_value variables exist
-            return self._paged_attend(q, k, v)
+            return self._paged_attend(q, k, v, index)
         if self.cache_layout != "dense":
             raise ValueError(
                 f"cache_layout must be 'dense' or 'paged', got "
@@ -588,10 +712,19 @@ class CausalSelfAttention(nn.Module):
         cached_v = self.variable(
             "cache", "cached_value", jnp.zeros,
             (b, cfg.max_seq_len, flat), cfg.compute_dtype)
+        cached_ik = None if index is None else self.variable(
+            "cache", "cached_ikey", jnp.zeros,
+            (b, cfg.max_seq_len, cfg.index_cache_width), cfg.compute_dtype)
         idx_var = self.variable(
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
         idx = idx_var.value
         if idx.ndim == 1:
+            if index is not None:
+                raise ValueError(
+                    "a model with an indexer decodes per-row through the "
+                    "paged cache (ServeLoop with cache_layout='paged'): "
+                    "the dense layout's per-row step has no index scores "
+                    "or selection")
             # PER-ROW cache positions (vector cache_index [B]) — the
             # continuous-batching serve mode: every slot decodes at its
             # own length (tpudist.models.serving swaps the scalar index
@@ -611,12 +744,28 @@ class CausalSelfAttention(nn.Module):
             (0, idx, 0))
         cached_k.value, cached_v.value = k_all, v_all
         idx_var.value = idx + s
+        if index is not None:
+            q_i, w_i, k_i = index
+            ikeys = jax.lax.dynamic_update_slice(
+                cached_ik.value, k_i.astype(cached_ik.value.dtype),
+                (0, idx, 0))
+            cached_ik.value = ikeys
+            index = (q_i, w_i, ikeys)
 
         def view4(x):
             return x.reshape(b, cfg.max_seq_len, h_kv, d)
 
         if s > 1:
-            return self._prefill_attend(q, view4(k_all), view4(v_all), idx)
+            return self._prefill_attend(q, view4(k_all), view4(v_all), idx,
+                                        index)
+        if index is not None:
+            # a scalar-index rollout's step: the chosen rows as a mask
+            mask = _index_choice(
+                *index, jnp.broadcast_to(
+                    jnp.arange(cfg.max_seq_len) <= idx,
+                    (b, 1, cfg.max_seq_len)), cfg.index_topk)
+            k4, v4 = repeat_kv(q, view4(k_all), view4(v_all))
+            return _masked_attend(q, k4, v4, mask[:, None])
         if self.decode_attention == "flash":
             from tpudist.ops.flash_decode import flash_decode
 
@@ -822,7 +971,7 @@ class CausalSelfAttention(nn.Module):
             side_k=side_k.value, side_v=side_v.value,
             side_len=side_idx.value, packed_kv_heads=h_kv)
 
-    def _paged_attend(self, q, k, v):
+    def _paged_attend(self, q, k, v, index=None):
         """One decode step against the PAGED cache: K/V live in a shared
         block pool (``paged_key``/``paged_value``,
         ``[kv_num_blocks, kv_block_size, Hkv*D]``) and each slot reaches
@@ -861,6 +1010,10 @@ class CausalSelfAttention(nn.Module):
         paged_v = self.variable(
             "cache", "paged_value", jnp.zeros, (nb, bs_, flat),
             cfg.compute_dtype)
+        # an indexer's keys ride in a third pool under the same table
+        paged_ik = None if index is None else self.variable(
+            "cache", "paged_ikey", jnp.zeros,
+            (nb, bs_, cfg.index_cache_width), cfg.compute_dtype)
         table = self.variable(
             "cache", "page_table", jnp.zeros, (b, m_blocks), jnp.int32)
         idx_var = self.variable(
@@ -883,6 +1036,12 @@ class CausalSelfAttention(nn.Module):
             raise NotImplementedError(
                 "sharded decode over the paged cache is not wired yet; "
                 "serve paged through the replicated path")
+        if index is not None and s != 1:
+            raise ValueError(
+                "a model with an indexer decodes one token a lane a step "
+                "over the paged cache (its scores, selection and attention "
+                f"over the chosen rows have no verify-chunk form); got "
+                f"s={s}")
         window = self.window
         if window is not None and (s != 1
                                    or self.serve_side_slots > window):
@@ -914,15 +1073,37 @@ class CausalSelfAttention(nn.Module):
             side_v.value,
             v.reshape(b, s, flat).astype(side_v.value.dtype), (0, s_at, 0))
         side_idx.value = s_base + s
+        if index is not None:
+            q_i, w_i, k_i = index
+            side_ik = self.variable(
+                "cache", "side_ikey", jnp.zeros,
+                (b, cap, cfg.index_cache_width), cfg.compute_dtype)
+            side_ik.value = jax.lax.dynamic_update_slice(
+                side_ik.value, k_i.astype(side_ik.value.dtype),
+                (0, s_at, 0))
 
         if self.decode_attention == "flash":
             from tpudist.ops.flash_decode import paged_flash_decode
 
-            return paged_flash_decode(
-                q, paged_k.value, paged_v.value, table.value, idx,
-                packed_kv_heads=h_kv, side_k=side_k.value,
-                side_v=side_v.value, side_len=side_idx.value,
-                window=window)
+            def every_row():
+                return paged_flash_decode(
+                    q, paged_k.value, paged_v.value, table.value, idx,
+                    packed_kv_heads=h_kv, side_k=side_k.value,
+                    side_v=side_v.value, side_len=side_idx.value,
+                    window=window)
+
+            if index is None:
+                return every_row()
+            # some lane holds more rows than a query attends: the step's
+            # lanes all take scores -> selection -> the chosen rows (a
+            # lane with fewer has them all chosen)
+            return jax.lax.cond(
+                jnp.max(idx) + side_idx.value > cfg.index_topk,
+                lambda: self._chosen_pages(
+                    q, q_i[:, 0], w_i[:, 0], paged_k.value, paged_v.value,
+                    paged_ik.value, table.value, idx, side_k.value,
+                    side_v.value, side_ik.value, side_idx.value),
+                every_row)
         # dense fallback: gather the slot's pages into a contiguous view
         # (one full-logical-cache copy per step — fine on CPU, the reason
         # the kernel exists on TPU) and mask main + side positions;
@@ -948,6 +1129,11 @@ class CausalSelfAttention(nn.Module):
             < s_base + jnp.arange(s)[None, :, None] + 1,
             (b, s, cap))                                       # [B, s, cap]
         mask = jnp.concatenate([mask_main, mask_side], axis=2)
+        if index is not None:
+            ikeys = jnp.concatenate(
+                [paged_gather_kv(paged_ik.value, table.value),
+                 side_ik.value], axis=1)
+            mask = _index_choice(q_i, w_i, ikeys, mask, cfg.index_topk)
         k_all = jnp.concatenate([k_main, side_k.value], axis=1)
         v_all = jnp.concatenate([v_main, side_v.value], axis=1)
         k4 = k_all.reshape(b, s_all + cap, h_kv, d)
@@ -955,14 +1141,98 @@ class CausalSelfAttention(nn.Module):
         k_rep, v_rep = repeat_kv(q, k4, v4)
         return _masked_attend(q, k_rep, v_rep, mask[:, None])
 
-    def _prefill_attend(self, q, k_all, v_all, idx):
+    def _chosen_pages(self, q, q_i, w_i, k_pool, v_pool, ik_pool, table,
+                      idx, side_k, side_v, side_ik, side_len):
+        """The decode step's attention over each lane's chosen rows: index
+        scores of the lane's pages on the paged walk, and of its staged
+        rows (the tokens this segment decoded are candidates like any
+        other) beside them; the exact top ``index_topk``; a candidate's
+        column is its position for a pool row (flat row ``page x block +
+        offset`` through the table) and ``rows of the table + j`` for
+        staged row ``j``."""
+        from tpudist.ops.flash_decode import (index_select,
+                                              paged_index_scores,
+                                              sparse_gqa_attend)
+
+        cfg = self.cfg
+        nb, bs_, flat = k_pool.shape
+        b, cap = side_k.shape[:2]
+        reach = table.shape[1] * bs_
+        main = paged_index_scores(q_i, w_i, ik_pool, table, idx)
+        staged = jnp.where(
+            jnp.arange(cap)[None, :] < side_len,
+            _index_scores(q_i[:, None], w_i[:, None], side_ik)[:, 0],
+            -jnp.inf)
+        ids = index_select(jnp.concatenate([main, staged], axis=1),
+                           cfg.index_topk)
+        pos = jnp.minimum(ids, reach - 1)
+        # a column's page out of the lane's table row by a masked sum over
+        # the row (a gather of one number a chosen row costs as much as
+        # the gather of the rows themselves)
+        page = jnp.sum(
+            jnp.where((pos // bs_)[..., None] == jnp.arange(table.shape[1]),
+                      table[:, None, :], 0), axis=-1)
+        rows = page * bs_ + pos % bs_
+        rows = jnp.where(ids >= reach, nb * bs_ + ids - reach, rows)
+        out = sparse_gqa_attend(
+            q[:, 0], k_pool.reshape(nb * bs_, flat),
+            v_pool.reshape(nb * bs_, flat), rows,
+            jnp.minimum(jnp.minimum(idx, reach) + side_len,
+                        cfg.index_topk),
+            packed_kv_heads=cfg.kv_heads, side_k=side_k, side_v=side_v)
+        return out[:, None]
+
+    def _chosen_rows(self, q, k_all, v_all, idx, index):
+        """A prefill chunk's queries at positions ``idx + [0, s)`` over
+        the chosen of the batch-1 cache's rows: index scores of the rows
+        at or below the chunk (the cache read in pages, several queries a
+        grid row), the exact top ``index_topk`` a query as a mask, one
+        masked flash pass."""
+        from tpudist.ops.flash_attention import flash_chosen_rows
+        from tpudist.ops.flash_decode import (block_of,
+                                              index_queries_per_row,
+                                              index_select_mask,
+                                              paged_index_scores)
+
+        cfg = self.cfg
+        s, n = q.shape[1], k_all.shape[1]
+        q_i, w_i, ikeys = index
+        page = block_of(n, 8)
+        tq = index_queries_per_row(s, cfg.index_heads, n)
+        seen = idx + (jnp.arange(s // tq) + 1) * tq
+        scores = paged_index_scores(
+            q_i[0], w_i[0], ikeys[0].reshape(n // page, page, -1),
+            jnp.arange(n // page)[None], seen)                   # [s, n]
+        mask = index_select_mask(scores, cfg.index_topk, rows=idx + s)
+        return flash_chosen_rows(q, k_all, v_all, mask, idx)
+
+    def _prefill_attend(self, q, k_all, v_all, idx, index=None):
         """Chunk prefill: queries at global positions [idx, idx+s) attend
         over the cache's first idx+s slots, causally.  The flash path
         reuses the forward kernel at ``q_offset=idx`` (its causal mask
         also silences the garbage in not-yet-written slots; dead tiles are
-        pruned); the dense path builds the banded mask explicitly."""
+        pruned); the dense path builds the banded mask explicitly.  With
+        an indexer a chunk that ends beyond row ``index_topk`` attends its
+        chosen rows (:meth:`_chosen_rows`; the dense path by a mask)."""
         cfg = self.cfg
         s = q.shape[1]
+        if index is not None and self.decode_attention == "flash":
+            if self.decode_shard is not None or q.shape[0] != 1:
+                raise ValueError(
+                    "a model with an indexer prefills through the "
+                    "replicated batch-1 cache")
+            return jax.lax.cond(
+                idx + s > cfg.index_topk,
+                lambda: self._chosen_rows(q, k_all, v_all, idx, index),
+                lambda: _flash_prefill(q, k_all, v_all, idx))
+        if index is not None:
+            q_pos = idx + jnp.arange(s)[:, None]
+            valid = jnp.broadcast_to(
+                jnp.arange(cfg.max_seq_len)[None, :] <= q_pos,
+                (q.shape[0], s, cfg.max_seq_len))
+            mask = _index_choice(*index, valid, cfg.index_topk)
+            k_all, v_all = repeat_kv(q, k_all, v_all)
+            return _masked_attend(q, k_all, v_all, mask[:, None])
         seq_sharded = (self.decode_shard is not None
                        and _shard_kind(self.decode_shard)
                        in ("seq", "heads_seq"))

@@ -257,6 +257,115 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
     return _unfuse(out, b, h), lse.reshape(b, h, s)
 
 
+def _flash_chosen_kernel(off_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
+                         m_scr, l_scr, acc_scr, *, scale: float,
+                         block_q: int, block_k: int, num_kb: int):
+    """:func:`_flash_kernel`'s causal step with a per-(query, row) mask on
+    top: a row enters a query's softmax where ``mask_ref`` is not 0.
+    ``off_ref`` (scalar prefetch) holds the queries' global offset; K
+    blocks wholly above the diagonal are skipped (the index maps hold
+    their copies at the last live block)."""
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    q0 = off_ref[0]
+
+    @pl.when(kj == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(_block_live(qi, kj, block_q, block_k, True, q0, 0))
+    def _compute():
+        q, kb, vb = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = _causal_mask(s, qi, kj, block_q, block_k, q0, 0)
+        s = jnp.where(mask_ref[...].astype(jnp.int32) != 0, s, -jnp.inf)
+        m = m_scr[:]
+        blk_max = jnp.max(s, axis=-1, keepdims=True)
+        new_m = jnp.maximum(m, jnp.maximum(blk_max, _NEG_BIG))
+        p = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        m_scr[:] = new_m
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
+            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+
+    @pl.when(kj == num_kb - 1)
+    def _finalize():
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+
+
+def flash_chosen_rows(q, k, v, mask, q_offset, *, scale=None,
+                      block_q: int | None = None,
+                      block_k: int | None = None,
+                      interpret: bool | None = None):
+    """Causal grouped-query attention of a block of queries over the rows
+    a mask names: ``q [1, S, H, D]`` at global positions ``q_offset + [0,
+    S)`` against ``k`` / ``v [1, R, Hkv, D]`` (a batch-1 cache, row = its
+    position), a row attended where ``mask [S, R]`` (bool or int8; read as int8) is not 0 and
+    the row is not after the query.  A prefill chunk's attention over the
+    rows an indexer chose: a dense flash pass whose work follows the rows
+    at or below the chunk (blocks above the diagonal are neither computed
+    nor copied), not a gather of ``S x k`` rows.  Returns ``[1, S, H,
+    D]``.  In a trace the kernel is ``sparse_gqa_prefill``."""
+    b, s, h, d = q.shape
+    sk, h_kv = k.shape[1], k.shape[2]
+    if b != 1 or mask.shape != (s, sk) or h % h_kv:
+        raise ValueError(
+            f"q [1, S, H, D], k / v [1, R, Hkv, D] and mask [S, R] needed; "
+            f"got {q.shape}, {k.shape}, {mask.shape}")
+    group = h // h_kv
+    block_q = block_q or _auto_block(s)
+    block_k = block_k or _auto_block(sk, 512)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    num_kb = sk // block_k
+    q3, k3, v3 = (_fuse(x) for x in (q, k, v))
+
+    def last_live(i, off):
+        return jnp.minimum((off[0] + (i + 1) * block_q - 1) // block_k,
+                           num_kb - 1)
+
+    kv_idx = lambda g, i, j, off: (  # noqa: E731
+        g // group, jnp.minimum(j, last_live(i, off)), 0)
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_chosen_kernel,
+            scale=d ** -0.5 if scale is None else scale, block_q=block_q,
+            block_k=block_k, num_kb=num_kb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h, s // block_q, num_kb),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d),
+                             lambda g, i, j, off: (g, i, 0)),
+                pl.BlockSpec((1, block_k, d), kv_idx),
+                pl.BlockSpec((1, block_k, d), kv_idx),
+                pl.BlockSpec((block_q, block_k),
+                             lambda g, i, j, off: (
+                                 i, jnp.minimum(j, last_live(i, off)))),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda g, i, j, off: (g, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((h, s, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=bool(interpret),
+        name="sparse_gqa_prefill",
+    )(jnp.asarray(q_offset, jnp.int32).reshape(1), q3, k3, v3,
+      mask.astype(jnp.int8))
+    return _unfuse(out, 1, h)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, block_q, block_k, interpret, window):
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret,

@@ -1164,10 +1164,11 @@ def paged_flash_decode(
         interpret=bool(interpret), window=window)
 
 
-@functools.partial(jax.jit, static_argnames=("h_kv", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("h_kv", "interpret", "window",
+                                             "name"))
 def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
                       side_k, side_v, *, h_kv: int, interpret: bool,
-                      window: int | None = None):
+                      window: int | None = None, name: str | None = None):
     """The validated single-query call of :func:`paged_flash_decode`.
     Under its own ``jit``: a segment program calls it once a layer with
     the same shapes, and the kernel body is then traced and lowered once
@@ -1201,8 +1202,8 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
         meta, q3, (k_pool, v_pool), (side_k, side_v) if side else None,
         scale=d ** -0.5, lanes=b, r_kv=r_kv, paired=paired, d_v=None,
         interpret=interpret, window=window,
-        name="paged_flash_decode" if window is None
-        else "paged_window_decode")
+        name=name or ("paged_flash_decode" if window is None
+                      else "paged_window_decode"))
     if paired:
         o = out.reshape(b, r_kv * 2, gp, d)
         return o[:, :, :g].reshape(b, 1, h, d)
@@ -1374,6 +1375,431 @@ def _paged_mla_one(q, pool, table, cache_len, side_len, side, *, d_v: int,
         lanes=b, r_kv=1, paired=False, d_v=d_v, interpret=interpret,
         name="paged_mla_decode")
     return out[:, :h]
+
+
+# -- learned sparse attention (an indexer) -----------------------------------
+#
+# Three routines, the same for a decode step and a prefill chunk: the index
+# scores of every cached row (a kernel on the paged walk), the exact top-k
+# of them (as a mask; as ascending positions where rows are gathered), and
+# grouped-query attention over the chosen rows alone.
+
+def _index_scores_kernel(meta_ref, q_ref, w_ref, pool, o_ref, buf, sems, *,
+                         block: int, pages_per_tile: int, m_blocks: int,
+                         lanes: int, tq: int, heads: int,
+                         shared_table: bool):
+    """Index scores of ONE grid row: ``tq`` consecutive queries (a decode
+    lane: one) against the rows of its pages, ``I[j, s] = sum_h w[j, h] x
+    relu(q[j, h] . k[s])`` in float32, ``-inf`` from each query's own limit
+    on.
+
+    The walk is :func:`_paged_decode_kernel`'s: ``meta_ref`` is ``[len_0 ..
+    len_{L-1}, table ...]`` (one table row a lane, or ONE row every
+    lane shares: a prefill chunk's queries read one batch-1 cache), the pool
+    stays in HBM and the body copies a lane's LIVE pages, a tile of
+    ``pages_per_tile`` at a time into two slots, the next tile's copies
+    started before this one is computed.  ``len`` is the rows the row's LAST
+    query sees; query ``j`` sees ``len - (tq - 1 - j)`` (causal inside the
+    row).  Columns are independent (no softmax runs across them), so a tile
+    is computed whole and masked: what an uncopied page of a slot holds
+    lands only in columns at or beyond the length.  The output block
+    ``[tiles, tq, tile rows]`` is filled with ``-inf`` first: tiles the walk
+    never reaches stay so."""
+    g = pl.program_id(0)
+    n_len = meta_ref[g]
+    n_pages = (n_len + block - 1) // block
+    n_tiles = (n_pages + pages_per_tile - 1) // pages_per_tile
+    tile_rows = pages_per_tile * block
+    base = lanes + (0 if shared_table else g * m_blocks)
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    def tile_copies(t, slot, fn):
+        live = jnp.minimum(n_pages - t * pages_per_tile, pages_per_tile)
+
+        def one_page(p, _):
+            page = meta_ref[base + t * pages_per_tile + p]
+            fn(pltpu.make_async_copy(pool.at[page], buf.at[slot, p],
+                                     sems.at[slot]))
+
+        jax.lax.fori_loop(0, live, one_page, None)
+
+    @pl.when(n_tiles > 0)
+    def _walk():
+        tile_copies(0, 0, lambda c: c.start())
+
+        def one_tile(t, _):
+            slot = t % 2
+
+            @pl.when(t + 1 < n_tiles)
+            def _next_tile():
+                tile_copies(t + 1, 1 - slot, lambda c: c.start())
+
+            tile_copies(t, slot, lambda c: c.wait())
+            keys = buf[slot].reshape(tile_rows, buf.shape[-1])
+            s = jax.lax.dot_general(
+                q_ref[0], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # [tq * heads, rows]
+            s = jnp.maximum(s, 0.0) * w_ref[0]
+            if tq == 1:
+                s = jnp.sum(s, axis=0, keepdims=True)
+            else:
+                s = jnp.sum(s.reshape(tq, heads, tile_rows), axis=1)
+            col = t * tile_rows + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            limit = n_len - (tq - 1) + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            o_ref[0, t] = jnp.where(col < limit, s, -jnp.inf)
+
+        jax.lax.fori_loop(0, n_tiles, one_tile, None)
+
+
+def index_queries_per_row(queries: int, heads: int, rows: int) -> int:
+    """Queries a grid row of :func:`paged_index_scores` takes where they
+    share their rows (a prefill chunk): the most of 16, 8, 4, 2 that divide
+    them while the row's float32 score tile ``[tq x heads, 1024]`` stays
+    2 MiB and its output block ``[tq, rows]`` 2 MiB (two of them in flight)
+    of the 16 MiB a kernel gets."""
+    for tq in (16, 8, 4, 2):
+        if (queries % tq == 0 and tq * heads <= 512
+                and tq * rows * 4 <= 2 << 20):
+            return tq
+    return 1
+
+
+def paged_index_scores(
+    q: jnp.ndarray,
+    w: jnp.ndarray,
+    pool: jnp.ndarray,
+    page_table: jnp.ndarray,
+    rows_seen: jnp.ndarray,
+    *,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """A learned indexer's scores of cached rows: ``I[t, s] = sum_h
+    w[t, h] relu(q[t, h] . k[s])`` for every row ``s`` query ``t`` sees,
+    ``-inf`` for every other, float32.
+
+    Args:
+      q: ``[T, H, D]`` index queries; w: ``[T, H]`` float32 head weights.
+      pool: ``[num_blocks, block, D]`` index keys by page.
+      page_table: ``[L, M]`` pages of each of ``L`` grid rows, ``T / L``
+        consecutive queries each (a decode step: ``L = T`` lanes, each its
+        own pages), or ``[1, M]``: one row of pages every grid row reads (a
+        prefill chunk over one batch-1 cache; the rows then take
+        :func:`index_queries_per_row` queries each).
+      rows_seen: ``[L]`` rows the LAST query of each grid row sees; the
+        query ``j`` places before it sees ``j`` rows fewer.
+
+    Returns ``[T, M x block]``.  In a trace the kernel is
+    ``paged_index_scores``."""
+    t, h, d = q.shape
+    table = jnp.asarray(page_table, jnp.int32)
+    rows_seen = jnp.asarray(rows_seen, jnp.int32)
+    lanes = rows_seen.shape[0]
+    if (pool.ndim != 3 or pool.shape[2] != d or w.shape != (t, h)
+            or table.ndim != 2 or table.shape[0] not in (1, lanes)
+            or t % lanes):
+        raise ValueError(
+            f"index scores take q [T, H, D], w [T, H], pool [N, block, D], "
+            f"a table [L or 1, M] and rows_seen [L] with L dividing T; got "
+            f"{q.shape}, {w.shape}, {pool.shape}, {table.shape}, "
+            f"{rows_seen.shape}")
+    block = pool.shape[1]
+    if block < 8 or block % 8 or h % 8:
+        raise ValueError(
+            f"block_size and the index heads must be multiples of 8, got "
+            f"{block}, {h}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _index_scores_one(q, w.astype(jnp.float32), pool, table,
+                             rows_seen, interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _index_scores_one(q, w, pool, table, rows_seen, *, interpret: bool):
+    t, h, d = q.shape
+    lanes, m_blocks = rows_seen.shape[0], table.shape[1]
+    tq = t // lanes
+    block = pool.shape[1]
+    ppt = paged_tile_pages(block, m_blocks)
+    n_tiles, tile_rows = -(-m_blocks // ppt), ppt * block
+    meta = jnp.concatenate([
+        jnp.minimum(rows_seen, m_blocks * block), table.reshape(-1)])
+    row = lambda g, m: (g, 0, 0)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(
+            _index_scores_kernel, block=block, pages_per_tile=ppt,
+            m_blocks=m_blocks, lanes=lanes, tq=tq, heads=h,
+            shared_table=table.shape[0] == 1 and lanes > 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(lanes,),
+            in_specs=[pl.BlockSpec((1, tq * h, d), row),
+                      pl.BlockSpec((1, tq * h, 1), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, n_tiles, tq, tile_rows),
+                                   lambda g, m: (g, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, ppt, block, d), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, n_tiles, tq, tile_rows),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_index_scores",
+    )(meta, q.reshape(lanes, tq * h, d).astype(pool.dtype),
+      w.reshape(lanes, tq * h, 1), pool)
+    out = out.transpose(0, 2, 1, 3).reshape(t, n_tiles * tile_rows)
+    return out[:, : m_blocks * block]
+
+
+def block_of(n: int, least: int = 1, most: int = 128) -> int:
+    """The largest power of two up to ``most`` (and not under ``least``)
+    that divides ``n``: rows a page, queries a block, columns a pass."""
+    block = most
+    while block >= max(least, 1):
+        if n % block == 0:
+            return block
+        block //= 2
+    raise ValueError(f"{n} is not a multiple of {least}")
+
+
+_SELECT_CHUNK = 128   # columns a chunk of the positions' prefix sum
+_SELECT_PASS = 2048   # columns a step of a counting pass that follows rows
+_TIE_ROWS = 8         # tied rows of a chunk whose cut is searched for apart
+
+
+def index_select_mask(scores: jnp.ndarray, k: int,
+                      rows: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Which ``k`` columns of each row of ``scores [T, R]`` are the
+    largest, EXACT, equal scores to the lower column (``jax.lax.top_k``'s
+    rule): a bool mask ``[T, R]``.  Only columns with a score above
+    ``-inf`` count: a row with fewer than ``k`` of them has those chosen.
+
+    No sort (on the chip ``jax.lax.top_k`` is a stable sort of the whole
+    row with its columns): the ``k``-th largest value by bisection on the
+    float32 bit pattern (32 counting passes over the row), then, where a
+    row holds more columns equal to it than it needs, the cut among them
+    by bisection on the column: over a decode step's few rows only when
+    one of them ties; of a chunk's rows over the first ``_TIE_ROWS`` that
+    tie, always, so that a chunk costs the same whatever its scores hold
+    (and over all rows where more tie).  ``rows``
+    (a scalar; every column from it on is ``-inf``): the counting passes
+    stop at the step of ``_SELECT_PASS`` columns that holds it, so a
+    prefill chunk's selection follows the rows cached and not the cache's
+    capacity.  Under the scope ``index_select``."""
+    with jax.named_scope("index_select"):
+        return _chosen_columns(scores, k, rows)
+
+
+def _chosen_columns(scores, k: int, rows=None):
+    t, width = scores.shape
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    # signed integers in the order of the floats they spell
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    col = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+
+    def counter(key):
+        """``count(pred)`` over the rows of ``key``: how many columns of
+        each row ``pred(keys, columns)`` holds for."""
+        if rows is None:
+            cols = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+            return lambda pred: jnp.sum(
+                pred(key, cols).astype(jnp.int32), axis=1)
+        step = block_of(width, most=_SELECT_PASS)
+        steps = jnp.clip(-(-jnp.asarray(rows, jnp.int32) // step), 1,
+                         width // step)
+
+        def count(pred):
+            def one(j, acc):
+                kb = jax.lax.dynamic_slice_in_dim(key, j * step, step, 1)
+                cb = j * step + jax.lax.broadcasted_iota(
+                    jnp.int32, kb.shape, 1)
+                return acc + jnp.sum(pred(kb, cb).astype(jnp.int32), axis=1)
+
+            return jax.lax.fori_loop(
+                0, steps, one, jnp.zeros((key.shape[0],), jnp.int32))
+
+        return count
+
+    count = counter(key)
+
+    def value_bit(i, tau):
+        # offset binary from the sign bit down: 1 << 31 wraps to the
+        # least integer, and the least integer plus itself to 0
+        cand = tau + jnp.left_shift(jnp.int32(1), 31 - i)
+        n = count(lambda kb, cb: kb >= cand[:, None])
+        return jnp.where(n >= k, cand, tau)
+
+    tau = jax.lax.fori_loop(
+        0, 32, value_bit, jnp.full((t,), -2 ** 31, jnp.int32))
+    # of the columns that equal the k-th value, the lowest `need`
+    need = k - count(lambda kb, cb: kb > tau[:, None])
+    equal = count(lambda kb, cb: kb == tau[:, None])
+    nbits = max(1, (width - 1).bit_length())
+
+    def cut(count, tau, need):
+        """The column of each row's ``need``-th key equal to ``tau``."""
+        def column_bit(i, p):
+            cand = p + jnp.left_shift(jnp.int32(1), nbits - 1 - i)
+            n = count(lambda kb, cb: (kb == tau[:, None])
+                      & (cb < cand[:, None]))
+            return jnp.where(n < need, cand, p)
+
+        return jax.lax.fori_loop(0, nbits, column_bit,
+                                 jnp.zeros(tau.shape, jnp.int32))
+
+    # the cut among the columns that equal the k-th value is searched for
+    # only in rows that hold more of them than they need (a row short of
+    # k finite scores needs them all)
+    tied = equal > need
+    everywhere = lambda: cut(count, tau, need)  # noqa: E731
+    if t < 8 * _TIE_ROWS:
+        # a decode step's few rows: float32 sums tie at the k-th value in
+        # one row of some thousands, so the passes seldom run
+        last = jax.lax.cond(jnp.any(tied), everywhere,
+                            lambda: jnp.full((t,), width, jnp.int32))
+    else:
+        # a chunk's thousands of rows: SOME row ties in every second call
+        # once the rows pass 20 k, and passes over all rows for its sake
+        # would make a chunk's cost the toss of a coin a layer.  The
+        # first `_TIE_ROWS` tied rows are searched apart, always (the
+        # same work whatever the scores hold); all rows only where more
+        # of them tie (scores in steps, a row all equal)
+        running = jnp.cumsum(tied.astype(jnp.int32))
+        some = jnp.sum((running[None, :] <= jnp.arange(_TIE_ROWS)[:, None]
+                        ).astype(jnp.int32), axis=1)    # t where none
+        at = jnp.minimum(some, t - 1)
+        few = cut(counter(key[at]), tau[at], need[at])
+        mine = jnp.sum(jnp.where(
+            (running - 1)[:, None] == jnp.arange(_TIE_ROWS)[None, :],
+            few[None, :], 0), axis=1)
+        last = jax.lax.cond(
+            running[-1] > _TIE_ROWS, everywhere,
+            lambda: jnp.where(tied, mine, width))
+    return ((key > tau[:, None])
+            | ((key == tau[:, None]) & (col <= last[:, None]))
+            ) & (scores > -jnp.inf)
+
+
+def index_select(scores: jnp.ndarray, k: int) -> jnp.ndarray:
+    """:func:`index_select_mask`'s columns as POSITIONS, ascending:
+    ``[T, k]`` int32.  A row with ``n < k`` columns above ``-inf`` lists
+    those in its first ``n`` places and anything after (the caller knows
+    how many it offered).
+
+    No scatter and no sort: the chosen columns from a prefix sum in two
+    levels (chunks of 128 columns, then inside the chunk of each output
+    place), products and comparisons only."""
+    with jax.named_scope("index_select"):
+        t, r = scores.shape
+        scores = jnp.pad(scores, ((0, 0), (0, -r % _SELECT_CHUNK)),
+                         constant_values=-jnp.inf)
+        chosen = _chosen_columns(scores, k)
+        # place j holds the (j + 1)-th chosen column: the place's chunk
+        # from the chunks' running counts; that chunk's own running count
+        # picked out by a one-hot product; the column inside the chunk is
+        # how many of its columns lie before the place's rank.  Counts up
+        # to 128 are exact in bfloat16.
+        n_chunks = scores.shape[1] // _SELECT_CHUNK
+        chunks = chosen.reshape(t, n_chunks, _SELECT_CHUNK)
+        triangle = (jnp.arange(_SELECT_CHUNK)[:, None]
+                    <= jnp.arange(_SELECT_CHUNK)[None, :]
+                    ).astype(jnp.bfloat16)
+        inside = jnp.einsum("tcd,de->tce", chunks.astype(jnp.bfloat16),
+                            triangle, preferred_element_type=jnp.float32)
+        per_chunk = inside[..., -1].astype(jnp.int32)         # [t, chunks]
+        running = jnp.cumsum(per_chunk, axis=1)
+        place = jnp.arange(k, dtype=jnp.int32)
+        done = running[:, None, :] <= place[None, :, None]  # [t, k, chunks]
+        chunk = jnp.sum(done.astype(jnp.int32), axis=-1)
+        before = jnp.sum(jnp.where(done, per_chunk[:, None, :], 0), axis=-1)
+        current = jnp.concatenate(
+            [jnp.ones((t, k, 1), bool), done[..., :-1]], axis=-1) & ~done
+        mine = jnp.einsum("tkc,tcd->tkd", current.astype(jnp.bfloat16),
+                          inside.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)  # [t, k, 128]
+        within = jnp.sum(
+            (mine <= (place[None, :] - before)[..., None].astype(jnp.float32)
+             ).astype(jnp.int32), axis=-1)
+        return jnp.minimum(chunk * _SELECT_CHUNK + within, r - 1)
+
+
+def sparse_gqa_attend(
+    q: jnp.ndarray,
+    k_source: jnp.ndarray,
+    v_source: jnp.ndarray,
+    ids: jnp.ndarray,
+    count: jnp.ndarray,
+    *,
+    packed_kv_heads: int,
+    side_k: jnp.ndarray | None = None,
+    side_v: jnp.ndarray | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Grouped-query attention of each query over ITS OWN chosen rows.
+
+    Args:
+      q: ``[T, H, D]`` queries (a decode step: one a lane).
+      k_source / v_source: ``[N, Hkv*D]`` packed K and V rows by flat row
+        id (a pool seen flat: ``page x block + offset``).
+      ids: ``[T, k]`` row ids; the first ``count[t]`` of query ``t``'s are
+        attended, the others ignored (any value).
+      side_k / side_v: ``[T, cap, Hkv*D]``: an id of ``N + j`` names row
+        ``j`` of the query's own side buffers (the segment's staging
+        rows).  Those are the highest ids, and ``ids`` must list a query's
+        first ``count`` in ASCENDING order (:func:`index_select`'s), so
+        that the staged rows are the last ``cap`` or fewer of them.
+
+    This version gathers the rows with XLA (``[T, k, Hkv*D]`` a pool:
+    written once and read once, where a kernel with row-granular copies
+    would read them once) and attends with the paged walk over those
+    buffers, the chosen rows their pages: the arithmetic is
+    :func:`paged_flash_decode`'s.  Returns ``[T, H, D]``.  In a trace the
+    kernel is ``sparse_gqa_attend``."""
+    t, h, d = q.shape
+    n, k = k_source.shape[0], ids.shape[1]
+    flat = packed_kv_heads * d
+    if (k_source.shape != (n, flat) or v_source.shape != (n, flat)
+            or ids.shape[0] != t or count.shape != (t,)
+            or h % packed_kv_heads):
+        raise ValueError(
+            f"q [T, H, D], sources [N, Hkv*D], ids [T, k], count [T] "
+            f"needed; got {q.shape}, {k_source.shape}, {v_source.shape}, "
+            f"{ids.shape}, {count.shape}")
+    count = jnp.minimum(jnp.asarray(count, jnp.int32), k)
+    at = window = None
+    if side_k is not None:
+        # the staged rows lie in the last ``cap`` places before ``count``:
+        # only that window of the gathered buffers is looked at again
+        cap = min(side_k.shape[1], k)
+        at = jnp.clip(count - cap, 0, k - cap)[:, None] + jnp.arange(cap)
+        window = jnp.take_along_axis(ids, at, axis=1)           # [T, cap]
+
+    def chosen(source, side):
+        rows = jnp.take(source, jnp.minimum(ids, n - 1), axis=0,
+                        mode="clip")
+        if side is None:
+            return rows
+        staged = jnp.take_along_axis(
+            side, jnp.clip(window - n, 0, side.shape[1] - 1)[..., None],
+            axis=1).astype(rows.dtype)
+        fixed = jnp.where((window >= n)[..., None], staged,
+                          jnp.take_along_axis(rows, at[..., None], axis=1))
+        return rows.at[jnp.arange(t)[:, None], at].set(fixed)
+
+    block = block_of(k, 8)   # rows a page of the gathered buffers
+    pages = k // block
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    out = _paged_decode_one(
+        q[:, None], chosen(k_source, side_k).reshape(t * pages, block, flat),
+        chosen(v_source, side_v).reshape(t * pages, block, flat),
+        jnp.arange(t * pages, dtype=jnp.int32).reshape(t, pages), count,
+        jnp.zeros((), jnp.int32), None, None, h_kv=packed_kv_heads,
+        interpret=bool(interpret), name="sparse_gqa_attend")
+    return out[:, 0]
 
 
 def quantize_kv(k: jnp.ndarray, v: jnp.ndarray):
