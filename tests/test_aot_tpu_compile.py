@@ -621,24 +621,52 @@ def test_flash_chosen_rows_at_the_cells_shapes(v5e):
 @pytest.mark.parametrize("path", ["decode_step", "prefill_chunk"])
 def test_index_select_at_the_cells_shapes(v5e, path):
     """The decode step's selection over the table's reach and the staged
-    rows (positions), a chunk's over the cache's rows (a mask, its passes
-    under a loop that follows the rows): passes and products, no sort of
-    the row."""
+    rows (positions), a chunk's over the cache's rows (a mask, with passes
+    that follow the rows): ONE kernel holds every counting pass, so no
+    sort of the row and no loop of XLA's is left around it."""
+    from benchmarks.layer_metrics.index_select_chunk_ms_per_call import (
+        KERNEL)
     from tpudist.ops.flash_decode import index_select, index_select_mask
 
     if path == "decode_step":
-        scores = _sds(v5e, (IDX_LANES, IDX_ENTRIES * BLOCK + IDX_SIDE),
-                      jnp.float32)
+        t = IDX_LANES
+        scores = _sds(v5e, (t, IDX_ENTRIES * BLOCK + IDX_SIDE), jnp.float32)
         hlo = _compile(lambda s: index_select(s, IDX_TOPK), scores)
         # what it replaces is one
         assert re.search(r"\bsort\(", _compile(
             lambda s: jax.lax.top_k(s, IDX_TOPK)[1], scores))
     else:
+        t = IDX_CHUNK
         hlo = _compile(
             lambda s, n: index_select_mask(s, IDX_TOPK, rows=n),
-            _sds(v5e, (IDX_CHUNK, IDX_ENTRIES * BLOCK), jnp.float32),
+            _sds(v5e, (t, IDX_ENTRIES * BLOCK), jnp.float32),
             _sds(v5e, (), jnp.int32))
-    assert not re.search(r"\bsort\(", hlo) and _kernel_calls(hlo) == 0
+    assert not re.search(r"\bsort\(", hlo) and _kernel_calls(hlo) == 1
+    op = _named_call(hlo, "index_select_threshold", KERNEL)
+    assert op["pallas"] and op["operands"] == 2          # rows, scores
+    assert op["outputs"] == (f"s32[{t},1]",) * 2         # tau, last
+    # the 32 value passes and the column passes were `while`s whose bodies
+    # reduced a [T, W] or a [T, 2048] operand to a count a row: no loop at
+    # all is left (what places a step's columns is products and sums)
+    assert not re.search(r"\bwhile\(", hlo)
+
+
+@pytest.mark.parametrize("shape, block", [
+    ((IDX_CHUNK, IDX_ENTRIES * BLOCK), 32),
+    ((IDX_LANES, IDX_ENTRIES * BLOCK + 128), 16)],
+    ids=["prefill_chunk", "decode_step"])
+def test_index_select_threshold_fits_vmem_at_the_widest_shapes(
+        v5e, shape, block):
+    """The cell's widest calls of the kernel alone: a block of queries'
+    scores twice (the pipeline's two buffers) and their keys in VMEM,
+    12 MiB for a chunk's block of 32 rows."""
+    from tpudist.ops.flash_decode import _select_queries, _select_threshold
+
+    assert _select_queries(*shape) == block
+    hlo = _compile(
+        lambda s, n: _select_threshold(s, n, k=IDX_TOPK, interpret=False),
+        _sds(v5e, shape, jnp.float32), _sds(v5e, (), jnp.int32))
+    assert _kernel_calls(hlo) == 1
 
 
 def _indexer_loop(**over):
@@ -680,12 +708,13 @@ def test_indexer_model_names_its_kernels(v5e):
     seg, chunk = _indexer_programs(v5e, loop)
     experts = {"moe_experts_gate_up", "moe_experts_down"}
     assert _kernel_scopes(loop._segment, *seg) == (
-        {"paged_flash_decode", "paged_index_scores", "sparse_gqa_attend"}
-        | experts)
+        {"paged_flash_decode", "paged_index_scores", "index_select_threshold",
+         "sparse_gqa_attend"} | experts)
     assert _kernel_scopes(loop._prefill_chunk, *chunk, chunk=1024) == (
-        {"flash_fwd", "paged_index_scores", "sparse_gqa_prefill"} | experts)
-    # two layers of three attention kernels and two expert kernels
-    assert _kernel_calls(_compile(loop._segment, *seg)) == 10
+        {"flash_fwd", "paged_index_scores", "index_select_threshold",
+         "sparse_gqa_prefill"} | experts)
+    # two layers of four attention kernels and two expert kernels
+    assert _kernel_calls(_compile(loop._segment, *seg)) == 12
     node = loop.cache["block0"]["attn"]
     assert node["paged_ikey"].shape[1:] == (BLOCK, 128)
     assert loop._blank1["block0"]["attn"]["cached_ikey"].shape == (

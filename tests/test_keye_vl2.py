@@ -389,49 +389,101 @@ def test_equal_scores_go_to_the_lower_position():
     assert np.flatnonzero(index_select_mask(few, 4)[0]).tolist() == [1, 3]
 
 
-@pytest.mark.parametrize("shape", [(3, 300, 16), (256, 1000, 64),
-                                   (5, 40, 16), (4, 70, 64),
-                                   (6, 8192, 32)],
-                         ids=lambda s: "x".join(map(str, s)))
-def test_threshold_selection_is_top_k(shape):
+def _rows_of_every_length(rng, sc):
+    live = rng.integers(1, sc.shape[1] + 1, len(sc))
+    return np.where(np.arange(sc.shape[1])[None] < live[:, None], sc,
+                    -np.inf)
+
+
+def _stepped(rng, t, r):
     """Scores in steps of a quarter (ties everywhere), rows of every
-    length, one row all equal: the same set as ``jax.lax.top_k``, in
-    ascending order; the mask names the same set, and passes that follow
-    the rows (a prefill chunk's) give the same mask."""
-    t, r, k = shape
-    rng = np.random.default_rng(3)
-    sc = np.round(rng.normal(size=(t, r)).astype(np.float32) * 4) / 4
-    live = rng.integers(1, r + 1, t)
-    sc = np.where(np.arange(r)[None] < live[:, None], sc, -np.inf)
+    length, one row all equal."""
+    sc = _rows_of_every_length(
+        rng, np.round(rng.normal(size=(t, r)).astype(np.float32) * 4) / 4)
     sc[2] = np.where(np.isfinite(sc[2]), 0.5, -np.inf)
+    return sc
+
+
+def _negative(rng, t, r):
+    """Every score below zero (keys whose bits run against their order),
+    in steps of an eighth, rows of every length."""
+    return _rows_of_every_length(
+        rng, -np.round(np.abs(rng.normal(size=(t, r))) * 8 + 1) / 8)
+
+
+def _zeros(rng, t, r):
+    """``-0.0`` beside ``+0.0`` and little else: two keys one apart that
+    compare equal as floats, which ``jax.lax.top_k`` tells apart."""
+    return rng.choice(np.float32([-0.0, 0.0, 0.0, -0.25, 0.25]), (t, r))
+
+
+def _shallow(rng, t, r):
+    """No row past a third of the width, and one with nothing but
+    ``-inf``: passes that follow the rows stop steps before the end."""
+    sc = _stepped(rng, t, r)
+    sc[:, r // 3:] = -np.inf
+    sc[1] = -np.inf
+    return sc
+
+
+@pytest.mark.parametrize("shape, scores", [
+    pytest.param(shape, scores, id="x".join(map(str, shape)) + name)
+    for shape, scores, name in [
+        ((3, 300, 16), _stepped, ""), ((256, 1000, 64), _stepped, ""),
+        ((5, 40, 16), _stepped, ""), ((4, 70, 64), _stepped, ""),
+        ((6, 8192, 32), _stepped, ""),
+        # a decode step's: 12 lanes, two pass steps and 128 columns more
+        ((12, 4224, 64), _stepped, "-step"),
+        # rows no multiple of the query block: a last block that overhangs
+        ((40, 1000, 64), _stepped, "-overhang"),
+        ((12, 2176, 48), _negative, "-negative"),
+        ((20, 640, 200), _zeros, "-signed-zeros"),
+        ((9, 8192, 32), _shallow, "-shallow"),
+        ((9, 6272, 32), _shallow, "-shallow-with-a-tail")]])
+def test_threshold_selection_is_top_k(shape, scores):
+    """The same set as ``jax.lax.top_k``, in ascending order, whatever the
+    scores hold; the mask names the same set, and passes that follow the
+    rows (a prefill chunk's) give the same mask, with ``rows`` inside a
+    pass step and at the width."""
+    t, r, k = shape
+    sc = scores(np.random.default_rng(3), t, r).astype(np.float32)
     k = min(k, r)
     want = np.asarray(jax.lax.top_k(jnp.asarray(sc), k)[1])
     got = np.asarray(index_select(jnp.asarray(sc), k))
     mask = np.asarray(index_select_mask(jnp.asarray(sc), k))
-    followed = np.asarray(jax.jit(
-        lambda s, n: index_select_mask(s, k, rows=n))(
-        jnp.asarray(sc), jnp.int32(live.max())))
-    assert np.array_equal(mask, followed)
+    follow = jax.jit(lambda s, n: index_select_mask(s, k, rows=n))
+    live = int(np.isfinite(sc).any(axis=0).nonzero()[0].max()) + 1
+    for rows in (live, r):
+        assert np.array_equal(mask, follow(jnp.asarray(sc), jnp.int32(rows)))
     for i in range(t):
         n = min(int(np.isfinite(sc[i]).sum()), k)
         assert sorted(want[i, :n].tolist()) == got[i, :n].tolist()
         assert np.flatnonzero(mask[i]).tolist() == got[i, :n].tolist()
 
 
-@pytest.mark.parametrize("tied_rows", [0, 3, 8, 9],
-                         ids=lambda n: f"{n}-rows-tie")
+@pytest.mark.parametrize("tied_rows", [
+    0, 3, 8, 9, pytest.param(96, id="every-row-ties"),
+    pytest.param((31, 32), id="ties-straddle-two-blocks")],
+    ids=lambda n: f"{n}-rows-tie")
 def test_a_chunks_few_tied_rows_are_cut_apart(tied_rows):
-    """A chunk's rows (64 and more) of float scores of which a few tie at
-    the k-th value, as a deep chunk's do: up to ``_TIE_ROWS`` of them are
-    cut in a search of their own, more send every row through it; the
-    mask is ``jax.lax.top_k``'s set either way, with passes that follow
-    the rows too."""
+    """A chunk's rows (three blocks of 32 queries) of float scores of which
+    a few tie at the k-th value, as a deep chunk's do: the kernel searches
+    for the cut among equal columns only in a block that holds such a row;
+    none, some, all of the blocks, and two neighbours for the last row of
+    one and the first of the next.  The mask is ``jax.lax.top_k``'s set
+    every time, with passes that follow the rows too."""
+    from tpudist.ops.flash_decode import _select_queries
+
     t, r, k = 96, 512, 32
-    rng = np.random.default_rng(tied_rows)
+    assert _select_queries(t, r) == 32
+    rng = np.random.default_rng(
+        tied_rows if isinstance(tied_rows, int) else 0)
     sc = rng.normal(size=(t, r)).astype(np.float32)
     live = rng.integers(k + 8, r + 1, t)
     sc = np.where(np.arange(r)[None] < live[:, None], sc, -np.inf)
-    for i in rng.choice(t, tied_rows, replace=False):
+    if isinstance(tied_rows, int):
+        tied_rows = rng.choice(t, tied_rows, replace=False)
+    for i in tied_rows:
         # the k-th value three more times, on both sides of its column
         order = np.argsort(-sc[i], kind="stable")
         kth = order[k - 1]

@@ -1567,7 +1567,6 @@ def block_of(n: int, least: int = 1, most: int = 128) -> int:
 
 _SELECT_CHUNK = 128   # columns a chunk of the positions' prefix sum
 _SELECT_PASS = 2048   # columns a step of a counting pass that follows rows
-_TIE_ROWS = 8         # tied rows of a chunk whose cut is searched for apart
 
 
 def index_select_mask(scores: jnp.ndarray, k: int,
@@ -1578,108 +1577,158 @@ def index_select_mask(scores: jnp.ndarray, k: int,
     ``-inf`` count: a row with fewer than ``k`` of them has those chosen.
 
     No sort (on the chip ``jax.lax.top_k`` is a stable sort of the whole
-    row with its columns): the ``k``-th largest value by bisection on the
-    float32 bit pattern (32 counting passes over the row), then, where a
-    row holds more columns equal to it than it needs, the cut among them
-    by bisection on the column: over a decode step's few rows only when
-    one of them ties; of a chunk's rows over the first ``_TIE_ROWS`` that
-    tie, always, so that a chunk costs the same whatever its scores hold
-    (and over all rows where more tie).  ``rows``
-    (a scalar; every column from it on is ``-inf``): the counting passes
-    stop at the step of ``_SELECT_PASS`` columns that holds it, so a
-    prefill chunk's selection follows the rows cached and not the cache's
-    capacity.  Under the scope ``index_select``."""
+    row with its columns): the ``k``-th largest value and the cut among
+    the columns equal to it from the kernel ``index_select_threshold``
+    (:func:`_select_threshold`), the mask from them in one elementwise
+    pass.  ``rows`` (a scalar; every column from it on is ``-inf``): the
+    kernel's passes stop at the step of ``_SELECT_PASS`` columns that
+    holds it, so a prefill chunk's selection follows the rows cached and
+    not the cache's capacity.  Under the scope ``index_select``."""
     with jax.named_scope("index_select"):
         return _chosen_columns(scores, k, rows)
 
 
-def _chosen_columns(scores, k: int, rows=None):
-    t, width = scores.shape
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    # signed integers in the order of the floats they spell
-    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-    col = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+def _select_queries(t: int, width: int) -> int:
+    """Query rows a grid row of the threshold kernel takes: the most of 32,
+    16, 8 whose float32 scores ``[tq, width]``, two buffers of them and
+    the int32 keys beside, stay 12 MiB of the 16 MiB a kernel gets, and no
+    more than the rows there are (rounded up to the 8 of a tile)."""
+    for tq in (32, 16):
+        if 3 * 4 * tq * width <= 12 << 20 and tq < t + 8:
+            return tq
+    return 8
 
-    def counter(key):
-        """``count(pred)`` over the rows of ``key``: how many columns of
-        each row ``pred(keys, columns)`` holds for."""
-        if rows is None:
-            cols = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
-            return lambda pred: jnp.sum(
-                pred(key, cols).astype(jnp.int32), axis=1)
-        step = block_of(width, most=_SELECT_PASS)
-        steps = jnp.clip(-(-jnp.asarray(rows, jnp.int32) // step), 1,
-                         width // step)
 
-        def count(pred):
-            def one(j, acc):
-                kb = jax.lax.dynamic_slice_in_dim(key, j * step, step, 1)
-                cb = j * step + jax.lax.broadcasted_iota(
-                    jnp.int32, kb.shape, 1)
-                return acc + jnp.sum(pred(kb, cb).astype(jnp.int32), axis=1)
+def _select_threshold_kernel(rows_ref, scores_ref, tau_ref, last_ref,
+                             key_ref, *, k: int, step: int):
+    """ONE grid row of the exact top-``k``'s search: a block of ``tq``
+    query rows whose scores ``[tq, width]`` came into VMEM once.
 
-            return jax.lax.fori_loop(
-                0, steps, one, jnp.zeros((key.shape[0],), jnp.int32))
+    Their float32 bit patterns become signed integers in the floats'
+    order (``key_ref``); the ``k``-th largest key of each row is found
+    bit by bit from the sign down, each bit one counting pass over the
+    block (compare and add on 128-column pieces, the partial counts kept
+    a lane and summed across the lanes once a pass); then how many
+    columns above it a row holds and how many equal to it, and, only in a
+    block where some row holds more equal columns than it needs, the
+    column of the last one it takes, bit by bit the same way.
 
-        return count
+    A pass covers the steps of ``step`` columns up to the one that holds
+    ``rows_ref[0]`` (every column from there on is ``-inf``), and a last
+    shorter step where the width is no multiple of ``step``."""
+    tq, width = scores_ref.shape
+    whole, tail = divmod(width, step)
+    steps = jnp.minimum((rows_ref[0] + step - 1) // step, whole)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tq, 128), 1)
 
-    count = counter(key)
+    def columns(fn, acc):
+        """``fn(acc, first column, columns)`` over the live steps."""
+        def one(j, acc):
+            return fn(acc, pl.multiple_of(j * step, step), step)
+
+        acc = jax.lax.fori_loop(0, steps, one, acc)
+        return fn(acc, whole * step, tail) if tail else acc
+
+    def to_keys(_, base, n):
+        bits = jax.lax.bitcast_convert_type(
+            scores_ref[:, pl.ds(base, n)], jnp.int32)
+        key_ref[:, pl.ds(base, n)] = jnp.where(
+            bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+    columns(to_keys, None)
+
+    def count(pred):
+        """How many live columns of each row ``pred(keys, first column)``
+        holds for: ``[tq, 1]``."""
+        def pieces(acc, base, n):
+            hits = [pred(key_ref[:, pl.ds(base + c, 128)], base + c)
+                    .astype(jnp.int32) for c in range(0, n, 128)]
+            while len(hits) > 1:     # pairwise: no chain of dependent adds
+                hits = [a + b for a, b in zip(hits[::2], hits[1::2])] + (
+                    hits[-1:] if len(hits) % 2 else [])
+            return acc + hits[0]
+
+        lanes = columns(pieces, jnp.zeros((tq, 128), jnp.int32))
+        return jnp.sum(lanes, axis=1, keepdims=True)
+
+    def wide(x):
+        return jnp.broadcast_to(x, (tq, 128))
 
     def value_bit(i, tau):
         # offset binary from the sign bit down: 1 << 31 wraps to the
         # least integer, and the least integer plus itself to 0
         cand = tau + jnp.left_shift(jnp.int32(1), 31 - i)
-        n = count(lambda kb, cb: kb >= cand[:, None])
-        return jnp.where(n >= k, cand, tau)
+        at = wide(cand)
+        return jnp.where(count(lambda kb, c: kb >= at) >= k, cand, tau)
 
-    tau = jax.lax.fori_loop(
-        0, 32, value_bit, jnp.full((t,), -2 ** 31, jnp.int32))
+    tau = jax.lax.fori_loop(0, 32, value_bit,
+                            jnp.full((tq, 1), -2 ** 31, jnp.int32))
+    at = wide(tau)
     # of the columns that equal the k-th value, the lowest `need`
-    need = k - count(lambda kb, cb: kb > tau[:, None])
-    equal = count(lambda kb, cb: kb == tau[:, None])
+    need = k - count(lambda kb, c: kb > at)
+    tied = count(lambda kb, c: kb == at) > need
+    tau_ref[...] = tau
+    last_ref[...] = jnp.full((tq, 1), width, jnp.int32)
     nbits = max(1, (width - 1).bit_length())
 
-    def cut(count, tau, need):
-        """The column of each row's ``need``-th key equal to ``tau``."""
+    # a row short of k finite scores needs all its equal columns, and a
+    # row whose k-th value stands alone its one: neither is searched
+    @pl.when(jnp.max(tied.astype(jnp.int32)) > 0)
+    def _cut():
         def column_bit(i, p):
             cand = p + jnp.left_shift(jnp.int32(1), nbits - 1 - i)
-            n = count(lambda kb, cb: (kb == tau[:, None])
-                      & (cb < cand[:, None]))
+            end = wide(cand)
+            n = count(lambda kb, c: (kb == at) & (lane + c < end))
             return jnp.where(n < need, cand, p)
 
-        return jax.lax.fori_loop(0, nbits, column_bit,
-                                 jnp.zeros(tau.shape, jnp.int32))
+        p = jax.lax.fori_loop(0, nbits, column_bit,
+                              jnp.zeros((tq, 1), jnp.int32))
+        last_ref[...] = jnp.where(tied, p, width)
 
-    # the cut among the columns that equal the k-th value is searched for
-    # only in rows that hold more of them than they need (a row short of
-    # k finite scores needs them all)
-    tied = equal > need
-    everywhere = lambda: cut(count, tau, need)  # noqa: E731
-    if t < 8 * _TIE_ROWS:
-        # a decode step's few rows: float32 sums tie at the k-th value in
-        # one row of some thousands, so the passes seldom run
-        last = jax.lax.cond(jnp.any(tied), everywhere,
-                            lambda: jnp.full((t,), width, jnp.int32))
-    else:
-        # a chunk's thousands of rows: SOME row ties in every second call
-        # once the rows pass 20 k, and passes over all rows for its sake
-        # would make a chunk's cost the toss of a coin a layer.  The
-        # first `_TIE_ROWS` tied rows are searched apart, always (the
-        # same work whatever the scores hold); all rows only where more
-        # of them tie (scores in steps, a row all equal)
-        running = jnp.cumsum(tied.astype(jnp.int32))
-        some = jnp.sum((running[None, :] <= jnp.arange(_TIE_ROWS)[:, None]
-                        ).astype(jnp.int32), axis=1)    # t where none
-        at = jnp.minimum(some, t - 1)
-        few = cut(counter(key[at]), tau[at], need[at])
-        mine = jnp.sum(jnp.where(
-            (running - 1)[:, None] == jnp.arange(_TIE_ROWS)[None, :],
-            few[None, :], 0), axis=1)
-        last = jax.lax.cond(
-            running[-1] > _TIE_ROWS, everywhere,
-            lambda: jnp.where(tied, mine, width))
-    return ((key > tau[:, None])
-            | ((key == tau[:, None]) & (col <= last[:, None]))
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _select_threshold(scores, rows, *, k: int, interpret: bool):
+    """``(tau, last)`` of each row of ``scores [T, W]`` (``W`` a multiple
+    of 128), ``[T, 1]`` int32 each: the key of its ``k``-th largest score
+    and the column of the last key equal to it that the top ``k`` take
+    (``W`` where the row takes all of them).  In a trace the kernel is
+    ``index_select_threshold``."""
+    t, width = scores.shape
+    tq = _select_queries(t, width)
+    out = jax.ShapeDtypeStruct((t, 1), jnp.int32)
+    block = lambda i, r: (i, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_select_threshold_kernel, k=k,
+                          step=min(_SELECT_PASS, width)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(-(-t // tq),),
+            in_specs=[pl.BlockSpec((tq, width), block)],
+            out_specs=[pl.BlockSpec((tq, 1), block),
+                       pl.BlockSpec((tq, 1), block)],
+            scratch_shapes=[pltpu.VMEM((tq, width), jnp.int32)],
+        ),
+        out_shape=[out, out],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="index_select_threshold",
+    )(jnp.reshape(rows, (1,)).astype(jnp.int32), scores)
+
+
+def _chosen_columns(scores, k: int, rows=None):
+    t, r = scores.shape
+    width = r + -r % 128
+    padded = jnp.pad(scores, ((0, 0), (0, width - r)),
+                     constant_values=-jnp.inf)
+    tau, last = _select_threshold(
+        padded, width if rows is None else rows, k=k,
+        interpret=jax.default_backend() == "cpu")
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    # signed integers in the order of the floats they spell
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    col = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    return ((key > tau) | ((key == tau) & (col <= last))
             ) & (scores > -jnp.inf)
 
 
