@@ -710,3 +710,31 @@ class TestSimAlerts:
         row = FleetSim(builtin("coord_brownout")).run()
         assert row["alerts_fired"] == ["CoordOutage"]
         assert row["envelope_ok"] is True, row["violations"]
+
+    @pytest.mark.parametrize("leftover", ["queue_wait", "degraded",
+                                          "kv_free"])
+    def test_a_process_leftover_fires_nothing_in_a_brownout(
+            self, leftover, monkeypatch):
+        """While the coord is down the scraper reads the process's registry
+        alone: replica state another test left there (a ``ServeLoop``'s
+        wait window, a gauge) is no state of the simulated fleet."""
+        from tpudist import obs
+        from tpudist.sim.scenario import builtin
+        from tpudist.sim.simulator import FleetSim
+
+        name = {"queue_wait": "serve/queue_wait_s",
+                "degraded": "serve/degraded",
+                "kv_free": "fleet/kv_free_frac"}[leftover]
+        # the leftover is made in a copy of the registry's table, so that
+        # it is no leftover for the tests after this one
+        monkeypatch.setattr(obs.registry, "_metrics", {
+            k: m for k, m in obs.registry.metrics().items() if k != name})
+        if leftover == "queue_wait":
+            waits = obs.histogram(name, unit="s", window_s=60.0)
+            for _ in range(20):
+                waits.record(3.0)
+        else:
+            obs.gauge(name).set(1.0 if leftover == "degraded" else 0.01)
+        row = FleetSim(builtin("coord_brownout")).run()
+        assert row["alerts_fired"] == ["CoordOutage"]
+        assert row["envelope_ok"] is True, row["violations"]

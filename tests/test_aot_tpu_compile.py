@@ -13,7 +13,10 @@ its interpret branch, so the lowering runs with that one function patched
 (the program grows no option for it).
 """
 
+import base64
+import contextlib
 import dataclasses
+import hashlib
 import os
 import re
 from unittest import mock
@@ -28,7 +31,10 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from tpudist.models import ServeLoop, TransformerConfig, TransformerLM
+from tpudist import obs
+from tpudist.models import (MLAConfig, MoEConfig, ServeLoop,
+                            TransformerConfig, TransformerLM, YarnScaling)
+from tpudist.models.serving import hlo_scopes
 from tpudist.ops.flash_attention import _flash_forward, flash_attention
 from tpudist.ops.flash_decode import (flash_decode, paged_flash_decode,
                                       paged_mla_decode)
@@ -57,17 +63,25 @@ def v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def _quiet_cache():
+@contextlib.contextmanager
+def _cache_off():
     """A described-device executable can be written to the persistent
     cache but not read back without a chip (it warns and recompiles), so
     the cache is off around these compiles."""
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _quiet_cache():
+    with _cache_off():
+        yield
 
 
 def _on(sharding, tree):
@@ -837,3 +851,194 @@ def test_report_fused_group_norm(v5e):
 
     hlo = _compile_or_report(jax.grad(loss, argnums=(0, 1, 2, 3)), x, c, c, x)
     assert _kernel_calls(hlo) >= 2  # forward and backward kernels
+
+
+# -- the routine scopes on the compiled serve programs (PR 37) --------------
+# ``obs.ROUTINE_SCOPES`` on the three programs of the four families the
+# benchmark serves (GPTBigCode; latent attention + experts; window +
+# grouped queries + experts; an indexer).  Scopes are metadata: the
+# programs with every piece of metadata taken out are the parent's, hash
+# for hash.
+
+SCOPE_STEPS = 16     # the recorded hashes are of 16-step segments
+_MOE = dict(num_experts=16, top_k=4, experts="gated_silu", d_ff=128,
+            held=(0, 4))
+_BASE = dict(vocab_size=1024, embed_dim=512, compute_dtype=jnp.bfloat16)
+_ROTARY = dict(norm="rmsnorm", positions="rotary", mlp="gated_silu")
+
+# family -> (config, prefill chunk, the kernels of segment / chunk by the
+# scope each must carry, the scopes its three programs must show between
+# them, recorded hashes of segment / chunk / finish with metadata out)
+FAMILIES = {
+    "gptbigcode": (
+        lambda: TransformerConfig(num_layers=2, num_heads=4, num_kv_heads=1,
+                                  max_seq_len=2048, **_BASE),
+        512, {"paged_flash_decode": "attn/core", "flash_fwd": "attn/core"},
+        {"attn/proj", "attn/cache", "attn/core", "mlp/dense", "head"},
+        ("54a131884cd7e181", "61089b8ea4eb6231", "d5b3b7579d73be0d")),
+    "latent_experts": (
+        lambda: TransformerConfig(
+            num_layers=2, num_heads=8, max_seq_len=2048,
+            rope_scaling=YarnScaling(mscale_all_dim=1.0), mlp_dim=1024,
+            mla=MLAConfig(384, 512, 128, 64, 128),
+            moe=MoEConfig(scoring="sigmoid", n_group=4, topk_group=2,
+                          routed_scale=2.5, correction_bias=True, n_shared=1,
+                          **_MOE),
+            first_k_dense=1, **_BASE, **_ROTARY),
+        512, {"paged_mla_decode": "attn/core", "flash_fwd": "attn/core",
+              "moe_experts_gate_up": "mlp/experts",
+              "moe_experts_down": "mlp/experts"},
+        {"attn/proj", "attn/cache", "attn/core", "mlp/dense", "mlp/route",
+         "mlp/experts", "mlp/shared", "head"},
+        ("7c7c358efe200325", "abca54c6f1eedc19", "b5f9ede904d8226c")),
+    "window_experts": (
+        lambda: TransformerConfig(
+            num_layers=4, num_heads=8, num_kv_heads=2, head_size=128,
+            max_seq_len=4096, rope_theta=500000.0,
+            rope_scaling=YarnScaling(16.0, 8192, 32.0, 1.0, 1.0, 0.0),
+            window_rope_scaling=None,
+            layer_windows=(1024, 1024, 1024, None), mlp_dim=128,
+            moe=MoEConfig(scoring="softmax", **_MOE), **_BASE, **_ROTARY),
+        512, {"paged_window_decode": "attn/core",
+              "paged_flash_decode": "attn/core", "flash_fwd": "attn/core",
+              "moe_experts_gate_up": "mlp/experts",
+              "moe_experts_down": "mlp/experts"},
+        {"attn/proj", "attn/cache", "attn/core", "mlp/route", "mlp/experts",
+         "head"},
+        ("307dda5a23aec04d", "9310ac3f7a357b0f", "c099691945c5e635")),
+    "indexer": (
+        lambda: TransformerConfig(
+            num_layers=2, num_heads=8, num_kv_heads=2, head_size=128,
+            max_seq_len=4096, rope_theta=1e7, mlp_dim=128,
+            moe=MoEConfig(scoring="softmax", **_MOE), qk_norm=True,
+            index_heads=16, index_head_dim=64, index_topk=1024, **_BASE,
+            **_ROTARY),
+        1024, {"paged_flash_decode": "attn/core",
+               "paged_index_scores": "attn/index",
+               "index_select_threshold": "attn/index",
+               "sparse_gqa_attend": "attn/core", "flash_fwd": "attn/core",
+               "sparse_gqa_prefill": "attn/core",
+               "moe_experts_gate_up": "mlp/experts",
+               "moe_experts_down": "mlp/experts"},
+        {"attn/proj", "attn/cache", "attn/index", "attn/rows", "attn/core",
+         "mlp/route", "mlp/experts", "head"},
+        ("aba455a9d909a756", "4deb752c1e41b7d9", "54dec2630fe1e1ac")),
+}
+PROGRAMS = ("_segment_impl", "_prefill_chunk_impl", "_admit_finish_impl")
+# instructions that carry no routine, of those that are not parameters,
+# constants, tuples or bitcasts: the compiler's own whose consumers do not
+# agree on one (broadcasts, converts inside fused computations) and the
+# unscoped remainder (embedding, block norms, residual adds, the loop's
+# bookkeeping, the page-table arithmetic of the insert).  By COUNT, at toy
+# widths; what they cost on the chip is step_other_ms
+UNSCOPED_SHARE = {"_segment_impl": 0.4, "_prefill_chunk_impl": 0.6,
+                  "_admit_finish_impl": 0.4}
+
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*\n?",
+    re.M)
+_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
+_OPCODE = re.compile(
+    r"^\s*(?:ROOT )?%([^\s=]+) = (?:\([^=]*?\)|\S+) ([a-z][a-z\-]*)\(")
+_NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+
+
+def _mosaic_without_locations(b64: str) -> str:
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(b64))
+        asm = module.operation.get_asm(enable_debug_info=False)
+    return hashlib.sha256(asm.encode()).hexdigest()
+
+
+def strip_metadata(hlo: str) -> str:
+    """HLO text with all that is only metadata taken out: instruction
+    metadata, the stack-frame tables, and the locations inside each Mosaic
+    module (a Pallas call's serialized body is replaced by the hash of its
+    assembly printed without debug info).  Names, shapes, layouts,
+    operands and backend configs stay."""
+    hlo = _METADATA.sub("", _TABLES.sub("", hlo))
+    return _BODY.sub(
+        lambda m: f'"body":"{_mosaic_without_locations(m.group(1))}"', hlo)
+
+
+@pytest.fixture(scope="module")
+def compiled(v5e):
+    """``{family: {program: HLO text}}`` for the described device, each
+    program at the shapes ``ServeLoop.serve_programs`` gives (a module's
+    fixture is set up before a test's own ``_quiet_cache``)."""
+    out = {}
+    with _cache_off():
+        for family, (make, chunk, *_) in FAMILIES.items():
+            cfg = make()
+            loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),
+                             num_slots=SLOTS, steps_per_sync=SCOPE_STEPS,
+                             decode_attention="flash", prefill_chunk=chunk,
+                             cache_layout="paged", kv_block_size=BLOCK)
+            out[family] = {
+                name: _compile(jitted, *_on(v5e, args), **static)
+                for name, (jitted, args, static)
+                in loop.serve_programs().items()}
+    return out
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_named_kernels_lie_under_their_routine(compiled, family):
+    """Every Pallas call of segment and chunk keeps the instruction name a
+    trace reader matches (``%<kernel>.<n> =``) and carries the scope the
+    table gives its routine."""
+    _, _, kernels, _, _ = FAMILIES[family]
+    seen = set()
+    for program in PROGRAMS[:2]:
+        text = compiled[family][program]
+        scopes = hlo_scopes(text)
+        for line in text.splitlines():
+            m = _OPCODE.match(line)
+            if m and "tpu_custom_call" in line:
+                name = re.sub(r"[.\d]+$", "", m.group(1))
+                assert scopes.get(m.group(1)) == kernels[name], (
+                    program, m.group(1))
+                seen.add(name)
+    assert seen == set(kernels)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_routine_the_family_has_is_scoped(compiled, family):
+    _, _, _, routines, _ = FAMILIES[family]
+    found = set()
+    for program in PROGRAMS:
+        found |= set(hlo_scopes(compiled[family][program]).values())
+    assert found == routines
+    assert found <= set(obs.ROUTINE_SCOPES)
+    # the finish moves the prefilled rows into the pool and picks the token
+    assert set(hlo_scopes(compiled[family][PROGRAMS[2]]).values()) == {
+        "attn/cache", "head"}
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_unscoped_instructions_stay_under_the_stated_share(
+        compiled, family, program):
+    text = compiled[family][program]
+    scopes = hlo_scopes(text)
+    work = [m.group(1) for m in map(_OPCODE.match, text.splitlines())
+            if m and m.group(2) not in _NO_WORK]
+    bare = sum(1 for name in work if name not in scopes)
+    assert work and bare / len(work) < UNSCOPED_SHARE[program], (
+        bare, len(work))
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_programs_without_metadata_are_the_parents(compiled, family,
+                                                   program):
+    """Operation for operation, name for name: the hash of the compiled
+    text with its metadata stripped is the one recorded from the commit
+    before the scopes (293f19f), on the same toy program."""
+    want = FAMILIES[family][4][PROGRAMS.index(program)]
+    text = strip_metadata(compiled[family][program])
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

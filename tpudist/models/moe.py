@@ -23,6 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tpudist.obs.spans import routine
 from tpudist.models.transformer import (
     AttentionFn,
     CausalSelfAttention,
@@ -263,26 +264,30 @@ class MoEMLP(nn.Module):
         first, count = moe.held or (0, e)
         if not (0 <= first and first + count <= e and count > 0):
             raise ValueError(f"held={moe.held} outside {e} experts")
-        logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
-                          name="router")(x)
-        bias = (self.param("router_bias", nn.initializers.zeros, (e,))
-                if moe.correction_bias else None)
-        weights, experts = route(logits, bias, moe)
+        with routine("mlp/route"):
+            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                              name="router")(x)
+            bias = (self.param("router_bias", nn.initializers.zeros, (e,))
+                    if moe.correction_bias else None)
+            weights, experts = route(logits, bias, moe)
         shape_up = (count, self.d_model, self.d_ff)
         init = nn.initializers.lecun_normal(batch_axis=0)
-        w_gate = self.param("w_gate", init, shape_up).astype(dt)
-        w_up = self.param("w_up", init, shape_up).astype(dt)
-        w_down = self.param(
-            "w_down", init, (count, self.d_ff, self.d_model)).astype(dt)
-        out, counts = grouped_gated_mlp(
-            x.astype(dt), w_gate, w_up, w_down, experts - first, weights,
-            num_experts=e)
+        with routine("mlp/experts"):
+            w_gate = self.param("w_gate", init, shape_up).astype(dt)
+            w_up = self.param("w_up", init, shape_up).astype(dt)
+            w_down = self.param(
+                "w_down", init, (count, self.d_ff, self.d_model)).astype(dt)
+            # its counting sort opens mlp/route, inside
+            out, counts = grouped_gated_mlp(
+                x.astype(dt), w_gate, w_up, w_down, experts - first,
+                weights, num_experts=e)
         self.sow("stats", "expert_tokens", counts,
                  reduce_fn=lambda a, b: a + b,
                  init_fn=lambda: jnp.zeros((count,), jnp.int32))
         if moe.n_shared:
-            out = out + GatedMLP(self.d_model, moe.n_shared * self.d_ff,
-                                 dt, name="shared")(x)
+            with routine("mlp/shared"):
+                out = out + GatedMLP(self.d_model, moe.n_shared * self.d_ff,
+                                     dt, name="shared")(x)
         return out, jnp.zeros((), jnp.float32)
 
     @nn.compact

@@ -38,6 +38,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import os
+import re
 import time
 from collections import deque
 from typing import Any, Optional, Sequence
@@ -239,6 +240,54 @@ def _kv_leaves(node: dict, prefix: str) -> list[str]:
     prefix and is left out here BY NAME (hence ``side_ikey``)."""
     return sorted(k[len(prefix) + 1:] for k in node
                   if k.startswith(prefix + "_") and k != "side_index")
+
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_OPERAND = re.compile(r"%([^\s,(){}]+)")
+
+
+def hlo_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: routine scope}`` of a compiled program's HLO
+    text, under the name a profiler's ``XLA Ops`` event begins with
+    (``%fusion.12 = ...``).  An instruction whose ``metadata={op_name=
+    ...}`` names one of ``obs.ROUTINE_SCOPES`` has that scope (a fusion
+    carries the ``op_name`` of the instruction it is named after).  One the
+    COMPILER made, with no ``op_name`` at all (the asynchronous copies and
+    slices that bring weights and tables next to their consumer, their
+    bitcasts and concatenations), takes the scope of what consumes it,
+    where every consumer has one and they agree: its time is that
+    routine's wait for its operands.  The program's own instructions
+    outside every scope stay out."""
+    scopes: dict[str, str] = {}
+    made, users = [], {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        op = _HLO_OP_NAME.search(line)
+        if op is None:
+            made.append(name)
+        else:
+            scope = obs.scope_of(op.group(1))
+            if scope:
+                scopes[name] = scope
+        for operand in set(_HLO_OPERAND.findall(line[m.end():])):
+            users.setdefault(operand, []).append(name)
+    left = made
+    while left:     # copy-start <- copy-done <- bitcast <- its consumer
+        still = []
+        for name in left:
+            found = {scopes.get(user) for user in users.get(name, ())}
+            if len(found) == 1 and None not in found:
+                scopes[name] = found.pop()
+            else:
+                still.append(name)
+        if len(still) == len(left):
+            break
+        left = still
+    return scopes
 
 
 def _bound_paged_walk(cache: Any, active) -> Any:
@@ -736,6 +785,9 @@ class ServeLoop:
         # VMEM) times the attention layers, each one call a step; 0 where
         # no kernel runs (the dense layout, the gather fallback)
         self._grid_rows = self._attn_layers = 0
+        # the device arrays _stamp_table makes a dispatch: a table a layer
+        self._table_copies = (len(self._paged_nodes(self.cache))
+                              if self.pool is not None else 0)
         if self.pool is not None and decode_attention == "flash":
             nodes = self._paged_nodes(self.cache)
             leaves = _kv_leaves(nodes[0], "paged")
@@ -1045,6 +1097,49 @@ class ServeLoop:
             return out
         return walk(cache)
 
+    def serve_programs(self) -> dict:
+        """``{program: (jitted, args, static kwargs)}`` of the compiled
+        serve programs at THIS loop's shapes, for ``.lower(*args,
+        **static)``: the segment and, with chunked admission, one prompt
+        chunk and the finish, under the names their runs carry in a trace
+        (``jit_<name>``).  Nothing is dispatched; the arguments are the
+        loop's own arrays or shapes."""
+        out = {"_segment_impl": (self._segment, (
+            self.params, self.cache, self._tok, self._active,
+            self._remaining, self._first, self._key, np.int32(self.steps),
+            np.bool_(False)), {})}
+        if self.chunked:
+            c = min(self.prefill_chunk, self.cfg.max_seq_len)
+            chunk = (self.params, self._blank1, np.zeros((1, c), np.int32),
+                     np.int32(0), np.int32(0))
+            out["_prefill_chunk_impl"] = (self._prefill_chunk, chunk,
+                                          {"chunk": c})
+            # the chunk hands back its cache, shaped as it came, and the
+            # one row of logits it was asked for
+            logits = jax.ShapeDtypeStruct((1, 1, self.cfg.vocab_size),
+                                          jnp.float32)
+            pages = (self._slot_pages(0) if self.pool is not None
+                     else _NO_PAGES)
+            out["_admit_finish_impl"] = (self._admit_finish, (
+                self.cache, self._tok, self._active, self._remaining,
+                self._first, self._blank1, logits, np.int32(0), np.int32(1),
+                np.int32(0), np.int32(1), pages, np.int32(0), self._key),
+                {})
+        return out
+
+    def scope_map(self) -> dict[str, dict[str, str]]:
+        """``{program: {instruction name: routine scope}}`` from the
+        compiled programs' HLO text (:func:`hlo_scopes`): how a reader of a
+        device trace, whose ``XLA Ops`` events carry an instruction's text
+        and no ``op_name``, gets from an event inside a run of ``program``
+        to its routine.  Built on demand by lowering and compiling
+        :meth:`serve_programs` (a persistent compilation cache answers for
+        programs this process already compiled); never on the serving
+        path."""
+        return {name: hlo_scopes(
+            jitted.lower(*args, **static).compile().as_text())
+            for name, (jitted, args, static) in self.serve_programs().items()}
+
     def _stamp_table(self) -> None:
         """Push the host allocator's page table into the device carry.
         Each layer gets a FRESH device array: the segment donates the
@@ -1117,19 +1212,20 @@ class ServeLoop:
                 X = X + jnp.stack([
                     mut["stats"][f"block{b}"]["moe"]["expert_tokens"]
                     for b in experts])
-            last = logits[:, -1]
-            last = jnp.where(poison, jnp.full_like(last, jnp.nan), last)
-            # integrity guard: freeze (not emit) lanes whose logits are
-            # no longer finite — overflowed accumulator, scrambled KV
-            # page, injected fault — so corruption surfaces as a
-            # verdict instead of as plausible-looking tokens
-            bad = active & ~jnp.all(jnp.isfinite(last), axis=-1)
-            corrupt = corrupt | bad
-            active = active & ~bad
-            key, sk = jax.random.split(key)
-            nxt = self._select(last, sk).astype(jnp.int32)
-            emit = jnp.where(active, nxt, pad)
-            E = lax.dynamic_update_slice(E, emit[:, None], (0, i))
+            with obs.routine("head"):
+                last = logits[:, -1]
+                last = jnp.where(poison, jnp.full_like(last, jnp.nan), last)
+                # integrity guard: freeze (not emit) lanes whose logits
+                # are no longer finite — overflowed accumulator, scrambled
+                # KV page, injected fault — so corruption surfaces as a
+                # verdict instead of as plausible-looking tokens
+                bad = active & ~jnp.all(jnp.isfinite(last), axis=-1)
+                corrupt = corrupt | bad
+                active = active & ~bad
+                key, sk = jax.random.split(key)
+                nxt = self._select(last, sk).astype(jnp.int32)
+                emit = jnp.where(active, nxt, pad)
+                E = lax.dynamic_update_slice(E, emit[:, None], (0, i))
             remaining = remaining - active.astype(jnp.int32)
             hit_stop = (jnp.isin(nxt, stop_arr)
                         if stop_arr is not None
@@ -1143,7 +1239,8 @@ class ServeLoop:
         corrupt0 = jnp.zeros((self.B,), bool)
         E0 = jnp.full((self.B, self.steps), pad, jnp.int32)
         X0 = jnp.zeros((len(experts), self._held), jnp.int32)
-        cache = _bound_paged_walk(cache, active)
+        with obs.routine("attn/cache"):
+            cache = _bound_paged_walk(cache, active)
         (_, cache, tok, active, remaining, lived, corrupt, key,
          E, X) = lax.while_loop(
             cond, step,
@@ -1153,7 +1250,8 @@ class ServeLoop:
             # side -> main merge INSIDE the segment executable: one
             # dispatch per wave instead of two, and XLA can overlap
             # the merge with the tail of the loop
-            cache = self._merge_impl(cache, lived)
+            with obs.routine("attn/cache"):
+                cache = self._merge_impl(cache, lived)
         # column 0 carries the admission-deferred first tokens so ONE
         # host fetch resolves them together with the segment's emits
         emits = jnp.concatenate([first[:, None], E], axis=1)
@@ -1175,9 +1273,10 @@ class ServeLoop:
         cache, logits = _prefill(self._prefill_model, params, self._blank1,
                                  prompt_padded, true_chunk)
         cache = _set_cache_index(cache, true_len)
-        last = logits[0, true_len - 1 - (prompt_padded.shape[1]
-                                         - logits.shape[1])]
-        first = self._select(last[None, :], key)[0].astype(jnp.int32)
+        with obs.routine("head"):
+            last = logits[0, true_len - 1 - (prompt_padded.shape[1]
+                                             - logits.shape[1])]
+            first = self._select(last[None, :], key)[0].astype(jnp.int32)
         return cache, first
 
     def _insert_impl(self, cache, cache1, slot, true_len, pages,
@@ -1303,8 +1402,9 @@ class ServeLoop:
             params, prompt_padded, true_len, key, true_chunk=true_chunk)
         width = prompt_padded.shape[1]
         chunk = min(true_chunk, width)
-        cache = self._insert_impl(cache, cache1, slot, true_len, pages,
-                                  off_last=(width - 1) // chunk * chunk)
+        with obs.routine("attn/cache"):
+            cache = self._insert_impl(cache, cache1, slot, true_len, pages,
+                                      off_last=(width - 1) // chunk * chunk)
         tok = tok.at[slot].set(first)
         act = max_new > 1
         if self._stop is not None:
@@ -1364,7 +1464,8 @@ class ServeLoop:
             {"params": params, "cache": cache1}, toks,
             positions=off + jnp.arange(chunk)[None, :], mutable=["cache"])
         if row is not None:
-            logits = lax.dynamic_slice_in_dim(logits, row, 1, axis=1)
+            with obs.routine("head"):
+                logits = lax.dynamic_slice_in_dim(logits, row, 1, axis=1)
         return mut["cache"], logits
 
     def _admit_finish_impl(self, cache, tok, active, remaining, first_buf,
@@ -1376,13 +1477,15 @@ class ServeLoop:
         LAST chunk's logits (position ``true_len - 1`` lives at row
         ``true_len - 1 - off`` of that chunk; a chunk program that was
         told the row hands back that row alone), stamp the lane."""
-        cache1 = _set_cache_index(cache1, true_len)
-        cache = self._insert_impl(cache, cache1, slot, true_len, pages,
-                                  write_block=write_block, off_last=off)
-        last = (logits[0, 0] if logits.shape[1] == 1
-                else lax.dynamic_index_in_dim(
-                    logits[0], true_len - 1 - off, keepdims=False))
-        first = self._select(last[None, :], key)[0].astype(jnp.int32)
+        with obs.routine("attn/cache"):
+            cache1 = _set_cache_index(cache1, true_len)
+            cache = self._insert_impl(cache, cache1, slot, true_len, pages,
+                                      write_block=write_block, off_last=off)
+        with obs.routine("head"):
+            last = (logits[0, 0] if logits.shape[1] == 1
+                    else lax.dynamic_index_in_dim(
+                        logits[0], true_len - 1 - off, keepdims=False))
+            first = self._select(last[None, :], key)[0].astype(jnp.int32)
         tok = tok.at[slot].set(first)
         act = max_new > 1
         if self._stop is not None:
@@ -3028,7 +3131,11 @@ class ServeLoop:
                         # blocks this segment's growth let go of
                         windowed = (rows_w, rows_w_live,
                                     wg.released - released)
-                    self._stamp_table()
+                    # a span of its own: the copies are the suspect of the
+                    # stalls inside serve/segment_plan (ROADMAP S6)
+                    with obs.span("serve/segment_stamp", seq=seq,
+                                  copies=self._table_copies):
+                        self._stamp_table()
             # the segment splits per-step keys and returns the advanced
             # key — no per-wave host-side split dispatch needed
             t_disp = time.perf_counter()

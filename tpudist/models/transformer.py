@@ -32,6 +32,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tpudist.obs.spans import routine
+
 # (q, k, v, *, causal, window=None) on [batch, seq, heads, head_dim]
 # arrays -> out shaped like q.  ``window`` is the sliding-window width
 # (None = full causal attention); implementations may reject it.
@@ -585,36 +587,37 @@ class CausalSelfAttention(nn.Module):
                  positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         cfg = self.cfg
         b, s, _ = x.shape
-        if cfg.kv_heads == cfg.num_heads:
-            qkv = nn.Dense(3 * cfg.attn_dim, use_bias=False,
-                           dtype=cfg.compute_dtype, name="qkv")(x)
-            qkv = qkv.reshape(b, s, 3, cfg.num_heads, cfg.head_dim)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:  # GQA: separate projections, K/V at the grouped head count
-            q = nn.Dense(cfg.attn_dim, use_bias=False,
-                         dtype=cfg.compute_dtype, name="q")(x)
-            q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
-            kv = nn.Dense(2 * cfg.kv_heads * cfg.head_dim, use_bias=False,
-                          dtype=cfg.compute_dtype, name="kv")(x)
-            kv = kv.reshape(b, s, 2, cfg.kv_heads, cfg.head_dim)
-            k, v = kv[:, :, 0], kv[:, :, 1]
-        if cfg.qk_norm:
-            q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
-                           name="q_norm")(q)
-            k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
-                           name="k_norm")(k)
-        if cfg.positions == "rotary":
-            # keys are cached AFTER the rotation, at their own positions
-            if positions is None:
-                positions = jnp.arange(s)[None, :]
-            rd = cfg.rope_dim or cfg.head_dim
-            sc = cfg.layer_rope_scaling(self.layer)
-            q = jnp.concatenate(
-                [apply_rope(q[..., :rd], positions, cfg, sc),
-                 q[..., rd:]], -1)
-            k = jnp.concatenate(
-                [apply_rope(k[..., :rd], positions, cfg, sc),
-                 k[..., rd:]], -1)
+        with routine("attn/proj"):
+            if cfg.kv_heads == cfg.num_heads:
+                qkv = nn.Dense(3 * cfg.attn_dim, use_bias=False,
+                               dtype=cfg.compute_dtype, name="qkv")(x)
+                qkv = qkv.reshape(b, s, 3, cfg.num_heads, cfg.head_dim)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            else:  # GQA: separate projections, K/V at the grouped head count
+                q = nn.Dense(cfg.attn_dim, use_bias=False,
+                             dtype=cfg.compute_dtype, name="q")(x)
+                q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+                kv = nn.Dense(2 * cfg.kv_heads * cfg.head_dim, use_bias=False,
+                              dtype=cfg.compute_dtype, name="kv")(x)
+                kv = kv.reshape(b, s, 2, cfg.kv_heads, cfg.head_dim)
+                k, v = kv[:, :, 0], kv[:, :, 1]
+            if cfg.qk_norm:
+                q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                               name="q_norm")(q)
+                k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                               name="k_norm")(k)
+            if cfg.positions == "rotary":
+                # keys are cached AFTER the rotation, at their own positions
+                if positions is None:
+                    positions = jnp.arange(s)[None, :]
+                rd = cfg.rope_dim or cfg.head_dim
+                sc = cfg.layer_rope_scaling(self.layer)
+                q = jnp.concatenate(
+                    [apply_rope(q[..., :rd], positions, cfg, sc),
+                     q[..., rd:]], -1)
+                k = jnp.concatenate(
+                    [apply_rope(k[..., :rd], positions, cfg, sc),
+                     k[..., rd:]], -1)
         # cfg is the single source of truth for the sliding window: a
         # factory built with its OWN window (flash_attention_fn(window=W))
         # that disagrees is rejected — in BOTH branches, since the decode
@@ -633,45 +636,51 @@ class CausalSelfAttention(nn.Module):
                 raise ValueError("an indexer chooses among the rows before "
                                  "a query: causal attention only")
             hi, di = cfg.index_heads, cfg.index_head_dim
-            # index queries and the one index key a token, every feature
-            # rotated at the token's position by plain frequencies
-            q_i = apply_rope(
-                nn.Dense(hi * di, use_bias=False, dtype=cfg.compute_dtype,
-                         name="idx_q")(x).reshape(b, s, hi, di),
-                positions, cfg, None)
-            k_i = apply_rope(
-                nn.LayerNorm(epsilon=1e-6, dtype=cfg.compute_dtype,
-                             name="idx_k_norm")(
-                    nn.Dense(di, use_bias=False, dtype=cfg.compute_dtype,
-                             name="idx_k")(x)),
-                positions, cfg, None)
-            w_i = nn.Dense(hi, use_bias=False, dtype=jnp.float32,
-                           param_dtype=jnp.float32, name="idx_w")(
-                x) * (hi ** -0.5 * di ** -0.5)
+            with routine("attn/proj"):
+                # index queries and the one index key a token, every feature
+                # rotated at the token's position by plain frequencies
+                q_i = apply_rope(
+                    nn.Dense(hi * di, use_bias=False, dtype=cfg.compute_dtype,
+                             name="idx_q")(x).reshape(b, s, hi, di),
+                    positions, cfg, None)
+                k_i = apply_rope(
+                    nn.LayerNorm(epsilon=1e-6, dtype=cfg.compute_dtype,
+                                 name="idx_k_norm")(
+                        nn.Dense(di, use_bias=False, dtype=cfg.compute_dtype,
+                                 name="idx_k")(x)),
+                    positions, cfg, None)
+                w_i = nn.Dense(hi, use_bias=False, dtype=jnp.float32,
+                               param_dtype=jnp.float32, name="idx_w")(
+                    x) * (hi ** -0.5 * di ** -0.5)
             index = (q_i, w_i, k_i)
         if self.decode:
             if index is not None:
                 # the cached key's width (index_cache_width): zero columns
                 pad = [(0, 0)] * 3 + [(0, cfg.index_cache_width - di)]
-                index = (jnp.pad(q_i, pad), w_i, jnp.pad(k_i, pad[1:]))
+                with routine("attn/proj"):
+                    index = (jnp.pad(q_i, pad), w_i, jnp.pad(k_i, pad[1:]))
             out = self._cached_attend(q, k, v, index)
         elif index is not None and s > cfg.index_topk:
             # the one-shot forward: the chosen rows as a mask
-            mask = _index_choice(
-                q_i, w_i, k_i, jnp.broadcast_to(
-                    jnp.tril(jnp.ones((s, s), bool)), (b, s, s)),
-                cfg.index_topk)
-            out = _masked_attend(q, *repeat_kv(q, k, v), mask[:, None])
+            with routine("attn/index"):
+                mask = _index_choice(
+                    q_i, w_i, k_i, jnp.broadcast_to(
+                        jnp.tril(jnp.ones((s, s), bool)), (b, s, s)),
+                    cfg.index_topk)
+            with routine("attn/core"):
+                out = _masked_attend(q, *repeat_kv(q, k, v), mask[:, None])
         else:
             # window passed unconditionally (None = full causal) so the
             # training path can never diverge from the decode cache mask,
             # and a fn that doesn't accept the kwarg fails loudly instead
             # of training full-attention against a windowed decode cache.
-            out = self.attention_fn(q, k, v, causal=causal,
-                                    window=self.window)
-        out = out.reshape(b, s, cfg.attn_dim)
-        return nn.Dense(cfg.embed_dim, use_bias=False,
-                        dtype=cfg.compute_dtype, name="proj")(out)
+            with routine("attn/core"):
+                out = self.attention_fn(q, k, v, causal=causal,
+                                        window=self.window)
+        with routine("attn/proj"):
+            out = out.reshape(b, s, cfg.attn_dim)
+            return nn.Dense(cfg.embed_dim, use_bias=False,
+                            dtype=cfg.compute_dtype, name="proj")(out)
 
     def _cached_attend(self, q, k, v, index=None):
         """Decoding against a KV cache of ``max_seq_len`` slots (the
@@ -734,23 +743,24 @@ class CausalSelfAttention(nn.Module):
             # and is INSERTED (serving._insert).
             return self._serve_attend(
                 q, k, v, cached_k, cached_v, idx_var)
-        k_all = jax.lax.dynamic_update_slice(
-            cached_k.value,
-            k.reshape(b, s, flat).astype(cached_k.value.dtype),
-            (0, idx, 0))
-        v_all = jax.lax.dynamic_update_slice(
-            cached_v.value,
-            v.reshape(b, s, flat).astype(cached_v.value.dtype),
-            (0, idx, 0))
-        cached_k.value, cached_v.value = k_all, v_all
-        idx_var.value = idx + s
-        if index is not None:
-            q_i, w_i, k_i = index
-            ikeys = jax.lax.dynamic_update_slice(
-                cached_ik.value, k_i.astype(cached_ik.value.dtype),
+        with routine("attn/cache"):
+            k_all = jax.lax.dynamic_update_slice(
+                cached_k.value,
+                k.reshape(b, s, flat).astype(cached_k.value.dtype),
                 (0, idx, 0))
-            cached_ik.value = ikeys
-            index = (q_i, w_i, ikeys)
+            v_all = jax.lax.dynamic_update_slice(
+                cached_v.value,
+                v.reshape(b, s, flat).astype(cached_v.value.dtype),
+                (0, idx, 0))
+            cached_k.value, cached_v.value = k_all, v_all
+            idx_var.value = idx + s
+            if index is not None:
+                q_i, w_i, k_i = index
+                ikeys = jax.lax.dynamic_update_slice(
+                    cached_ik.value, k_i.astype(cached_ik.value.dtype),
+                    (0, idx, 0))
+                cached_ik.value = ikeys
+                index = (q_i, w_i, ikeys)
 
         def view4(x):
             return x.reshape(b, cfg.max_seq_len, h_kv, d)
@@ -760,32 +770,36 @@ class CausalSelfAttention(nn.Module):
                                         index)
         if index is not None:
             # a scalar-index rollout's step: the chosen rows as a mask
-            mask = _index_choice(
-                *index, jnp.broadcast_to(
-                    jnp.arange(cfg.max_seq_len) <= idx,
-                    (b, 1, cfg.max_seq_len)), cfg.index_topk)
-            k4, v4 = repeat_kv(q, view4(k_all), view4(v_all))
-            return _masked_attend(q, k4, v4, mask[:, None])
-        if self.decode_attention == "flash":
-            from tpudist.ops.flash_decode import flash_decode
+            with routine("attn/index"):
+                mask = _index_choice(
+                    *index, jnp.broadcast_to(
+                        jnp.arange(cfg.max_seq_len) <= idx,
+                        (b, 1, cfg.max_seq_len)), cfg.index_topk)
+            with routine("attn/core"):
+                k4, v4 = repeat_kv(q, view4(k_all), view4(v_all))
+                return _masked_attend(q, k4, v4, mask[:, None])
+        with routine("attn/core"):
+            if self.decode_attention == "flash":
+                from tpudist.ops.flash_decode import flash_decode
 
-            if self.decode_shard is not None:
-                if _shard_kind(self.decode_shard) in ("seq", "heads_seq"):
-                    return _seq_sharded_decode(
+                if self.decode_shard is not None:
+                    if _shard_kind(self.decode_shard) in ("seq",
+                                                          "heads_seq"):
+                        return _seq_sharded_decode(
+                            self.decode_shard, q, k_all, v_all, idx + 1,
+                            self.window, h_kv)
+                    return _head_sharded_packed(
                         self.decode_shard, q, k_all, v_all, idx + 1,
                         self.window, h_kv)
-                return _head_sharded_packed(
-                    self.decode_shard, q, k_all, v_all, idx + 1,
-                    self.window, h_kv)
-            return flash_decode(q, k_all, v_all, idx + 1,
-                                window=self.window,
-                                packed_kv_heads=h_kv)
-        mask = jnp.arange(cfg.max_seq_len) <= idx            # causal: ≤ self
-        if self.window is not None:  # sliding window: last W only
-            mask = mask & (
-                idx - jnp.arange(cfg.max_seq_len) < self.window)
-        k4, v4 = repeat_kv(q, view4(k_all), view4(v_all))
-        return _masked_attend(q, k4, v4, mask[None, None, None, :])
+                return flash_decode(q, k_all, v_all, idx + 1,
+                                    window=self.window,
+                                    packed_kv_heads=h_kv)
+            mask = jnp.arange(cfg.max_seq_len) <= idx        # causal: ≤ self
+            if self.window is not None:  # sliding window: last W only
+                mask = mask & (
+                    idx - jnp.arange(cfg.max_seq_len) < self.window)
+            k4, v4 = repeat_kv(q, view4(k_all), view4(v_all))
+            return _masked_attend(q, k4, v4, mask[None, None, None, :])
 
     def _rolling_prefill(self, q, k, v):
         """Chunk prefill of a WINDOWED layer through a rolling buffer of
@@ -837,18 +851,21 @@ class CausalSelfAttention(nn.Module):
                 buf, new.reshape(b, s, flat).astype(buf.dtype),
                 (0, idx - base, 0))
 
-        k_all, v_all = put(cached_k, k), put(cached_v, v)
-        cached_k.value, cached_v.value = k_all, v_all
-        idx_var.value = idx + s
-        k4 = k_all.reshape(b, rows, h_kv, d)
-        v4 = v_all.reshape(b, rows, h_kv, d)
-        if self.decode_attention == "flash":
-            return _flash_prefill(q, k4, v4, idx, window=w, k_offset=base)
-        q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
-        k_pos = base + jnp.arange(rows)[None, :]              # [1, rows]
-        mask = (k_pos <= q_pos) & (q_pos - k_pos < w)
-        k4, v4 = repeat_kv(q, k4, v4)
-        return _masked_attend(q, k4, v4, mask[None, None])
+        with routine("attn/cache"):
+            k_all, v_all = put(cached_k, k), put(cached_v, v)
+            cached_k.value, cached_v.value = k_all, v_all
+            idx_var.value = idx + s
+        with routine("attn/core"):
+            k4 = k_all.reshape(b, rows, h_kv, d)
+            v4 = v_all.reshape(b, rows, h_kv, d)
+            if self.decode_attention == "flash":
+                return _flash_prefill(q, k4, v4, idx, window=w,
+                                      k_offset=base)
+            q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
+            k_pos = base + jnp.arange(rows)[None, :]              # [1, rows]
+            mask = (k_pos <= q_pos) & (q_pos - k_pos < w)
+            k4, v4 = repeat_kv(q, k4, v4)
+            return _masked_attend(q, k4, v4, mask[None, None])
 
     def _serve_attend(self, q, k, v, cached_k, cached_v, idx_var):
         """One decode step with PER-ROW cache positions: row ``r``'s K/V
@@ -890,38 +907,39 @@ class CausalSelfAttention(nn.Module):
         k_all, v_all = cached_k.value, cached_v.value
         kf = k.reshape(b, s, flat)
         vf = v.reshape(b, s, flat)
-        for r in range(b):
-            k_all = jax.lax.dynamic_update_slice(
-                k_all, kf[r:r + 1].astype(k_all.dtype), (r, at[r], 0))
-            v_all = jax.lax.dynamic_update_slice(
-                v_all, vf[r:r + 1].astype(v_all.dtype), (r, at[r], 0))
-        cached_k.value, cached_v.value = k_all, v_all
-        idx_var.value = idx + s
+        with routine("attn/cache"):
+            for r in range(b):
+                k_all = jax.lax.dynamic_update_slice(
+                    k_all, kf[r:r + 1].astype(k_all.dtype), (r, at[r], 0))
+                v_all = jax.lax.dynamic_update_slice(
+                    v_all, vf[r:r + 1].astype(v_all.dtype), (r, at[r], 0))
+            cached_k.value, cached_v.value = k_all, v_all
+            idx_var.value = idx + s
+        with routine("attn/core"):
+            n = idx + 1  # [B] valid lengths including the current token
+            if (s == 1 and self.decode_attention == "flash"
+                    and self.window is None):
+                from tpudist.ops.flash_decode import flash_decode
 
-        n = idx + 1  # [B] valid lengths including the current token
-        if (s == 1 and self.decode_attention == "flash"
-                and self.window is None):
-            from tpudist.ops.flash_decode import flash_decode
-
-            return flash_decode(q, k_all, v_all, n,
-                                packed_kv_heads=h_kv)
-        # NOTE: flash + attention_window falls back to the dense masked
-        # path here (the per-row kernel has no per-row window trim yet) —
-        # ServeLoop warns about the bandwidth cost at construction.
-        # Multi-query chunks (s > 1) are dense banded too: the chunk was
-        # just written to the main cache, so one banded mask covers main
-        # history and the in-chunk causal structure together (the flash
-        # s>1 wrapper exists for the sided/frozen-main-cache layout).
-        positions = jnp.arange(cfg.max_seq_len)[None, None, :]  # [1,1,S]
-        q_pos = idx[:, None] + jnp.arange(s)[None, :]           # [B, s]
-        mask = positions < (q_pos + 1)[:, :, None]              # [B,s,S]
-        if self.window is not None:
-            mask = mask & (q_pos[:, :, None] - positions
-                           < self.window)
-        k4 = k_all.reshape(b, cfg.max_seq_len, h_kv, d)
-        v4 = v_all.reshape(b, cfg.max_seq_len, h_kv, d)
-        k_rep, v_rep = repeat_kv(q, k4, v4)
-        return _masked_attend(q, k_rep, v_rep, mask[:, None])
+                return flash_decode(q, k_all, v_all, n,
+                                    packed_kv_heads=h_kv)
+            # NOTE: flash + attention_window falls back to the dense masked
+            # path here (the per-row kernel has no per-row window trim yet) —
+            # ServeLoop warns about the bandwidth cost at construction.
+            # Multi-query chunks (s > 1) are dense banded too: the chunk was
+            # just written to the main cache, so one banded mask covers main
+            # history and the in-chunk causal structure together (the flash
+            # s>1 wrapper exists for the sided/frozen-main-cache layout).
+            positions = jnp.arange(cfg.max_seq_len)[None, None, :]  # [1,1,S]
+            q_pos = idx[:, None] + jnp.arange(s)[None, :]           # [B, s]
+            mask = positions < (q_pos + 1)[:, :, None]              # [B,s,S]
+            if self.window is not None:
+                mask = mask & (q_pos[:, :, None] - positions
+                               < self.window)
+            k4 = k_all.reshape(b, cfg.max_seq_len, h_kv, d)
+            v4 = v_all.reshape(b, cfg.max_seq_len, h_kv, d)
+            k_rep, v_rep = repeat_kv(q, k4, v4)
+            return _masked_attend(q, k_rep, v_rep, mask[:, None])
 
     def _serve_attend_sided(self, q, k, v, cached_k, cached_v, idx_var):
         """The side-buffer serve step (see :meth:`_serve_attend`).
@@ -955,21 +973,25 @@ class CausalSelfAttention(nn.Module):
         # s > 1 writes a verify chunk (speculative decode); the chunk
         # lands contiguously and flash_decode's multi-query wrapper gives
         # query j visibility over side positions [0, side_idx + j].
-        s_at = jnp.minimum(side_idx.value, cap - s)
-        side_k.value = jax.lax.dynamic_update_slice(
-            side_k.value, k.reshape(b, s, flat).astype(side_k.value.dtype),
-            (0, s_at, 0))
-        side_v.value = jax.lax.dynamic_update_slice(
-            side_v.value, v.reshape(b, s, flat).astype(side_v.value.dtype),
-            (0, s_at, 0))
-        side_idx.value = side_idx.value + s
+        with routine("attn/cache"):
+            s_at = jnp.minimum(side_idx.value, cap - s)
+            side_k.value = jax.lax.dynamic_update_slice(
+                side_k.value,
+                k.reshape(b, s, flat).astype(side_k.value.dtype),
+                (0, s_at, 0))
+            side_v.value = jax.lax.dynamic_update_slice(
+                side_v.value,
+                v.reshape(b, s, flat).astype(side_v.value.dtype),
+                (0, s_at, 0))
+            side_idx.value = side_idx.value + s
 
         from tpudist.ops.flash_decode import flash_decode
 
-        return flash_decode(
-            q, cached_k.value, cached_v.value, idx_var.value,
-            side_k=side_k.value, side_v=side_v.value,
-            side_len=side_idx.value, packed_kv_heads=h_kv)
+        with routine("attn/core"):
+            return flash_decode(
+                q, cached_k.value, cached_v.value, idx_var.value,
+                side_k=side_k.value, side_v=side_v.value,
+                side_len=side_idx.value, packed_kv_heads=h_kv)
 
     def _paged_attend(self, q, k, v, index=None):
         """One decode step against the PAGED cache: K/V live in a shared
@@ -1065,32 +1087,36 @@ class CausalSelfAttention(nn.Module):
         side_idx = self.variable(
             "cache", "side_index", lambda: jnp.zeros((), jnp.int32))
         s_base = side_idx.value
-        s_at = jnp.minimum(s_base, cap - s)
-        side_k.value = jax.lax.dynamic_update_slice(
-            side_k.value,
-            k.reshape(b, s, flat).astype(side_k.value.dtype), (0, s_at, 0))
-        side_v.value = jax.lax.dynamic_update_slice(
-            side_v.value,
-            v.reshape(b, s, flat).astype(side_v.value.dtype), (0, s_at, 0))
-        side_idx.value = s_base + s
-        if index is not None:
-            q_i, w_i, k_i = index
-            side_ik = self.variable(
-                "cache", "side_ikey", jnp.zeros,
-                (b, cap, cfg.index_cache_width), cfg.compute_dtype)
-            side_ik.value = jax.lax.dynamic_update_slice(
-                side_ik.value, k_i.astype(side_ik.value.dtype),
+        with routine("attn/cache"):
+            s_at = jnp.minimum(s_base, cap - s)
+            side_k.value = jax.lax.dynamic_update_slice(
+                side_k.value,
+                k.reshape(b, s, flat).astype(side_k.value.dtype),
                 (0, s_at, 0))
+            side_v.value = jax.lax.dynamic_update_slice(
+                side_v.value,
+                v.reshape(b, s, flat).astype(side_v.value.dtype),
+                (0, s_at, 0))
+            side_idx.value = s_base + s
+            if index is not None:
+                q_i, w_i, k_i = index
+                side_ik = self.variable(
+                    "cache", "side_ikey", jnp.zeros,
+                    (b, cap, cfg.index_cache_width), cfg.compute_dtype)
+                side_ik.value = jax.lax.dynamic_update_slice(
+                    side_ik.value, k_i.astype(side_ik.value.dtype),
+                    (0, s_at, 0))
 
         if self.decode_attention == "flash":
             from tpudist.ops.flash_decode import paged_flash_decode
 
             def every_row():
-                return paged_flash_decode(
-                    q, paged_k.value, paged_v.value, table.value, idx,
-                    packed_kv_heads=h_kv, side_k=side_k.value,
-                    side_v=side_v.value, side_len=side_idx.value,
-                    window=window)
+                with routine("attn/core"):
+                    return paged_flash_decode(
+                        q, paged_k.value, paged_v.value, table.value, idx,
+                        packed_kv_heads=h_kv, side_k=side_k.value,
+                        side_v=side_v.value, side_len=side_idx.value,
+                        window=window)
 
             if index is None:
                 return every_row()
@@ -1111,35 +1137,38 @@ class CausalSelfAttention(nn.Module):
         # [0, s_base + j] — causal within the chunk it just wrote
         from tpudist.ops.flash_decode import paged_gather_kv
 
-        k_main = paged_gather_kv(paged_k.value, table.value)
-        v_main = paged_gather_kv(paged_v.value, table.value)
-        s_all = k_main.shape[1]
-        live_main = jnp.arange(s_all)[None, :] < idx[:, None]
-        if window is not None:
-            # the query sits at idx + s_base: the side rows are all inside
-            # its window (cap <= window), the pool's from idx + s_base -
-            # window + 1 on
-            live_main = live_main & (
-                jnp.arange(s_all)[None, :]
-                > (idx + s_base - window)[:, None])
-        mask_main = jnp.broadcast_to(live_main[:, None],
-                                     (b, s, s_all))            # [B, s, S']
-        mask_side = jnp.broadcast_to(
-            jnp.arange(cap)[None, None, :]
-            < s_base + jnp.arange(s)[None, :, None] + 1,
-            (b, s, cap))                                       # [B, s, cap]
-        mask = jnp.concatenate([mask_main, mask_side], axis=2)
-        if index is not None:
-            ikeys = jnp.concatenate(
-                [paged_gather_kv(paged_ik.value, table.value),
-                 side_ik.value], axis=1)
-            mask = _index_choice(q_i, w_i, ikeys, mask, cfg.index_topk)
-        k_all = jnp.concatenate([k_main, side_k.value], axis=1)
-        v_all = jnp.concatenate([v_main, side_v.value], axis=1)
-        k4 = k_all.reshape(b, s_all + cap, h_kv, d)
-        v4 = v_all.reshape(b, s_all + cap, h_kv, d)
-        k_rep, v_rep = repeat_kv(q, k4, v4)
-        return _masked_attend(q, k_rep, v_rep, mask[:, None])
+        with routine("attn/core"):
+            k_main = paged_gather_kv(paged_k.value, table.value)
+            v_main = paged_gather_kv(paged_v.value, table.value)
+            s_all = k_main.shape[1]
+            live_main = jnp.arange(s_all)[None, :] < idx[:, None]
+            if window is not None:
+                # the query sits at idx + s_base: the side rows are all
+                # inside its window (cap <= window), the pool's from idx +
+                # s_base - window + 1 on
+                live_main = live_main & (
+                    jnp.arange(s_all)[None, :]
+                    > (idx + s_base - window)[:, None])
+            mask_main = jnp.broadcast_to(live_main[:, None],
+                                         (b, s, s_all))        # [B, s, S']
+            mask_side = jnp.broadcast_to(
+                jnp.arange(cap)[None, None, :]
+                < s_base + jnp.arange(s)[None, :, None] + 1,
+                (b, s, cap))                                   # [B, s, cap]
+            mask = jnp.concatenate([mask_main, mask_side], axis=2)
+            if index is not None:
+                with routine("attn/index"):
+                    ikeys = jnp.concatenate(
+                        [paged_gather_kv(paged_ik.value, table.value),
+                         side_ik.value], axis=1)
+                    mask = _index_choice(q_i, w_i, ikeys, mask,
+                                         cfg.index_topk)
+            k_all = jnp.concatenate([k_main, side_k.value], axis=1)
+            v_all = jnp.concatenate([v_main, side_v.value], axis=1)
+            k4 = k_all.reshape(b, s_all + cap, h_kv, d)
+            v4 = v_all.reshape(b, s_all + cap, h_kv, d)
+            k_rep, v_rep = repeat_kv(q, k4, v4)
+            return _masked_attend(q, k_rep, v_rep, mask[:, None])
 
     def _chosen_pages(self, q, q_i, w_i, k_pool, v_pool, ik_pool, table,
                       idx, side_k, side_v, side_ik, side_len):
@@ -1158,22 +1187,25 @@ class CausalSelfAttention(nn.Module):
         nb, bs_, flat = k_pool.shape
         b, cap = side_k.shape[:2]
         reach = table.shape[1] * bs_
-        main = paged_index_scores(q_i, w_i, ik_pool, table, idx)
-        staged = jnp.where(
-            jnp.arange(cap)[None, :] < side_len,
-            _index_scores(q_i[:, None], w_i[:, None], side_ik)[:, 0],
-            -jnp.inf)
-        ids = index_select(jnp.concatenate([main, staged], axis=1),
-                           cfg.index_topk)
-        pos = jnp.minimum(ids, reach - 1)
-        # a column's page out of the lane's table row by a masked sum over
-        # the row (a gather of one number a chosen row costs as much as
-        # the gather of the rows themselves)
-        page = jnp.sum(
-            jnp.where((pos // bs_)[..., None] == jnp.arange(table.shape[1]),
-                      table[:, None, :], 0), axis=-1)
-        rows = page * bs_ + pos % bs_
-        rows = jnp.where(ids >= reach, nb * bs_ + ids - reach, rows)
+        with routine("attn/index"):
+            main = paged_index_scores(q_i, w_i, ik_pool, table, idx)
+            staged = jnp.where(
+                jnp.arange(cap)[None, :] < side_len,
+                _index_scores(q_i[:, None], w_i[:, None], side_ik)[:, 0],
+                -jnp.inf)
+            ids = index_select(jnp.concatenate([main, staged], axis=1),
+                               cfg.index_topk)
+            pos = jnp.minimum(ids, reach - 1)
+            # a column's page out of the lane's table row by a masked sum
+            # over the row (a gather of one number a chosen row costs as
+            # much as the gather of the rows themselves)
+            page = jnp.sum(
+                jnp.where(
+                    (pos // bs_)[..., None] == jnp.arange(table.shape[1]),
+                    table[:, None, :], 0), axis=-1)
+            rows = page * bs_ + pos % bs_
+            rows = jnp.where(ids >= reach, nb * bs_ + ids - reach, rows)
+        # the gathers open attn/rows and the kernel attn/core, inside
         out = sparse_gqa_attend(
             q[:, 0], k_pool.reshape(nb * bs_, flat),
             v_pool.reshape(nb * bs_, flat), rows,
@@ -1199,12 +1231,14 @@ class CausalSelfAttention(nn.Module):
         q_i, w_i, ikeys = index
         page = block_of(n, 8)
         tq = index_queries_per_row(s, cfg.index_heads, n)
-        seen = idx + (jnp.arange(s // tq) + 1) * tq
-        scores = paged_index_scores(
-            q_i[0], w_i[0], ikeys[0].reshape(n // page, page, -1),
-            jnp.arange(n // page)[None], seen)                   # [s, n]
-        mask = index_select_mask(scores, cfg.index_topk, rows=idx + s)
-        return flash_chosen_rows(q, k_all, v_all, mask, idx)
+        with routine("attn/index"):
+            seen = idx + (jnp.arange(s // tq) + 1) * tq
+            scores = paged_index_scores(
+                q_i[0], w_i[0], ikeys[0].reshape(n // page, page, -1),
+                jnp.arange(n // page)[None], seen)               # [s, n]
+            mask = index_select_mask(scores, cfg.index_topk, rows=idx + s)
+        with routine("attn/core"):
+            return flash_chosen_rows(q, k_all, v_all, mask, idx)
 
     def _prefill_attend(self, q, k_all, v_all, idx, index=None):
         """Chunk prefill: queries at global positions [idx, idx+s) attend
@@ -1221,18 +1255,24 @@ class CausalSelfAttention(nn.Module):
                 raise ValueError(
                     "a model with an indexer prefills through the "
                     "replicated batch-1 cache")
+            def every_row():
+                with routine("attn/core"):
+                    return _flash_prefill(q, k_all, v_all, idx)
+
             return jax.lax.cond(
                 idx + s > cfg.index_topk,
                 lambda: self._chosen_rows(q, k_all, v_all, idx, index),
-                lambda: _flash_prefill(q, k_all, v_all, idx))
+                every_row)
         if index is not None:
-            q_pos = idx + jnp.arange(s)[:, None]
-            valid = jnp.broadcast_to(
-                jnp.arange(cfg.max_seq_len)[None, :] <= q_pos,
-                (q.shape[0], s, cfg.max_seq_len))
-            mask = _index_choice(*index, valid, cfg.index_topk)
-            k_all, v_all = repeat_kv(q, k_all, v_all)
-            return _masked_attend(q, k_all, v_all, mask[:, None])
+            with routine("attn/index"):
+                q_pos = idx + jnp.arange(s)[:, None]
+                valid = jnp.broadcast_to(
+                    jnp.arange(cfg.max_seq_len)[None, :] <= q_pos,
+                    (q.shape[0], s, cfg.max_seq_len))
+                mask = _index_choice(*index, valid, cfg.index_topk)
+            with routine("attn/core"):
+                k_all, v_all = repeat_kv(q, k_all, v_all)
+                return _masked_attend(q, k_all, v_all, mask[:, None])
         seq_sharded = (self.decode_shard is not None
                        and _shard_kind(self.decode_shard)
                        in ("seq", "heads_seq"))
@@ -1241,17 +1281,18 @@ class CausalSelfAttention(nn.Module):
         # partitions into per-shard partial attention + reductions
         # (measured HLO: no cache all-gather), while a Pallas call cannot
         # be partitioned at all
-        if self.decode_attention == "flash" and not seq_sharded:
-            return _flash_prefill(q, k_all, v_all, idx,
-                                  window=self.window,
-                                  decode_shard=self.decode_shard)
-        q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
-        k_pos = jnp.arange(cfg.max_seq_len)[None, :]          # [1, S]
-        mask = k_pos <= q_pos
-        if self.window is not None:
-            mask = mask & (q_pos - k_pos < self.window)
-        k_all, v_all = repeat_kv(q, k_all, v_all)
-        return _masked_attend(q, k_all, v_all, mask[None, None])
+        with routine("attn/core"):
+            if self.decode_attention == "flash" and not seq_sharded:
+                return _flash_prefill(q, k_all, v_all, idx,
+                                      window=self.window,
+                                      decode_shard=self.decode_shard)
+            q_pos = idx + jnp.arange(s)[:, None]                  # [s, 1]
+            k_pos = jnp.arange(cfg.max_seq_len)[None, :]          # [1, S]
+            mask = k_pos <= q_pos
+            if self.window is not None:
+                mask = mask & (q_pos - k_pos < self.window)
+            k_all, v_all = repeat_kv(q, k_all, v_all)
+            return _masked_attend(q, k_all, v_all, mask[None, None])
 
 
 # rows of the latent cache the expanded prefill rebuilds keys and values
@@ -1324,21 +1365,24 @@ class LatentSelfAttention(nn.Module):
             positions = jnp.arange(s)[None, :]
         dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
         norm = functools.partial(nn.RMSNorm, epsilon=cfg.norm_eps, dtype=dt)
-        c_q = norm(name="q_norm")(dense(m.q_lora_rank, name="q_a")(x))
-        q = dense(h * m.qk_head_dim, name="q_b")(c_q).reshape(
-            b, s, h, m.qk_head_dim)
-        q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:],
-                                                   positions, cfg)
-        kv_a = dense(m.kv_lora_rank + m.qk_rope_head_dim, name="kv_a")(x)
-        c_kv = norm(name="kv_norm")(kv_a[..., : m.kv_lora_rank])
-        k_rope = apply_rope(kv_a[..., m.kv_lora_rank:], positions, cfg)
-        pad = m.cache_width - m.kv_lora_rank - m.qk_rope_head_dim
-        row = jnp.concatenate(
-            [c_kv, k_rope, jnp.zeros((b, s, pad), c_kv.dtype)], -1)
-        # [kv_lora, H, nope | v]: applied to latent rows (expanded) or to
-        # the queries and the attended latent (absorbed)
-        w_kvb = _Kernel((m.kv_lora_rank, h * (nope + dv)), dt,
-                        name="kv_b")().reshape(m.kv_lora_rank, h, nope + dv)
+        with routine("attn/proj"):
+            c_q = norm(name="q_norm")(dense(m.q_lora_rank, name="q_a")(x))
+            q = dense(h * m.qk_head_dim, name="q_b")(c_q).reshape(
+                b, s, h, m.qk_head_dim)
+            q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:],
+                                                       positions, cfg)
+            kv_a = dense(m.kv_lora_rank + m.qk_rope_head_dim,
+                         name="kv_a")(x)
+            c_kv = norm(name="kv_norm")(kv_a[..., : m.kv_lora_rank])
+            k_rope = apply_rope(kv_a[..., m.kv_lora_rank:], positions, cfg)
+            pad = m.cache_width - m.kv_lora_rank - m.qk_rope_head_dim
+            row = jnp.concatenate(
+                [c_kv, k_rope, jnp.zeros((b, s, pad), c_kv.dtype)], -1)
+            # [kv_lora, H, nope | v]: applied to latent rows (expanded) or
+            # to the queries and the attended latent (absorbed)
+            w_kvb = _Kernel(
+                (m.kv_lora_rank, h * (nope + dv)), dt,
+                name="kv_b")().reshape(m.kv_lora_rank, h, nope + dv)
 
         if not self.decode:
             out = self._expanded(q_nope, q_rope, row, w_kvb, 0, causal)
@@ -1350,7 +1394,9 @@ class LatentSelfAttention(nn.Module):
             raise ValueError(
                 f"cache_layout must be 'dense' or 'paged', got "
                 f"{self.cache_layout!r}")
-        return dense(cfg.embed_dim, name="proj")(out.reshape(b, s, h * dv))
+        with routine("attn/proj"):
+            return dense(cfg.embed_dim, name="proj")(
+                out.reshape(b, s, h * dv))
 
     def _expanded(self, q_nope, q_rope, rows, w_kvb, idx, causal=True):
         """MHA of queries at positions ``idx + [0, s)`` over the latent
@@ -1358,22 +1404,25 @@ class LatentSelfAttention(nn.Module):
         cfg, m = self.cfg, self.cfg.mla
         b, r, _ = rows.shape
         h, nope = cfg.num_heads, m.qk_nope_head_dim
-        kv = jnp.einsum("brc,chd->brhd", rows[..., : m.kv_lora_rank], w_kvb)
-        k_rope = rows[..., m.kv_lora_rank:
-                      m.kv_lora_rank + m.qk_rope_head_dim]
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_rope[:, :, None, :],
-                              (b, r, h, m.qk_rope_head_dim))], -1)
-        v = kv[..., nope:]
-        q = jnp.concatenate([q_nope, q_rope], -1)
-        if self.decode and self.decode_attention == "flash":
-            return _flash_prefill(q, k, v, idx, scale=self.softmax_scale)
-        mask = None
-        if causal:
-            q_pos = idx + jnp.arange(q.shape[1])[:, None]
-            mask = (jnp.arange(r)[None, :] <= q_pos)[None, None]
-        return _masked_attend(q, k, v, mask, scale=self.softmax_scale)
+        with routine("attn/proj"):
+            kv = jnp.einsum("brc,chd->brhd", rows[..., : m.kv_lora_rank],
+                            w_kvb)
+            k_rope = rows[..., m.kv_lora_rank:
+                          m.kv_lora_rank + m.qk_rope_head_dim]
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (b, r, h, m.qk_rope_head_dim))], -1)
+            v = kv[..., nope:]
+            q = jnp.concatenate([q_nope, q_rope], -1)
+        with routine("attn/core"):
+            if self.decode and self.decode_attention == "flash":
+                return _flash_prefill(q, k, v, idx, scale=self.softmax_scale)
+            mask = None
+            if causal:
+                q_pos = idx + jnp.arange(q.shape[1])[:, None]
+                mask = (jnp.arange(r)[None, :] <= q_pos)[None, None]
+            return _masked_attend(q, k, v, mask, scale=self.softmax_scale)
 
     def _cached_expanded(self, q_nope, q_rope, row, w_kvb):
         """Prefill (and scalar-index rollouts) through a dense latent
@@ -1394,10 +1443,11 @@ class LatentSelfAttention(nn.Module):
             raise ValueError(
                 "latent attention serves per-row positions through "
                 "cache_layout='paged' only")
-        rows = jax.lax.dynamic_update_slice(
-            cached.value, row.astype(cached.value.dtype), (0, idx, 0))
-        cached.value = rows
-        idx_var.value = idx + s
+        with routine("attn/cache"):
+            rows = jax.lax.dynamic_update_slice(
+                cached.value, row.astype(cached.value.dtype), (0, idx, 0))
+            cached.value = rows
+            idx_var.value = idx + s
         buckets = []
         r = min(S, _MLA_PREFILL_ROWS)
         while r < S:
@@ -1457,44 +1507,50 @@ class LatentSelfAttention(nn.Module):
         side_idx = self.variable(
             "cache", "side_index", lambda: jnp.zeros((), jnp.int32))
         s_base = side_idx.value
-        side.value = jax.lax.dynamic_update_slice(
-            side.value, row.astype(side.value.dtype),
-            (0, jnp.minimum(s_base, cap - 1), 0))
-        side_idx.value = s_base + 1
+        with routine("attn/cache"):
+            side.value = jax.lax.dynamic_update_slice(
+                side.value, row.astype(side.value.dtype),
+                (0, jnp.minimum(s_base, cap - 1), 0))
+            side_idx.value = s_base + 1
 
-        # absorb W_kvb^K into the queries: [B, H, nope] x [lat, H, nope]
-        q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_kvb[..., :nope])
-        q_row = jnp.concatenate(
-            [q_abs, q_rope[:, 0],
-             jnp.zeros((b, cfg.num_heads, w - lat - m.qk_rope_head_dim),
-                       q_abs.dtype)], -1)                   # [B, H, W]
+        with routine("attn/proj"):
+            # absorb W_kvb^K into the queries: [B, H, nope] x [lat, H, nope]
+            q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0],
+                               w_kvb[..., :nope])
+            q_row = jnp.concatenate(
+                [q_abs, q_rope[:, 0],
+                 jnp.zeros((b, cfg.num_heads, w - lat - m.qk_rope_head_dim),
+                           q_abs.dtype)], -1)               # [B, H, W]
         if self.decode_attention == "flash":
             from tpudist.ops.flash_decode import paged_mla_decode
 
-            o_lat = paged_mla_decode(
-                q_row, pool.value, table.value, idx, d_v=lat,
-                scale=self.softmax_scale, side=side.value,
-                side_len=side_idx.value)
+            with routine("attn/core"):
+                o_lat = paged_mla_decode(
+                    q_row, pool.value, table.value, idx, d_v=lat,
+                    scale=self.softmax_scale, side=side.value,
+                    side_len=side_idx.value)
         else:
             # dense fallback (CPU / tests): gather the lane's pages and
             # mask pool + side positions
             from tpudist.ops.flash_decode import paged_gather_kv
 
-            main = paged_gather_kv(pool.value, table.value)
-            keys = jnp.concatenate([main, side.value], axis=1)
-            mask = jnp.concatenate(
-                [jnp.arange(main.shape[1])[None, :] < idx[:, None],
-                 jnp.broadcast_to(jnp.arange(cap)[None, :] < s_base + 1,
-                                  (b, cap))], axis=1)
-            logits = jnp.einsum(
-                "bhw,bkw->bhk", q_row, keys,
-                preferred_element_type=jnp.float32) * self.softmax_scale
-            logits = jnp.where(mask[:, None, :], logits,
-                               jnp.finfo(jnp.float32).min)
-            probs = jax.nn.softmax(logits, axis=-1).astype(keys.dtype)
-            o_lat = jnp.einsum("bhk,bkc->bhc", probs, keys[..., :lat])
-        out = jnp.einsum("bhc,chd->bhd", o_lat, w_kvb[..., nope:])
-        return out[:, None]
+            with routine("attn/core"):
+                main = paged_gather_kv(pool.value, table.value)
+                keys = jnp.concatenate([main, side.value], axis=1)
+                mask = jnp.concatenate(
+                    [jnp.arange(main.shape[1])[None, :] < idx[:, None],
+                     jnp.broadcast_to(jnp.arange(cap)[None, :] < s_base + 1,
+                                      (b, cap))], axis=1)
+                logits = jnp.einsum(
+                    "bhw,bkw->bhk", q_row, keys,
+                    preferred_element_type=jnp.float32) * self.softmax_scale
+                logits = jnp.where(mask[:, None, :], logits,
+                                   jnp.finfo(jnp.float32).min)
+                probs = jax.nn.softmax(logits, axis=-1).astype(keys.dtype)
+                o_lat = jnp.einsum("bhk,bkc->bhc", probs, keys[..., :lat])
+        with routine("attn/proj"):
+            out = jnp.einsum("bhc,chd->bhd", o_lat, w_kvb[..., nope:])
+            return out[:, None]
 
 
 class MLPBlock(nn.Module):
@@ -1505,15 +1561,16 @@ class MLPBlock(nn.Module):
         cfg = self.cfg
         dense = functools.partial(nn.Dense, use_bias=False,
                                   dtype=cfg.compute_dtype)
-        h = dense(cfg.ffn_dim, name="up")(x)
-        if cfg.mlp == "gelu":
-            h = nn.gelu(h)
-        elif cfg.mlp == "gated_silu":
-            h = nn.silu(dense(cfg.ffn_dim, name="gate")(x)) * h
-        else:
-            raise ValueError(f"mlp must be 'gelu' or 'gated_silu', got "
-                             f"{cfg.mlp!r}")
-        return dense(cfg.embed_dim, name="down")(h)
+        with routine("mlp/dense"):
+            h = dense(cfg.ffn_dim, name="up")(x)
+            if cfg.mlp == "gelu":
+                h = nn.gelu(h)
+            elif cfg.mlp == "gated_silu":
+                h = nn.silu(dense(cfg.ffn_dim, name="gate")(x)) * h
+            else:
+                raise ValueError(f"mlp must be 'gelu' or 'gated_silu', got "
+                                 f"{cfg.mlp!r}")
+            return dense(cfg.embed_dim, name="down")(h)
 
 
 class DecoderBlock(nn.Module):
@@ -1539,7 +1596,9 @@ class DecoderBlock(nn.Module):
         # NOTE: ``causal`` is positional (arg 2) so nn.remat can mark it
         # static (static_argnums) — keyword args would be traced.
         cfg = self.cfg
-        h = make_norm(cfg, "ln1")(x)
+        # a block's norm counts to the routine that reads it first
+        with routine("attn/proj"):
+            h = make_norm(cfg, "ln1")(x)
         cache_kw = dict(decode=self.decode,
                         decode_attention=self.decode_attention,
                         serve_side_slots=self.serve_side_slots,
@@ -1559,7 +1618,8 @@ class DecoderBlock(nn.Module):
                 layer=self.layer,
                 prefill_window_rows=self.prefill_window_rows, **cache_kw)
         x = x + attn(h, causal=causal, positions=positions)
-        h = make_norm(cfg, "ln2")(x)
+        with routine("mlp/route" if self.expert_layer else "mlp/dense"):
+            h = make_norm(cfg, "ln2")(x)
         if not self.expert_layer:
             return x + MLPBlock(cfg, name="mlp")(h)
         from tpudist.models.moe import MoEMLP
@@ -1714,7 +1774,8 @@ class TransformerLM(nn.Module):
                                      else None),
                               prefill_window_rows=self.prefill_window_rows,
                               name=f"block{i}")(x, causal, positions)
-        x = make_norm(cfg, "ln_f")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.compute_dtype, name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        with routine("head"):
+            x = make_norm(cfg, "ln_f")(x)
+            logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                              dtype=cfg.compute_dtype, name="lm_head")(x)
+            return logits.astype(jnp.float32)

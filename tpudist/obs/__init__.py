@@ -87,7 +87,13 @@ from tpudist.obs.registry import (
     hist_quantile,
     summarize,
 )
-from tpudist.obs.spans import SpanTracer, atomic_write_json
+from tpudist.obs.spans import (
+    ROUTINE_SCOPES,
+    SpanTracer,
+    atomic_write_json,
+    routine,
+    scope_of,
+)
 from tpudist.obs.tsdb import TSDB, FleetScraper
 from tpudist.obs.xla import (
     install_compile_telemetry,
@@ -113,6 +119,7 @@ __all__ = [
     "MetricsPublisher",
     "MetricsServer",
     "POSTMORTEM_SCHEMA",
+    "ROUTINE_SCOPES",
     "RequestEventLog",
     "SLOTracker",
     "SpanTracer",
@@ -142,7 +149,9 @@ __all__ = [
     "peak_tflops",
     "recorder",
     "registry",
+    "routine",
     "rules_hash",
+    "scope_of",
     "slo",
     "snapshot",
     "snapshot_to_jsonl",

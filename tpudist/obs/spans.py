@@ -19,9 +19,16 @@ Two accelerator-facing hooks:
   whichever span happens to be open when the queue drains.  Off by
   default: fencing serializes dispatch and is a measurement tool, not a
   production default.
-* every span is also wrapped in ``jax.profiler.TraceAnnotation`` when a
-  profiler trace is active, so spans appear as named regions inside the
-  XProf timeline captured by :func:`tpudist.utils.metrics.maybe_profile`.
+* every span is also wrapped in ``jax.profiler.TraceAnnotation``, so
+  while a profiler trace is active spans appear as named regions on the
+  trace's host plane, each with the scalar ``args`` it was given (``seq``,
+  ``steps``, ``slot``...) and ``pc_us``, the ``time.perf_counter()`` stamp
+  of its entry in microseconds: the one number that ties the ring's clock
+  to the profiler's (offset = median over the traced annotations of their
+  start on the trace less ``pc_us``), so that a :meth:`SpanTracer.complete`
+  span, which is never annotated, can be placed on the trace's time line
+  too.  Without an active session the annotation is handed the name alone:
+  an untraced run pays one flag read more than before.
 
 Spans stay importable and functional without a jax backend: both hooks
 degrade to no-ops when jax (or the annotation API) is unavailable.
@@ -33,13 +40,55 @@ import collections
 import contextlib
 import json
 import os
+import re
 import tempfile
 import threading
 import time
 
 from tpudist.utils.config import env_flag
 
-__all__ = ["SpanTracer", "atomic_write_json"]
+__all__ = ["ROUTINE_SCOPES", "SpanTracer", "atomic_write_json", "routine",
+           "scope_of"]
+
+# The routines of a compiled serve program, as ``jax.named_scope`` names
+# (metadata on the instructions' ``op_name``: no operation added or moved).
+# ONE vocabulary: the models open these and nothing else
+# (:func:`routine`), ``docs/OBSERVABILITY.md`` tabulates them, the
+# benchmark's readers sum a traced step's device time by them
+# (:func:`scope_of`).  What carries none reads as ``other``: embedding,
+# residual adds, the ``while_loop``'s bookkeeping, the expert counts.  A
+# block's norm counts to the routine that reads it first (``attn/proj``;
+# ``mlp/dense`` or, in an expert layer, ``mlp/route``).
+ROUTINE_SCOPES = (
+    "attn/proj",    # q/k/v/latent/output projections, q-k norms, rotary
+    "attn/cache",   # K/V/latent/index-key writes, the side -> pool merge
+    "attn/index",   # an indexer's scores, exact top-k, chosen columns
+    "attn/rows",    # the gathers of the chosen rows, staged rows patched in
+    "attn/core",    # the attention itself (a kernel, or the dense fallback)
+    "mlp/dense",    # MLPBlock
+    "mlp/route",    # router matmul, top-k, the counting sort
+    "mlp/experts",  # the grouped products and the combine
+    "mlp/shared",   # the shared expert
+    "head",         # final norm, lm_head, sampling, the emit buffer's write
+)
+_SCOPE_IN_OP_NAME = re.compile(
+    "(?:^|/)(" + "|".join(map(re.escape, ROUTINE_SCOPES)) + ")(?=/|$)")
+
+
+def routine(name: str):
+    """``jax.named_scope(name)`` for one of :data:`ROUTINE_SCOPES`."""
+    if name not in ROUTINE_SCOPES:
+        raise ValueError(f"{name!r} is not one of {ROUTINE_SCOPES}")
+    import jax
+
+    return jax.named_scope(name)
+
+
+def scope_of(op_name: str) -> str | None:
+    """The innermost of :data:`ROUTINE_SCOPES` on an instruction's
+    ``op_name`` (its name stack), or None."""
+    found = _SCOPE_IN_OP_NAME.findall(op_name)
+    return found[-1] if found else None
 
 
 def atomic_write_json(path: str | os.PathLike, doc,
@@ -66,11 +115,20 @@ def atomic_write_json(path: str | os.PathLike, doc,
     return path
 
 
-def _trace_annotation(name: str):
+def _trace_annotation(name: str, start: float, args: dict):
+    """The profiler's annotation of a span that began at ``start``.  Only
+    while a session is active (``is_enabled``: a flag read, 0.02 us) does
+    it get the span's scalar ``args`` and ``pc_us``; without one it is
+    the bare name, as ever."""
     try:
         import jax.profiler
 
-        return jax.profiler.TraceAnnotation(name)
+        annotation = jax.profiler.TraceAnnotation
+        if not annotation.is_enabled():
+            return annotation(name)
+        return annotation(name, pc_us=start * 1e6, **{
+            k: v for k, v in args.items()
+            if isinstance(v, (int, float, str))})
     except Exception:
         return contextlib.nullcontext()
 
@@ -117,12 +175,14 @@ class SpanTracer:
     def span(self, name: str, **args):
         """Record a complete ("ph": "X") event for the enclosed block.
         ``args`` must be JSON-serializable; they land in the event's
-        ``args`` field next to the nesting ``depth``."""
+        ``args`` field next to the nesting ``depth``.  While a profiler
+        trace runs the scalar ones go to its annotation as they are, with
+        ``pc_us`` (the entry stamp)."""
         stack = self._depth()
         stack.append(name)
         start = time.perf_counter()
         try:
-            with _trace_annotation(name):
+            with _trace_annotation(name, start, args):
                 yield self
         finally:
             if self.fence:
@@ -146,8 +206,9 @@ class SpanTracer:
         """Record a span from two ``time.perf_counter()`` stamps already
         taken: for a phase whose ends, or whose ``args``, are only known
         after it is over (a request's life, a drained segment's sums).
-        No nesting depth and no ``TraceAnnotation``: it lies in the ring,
-        not on a profiler's host plane."""
+        No nesting depth and no ``TraceAnnotation``: it lies in the ring
+        alone, and a reader places it on a profiler's time line by the
+        clock offset that the annotated spans' ``pc_us`` give."""
         self._append({
             "name": name, "ph": "X", "ts": start * 1e6,
             "dur": (end - start) * 1e6, "pid": self._pid,

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.obs.spans import routine
 from tpudist.utils.config import env_flag
 
 _NEG_BIG = -1e30
@@ -1823,8 +1824,10 @@ def sparse_gqa_attend(
         # the staged rows lie in the last ``cap`` places before ``count``:
         # only that window of the gathered buffers is looked at again
         cap = min(side_k.shape[1], k)
-        at = jnp.clip(count - cap, 0, k - cap)[:, None] + jnp.arange(cap)
-        window = jnp.take_along_axis(ids, at, axis=1)           # [T, cap]
+        with routine("attn/rows"):
+            at = (jnp.clip(count - cap, 0, k - cap)[:, None]
+                  + jnp.arange(cap))
+            window = jnp.take_along_axis(ids, at, axis=1)       # [T, cap]
 
     def chosen(source, side):
         rows = jnp.take(source, jnp.minimum(ids, n - 1), axis=0,
@@ -1842,13 +1845,16 @@ def sparse_gqa_attend(
     pages = k // block
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    out = _paged_decode_one(
-        q[:, None], chosen(k_source, side_k).reshape(t * pages, block, flat),
-        chosen(v_source, side_v).reshape(t * pages, block, flat),
-        jnp.arange(t * pages, dtype=jnp.int32).reshape(t, pages), count,
-        jnp.zeros((), jnp.int32), None, None, h_kv=packed_kv_heads,
-        interpret=bool(interpret), name="sparse_gqa_attend")
-    return out[:, 0]
+    with routine("attn/rows"):
+        k_rows = chosen(k_source, side_k).reshape(t * pages, block, flat)
+        v_rows = chosen(v_source, side_v).reshape(t * pages, block, flat)
+    with routine("attn/core"):
+        out = _paged_decode_one(
+            q[:, None], k_rows, v_rows,
+            jnp.arange(t * pages, dtype=jnp.int32).reshape(t, pages), count,
+            jnp.zeros((), jnp.int32), None, None, h_kv=packed_kv_heads,
+            interpret=bool(interpret), name="sparse_gqa_attend")
+        return out[:, 0]
 
 
 def quantize_kv(k: jnp.ndarray, v: jnp.ndarray):

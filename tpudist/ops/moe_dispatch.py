@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.obs.spans import routine
+
 
 def _gmm_kernel(meta_ref, xs_ref, gate_ref, w_up_ref, w_down_ref, y_ref):
     """One grid step = one [bn, d] slot block of one expert: both expert
@@ -269,24 +271,26 @@ def grouped_gated_mlp(x: jnp.ndarray, w_gate: jnp.ndarray,
     k = local_idx.shape[1]
     n = t * k
     bn = row_block(n, num_experts or e)
-    held = (local_idx >= 0) & (local_idx < e)
-    # the choices held elsewhere sort into a last group no block visits
-    flat_e = jnp.where(held, local_idx, e).reshape(-1)
-    pos, order, sizes, starts, np_pad = _counting_sort(
-        flat_e, e + 1, block_rows=bn)
-    nb = np_pad // bn
-    n_live = starts[e] // bn
-    block_expert = _block_experts(starts[:e], bn, nb)
-    xs = x[order // k]                                  # [NP, d] sorted rows
-    h = _grouped_matmul(xs, (w_gate, w_up), block_expert, n_live, bn,
-                        gated=True, interpret=interpret,
-                        name="moe_experts_gate_up")
-    ys = _grouped_matmul(h, (w_down,), block_expert, n_live, bn,
-                         gated=False, interpret=interpret,
-                         name="moe_experts_down")
-    # a choice held elsewhere points past the visited blocks: masked, never
-    # multiplied (those rows are not written)
-    y = jnp.where(held[..., None], ys[pos].reshape(t, k, d), 0)
-    y = jnp.sum(y.astype(jnp.float32)
-                * weights[..., None].astype(jnp.float32), axis=1)
-    return y.astype(x.dtype), sizes[:e]
+    with routine("mlp/route"):
+        held = (local_idx >= 0) & (local_idx < e)
+        # the choices held elsewhere sort into a last group no block visits
+        flat_e = jnp.where(held, local_idx, e).reshape(-1)
+        pos, order, sizes, starts, np_pad = _counting_sort(
+            flat_e, e + 1, block_rows=bn)
+        nb = np_pad // bn
+        n_live = starts[e] // bn
+        block_expert = _block_experts(starts[:e], bn, nb)
+    with routine("mlp/experts"):
+        xs = x[order // k]                              # [NP, d] sorted rows
+        h = _grouped_matmul(xs, (w_gate, w_up), block_expert, n_live, bn,
+                            gated=True, interpret=interpret,
+                            name="moe_experts_gate_up")
+        ys = _grouped_matmul(h, (w_down,), block_expert, n_live, bn,
+                             gated=False, interpret=interpret,
+                             name="moe_experts_down")
+        # a choice held elsewhere points past the visited blocks: masked,
+        # never multiplied (those rows are not written)
+        y = jnp.where(held[..., None], ys[pos].reshape(t, k, d), 0)
+        y = jnp.sum(y.astype(jnp.float32)
+                    * weights[..., None].astype(jnp.float32), axis=1)
+        return y.astype(x.dtype), sizes[:e]

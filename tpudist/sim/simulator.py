@@ -683,6 +683,28 @@ class SimReplica:
             pass   # can't verify an empty inbox blind; close next step
 
 
+class _ControlPlaneRegistry:
+    """The process's registry as the fleet's control process would hold
+    it: without the STATE a replica publishes about itself (the ``serve/``
+    and ``fleet/`` gauges and windowed histograms), which reaches the alert
+    plane through the fabric alone.  A simulator shares its process with
+    whatever ran before it (a test's ``ServeLoop`` leaves its
+    ``serve/queue_wait_s`` window and its ``serve/degraded`` gauge), and
+    while the coordinator is down the scraper has nothing but this
+    registry to read: the leftovers then fired ``QueueWaitHigh`` beside
+    ``CoordOutage``.  Counters stay: the router counts under ``serve/``
+    too, and rules read their deltas."""
+
+    _REPLICA_STATE = ("serve/", "fleet/")
+
+    def snapshot(self) -> dict:
+        snap = obs.registry.snapshot()
+        for kind in ("gauges", "histograms"):
+            snap[kind] = {name: m for name, m in snap[kind].items()
+                          if not name.startswith(self._REPLICA_STATE)}
+        return snap
+
+
 class FleetSim:
     """One offline scenario run (see module docstring).
 
@@ -740,7 +762,7 @@ class FleetSim:
                                    clock=self.vc.monotonic)
         self.scraper = FleetScraper(
             self.tsdb, client=self.fabric, namespace=self.ns,
-            registry=obs.registry, alerts=self.alerts,
+            registry=_ControlPlaneRegistry(), alerts=self.alerts,
             interval_s=float(fleet["alert_scrape_s"]),
             clock=self.vc.monotonic)
         self._scrape_next = self.scraper.interval_s
