@@ -149,11 +149,30 @@ def read_layer_metrics(cell: dict, run: dict) -> dict:
     return out
 
 
+# the rows of the run's ``say(phase="correct", compared=...)``: every runner
+# says them just before it returns its result
+_compared: list = []
+
+
 def say(**fields) -> None:
     """An earlier output line (the last line is the result's)."""
+    if fields.get("phase") == "correct":
+        _compared[:] = fields.get("compared", [])
     print(json.dumps(fields), flush=True)
 
 
 def print_result(result: dict) -> None:
+    """The result as the last line of standard output.  The numbers that
+    decided ``correct``, each beside its limit, come last in it under
+    ``compared`` and are the last lines of standard error: of a run that is
+    not correct the driver's record keeps the end of both."""
     sys.stdout.flush()
+    if _compared:
+        result = {**result, "compared": {
+            r["number"]: {"value": r["value"], "limit": r["limit"]}
+            for r in _compared}}
+        for r in _compared:
+            print(f"compared {r['number']}: {r['value']} limit {r['limit']}",
+                  file=sys.stderr)
+        sys.stderr.flush()
     print(json.dumps(result), flush=True)

@@ -270,6 +270,14 @@ def reference_gaps(params, dims, sample, quant=None) -> dict:
             "requests": len(sample)}
 
 
+def _longest_gap(load: Load, start: float, end: float) -> float:
+    """The longest gap between two polls that both lie in ``[start, end]``
+    (``perf_counter`` times)."""
+    ts = [load.t0 + p[0] for p in load.polls]
+    return max((y - x for x, y in zip(ts, ts[1:]) if start <= x and y <= end),
+               default=0.0)
+
+
 def summarize(load: Load, loop, seconds: float) -> dict:
     """End-to-end numbers and the counters' differences over the window."""
     a, b = load.edges["start"], load.edges["end"]
@@ -304,6 +312,10 @@ def summarize(load: Load, loop, seconds: float) -> dict:
         "compiles_in_window": b["compiles"][0] - a["compiles"][0],
         "kv_blocks_peak": max(load.block_samples, default=0),
         "kv_blocks_total": loop.kv_num_blocks,
+        # the longest time between two polls: where the host stood still
+        # (PERF.md section 6, PR 38) it is seconds, and an iteration's else
+        "poll_gap_max_s": _longest_gap(load, a["t"], b["t"]),
+        "ramp_poll_gap_max_s": _longest_gap(load, load.t0, a["t"]),
     }
     if not load.closed_loop:
         lat.sort()

@@ -1,8 +1,6 @@
 """What the serving readers share: the traced programs' names and the
 window's events reduced to sums."""
 
-import re
-
 from benchmarks.trace import reduce as tr
 
 SEGMENT = r"_segment_impl"
@@ -12,11 +10,6 @@ FINISH = r"_admit_finish_impl"
 
 def module_seconds(trace: dict, pattern: str) -> float:
     return tr.seconds_matching(trace["by_module"], pattern)
-
-
-def module_runs(trace: dict, pattern: str) -> int:
-    return sum(n for name, n in trace["module_runs"].items()
-               if re.search(pattern, name))
 
 
 def decode_context(run: dict) -> tuple[float, float]:

@@ -7,7 +7,7 @@ from benchmarks.roofline import bound, flash_bwd
 
 
 def read(run: dict):
-    sec = tt.kernel_seconds_per_call(run, flash_bwd.is_kernel, 1)
+    sec = tt.kernel_seconds_per_layer(run, flash_bwd.is_kernel)
     if sec is None:
         return None
     d = run["dims"]

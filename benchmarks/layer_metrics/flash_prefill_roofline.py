@@ -1,20 +1,21 @@
-"""``_flash_forward``'s share of its roofline in chunked prefill: the
-kernel's device time per call in the trace against what the window's mean
-chunk needs (offsets and widths from the loop's ``prefill_chunk`` events)."""
+"""``flash_fwd``'s share of its roofline in chunked prefill: the device time
+of ONE event of the kernel (found by name and counted: one a layer of a
+dense chunk; a sparse chunk's masked pass is ``sparse_gqa_prefill``, a
+kernel of its own) against what the window's mean chunk needs (offsets and
+widths from the loop's ``prefill_chunk`` events)."""
 
-from benchmarks.layer_metrics import _serve_trace as st
+from benchmarks.layer_metrics import _named_kernels as nk
+from benchmarks.layer_metrics.flash_fwd_roofline import KERNEL
 from benchmarks.roofline import bound, flash_prefill
-from benchmarks.trace import reduce as tr
 
 
 def read(run: dict):
-    trace, dims = run["trace"], run["dims"]
+    dims = run["dims"]
     chunks = [e for e in run["events"] if e["kind"] == "prefill_chunk"]
-    if not trace or not chunks:
+    if not run["trace"] or not chunks:
         return None
-    sec = tr.pallas_seconds(trace["by_op"], flash_prefill.is_kernel)
-    runs = st.module_runs(trace, st.PREFILL)
-    if not (sec and runs):
+    calls, seconds = nk.calls(run, KERNEL)
+    if not calls:
         return None
     args = (dims.heads, dims.head_dim)
     flops = sum(flash_prefill.flops(e["off"], e["width"], *args)
@@ -22,5 +23,4 @@ def read(run: dict):
     nbytes = sum(flash_prefill.bytes_moved(
         e["off"], e["width"], dims.heads, dims.kv_heads, dims.head_dim)
         for e in chunks) / len(chunks)
-    return bound.share(flops, nbytes, sec / (runs * dims.layers),
-                       run["peaks"])
+    return bound.share(flops, nbytes, seconds / calls, run["peaks"])

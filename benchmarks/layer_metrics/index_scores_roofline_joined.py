@@ -2,10 +2,9 @@
 for like: the kernel's events (by name) inside the JOINED runs of the
 segment program (``_joined``) against the operations and bytes of the index
 keys THOSE segments read (``rows_scored`` and ``lanes`` of each run's own
-drain, once a call of the kernel), where ``index_scores_roofline`` sets the
-traced calls against the window's mean step.  Each key counted once at the
-64 numbers it has: the walk reads the 256 B a key is stored at, so 50% is
-its ceiling."""
+drain, once a call of the kernel), not of the window's mean step.  Each key
+counted once at the 64 numbers it has: the walk reads the 256 B a key is
+stored at, so 50% is its ceiling."""
 
 from benchmarks.layer_metrics import _index_spans as ix
 from benchmarks.layer_metrics import _joined, _scopes

@@ -4,8 +4,8 @@ prefill chunk (one layer of one sparse chunk): the kernel
 of the prefill-chunk program only.  There it searches the ``k``-th largest
 score and the cut among equal ones of 2048 queries over the rows cached so
 far, a block of queries at a time in VMEM; the decode step calls the same
-kernel on its lanes' rows (part of ``index_select_us_per_call``), which
-does not count here.  ``None`` where the trace holds none (a program whose
+kernel on its lanes' rows (part of ``step_index_ms``), which does not
+count here.  ``None`` where the trace holds none (a program whose
 selection is XLA's passes, or a model without an indexer)."""
 
 from benchmarks.layer_metrics import _named_kernels as nk

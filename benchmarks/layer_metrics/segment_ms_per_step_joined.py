@@ -1,8 +1,7 @@
 """Device time of the segment program per decode step it RAN: the whole
 runs of the trace joined to their ``seq`` (``_joined``), their time over the
 sum of the ``steps_run`` their own drains report.  A run the trace's edge
-cut is left out and a segment that froze early divides by what it ran,
-which is what ``segment_ms_per_step`` (runs x ``steps_per_sync``) cannot."""
+cut is left out and a segment that froze early divides by what it ran."""
 
 from benchmarks.layer_metrics import _joined
 

@@ -6,10 +6,12 @@ lanes all hold fewer rows than a query attends is left out by its name)
 inside the JOINED runs of the segment program, against the operations and
 bytes of the rows THOSE segments attend (``rows_selected`` and ``lanes`` of
 each run's own drain, once a call of the kernel).  The scopes are the
-program's own, where ``sparse_attend_roofline`` guesses the routine's first
-instruction by its shape and divides by the window's mean step.  Each
-chosen row of each pool counted once at its stored width: a route that
-gathers first reads as a third or less."""
+program's own and the kernels are known by their names: no buffer's shape
+is looked at, so a program that gathers K and V as one wide row, or whose
+kernel reads the rows itself (nothing under ``attn/rows``), is timed as
+what it is.  K's and V's bytes of each chosen row are counted once,
+wherever they are stored: a route that gathers first reads as a third or
+less."""
 
 from benchmarks.layer_metrics import _index_spans as ix
 from benchmarks.layer_metrics import _joined, _scopes

@@ -1,11 +1,13 @@
 """Operations and bytes of the two flash-attention backward kernels taken
 together (dQ, then dK/dV; ``ops/flash_attention.flash_block_grads``)."""
 
+NAMES = ("flash_bwd_dq", "flash_bwd_dkv")
+
+
 def is_kernel(op: dict) -> bool:
-    """The two backward kernels in a trace: Pallas calls with seven
-    operands (offsets, q, k, v, dO, log-sum-exp, and O or delta); dQ comes
-    with the float32 delta, dK and dV come as a pair."""
-    return len(op["outputs"]) == 2 and op["operands"] == 7
+    """The two backward kernels in a trace, by their instructions' names
+    (``trace/reduce.parse_op``'s): dQ, and dK with dV."""
+    return op["name"] in NAMES
 
 
 def flops(rows: int, seq: int, heads: int, head_dim: int) -> float:

@@ -1,9 +1,6 @@
 """Operations and bytes one call of ``ops/flash_attention._flash_forward``
 needs at a query offset (one layer, one prefill chunk of one lane)."""
 
-# the same kernel as in training, so the same signature in a trace
-from benchmarks.roofline.flash_fwd import is_kernel  # noqa: F401
-
 
 def flops(offset: int, width: int, heads: int, head_dim: int) -> float:
     """Row ``r`` of the chunk attends ``offset + r + 1`` keys (causal)."""

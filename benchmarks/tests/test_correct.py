@@ -3,6 +3,8 @@ is broken underneath or computed in the next lower precision.  Tiny sizes on
 the CPU; the same comparisons ran at the cells' own sizes on the chip
 (PERF.md, section 2)."""
 
+import json
+
 import numpy as np
 
 from benchmarks.harness import serve, train
@@ -46,7 +48,7 @@ def test_serve_fp8_control_is_not_correct(run_tiny, monkeypatch):
         return real(params, dims, sample)
 
     monkeypatch.setattr(serve, "reference_gaps", both)
-    out = run_tiny("sc3b_longgen_batch", seconds=6.0)
+    out = run_tiny("sc3b_code_steady", seconds=6.0)
     limit = _compared(out)["worst_logit_gap"]["limit"]
     assert out["correct"] is True
     assert seen["control"]["worst_gap"] > limit
@@ -120,3 +122,25 @@ def test_train_bf16_state_control_is_not_correct(run_tiny, monkeypatch):
     assert out["correct"] is False
     row = _compared(out)["param_change_norm_worst_leaf"]
     assert row["value"] > row["limit"]
+
+
+def test_the_numbers_compared_end_the_result_line_and_stderr(capsys):
+    """``print_result`` repeats what the runner said under ``phase:
+    correct``: last in the result's line, and as standard error's last
+    lines."""
+    from benchmarks.harness import common
+
+    rows = [{"number": "worst_logit_gap", "value": 0.25, "limit": 0.12},
+            {"number": "failed_requests", "value": 0, "limit": 0}]
+    common.say(phase="correct", compared=rows, worst_gap=0.25)
+    common.print_result({"correct": False, "attempted": 3, "failed": 0,
+                         "metrics": {}, "device": {}})
+    cap = capsys.readouterr()
+    line = json.loads(cap.out.strip().splitlines()[-1])
+    assert list(line)[-1] == "compared"
+    assert line["compared"] == {
+        "worst_logit_gap": {"value": 0.25, "limit": 0.12},
+        "failed_requests": {"value": 0, "limit": 0}}
+    assert cap.err.strip().splitlines()[-2:] == [
+        "compared worst_logit_gap: 0.25 limit 0.12",
+        "compared failed_requests: 0 limit 0"]

@@ -3,9 +3,10 @@ trace made by hand: five runs of the segment program of which the trace's
 edges cut the first and the last, their annotations on a host plane whose
 clock lies a thousand seconds from the ring's and jitters by microseconds,
 a warm-up in the ring under the same ``seq`` numbers, and operations under
-routine scopes.  One case a metric."""
+routine scopes.  One case a metric; and two programs that move the chosen
+rows otherwise (one wide gather; no gather at all), which the readers tell
+by the scopes and the kernels' names alone."""
 
-import copy
 import types
 
 import pytest
@@ -56,7 +57,10 @@ def _host(name, start, end, jitter_us, **stats):
     return (name, start, end, {"pc_us": pc * 1e6, **stats})
 
 
-def make_bag(stamps: bool = True) -> dict:
+def make_bag(stamps: bool = True, step=OPS, texts=None) -> dict:
+    """``step``: the instructions every step runs; ``texts``: an
+    instruction's whole text where ``_op``'s will not do."""
+    texts = texts or {}
     modules, ops, host, ring = [], [], [], []
     for seq, (start, end, steps_run) in RUNS.items():
         modules.append((f"jit__segment_impl({7})", start, end))
@@ -65,8 +69,9 @@ def make_bag(stamps: bool = True) -> dict:
         ops.append(("%while.1 = (s32[]) while((s32[]) %t)", start, end))
         t = start
         for _ in range(steps_run if seq != 10 else 12):
-            for name, (_, ms) in OPS.items():
-                ops.append((_op(name), t, t + ms * 1e-3))
+            for name, (_, ms) in step.items():
+                ops.append((texts.get(name) or _op(name), t,
+                            t + ms * 1e-3))
                 t += ms * 1e-3
         fetch_end = end + 200e-6
         if seq >= 12:       # 10 and 11 were dispatched before the trace
@@ -92,7 +97,8 @@ def make_bag(stamps: bool = True) -> dict:
         host = [(n, s, e, {}) for n, s, e, _ in host]
     return {"cell": {"name": "toy"}, "dims": DIMS, "peaks": PEAKS,
             "trace_events": {"modules": modules, "ops": ops, "host": host},
-            "spans": ring, "scope_map": copy.deepcopy(SCOPES)}
+            "spans": ring, "scope_map": {"_segment_impl": {
+                k: v for k, (v, _) in step.items() if v}}}
 
 
 STEPS_RUN = sum(RUNS[s][2] for s in WHOLE)
@@ -102,6 +108,13 @@ STEP_MS = 1e3 * sum(RUNS[s][1] - RUNS[s][0] for s in WHOLE) / STEPS_RUN
 def _under(*routines) -> float:
     """Every whole run's every step runs every instruction once."""
     return sum(ms for scope, ms in OPS.values() if scope in routines)
+
+
+def _attend_share(ms: float) -> float:
+    call = bound.least_seconds(
+        sparse_attend.flops(12 * 2048, 32, 128),
+        sparse_attend.bytes_moved(12 * 2048, 12, 32, 4, 128), PEAKS)
+    return 100.0 * call / (ms * 1e-3)
 
 
 def test_cut_runs_are_left_out_and_seqs_joined():
@@ -171,13 +184,45 @@ def test_index_scores_roofline_joined_reads_its_own_segments_rows():
 
 
 def test_sparse_attend_roofline_joined_times_rows_and_core():
-    call = bound.least_seconds(
-        sparse_attend.flops(12 * 2048, 32, 128),
-        sparse_attend.bytes_moved(12 * 2048, 12, 32, 4, 128), PEAKS)
     # the gathers and the kernel; the every-row kernel is left out by name
-    seconds = (OPS["gather.5"][1] + OPS["sparse_gqa_attend.7"][1]) * 1e-3
     assert sparse_attend_roofline_joined.read(make_bag()) == pytest.approx(
-        100.0 * call / seconds)
+        _attend_share(OPS["gather.5"][1] + OPS["sparse_gqa_attend.7"][1]))
+
+
+def test_one_wide_gather_reads_as_two_narrow_ones():
+    """K and V of a token in ONE pool row: one gather of ``[lanes, topk,
+    2 x kv_heads x head_dim]`` and a kernel with one operand fewer.  No
+    reader looks at a shape: ``attn/rows`` is what the program scoped so,
+    the kernel is known by its name, and the chosen rows' bytes are K's
+    and V's either way."""
+    wide = {
+        "gather.5": "%gather.5 = bf16[12,2048,1024]{2,1,0} fusion("
+                    "bf16[307200,1024]{1,0} %pool, s32[12,2048]{1,0} %ids)",
+        "sparse_gqa_attend.7":
+            "%sparse_gqa_attend.7 = bf16[48,8,128]{2,1,0} custom-call("
+            "s32[12,3]{1,0} %meta, bf16[48,8,128]{2,1,0} %q, "
+            "bf16[12,2048,1024]{2,1,0} %gather.5), "
+            'custom_call_target="tpu_custom_call"'}
+    bag, narrow = make_bag(texts=wide), make_bag()
+    assert step_rows_ms.read(bag) == pytest.approx(step_rows_ms.read(narrow))
+    share = sparse_attend_roofline_joined.read(bag)
+    assert share == pytest.approx(
+        sparse_attend_roofline_joined.read(narrow))
+    assert share == pytest.approx(_attend_share(2.0 + 0.75)) and share < 100
+
+
+def test_a_kernel_that_reads_the_rows_itself_is_the_core_alone():
+    """No instruction under ``attn/rows``: the routine is ``attn/core``,
+    and the gathers off the path show in the share."""
+    step = {k: v for k, v in OPS.items() if v[0] != "attn/rows"}
+    bag = make_bag(step=step)
+    assert step_rows_ms.read(bag) == 0.0
+    assert step_attn_ms.read(bag) == pytest.approx(_under(
+        "attn/proj", "attn/cache", "attn/index", "attn/core"))
+    assert sparse_attend_roofline_joined.read(bag) == pytest.approx(
+        _attend_share(0.75))
+    assert sparse_attend_roofline_joined.read(bag) == pytest.approx(
+        sparse_attend_roofline_joined.read(make_bag()) * 2.75 / 0.75)
 
 
 READERS = (segment_ms_per_step_joined, step_attn_ms, step_mlp_ms,

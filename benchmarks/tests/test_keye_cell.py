@@ -11,16 +11,11 @@ import pytest
 
 from benchmarks.harness import common, serve_keye as runner
 from benchmarks.layer_metrics import (
-    index_scores_roofline,
     index_scores_us_per_call,
-    index_select_us_per_call,
     index_selected_share,
-    sparse_attend_roofline,
-    sparse_attend_us_per_call,
     sparse_chunk_ms,
 )
-from benchmarks.roofline import bound, index_scores, paged_decode
-from benchmarks.roofline import sparse_attend
+from benchmarks.roofline import index_scores, paged_decode, sparse_attend
 from benchmarks.tests.test_deepseek_cell import _compared, _trace
 from benchmarks.tests.test_layer_readers import SPANS, bag
 from benchmarks.trace import reduce as tr
@@ -158,7 +153,6 @@ def index_bag(spans=INDEX_SPANS, **kw):
 # segments 1, 2, 3 drain inside the window: 8 + 4 + 8 steps, 3, 2, 1 lanes
 SCORED = (30000 * 8 + 20000 * 4 + 45000 * 8) / 20
 CHOSEN = (6000 * 8 + 4000 * 4 + 6144 * 8) / 20
-LANES = (3 * 8 + 2 * 4 + 1 * 8) / 20
 
 
 def test_selected_share_weighs_segments_by_their_steps():
@@ -173,69 +167,29 @@ def _kernel(name, operands, i):
             'custom_call_target="tpu_custom_call"')
 
 
-def _segment_ops(t0, gather=True):
-    """One layer of one step inside a segment run: the scores, 40 us of
-    selection, the chosen rows of K and of V gathered (the hand-made loop
-    has 4 lanes: ``[4 x 2048, 512]`` each) and patched, the attention
-    kernel."""
-    rows = [
-        tr.Event("%fusion.11 = bf16[8192,512]{1,0} fusion(s32[4,2048]{1,0} "
-                 "%fusion.9)", t0 + 340e-6, t0 + 385e-6),
-        tr.Event("%fusion.12 = bf16[8192,512]{1,0} fusion(s32[4,2048]{1,0} "
-                 "%fusion.9)", t0 + 385e-6, t0 + 430e-6),
-        tr.Event("%scatter.2 = bf16[4,2048,512]{2,1,0} fusion(bf16[8192,512]"
-                 "{1,0} %fusion.11)", t0 + 430e-6, t0 + 445e-6)]
+def _segment_ops(t0):
+    """One layer of one step inside a segment run: the scores, the
+    selection, the attention kernel."""
     return [
         tr.Event(_kernel("paged_index_scores", 4, int(t0)), t0,
                  t0 + 300e-6),
         tr.Event("%fusion.9 = s32[4,2048]{1,0} fusion()", t0 + 310e-6,
                  t0 + 340e-6),
-        *(rows if gather else ()),
         tr.Event(_kernel("sparse_gqa_attend", 4, int(t0)), t0 + 450e-6,
                  t0 + 650e-6)]
 
 
-def test_the_routines_are_cut_in_the_segment_only(monkeypatch):
-    ops = _segment_ops(1.0) + _segment_ops(2.0) + _segment_ops(5.0)
+def test_the_scores_kernel_is_timed_in_the_segment_only(monkeypatch):
+    ops = (_segment_ops(1.0) + _segment_ops(2.0)
+           + [tr.Event(_kernel("paged_index_scores", 4, 5), 5.0, 5.0009)])
     modules = [tr.Event("jit__segment_impl(7)", 0.9, 3.0),
                tr.Event("jit__prefill_chunk_impl(3)", 4.9, 6.0)]
     _trace(monkeypatch, ops, modules)
     run = index_bag()
+    # the chunk's call of the same kernel (900 us) does not count
     assert index_scores_us_per_call.read(run) == pytest.approx(300.0)
-    # the selection ends where the first chosen row is touched; the
-    # attention is the gathers, the patch and the kernel
-    assert index_select_us_per_call.read(run) == pytest.approx(40.0)
-    assert sparse_attend_us_per_call.read(run) == pytest.approx(310.0)
-    assert index_scores_roofline.read(run) == pytest.approx(bound.share(
-        index_scores.flops(SCORED, 16, 64),
-        index_scores.bytes_moved(SCORED, LANES, 16, 64), 300e-6, PEAKS))
-    assert sparse_attend_roofline.read(run) == pytest.approx(bound.share(
-        sparse_attend.flops(CHOSEN, 32, 128),
-        sparse_attend.bytes_moved(CHOSEN, LANES, 32, 4, 128), 310e-6,
-        PEAKS))
-    _trace(monkeypatch, ops[12:], modules)
+    _trace(monkeypatch, ops[6:], modules)
     assert index_scores_us_per_call.read(run) is None
-    assert index_select_us_per_call.read(run) is None
-    assert sparse_attend_us_per_call.read(run) is None
-    assert sparse_attend_roofline.read(run) is None
-
-
-def test_a_kernel_that_reads_the_rows_itself_is_the_whole_routine(
-        monkeypatch):
-    _trace(monkeypatch, _segment_ops(1.0, gather=False),
-           [tr.Event("jit__segment_impl(7)", 0.9, 3.0)])
-    run = index_bag()
-    assert index_select_us_per_call.read(run) == pytest.approx(150.0)
-    assert sparse_attend_us_per_call.read(run) == pytest.approx(200.0)
-    # the gathers off the path show under the attention's name: the same
-    # rows in 200 us and not 310
-    _trace(monkeypatch, _segment_ops(1.0),
-           [tr.Event("jit__segment_impl(7)", 0.9, 3.0)])
-    gathered = sparse_attend_roofline.read(run)
-    _trace(monkeypatch, _segment_ops(1.0, gather=False),
-           [tr.Event("jit__segment_impl(7)", 0.9, 3.0)])
-    assert sparse_attend_roofline.read(run) == pytest.approx(
-        gathered * 310 / 200)
 
 
 def test_sparse_chunk_ms_is_the_chunk_programs_sparse_runs(monkeypatch):
@@ -257,9 +211,7 @@ def test_sparse_chunk_ms_is_the_chunk_programs_sparse_runs(monkeypatch):
 
 
 @pytest.mark.parametrize("reader", [
-    index_scores_us_per_call, sparse_attend_us_per_call,
-    index_select_us_per_call, index_scores_roofline, sparse_attend_roofline,
-    index_selected_share, sparse_chunk_ms])
+    index_scores_us_per_call, index_selected_share, sparse_chunk_ms])
 def test_an_empty_window_or_a_program_without_the_fields_gives_none(
         reader, monkeypatch):
     monkeypatch.setattr(tr, "find_xplane", lambda d: None)
@@ -267,8 +219,7 @@ def test_an_empty_window_or_a_program_without_the_fields_gives_none(
     assert reader.read(index_bag(INDEX_SPANS, rids=())) is None
     _trace(monkeypatch, _segment_ops(1.0),
            [tr.Event("jit__segment_impl(7)", 0.9, 3.0)])
-    if reader in (index_scores_roofline, sparse_attend_roofline,
-                  index_selected_share):
+    if reader is index_selected_share:
         # the parent's spans: no rows_scored, no rows_selected
         assert reader.read(index_bag(SPANS)) is None
 

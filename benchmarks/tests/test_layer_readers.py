@@ -3,17 +3,21 @@ made by hand: each has a known answer, a request enqueued in the ramp and a
 warm-up request are left out, and an empty window gives ``None``."""
 
 import json
+import types
 
 import pytest
 
 from benchmarks.layer_metrics import (
     _loop_spans as ls,
     decode_occupancy,
+    flash_prefill_roofline,
     host_ms_per_segment,
+    paged_decode_roofline,
     paged_decode_us_per_call,
     prefill_ms_per_chunk,
     ttft_p90_ms,
 )
+from benchmarks.roofline import bound, flash_prefill, paged_decode
 from benchmarks.trace import reduce as tr
 
 T0 = 1000.0     # perf_counter at the first enqueue
@@ -122,6 +126,56 @@ def test_paged_decode_is_found_by_its_name(monkeypatch):
     monkeypatch.setattr(tr, "load", lambda p: {
         "devices": {0: {"ops": ops[4:], "modules": []}}, "host": []})
     assert paged_decode_us_per_call.read(bag(SPANS)) is None
+
+
+def _pallas(name: str, i: int) -> str:
+    return (f"%{name}.{i} = bf16[24,22,128]{{2,1,0}} custom-call("
+            's32[24,66]{1,0} %a), custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("cut", [False, True])
+def test_the_serve_rooflines_count_their_kernels_events(monkeypatch, cut):
+    """Each event of the kernel is one call: a run of the program that the
+    trace's edge cut (here: only its last two of eight calls are left)
+    brings the events it holds and is not counted as a whole run."""
+    layers, peaks = 4, {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+    ops, modules = [], []
+    for r in range(-1 if cut else 0, 3):
+        first = 6 if r < 0 else 0       # the cut run: two calls are left
+        modules += [tr.Event("jit__segment_impl(7)", r + first * 0.01,
+                             r + 0.09),
+                    tr.Event("jit__prefill_chunk_impl(3)", r + 0.5, r + 0.6)]
+        for i in range(first, 2 * layers):          # two steps a segment
+            ops.append(tr.Event(_pallas("paged_flash_decode", i),
+                                r + i * 0.01, r + i * 0.01 + 40e-6))
+        for i in range(layers):
+            ops.append(tr.Event(_pallas("flash_fwd", i), r + 0.5 + i * 0.01,
+                                r + 0.5 + i * 0.01 + 300e-6))
+        ops.append(tr.Event(_pallas("sparse_gqa_prefill", 1), r + 0.58,
+                            r + 0.59))
+    monkeypatch.setattr(tr, "find_xplane", lambda d: "hand.xplane.pb")
+    monkeypatch.setattr(tr, "load", lambda p: {
+        "devices": {0: {"ops": ops, "modules": modules}}, "host": []})
+    run = {"cell": {"name": "hand"}, "peaks": peaks, "trace": {"by_op": {}},
+           "options": {"steps_per_sync": 2},
+           "dims": types.SimpleNamespace(layers=layers, heads=22,
+                                         kv_heads=1, head_dim=128),
+           "events": [
+               {"kind": "admit", "trace": "a", "prompt_len": 1000},
+               {"kind": "segment", "trace": "a", "seq": 0, "steps": 2,
+                "tokens": 10},
+               {"kind": "prefill_chunk", "off": 512, "width": 512}]}
+    # one lane: 1010 and 1011 tokens of context at the two steps
+    assert paged_decode_roofline.read(run) == pytest.approx(bound.share(
+        paged_decode.flops(1010.5, 22, 128),
+        paged_decode.bytes_moved(1010.5, 1, 22, 1, 128), 40e-6, peaks))
+    assert flash_prefill_roofline.read(run) == pytest.approx(bound.share(
+        flash_prefill.flops(512, 512, 22, 128),
+        flash_prefill.bytes_moved(512, 512, 22, 1, 128), 300e-6, peaks))
+    monkeypatch.setattr(tr, "load", lambda p: {
+        "devices": {0: {"ops": ops[-1:], "modules": modules}}, "host": []})
+    assert paged_decode_roofline.read(run) is None
+    assert flash_prefill_roofline.read(run) is None
 
 
 @pytest.mark.parametrize("reader", [

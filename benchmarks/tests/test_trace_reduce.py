@@ -30,7 +30,7 @@ def test_gaps_go_to_the_host_span_that_covers_them():
     assert gaps == pytest.approx({"(none)": 2.0, "serve/admit": 2.0})
 
 
-DQ = ('%attn.53 = (bf16[32,8192,128]{2,1,0:T(8,128)(2,1)}, f32[32,1,8192]'
+DQ = ('%flash_bwd_dq.53 = (bf16[32,8192,128]{2,1,0:T(8,128)(2,1)}, f32[32,1,8192]'
       '{2,1,0:T(1,128)S(1)}) custom-call(s32[1,2]{1,0:T(1,128)S(1)} '
       '%copy-done.215, bf16[32,8192,128]{2,1,0} %bitcast.1, bf16[2,8192,128]'
       '{2,1,0} %bitcast.2, bf16[2,8192,128]{2,1,0} %bitcast.3, '
@@ -41,23 +41,26 @@ DQ = ('%attn.53 = (bf16[32,8192,128]{2,1,0:T(8,128)(2,1)}, f32[32,1,8192]'
 
 def test_an_op_is_parsed_from_its_hlo_text():
     op = tr.parse_op(DQ)
-    assert op == {"name": "attn", "opcode": "custom-call",
+    assert op == {"name": "flash_bwd_dq", "opcode": "custom-call",
                   "outputs": ("bf16[32,8192,128]", "f32[32,1,8192]"),
                   "operands": 7, "pallas": True}
     assert tr.label(DQ) == (
-        "attn:pallas/7->bf16[32,8192,128],f32[32,1,8192]")
+        "flash_bwd_dq:pallas/7->bf16[32,8192,128],f32[32,1,8192]")
     fusion = ("%fusion.24 = f32[2048,49152]{1,0:T(8,128)} fusion("
               "f32[2048,49152]{1,0} %p.1, f32[]{:T(128)} %sub.3), "
               "kind=kOutput, calls=%fused_computation")
     assert tr.label(fusion) == "fusion:fusion->f32[2048,49152]"
 
 
-def test_kernels_are_told_by_their_signature():
-    from benchmarks.roofline import flash_bwd, flash_fwd, paged_decode
+def test_the_flash_kernels_are_told_by_their_names():
+    from benchmarks.roofline import flash_bwd, paged_decode
 
     op = tr.parse_op(DQ)
-    assert flash_bwd.is_kernel(op) and not flash_fwd.is_kernel(op)
-    assert not paged_decode.is_kernel(op)
+    assert flash_bwd.is_kernel(op) and not paged_decode.is_kernel(op)
+    # neither the operands nor the outputs decide: a dQ that kept one
+    # residual more, or came without its delta, is still the kernel
+    assert flash_bwd.is_kernel(dict(op, operands=8, outputs=op["outputs"][:1]))
+    assert not flash_bwd.is_kernel(dict(op, name="attn"))
     assert tr.pallas_seconds({DQ: 2.0, "%copy.1 = f32[2]{0} copy(f32[2]{0} "
                                         "%x)": 5.0},
                              flash_bwd.is_kernel) == 2.0
@@ -65,8 +68,10 @@ def test_kernels_are_told_by_their_signature():
 
 @pytest.mark.parametrize("name", ["serve", "train"])
 def test_recorded_trace(name, tmp_path):
-    """Traces recorded on the v5e in PR 24 (two train steps; half a second
-    of the steady serving cell), kept gzipped."""
+    """Traces recorded on the v5e, kept gzipped: half a second of the
+    steady serving cell (PR 24, before the kernels had names) and the train
+    cell's two traced steps (PR 38: ``flash_fwd``, ``flash_bwd_dq`` and
+    ``flash_bwd_dkv`` by name)."""
     import gzip
 
     packed = RECORDED / f"{name}.xplane.pb.gz"
@@ -82,7 +87,9 @@ def test_recorded_trace(name, tmp_path):
                                                        rel=0.02)
     from benchmarks.roofline import flash_bwd, flash_fwd, paged_decode
 
-    kernels = {"train": (flash_fwd, flash_bwd),
-               "serve": (paged_decode,)}[name]
-    for k in kernels:
-        assert tr.pallas_seconds(out["by_op"], k.is_kernel) > 0
+    is_kernel = {"train": flash_bwd.is_kernel,
+                 "serve": paged_decode.is_kernel}[name]
+    assert tr.pallas_seconds(out["by_op"], is_kernel) > 0
+    if name == "train":
+        assert tr.pallas_seconds(
+            out["by_op"], lambda op: op["name"] in flash_fwd.NAMES) > 0

@@ -168,8 +168,9 @@ def label(name: str) -> str:
 
 def pallas_seconds(by_op: dict[str, float], is_kernel) -> float:
     """Seconds of the Pallas custom calls that ``is_kernel(parsed op)``
-    accepts (the HLO carries no kernel name, so a kernel is told by the
-    shapes it produces and the number of its operands)."""
+    accepts (by the instruction's name, which is the ``pallas_call``'s
+    ``name=``; ``roofline/paged_decode`` still tells its kernel by operands
+    and outputs)."""
     total = 0.0
     for name, sec in by_op.items():
         op = parse_op(name)
@@ -208,8 +209,6 @@ def reduce(path: str, chips: int, top: int = 10) -> dict | None:
         d.items(), key=lambda kv: -kv[1])[:top]]
     return {"busy_s": busy_s, "window_s": hi - lo, "by_op": by_op,
             "by_module": by_module, "gaps": gaps,
-            "module_runs": {n: sum(1 for e in devs[0]["modules"]
-                                   if e.name == n) for n in by_module},
             "breakdown": {"device_ops": rank(grouped),
                           "idle_gaps": rank(gaps)}}
 
