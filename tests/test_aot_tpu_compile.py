@@ -810,10 +810,18 @@ def test_composed_train_step_names_its_kernels(v5e):
         lambda p: TrainState.create(model.apply, p, optax.adamw(1e-4),
                                     rng=0), params)
     batch = jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
-    scopes = _kernel_scopes(
-        step, _on(NamedSharding(mesh, P()), state),
-        *_on(NamedSharding(mesh, spec.batch_spec()), (batch, batch)))
+    args = (_on(NamedSharding(mesh, P()), state),
+            *_on(NamedSharding(mesh, spec.batch_spec()), (batch, batch)))
+    scopes = _kernel_scopes(step, *args)
     assert scopes == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    # remat keeps the forward kernel's output and log-sum-exp, so the
+    # COMPILED step runs each kernel once a layer: the backward pass's
+    # recomputation holds no second flash_fwd
+    hlo = _compile(step, *args)
+    calls = {name: len(re.findall(rf"^\s*%{name}[.\d]* = .*custom-call\(",
+                                  hlo, re.M))
+             for name in scopes}
+    assert calls == {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
 
 
 # Off this PR's path (ROADMAP R1 and D7): compiled and REPORTED, not gated —

@@ -275,3 +275,100 @@ def test_flash_window_requires_causal():
     q, k, v = _qkv(s=32)
     with pytest.raises(ValueError, match="requires causal"):
         flash_attention(q, k, v, causal=False, window=8)
+
+
+# -- what TransformerLM(remat=True) keeps of the kernel ----------------------
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside its equations'
+    parameters (remat, scan, custom_vjp and jit bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _grad_of(model, toks):
+    from tpudist.ops.losses import cross_entropy
+
+    def loss(p):
+        logits = model.apply({"params": p}, toks)
+        return cross_entropy(logits[:, :-1].reshape(-1, logits.shape[-1]),
+                             toks[:, 1:].reshape(-1))
+
+    return jax.value_and_grad(loss)
+
+
+@pytest.mark.parametrize("attention,scan_layers,fwd_calls", [
+    ("flash", False, 2),    # one forward kernel a layer (a bare remat: 4)
+    ("flash", True, 1),     # the forward scan's body alone (a bare remat: 2)
+    ("sdpa", False, 0),     # no kernel, no names: the bare remat's step
+], ids=["flash_unrolled", "flash_scan_layers", "sdpa"])
+def test_remat_keeps_the_kernels_residuals(monkeypatch, attention,
+                                           scan_layers, fwd_calls):
+    """Under ``remat`` a block keeps its flash kernel's output and
+    log-sum-exp, so the backward pass's recomputation holds no second
+    forward kernel; what it hands the backward kernels is bit for bit what
+    a second call would have produced."""
+    import flax.linen as nn
+
+    from tpudist.models import transformer
+    from tpudist.ops.flash_attention import FLASH_RESIDUALS
+
+    cfg = TransformerConfig(vocab_size=32, num_layers=2, num_heads=2,
+                            embed_dim=32, max_seq_len=32,
+                            scan_layers=scan_layers)
+    fn = (flash_attention_fn(block_q=16, block_k=16) if attention == "flash"
+          else sdpa)
+    toks = jnp.asarray(
+        np.random.default_rng(0).integers(0, 32, (2, 32)), jnp.int32)
+    plain = TransformerLM(cfg, attention_fn=fn)
+    remat = TransformerLM(cfg, attention_fn=fn, remat=True)
+    params = plain.init(jax.random.key(0), toks)["params"]
+
+    def census(model):
+        eqns = list(_eqns(jax.make_jaxpr(
+            _grad_of(model, toks))(params).jaxpr))
+        kernels = [e.params["name"] for e in eqns
+                   if e.primitive.name == "pallas_call"]
+        names = sorted(e.params["name"] for e in eqns
+                       if e.primitive.name == "name")
+        return len(eqns), kernels, names
+
+    n_eqns, kernels, names = census(remat)
+    assert kernels.count("flash_fwd") == fwd_calls
+    if attention == "flash":
+        # the backward kernels stand: one of each a layer (one scan body)
+        assert kernels.count("flash_bwd_dq") == fwd_calls
+        assert kernels.count("flash_bwd_dkv") == fwd_calls
+        assert set(names) == set(FLASH_RESIDUALS)
+    else:
+        assert names == []
+
+    def same(a, b):
+        jax.tree.map(lambda x, y: np.testing.assert_array_equal(
+            np.asarray(x), np.asarray(y)), a, b)
+
+    got = _grad_of(remat, toks)(params)
+    # the same model under a bare remat, which keeps a block's input alone:
+    # twice the forward kernels, and the same numbers to the bit (the saved
+    # tensor IS the recomputed one)
+    with monkeypatch.context() as m:
+        m.setattr(
+            transformer, "_remat_block",
+            lambda: nn.remat(transformer.DecoderBlock, static_argnums=(2,)))
+        bare_eqns, bare_kernels, _ = census(remat)
+        if attention == "flash":
+            assert bare_kernels.count("flash_fwd") == 2 * fwd_calls
+        else:
+            assert (bare_eqns, bare_kernels) == (n_eqns, kernels)
+        same(got, _grad_of(remat, toks)(params))
+
+    want = _grad_of(plain, toks)(params)
+    if scan_layers:
+        # a scan body compiled with its remat is another CPU program than
+        # the plain body (so it was under the bare remat): to rounding
+        jax.tree.map(lambda x, y: np.testing.assert_allclose(
+            np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-7), got, want)
+    else:
+        same(got, want)

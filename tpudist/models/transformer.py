@@ -1631,6 +1631,24 @@ class DecoderBlock(nn.Module):
         return x + y.reshape(b, s, d)
 
 
+def _remat_block():
+    """:class:`DecoderBlock` under ``nn.remat``, for both layer layouts:
+    the backward pass recomputes a block from its input, except the two
+    residuals its flash-attention kernel produced (the output and the
+    log-sum-exp), which are kept.  They are the one activation dearer to
+    recompute than to hold: giving them back costs a whole second run of
+    the forward kernel, holding them costs about one more block input
+    (``B x S x E`` in the compute dtype, plus 1/64 of it for the
+    log-sum-exp).  Another attention function carries no such names, so
+    the policy keeps nothing and the block is recomputed whole."""
+    from tpudist.ops.flash_attention import FLASH_RESIDUALS
+
+    return nn.remat(
+        DecoderBlock, static_argnums=(2,),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS))
+
+
 class _ScanBody(nn.Module):
     """One scanned step of the layer stack: wraps :class:`DecoderBlock`
     with the ``(carry, x) -> (carry, y)`` signature ``nn.scan`` expects.
@@ -1646,8 +1664,7 @@ class _ScanBody(nn.Module):
 
     @nn.compact
     def __call__(self, x, _):
-        blk = (nn.remat(DecoderBlock, static_argnums=(2,)) if self.remat
-               else DecoderBlock)
+        blk = _remat_block() if self.remat else DecoderBlock
         x = blk(self.cfg, self.attention_fn, decode=self.decode,
                 decode_attention=self.decode_attention,
                 decode_shard=self.decode_shard,
@@ -1722,7 +1739,11 @@ class TransformerLM(nn.Module):
                              f"got {cfg.positions!r}")
         # remat: recompute each block's activations in backward instead of
         # storing them — the jax.checkpoint memory/FLOPs trade that makes
-        # long-context training fit in HBM.  Default prevent_cse=True:
+        # long-context training fit in HBM.  What is kept a layer is the
+        # block's input and, where the attention is the flash kernel, its
+        # output and log-sum-exp (about a second block input): the backward
+        # kernels need them and only a second forward kernel call could
+        # give them back (see _remat_block).  Default prevent_cse=True:
         # under plain jit XLA could otherwise CSE the recomputation back
         # into the stored forward and silently undo the memory savings.
         if cfg.scan_layers:
@@ -1752,8 +1773,7 @@ class TransformerLM(nn.Module):
                            self.decode_attention, self.decode_shard,
                            causal, self.remat, name="blocks")(x, None)
         else:
-            block_cls = (nn.remat(DecoderBlock, static_argnums=(2,))
-                         if self.remat else DecoderBlock)
+            block_cls = _remat_block() if self.remat else DecoderBlock
             for i in range(cfg.num_layers):
                 windowed = cfg.layer_window(i) is not None
                 x = block_cls(cfg, self.attention_fn, decode=self.decode,

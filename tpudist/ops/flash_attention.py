@@ -22,7 +22,11 @@ dK/dV pass) that recompute P block-by-block from the saved (q, k, v, lse)
 with the standard dS = P ∘ (dO·Vᵀ − rowsum(dO ∘ O)) identities, so the
 [S, S] score matrix never exists in HBM in either direction and training
 memory stays linear in sequence length.  The per-row
-Δ = rowsum(dO ∘ O) is an O(S·D) elementwise reduction left to XLA.
+Δ = rowsum(dO ∘ O) is an O(S·D) elementwise reduction left to XLA.  The
+forward rule names the kernel's output and log-sum-exp
+(:data:`FLASH_RESIDUALS`), so a ``jax.checkpoint`` policy can keep them:
+under ``TransformerLM(remat=True)`` the backward pass recomputes a block
+without a second run of the forward kernel.
 
 On CPU (tests, CI) the kernel runs in interpreter mode automatically;
 numerics match :func:`tpudist.models.sdpa` to float tolerance either way.
@@ -34,6 +38,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,6 +47,12 @@ _NEG_BIG = -1e30
 # applies) from an explicit window=None (full causal attention) — so a
 # model config's attention_window always overrides the factory's.
 _UNSET = object()
+# The two residuals only the forward kernel can give the backward pass,
+# named so that a ``jax.checkpoint`` policy can keep them, as
+# ``TransformerLM(remat=True)`` does.
+FLASH_OUT_NAME = "flash_out"
+FLASH_LSE_NAME = "flash_lse"
+FLASH_RESIDUALS = (FLASH_OUT_NAME, FLASH_LSE_NAME)
 
 
 def _can_prune(window, causal, q_offset, k_offset) -> bool:
@@ -376,6 +387,12 @@ def _flash(q, k, v, causal, block_q, block_k, interpret, window):
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
                               window=window)
+    # The TAGGED values are both the primal output and the residuals, so
+    # nothing downstream reads the kernel's untagged outputs: under a
+    # policy that saves these names the recomputation's forward kernel has
+    # no consumer and is dropped.  q, k, v stay untagged (cheap to redo).
+    out = checkpoint_name(out, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, out, lse)
 
 
