@@ -530,6 +530,102 @@ def test_mixed_window_model_names_its_kernels(v5e):
     _compile(loop._admit_finish, *finish)
 
 
+# The Olmo-Hybrid cell (olmoh7b_doc_mixed): 16 lanes, 30 linear-attention
+# heads of a 96 x 192 float32 state each (kept [96, 30 x 192] a lane), prefill
+# chunks of 512 tokens; the full layers 30 query heads on 30 K/V heads of 128
+# over 72 table entries of 128 rows.
+LIN_LANES, LIN_HEADS, LIN_DK, LIN_DV, LIN_CHUNK = 16, 30, 96, 192, 512
+
+
+def test_delta_step_at_the_cells_shapes(v5e):
+    """The decode kernel of the gated delta rule: every lane's state read
+    once and written once IN PLACE (the state argument is donated and the
+    second output aliases it), ten heads a grid step, under its name."""
+    from tpudist.ops.delta_rule import gated_delta_step, step_heads
+
+    assert step_heads(LIN_HEADS, LIN_DK, LIN_DV) == (10, 2)
+    f32 = jnp.float32
+    vec = lambda *shape: _sds(v5e, (LIN_LANES, LIN_HEADS, *shape), f32)  # noqa
+    state = _sds(v5e, (LIN_LANES, LIN_DK, LIN_HEADS * LIN_DV), f32)
+    step = jax.jit(
+        lambda q, k, v, g, b, s, ok: gated_delta_step(q, k, v, g, b, s, ok),
+        donate_argnums=(5,))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = step.lower(
+            vec(LIN_DK), vec(LIN_DK), vec(LIN_DV), vec(), vec(), state,
+            _sds(v5e, (LIN_LANES,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert _kernel_calls(hlo) == 1
+    op = _custom_call(hlo, "delta_step")
+    assert op["operands"] == 6 and len(op["outputs"]) == 2
+    # no second copy of the 35 MB of states: the output is the argument
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= LIN_LANES * LIN_DK * LIN_HEADS \
+        * LIN_DV * 4
+    assert memory.temp_size_in_bytes < 4 << 20
+
+
+def test_delta_chunk_at_the_cells_shapes(v5e):
+    """The chunk form at a prefill chunk's width: XLA products, no kernel,
+    a loop over the sub-blocks."""
+    from tpudist.ops.delta_rule import gated_delta_chunk
+
+    f32 = jnp.float32
+    tok = lambda *shape: _sds(v5e, (1, LIN_CHUNK, LIN_HEADS, *shape), f32)  # noqa
+    hlo = _compile(
+        gated_delta_chunk, tok(LIN_DK), tok(LIN_DK), tok(LIN_DV), tok(),
+        tok(), _sds(v5e, (1, LIN_HEADS, LIN_DK, LIN_DV), f32),
+        _sds(v5e, (1, LIN_CHUNK), jnp.bool_))
+    assert _kernel_calls(hlo) == 0
+    assert re.search(r"^\s*%while[.\d]* = ", hlo, re.M)
+
+
+def test_mixed_kind_model_names_its_kernels(v5e):
+    """Linear-attention layers beside full attention at many K/V heads (the
+    Olmo-Hybrid block at a small size: three linear layers then a full one,
+    the norm after the sublayer, no positions): the segment's kernels and
+    the prefill chunk's by the names a trace shows, each under its routine,
+    and the finish program, which copies the state by slot."""
+    from tpudist.models.transformer import LinearAttentionConfig
+
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=4, num_heads=8, head_size=128,
+        embed_dim=512, max_seq_len=2048, compute_dtype=jnp.bfloat16,
+        norm="rmsnorm", norm_order="post", positions="none",
+        mlp="gated_silu", mlp_dim=1024, qk_norm="whole",
+        layer_kinds=("linear", "linear", "linear", "full"),
+        linear=LinearAttentionConfig(num_heads=4, key_dim=96,
+                                     value_dim=192))
+    loop = ServeLoop(cfg, _abstract_params(TransformerLM(cfg)),
+                     num_slots=SLOTS, steps_per_sync=SCOPE_STEPS,
+                     decode_attention="flash", prefill_chunk=CHUNK,
+                     cache_layout="paged", kv_block_size=BLOCK,
+                     max_prefill_lanes=2)
+    programs = {
+        name: _compile(jitted, *_on(v5e, args), **static)
+        for name, (jitted, args, static) in loop.serve_programs().items()}
+    seg, chunk = programs["_segment_impl"], programs["_prefill_chunk_impl"]
+    assert _kernel_calls(seg) == 3 + 1 and _kernel_calls(chunk) == 1
+    found = {prog: set(hlo_scopes(text).values())
+             for prog, text in programs.items()}
+    assert {"linear_attn", "delta_step", "attn/core", "attn/cache",
+            "mlp/dense", "head"} <= found["_segment_impl"]
+    assert {"linear_attn", "delta_chunk", "attn/core"} <= found[
+        "_prefill_chunk_impl"]
+    assert "delta_chunk" not in found["_segment_impl"]
+    assert "delta_step" not in found["_prefill_chunk_impl"]
+    for name, routine in (("delta_step", "delta_step"),
+                          ("paged_flash_decode", "attn/core")):
+        scoped = [scope for inst, scope in hlo_scopes(seg).items()
+                  if re.match(rf"{name}[.\d]*$", inst)]
+        assert scoped and set(scoped) == {routine}, name
+    # the state rides in the slot cache beside the pages, by slot
+    lin = loop.cache["block0"]["linear_attn"]
+    assert lin["state"].shape == (SLOTS, 96, 4 * 192)
+    assert lin["conv"].shape == (SLOTS, 3 * 4 * (2 * 96 + 192))
+    assert "page_table" in loop.cache["block3"]["attn"]
+
+
 # The Keye-VL 2.0 cell (keye2_longdoc_mixed): 16 lanes, 256 table entries of
 # 128 rows, a pool of 2900 blocks, side 16; 32 query heads on 4 K/V heads of
 # 128; an indexer of 16 heads of 64 (a key stored 128 wide) that keeps 2048

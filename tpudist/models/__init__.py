@@ -36,6 +36,7 @@ from tpudist.models.serving import (
     ServeLoop,
 )
 from tpudist.models.transformer import (
+    LinearAttentionConfig,
     MLAConfig,
     TransformerConfig,
     TransformerLM,
@@ -58,6 +59,7 @@ __all__ = [
     "adaptive_speculative_generate",
     "beam_search_generate",
     "EmbeddingBagClassifier",
+    "LinearAttentionConfig",
     "MLAConfig",
     "MLP",
     "MoEConfig",

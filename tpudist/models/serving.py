@@ -290,6 +290,24 @@ def hlo_scopes(hlo_text: str) -> dict[str, str]:
     return scopes
 
 
+def _state_nodes(cache: Any) -> list[dict]:
+    """The cache nodes of the layers whose past is a STATE and not rows (a
+    linear-attention layer's ``state`` and ``conv`` leaves): a slot's slice
+    of each leaf is the whole of what the layer keeps for that lane."""
+    found = []
+
+    def walk(node):
+        if isinstance(node, dict):
+            if "state" in node:
+                found.append(node)
+            else:
+                for v in node.values():
+                    walk(v)
+
+    walk(cache)
+    return found
+
+
 def _bound_paged_walk(cache: Any, active) -> Any:
     """Entry of a segment, paged layout: a lane that is not active holds
     no request (released, finalised, or still prefilling through the
@@ -610,6 +628,29 @@ class ServeLoop:
                     "a model with an indexer serves through "
                     "cache_layout='paged': the dense layout's per-row "
                     "decode has no index scores or selection")
+        # layers whose past is a fixed-size state a lane (cfg.layer_kinds
+        # "linear"): what such a cache cannot do yet is refused here with
+        # the reason (docs/DESIGN.md has the list)
+        self._state_layers = [i for i, kind in enumerate(cfg.kinds)
+                              if kind == "linear"]
+        if self._state_layers:
+            if decode_mode == "speculative":
+                raise ValueError(
+                    "a model with linear-attention layers decodes one "
+                    "token a lane a step: a rejected draft token has "
+                    "already moved the state and there is no rollback of "
+                    "it: decode_mode='plain'")
+            if role != "both" or preempt == "migrate":
+                raise ValueError(
+                    "KV handoff and migration payloads carry a lane's "
+                    "blocks and no state; a model with linear-attention "
+                    "layers serves with role='both' and preempt='degrade'")
+            if not chunked_prefill:
+                raise ValueError(
+                    "a model with linear-attention layers is admitted "
+                    "chunk by chunk (chunked_prefill=True): the one-shot "
+                    "admission pads the prompt and has no mask for the "
+                    "padded rows, which would move the state")
         if decode_mode == "speculative":
             if draft_cfg is None or draft_params is None:
                 raise ValueError(
@@ -697,11 +738,15 @@ class ServeLoop:
         # layout.  A model with an indexer shares nothing either: a hit
         # starts the suffix's first chunk at a block boundary inside a
         # chunk, and no test holds a chunk's selection there, nor three
-        # leaves through the gathered prefix)
+        # leaves through the gathered prefix.  A model with state layers
+        # shares nothing: a hit would need the STATE as it stood at the
+        # prefix's last block boundary, and only the state after the whole
+        # prompt is kept; and so no host tier either)
         self._prefix_cache = (
             PrefixCache(self.pool)
             if prefix_sharing and self.chunked and self.pool is not None
-            and wg is None and self._index_topk is None else None)
+            and wg is None and self._index_topk is None
+            and not self._state_layers else None)
         # the weights version the loop's CURRENT params correspond to;
         # stamps tier entries and pull-mode exports so KV computed
         # under one version can never be adopted under another (the
@@ -764,6 +809,12 @@ class ServeLoop:
         if self.side:
             self.cache = self._with_side_buffers(self.cache)
         self._blank1 = _blank_cache(self._prefill_model, 1)  # prefill cache
+        # what the state layers keep for ONE lane, all of them together
+        # (fixed at construction: num_slots bounds it, where the pool
+        # bounds the rows)
+        self._state_lane_bytes = sum(
+            leaf.nbytes for node in _state_nodes(self.cache)
+            for leaf in node.values()) // num_slots
         if self.pool is not None and _kv_leaves(
                 self._paged_nodes(self.cache)[0], "paged") != ["key",
                                                                "value"]:
@@ -940,6 +991,10 @@ class ServeLoop:
         self._obs_expert_slots = obs.counter("serve/expert_slots",
                                              unit="slots")
         self._obs_segments = obs.counter("serve/segments", unit="segments")
+        # state layers: bytes of state the decoding lanes held when the
+        # last segment was dispatched (lanes x _state_lane_bytes; 0 for a
+        # model without such layers)
+        self._obs_state_bytes = obs.gauge("serve/state_bytes", unit="bytes")
         self._obs_queue = obs.gauge("serve/queue_depth", unit="reqs")
         self._obs_degraded = obs.gauge("serve/degraded", unit="bool")
         self._obs_degrade_clamped = obs.counter("serve/degrade_clamped",
@@ -1204,9 +1259,14 @@ class ServeLoop:
             # a row active at step ENTRY writes a real token's K/V this
             # step — the merge later scatters exactly these side slots
             lived = lived + active.astype(jnp.int32)
+            # a lane that is frozen, empty or past its budget computes a
+            # step like any other; rows of a cache shrug that off, a STATE
+            # must be told (``lived`` above counts by the same rule)
+            owned = ({"valid": active[:, None]} if self._state_layers
+                     else {})
             logits, mut = self.model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
-                positions=pos[:, None],
+                positions=pos[:, None], **owned,
                 mutable=["cache", "stats"] if experts else ["cache"])
             if experts:
                 X = X + jnp.stack([
@@ -1460,9 +1520,15 @@ class ServeLoop:
         admission does not hold ``[1, chunk, V]`` float32 from its last
         chunk to its finish; None: all of them."""
         cache1 = _set_cache_index(cache1, off)
+        # the prompt's tokens end at ``row`` (the loop asks for the row of
+        # the prompt's last token, or a chunk's last): the padded rows of a
+        # last chunk past it must not move a state
+        owned = ({"valid": jnp.arange(chunk)[None, :] <= row}
+                 if self._state_layers and row is not None else {})
         logits, mut = self._prefill_model.apply(
             {"params": params, "cache": cache1}, toks,
-            positions=off + jnp.arange(chunk)[None, :], mutable=["cache"])
+            positions=off + jnp.arange(chunk)[None, :], **owned,
+            mutable=["cache"])
         if row is not None:
             with obs.routine("head"):
                 logits = lax.dynamic_slice_in_dim(logits, row, 1, axis=1)
@@ -3089,6 +3155,16 @@ class ServeLoop:
                      if self.decode_mode == "speculative" else 0)
                 pages = rows = rows_live = rows_selected = 0
                 windowed = None
+                # the lanes whose state the slot cache holds (a lane in
+                # admission carries its state in its batch-1 cache)
+                state_lanes = 0
+                if self._state_layers:
+                    state_lanes = sum(
+                        1 for st in slot_state
+                        if st is not None and not st.get("zombie")
+                        and "prefill" not in st)
+                    self._obs_state_bytes.set(
+                        state_lanes * self._state_lane_bytes)
                 if self.pool is not None:
                     wg = self.pool.window_group
                     rows_w = rows_w_live = 0
@@ -3178,7 +3254,7 @@ class ServeLoop:
                 pass
             inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
                              (pages, rows, rows_live, rows_selected,
-                              windowed)))
+                              windowed, state_lanes)))
             seq += 1
             self._obs_depth.set(len(inflight))
             # fault harness: a configured kill-after-K-segments SIGKILLs
@@ -3220,10 +3296,14 @@ class ServeLoop:
             A model with an indexer adds ``rows_scored`` (the index keys its
             scores read, a layer: the live lanes' lengths) and
             ``rows_selected`` (the rows its attention reads:
-            ``min(length, index_topk)`` a lane)."""
+            ``min(length, index_topk)`` a lane).  A model with
+            linear-attention layers adds ``state_lanes`` (the lanes whose
+            state the slot cache held at dispatch: the decoding ones) and
+            ``state_bytes`` (those lanes times what the state layers keep
+            for one)."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
              t_disp, (pages, rows, rows_live, rows_selected,
-                      windowed)) = inflight.popleft()
+                      windowed, state_lanes)) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
                    and "seq" in st and st["seq"] <= s_idx
@@ -3336,6 +3416,10 @@ class ServeLoop:
                     self._obs_rows_selected.inc(rows_selected * steps_run)
                     routed.update(rows_scored=rows_live,
                                   rows_selected=rows_selected)
+                if self._state_layers:
+                    routed.update(
+                        state_lanes=state_lanes,
+                        state_bytes=state_lanes * self._state_lane_bytes)
                 if self._expert_blocks:
                     n_cells = len(self._expert_blocks) * self._held
                     counts = emits[self.B:].reshape(-1)[:n_cells]

@@ -215,6 +215,26 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearAttentionConfig:
+    """The sizes of a gated delta-rule layer (:class:`LinearAttention`):
+    ``num_heads`` heads, each a ``key_dim x value_dim`` float32 matrix of
+    state; a causal depthwise convolution of ``conv_width`` taps over the
+    q, k and v channels; ``neg_eigval`` doubles the write strength
+    (``beta`` in (0, 2): a state's eigenvalues may go negative)."""
+
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+    neg_eigval: bool = True
+
+    @property
+    def conv_channels(self) -> int:
+        """q, k and v side by side: what the convolution runs over."""
+        return self.num_heads * (2 * self.key_dim + self.value_dim)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256
     num_layers: int = 2
@@ -236,6 +256,12 @@ class TransformerConfig:
     # causal attention (None here: every layer takes attention_window).
     # Read through ``layer_window(i)``.
     layer_windows: tuple | None = None
+    # One entry a layer: its KIND, "full" | "window" | "linear" (None: by
+    # its window alone, as ever).  "linear" is a gated delta-rule layer of
+    # the sizes under ``linear``: its past is a fixed-size state and not
+    # rows of a cache.  Read through ``layer_kind(i)``.
+    layer_kinds: tuple | None = None
+    linear: LinearAttentionConfig | None = None
     # A head's width where it is not embed_dim // num_heads (None: that
     # quotient).  Read through ``head_dim``.
     head_size: int | None = None
@@ -264,7 +290,12 @@ class TransformerConfig:
     # where the block is built, and nothing is keyed on a model's name.
     norm: str = "layernorm"            # | "rmsnorm"
     norm_eps: float = 1e-6
-    positions: str = "learned"         # | "rotary" (no position table)
+    # "pre": x + f(norm(x)); "post": x + norm(f(x)) (the norm AFTER the
+    # sublayer, on its output alone)
+    norm_order: str = "pre"
+    # | "rotary" (no position table) | "none" (no positions at all: layers
+    # that carry the order themselves, a convolution and a decay)
+    positions: str = "learned"
     rope_theta: float = 10000.0
     rope_scaling: YarnScaling | None = None
     # the windowed layers' rotary scaling where it is not the full
@@ -283,9 +314,11 @@ class TransformerConfig:
     # ``first_k_dense`` layers keep the dense MLP
     moe: Any = None
     first_k_dense: int = 0
-    # a per-head RMSNorm (one scale of head_dim, shared by the heads) on
-    # the queries and the keys before the rotation
-    qk_norm: bool = False
+    # True: a per-head RMSNorm (one scale of head_dim, shared by the
+    # heads) on the queries and the keys before the rotation; "whole": one
+    # RMSNorm over the WHOLE projection (a scale of every head's every
+    # feature), before the split into heads
+    qk_norm: bool | str = False
     # a learned indexer (sparse attention over grouped-query K/V):
     # ``index_heads`` index queries of ``index_head_dim`` a token, ONE index
     # key a token (cached beside K and V), a float32 weight a head; a query
@@ -296,6 +329,36 @@ class TransformerConfig:
     index_topk: int | None = None
 
     def __post_init__(self):
+        if self.norm_order not in ("pre", "post"):
+            raise ValueError(f"norm_order must be 'pre' or 'post', got "
+                             f"{self.norm_order!r}")
+        if self.qk_norm not in (False, True, "whole"):
+            raise ValueError(f"qk_norm must be False, True or 'whole', got "
+                             f"{self.qk_norm!r}")
+        if self.layer_kinds is not None:
+            if len(self.layer_kinds) != self.num_layers:
+                raise ValueError(
+                    f"layer_kinds has {len(self.layer_kinds)} entries for "
+                    f"{self.num_layers} layers")
+            for i, kind in enumerate(self.layer_kinds):
+                if kind not in ("full", "window", "linear"):
+                    raise ValueError(
+                        f"a layer's kind is 'full', 'window' or 'linear', "
+                        f"got {kind!r}")
+                if (kind == "window") != (kind != "linear" and
+                                          self.layer_window(i) is not None):
+                    raise ValueError(
+                        f"layer {i} is {kind!r} with window "
+                        f"{self.layer_window(i)}: a 'window' layer states "
+                        "its width, a 'full' layer none")
+            if "linear" in self.layer_kinds and (
+                    self.linear is None or self.mla is not None
+                    or self.index_topk is not None or self.scan_layers):
+                raise ValueError(
+                    "a 'linear' layer takes its sizes from `linear` and "
+                    "stands beside multi-head / grouped-query layers in "
+                    "the unrolled layout: no latent attention, no "
+                    "indexer, no scan_layers")
         sizes = (self.index_heads, self.index_head_dim, self.index_topk)
         if any(v is None for v in sizes) and any(v is not None
                                                  for v in sizes):
@@ -362,6 +425,18 @@ class TransformerConfig:
     def windows(self) -> tuple:
         """``layer_window`` of every layer."""
         return tuple(self.layer_window(i) for i in range(self.num_layers))
+
+    def layer_kind(self, i: int | None) -> str:
+        """Layer ``i``'s kind: ``layer_kinds[i]`` where the model states
+        kinds, else by its window."""
+        if self.layer_kinds is not None and i is not None:
+            return self.layer_kinds[i]
+        return "full" if self.layer_window(i) is None else "window"
+
+    @property
+    def kinds(self) -> tuple:
+        """``layer_kind`` of every layer."""
+        return tuple(self.layer_kind(i) for i in range(self.num_layers))
 
     def layer_rope_scaling(self, i: int | None) -> YarnScaling | None:
         if (self.layer_window(i) is not None
@@ -601,7 +676,15 @@ class CausalSelfAttention(nn.Module):
                               dtype=cfg.compute_dtype, name="kv")(x)
                 kv = kv.reshape(b, s, 2, cfg.kv_heads, cfg.head_dim)
                 k, v = kv[:, :, 0], kv[:, :, 1]
-            if cfg.qk_norm:
+            if cfg.qk_norm == "whole":
+                def whole(x, name):
+                    flat = x.reshape(b, s, -1)
+                    return nn.RMSNorm(
+                        epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                        name=name)(flat).reshape(x.shape)
+
+                q, k = whole(q, "q_norm"), whole(k, "k_norm")
+            elif cfg.qk_norm:
                 q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
                                name="q_norm")(q)
                 k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
@@ -1553,6 +1636,114 @@ class LatentSelfAttention(nn.Module):
             return out[:, None]
 
 
+class LinearAttention(nn.Module):
+    """A gated delta-rule layer: the layer of the public
+    ``flash-linear-attention`` ``GatedDeltaNet``.  From the block's input
+    ``u``: ``q, k, v = SiLU(conv(u W))`` (one projection, a causal
+    depthwise convolution a channel, no bias); a head's ``q`` and ``k``
+    scaled to unit length, ``q`` by ``key_dim ** -0.5`` besides; ``beta =
+    sigmoid(u Wb)`` (doubled under ``neg_eigval``), ``log alpha = -exp(A_log)
+    * softplus(u Wa + dt_bias)``, both a number a head, float32; the state
+    through :mod:`tpudist.ops.delta_rule`; ``y = Wo[RMSNorm(o) * SiLU(u
+    Wg)]``, the norm a head.
+
+    With ``decode`` its past lives in two cache leaves that are NOT rows:
+    ``state [B, key_dim, heads * value_dim]`` float32 (the heads' matrices
+    side by side: ``ops.delta_rule`` says why) and ``conv [B, (conv_width -
+    1) * channels]`` (the last inputs of the convolution, oldest first, side
+    by side).  One token a call takes the recurrent step, several the chunk
+    form.  ``valid [B, S]`` (None: every token): the tokens that count, a
+    PREFIX of each row; the rest move neither leaf."""
+
+    cfg: TransformerConfig
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, *,
+                 valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        from tpudist.ops import delta_rule as dr
+
+        cfg, lin = self.cfg, self.cfg.linear
+        f32 = jnp.float32
+        b, s, _ = x.shape
+        h, dk, dv = lin.num_heads, lin.key_dim, lin.value_dim
+        taps, chans = lin.conv_width, lin.conv_channels
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=cfg.compute_dtype)
+        with routine("linear_attn"):
+            mixed = dense(chans, name="qkv")(x)
+            gate = dense(h * dv, name="gate")(x)
+            # the decay's and the write strength's inputs: float32 sums of
+            # bf16-valued products
+            ab = nn.Dense(2 * h, use_bias=False, dtype=f32, name="ab")(x)
+            w = self.param("conv", nn.initializers.lecun_normal(),
+                           (taps, chans))
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1.0, 16.0)), (h,))
+
+            def dt_init(key, shape):
+                dt = jnp.exp(jax.random.uniform(
+                    key, shape, f32, jnp.log(1e-3), jnp.log(1e-1)))
+                return dt + jnp.log(-jnp.expm1(-dt))    # softplus^-1
+
+            dt_bias = self.param("dt_bias", dt_init, (h,))
+            tail_var = state_var = None
+            if self.decode:
+                tail_var = self.variable(
+                    "cache", "conv", jnp.zeros, (b, (taps - 1) * chans),
+                    cfg.compute_dtype)
+                state_var = self.variable(
+                    "cache", "state", jnp.zeros, (b, dk, h * dv), f32)
+                tail = tail_var.value.reshape(b, taps - 1, chans)
+            else:
+                tail = jnp.zeros((b, taps - 1, chans), mixed.dtype)
+            seen = jnp.concatenate([tail, mixed.astype(tail.dtype)], axis=1)
+            conv = sum(w[j].astype(f32) * seen[:, j:j + s].astype(f32)
+                       for j in range(taps))
+            conv = nn.silu(conv)
+            if tail_var is not None:
+                # the last inputs that count: rows [n, n + taps - 1) of
+                # tail + chunk, n the valid tokens (none: the tail stays)
+                if valid is None:
+                    kept = seen[:, s:]
+                else:
+                    n = jnp.sum(valid.astype(jnp.int32), axis=1)
+                    kept = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+                        row, at, taps - 1, axis=0))(seen, n)
+                tail_var.value = kept.reshape(b, -1)
+
+            def unit(t):
+                return t * jax.lax.rsqrt(
+                    jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+            q = unit(conv[..., :h * dk].reshape(b, s, h, dk)) * dk ** -0.5
+            k = unit(conv[..., h * dk:2 * h * dk].reshape(b, s, h, dk))
+            v = conv[..., 2 * h * dk:].reshape(b, s, h, dv)
+            g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                ab[..., :h] + dt_bias.astype(f32))
+            beta = jax.nn.sigmoid(ab[..., h:]) * (
+                2.0 if lin.neg_eigval else 1.0)
+            if state_var is not None and s == 1:
+                o, state_var.value = dr.gated_delta_step(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    state_var.value,
+                    None if valid is None else valid[:, 0])
+                o = o[:, None]
+            else:
+                state = (jnp.zeros((b, h, dk, dv), f32) if state_var is None
+                         else dr.state_to_heads(state_var.value, h))
+                o, state = dr.gated_delta_chunk(q, k, v, g, beta, state,
+                                                valid)
+                if state_var is not None:
+                    state_var.value = dr.state_from_heads(state)
+            o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32,
+                           name="o_norm")(o)
+            o = o * nn.silu(gate.reshape(b, s, h, dv).astype(f32))
+            return dense(cfg.embed_dim, name="out")(
+                o.reshape(b, s, h * dv).astype(cfg.compute_dtype))
+
+
 class MLPBlock(nn.Module):
     cfg: TransformerConfig
 
@@ -1592,43 +1783,72 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, causal: bool = True,
-                 positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 positions: Optional[jnp.ndarray] = None,
+                 valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         # NOTE: ``causal`` is positional (arg 2) so nn.remat can mark it
         # static (static_argnums) — keyword args would be traced.
         cfg = self.cfg
-        # a block's norm counts to the routine that reads it first
-        with routine("attn/proj"):
-            h = make_norm(cfg, "ln1")(x)
+        linear = cfg.layer_kind(self.layer) == "linear"
+        post = cfg.norm_order == "post"
+        # a block's norm counts to the routine that reads it first (under
+        # "post": that wrote what it reads)
+        mixer_scope = "linear_attn" if linear else "attn/proj"
+        mlp_scope = "mlp/route" if self.expert_layer else "mlp/dense"
+        h = x
+        if not post:
+            with routine(mixer_scope):
+                h = make_norm(cfg, "ln1")(x)
         cache_kw = dict(decode=self.decode,
                         decode_attention=self.decode_attention,
                         serve_side_slots=self.serve_side_slots,
                         cache_layout=self.cache_layout,
                         kv_num_blocks=self.kv_num_blocks,
                         kv_block_size=self.kv_block_size, name="attn")
-        if cfg.mla is not None:
+        if linear:
+            if not causal:
+                raise ValueError("a linear-attention layer is a recurrence "
+                                 "over the tokens in order: causal only")
+            if self.decode_shard is not None:
+                raise NotImplementedError(
+                    "a linear-attention layer has no sharded decode yet")
+            y = LinearAttention(cfg, decode=self.decode,
+                                name="linear_attn")(h, valid=valid)
+        elif cfg.mla is not None:
             if self.decode_shard is not None:
                 raise NotImplementedError(
                     "latent attention has no sharded decode yet")
             if any(w is not None for w in cfg.windows):
                 raise ValueError("latent attention has no sliding window")
-            attn = LatentSelfAttention(cfg, **cache_kw)
+            y = LatentSelfAttention(cfg, **cache_kw)(
+                h, causal=causal, positions=positions)
         else:
-            attn = CausalSelfAttention(
+            y = CausalSelfAttention(
                 cfg, self.attention_fn, decode_shard=self.decode_shard,
                 layer=self.layer,
-                prefill_window_rows=self.prefill_window_rows, **cache_kw)
-        x = x + attn(h, causal=causal, positions=positions)
-        with routine("mlp/route" if self.expert_layer else "mlp/dense"):
-            h = make_norm(cfg, "ln2")(x)
+                prefill_window_rows=self.prefill_window_rows, **cache_kw)(
+                h, causal=causal, positions=positions)
+        if post:
+            with routine(mixer_scope):
+                y = make_norm(cfg, "ln1")(y)
+        x = x + y
+        h = x
+        if not post:
+            with routine(mlp_scope):
+                h = make_norm(cfg, "ln2")(x)
         if not self.expert_layer:
-            return x + MLPBlock(cfg, name="mlp")(h)
-        from tpudist.models.moe import MoEMLP
+            y = MLPBlock(cfg, name="mlp")(h)
+        else:
+            from tpudist.models.moe import MoEMLP
 
-        b, s, d = h.shape
-        y, _ = MoEMLP(d_model=d, d_ff=cfg.moe.d_ff or cfg.ffn_dim,
-                      moe=cfg.moe, dtype=cfg.compute_dtype,
-                      name="moe")(h.reshape(b * s, d))
-        return x + y.reshape(b, s, d)
+            b, s, d = h.shape
+            y, _ = MoEMLP(d_model=d, d_ff=cfg.moe.d_ff or cfg.ffn_dim,
+                          moe=cfg.moe, dtype=cfg.compute_dtype,
+                          name="moe")(h.reshape(b * s, d))
+            y = y.reshape(b, s, d)
+        if post:
+            with routine(mlp_scope):
+                y = make_norm(cfg, "ln2")(y)
+        return x + y
 
 
 def _remat_block():
@@ -1724,7 +1944,13 @@ class TransformerLM(nn.Module):
         *,
         causal: bool = True,
         positions: Optional[jnp.ndarray] = None,
+        valid: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
+        """``valid [B, S]`` bool (None: every token): the tokens that
+        count, a prefix of each row.  Read by the layers whose past is a
+        STATE (``layer_kinds`` "linear"), which a token that does not count
+        must not move; a cache of rows takes such a token as a row nobody
+        reads."""
         cfg = self.cfg
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
@@ -1734,9 +1960,9 @@ class TransformerLM(nn.Module):
             x = x + nn.Embed(cfg.max_seq_len, cfg.embed_dim,
                              dtype=cfg.compute_dtype,
                              name="pos_embed")(positions)
-        elif cfg.positions != "rotary":
-            raise ValueError(f"positions must be 'learned' or 'rotary', "
-                             f"got {cfg.positions!r}")
+        elif cfg.positions not in ("rotary", "none"):
+            raise ValueError(f"positions must be 'learned', 'rotary' or "
+                             f"'none', got {cfg.positions!r}")
         # remat: recompute each block's activations in backward instead of
         # storing them — the jax.checkpoint memory/FLOPs trade that makes
         # long-context training fit in HBM.  What is kept a layer is the
@@ -1791,9 +2017,12 @@ class TransformerLM(nn.Module):
                               # them apart: every other stack is built of
                               # the blocks it always was
                               layer=(i if cfg.layer_windows is not None
+                                     or cfg.layer_kinds is not None
                                      else None),
                               prefill_window_rows=self.prefill_window_rows,
-                              name=f"block{i}")(x, causal, positions)
+                              name=f"block{i}")(
+                    x, causal, positions,
+                    *(() if valid is None else (valid,)))
         with routine("head"):
             x = make_norm(cfg, "ln_f")(x)
             logits = nn.Dense(cfg.vocab_size, use_bias=False,

@@ -70,6 +70,11 @@ ROUTINE_SCOPES = (
     "mlp/experts",  # the grouped products and the combine
     "mlp/shared",   # the shared expert
     "head",         # final norm, lm_head, sampling, the emit buffer's write
+    # a linear-attention layer: its projections, conv, gates, norms and
+    # state copies, and nested in it the recurrence itself, by its form
+    "linear_attn",
+    "delta_step",   # one token a lane (the Pallas kernel of that name)
+    "delta_chunk",  # a chunk of tokens from a carried state
 )
 _SCOPE_IN_OP_NAME = re.compile(
     "(?:^|/)(" + "|".join(map(re.escape, ROUTINE_SCOPES)) + ")(?=/|$)")
