@@ -7,11 +7,18 @@ flight, as single rows and, where those do not lower, as the aligned 2-,
 copy: the descriptors' rate and not the memory's); against ``jnp.take`` of
 the same rows.  Prints ns a row of each.
 
-    python scripts/row_copy_rate.py
+``jnp.take`` itself is timed over four sources (ISSUE 42, step 1): the K/V
+pool row of today (bf16 ``[., 512]``), K and V of a token as ONE row (bf16
+``[., 1024]``), and the same bytes as 32-bit words (uint32 ``[., 256]`` and
+``[., 512]``: a bf16 row shares its sublanes with its neighbour, a 32-bit
+row does not).  ``--take-only`` stops after those four.
+
+    python scripts/row_copy_rate.py [--take-only]
 """
 
 import functools
 import json
+import sys
 import time
 
 import jax
@@ -23,6 +30,9 @@ from jax.experimental.pallas import tpu as pltpu
 ROWS, SOURCE, WIDTH, STEP = 24576, 300000, 512, 2048
 PIECES = ((1, 512), (2, 512), (8, 512), (16, 512), (8, 128))
 INFLIGHT = (16, 32)
+# jnp.take's sources: (name, dtype, columns); each of 300,000 rows
+TAKE_SOURCES = (("bf16_512", jnp.bfloat16, 512), ("bf16_1024", jnp.bfloat16, 1024),
+                ("u32_256", jnp.uint32, 256), ("u32_512", jnp.uint32, 512))
 
 
 def _kernel(ids_ref, src, out_ref, buf, sems, *, piece, cols, inflight):
@@ -72,8 +82,20 @@ def main():
     rng = np.random.default_rng(0)
     src = jnp.asarray(rng.normal(size=(SOURCE, WIDTH)), jnp.bfloat16)
     ids = jnp.sort(jnp.asarray(rng.choice(SOURCE, ROWS, False), jnp.int32))
-    took = seconds(jax.jit(lambda s, i: jnp.take(s, i, axis=0)), src, ids)
-    print(json.dumps({"jnp_take_ns_a_row": 1e9 * took / ROWS}), flush=True)
+    take = jax.jit(lambda s, i: jnp.take(s, i, axis=0))
+    for name, dtype, cols in TAKE_SOURCES:
+        wide = src if (dtype, cols) == (src.dtype, WIDTH) else jnp.asarray(
+            rng.integers(0, 2 ** 16, size=(SOURCE, cols)), dtype)
+        exact = bool((np.asarray(take(wide, ids))
+                      == np.asarray(wide)[np.asarray(ids)]).all())
+        took = seconds(take, wide, ids)
+        print(json.dumps({"jnp_take": name, "exact": exact,
+                          "ns_a_row": 1e9 * took / ROWS,
+                          "gb_s": ROWS * cols * wide.dtype.itemsize * 1e-9
+                          / took}), flush=True)
+        del wide
+    if "--take-only" in sys.argv[1:]:
+        return
     plain = np.asarray(src.astype(jnp.float32))
     for piece, cols, inflight in [(*p, n) for p in PIECES for n in INFLIGHT]:
         fn = jax.jit(functools.partial(row_copies, piece=piece, cols=cols,

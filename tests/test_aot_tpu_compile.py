@@ -685,30 +685,62 @@ def test_a_page_of_64_wide_index_keys_is_refused(v5e):
 
 
 def test_sparse_gqa_attend_at_the_cells_shapes(v5e):
-    """The chosen rows of both pools gathered by flat row id (the staged
-    rows out of the side buffers) and attended as pages of the gathered
-    buffers, on the shared walk under its own name."""
+    """The chosen rows of the ONE pool of K beside V gathered by flat row
+    id, once (the staged rows out of the one side buffer), and attended as
+    pages of the gathered buffer, keys and values the two halves of a
+    tile, on the shared walk under its own name."""
     from benchmarks.layer_metrics import _index_spans
     from benchmarks.layer_metrics.paged_decode_us_per_call import KERNEL
     from tpudist.ops.flash_decode import sparse_gqa_attend
 
-    flat = IDX_KV * IDX_D
+    width = 2 * IDX_KV * IDX_D
     hlo = _compile(
-        lambda q, k, v, i, c, sk, sv: sparse_gqa_attend(
-            q, k, v, i, c, packed_kv_heads=IDX_KV, side_k=sk, side_v=sv),
+        lambda q, kv, i, c, side: sparse_gqa_attend(
+            q, kv, i, c, packed_kv_heads=IDX_KV, side_kv=side),
         _sds(v5e, (IDX_LANES, IDX_Q, IDX_D)),
-        _sds(v5e, (IDX_BLOCKS * BLOCK, flat)),
-        _sds(v5e, (IDX_BLOCKS * BLOCK, flat)),
+        _sds(v5e, (IDX_BLOCKS * BLOCK, width)),
         _sds(v5e, (IDX_LANES, IDX_TOPK), jnp.int32),
         _sds(v5e, (IDX_LANES,), jnp.int32),
-        _sds(v5e, (IDX_LANES, IDX_SIDE, flat)),
-        _sds(v5e, (IDX_LANES, IDX_SIDE, flat)))
+        _sds(v5e, (IDX_LANES, IDX_SIDE, width)))
     assert _kernel_calls(hlo) == 1
     op = _named_call(hlo, "sparse_gqa_attend", _index_spans.ATTEND)
-    assert op["pallas"] and op["operands"] == 4   # meta, q, K rows, V rows
+    assert op["pallas"] and op["operands"] == 3   # meta, q, the rows
     assert op["outputs"] == (
         f"bf16[{IDX_LANES * IDX_KV},{IDX_Q // IDX_KV},{IDX_D}]",)
     assert not any(KERNEL.match(l.strip()) for l in hlo.splitlines())
+    # ONE gather of the chosen rows, K and V of a token in one row
+    assert len(re.findall(
+        rf"= bf16\[{IDX_LANES},{IDX_TOPK},{width}\]\S* gather\(", hlo)) == 1
+
+
+@pytest.mark.parametrize("kv_heads", [IDX_KV, 2 * IDX_KV])
+def test_every_row_walk_of_the_one_pool_at_the_cells_shapes(v5e, kv_heads):
+    """The every-row branch of an indexer's layer: ``paged_flash_decode``
+    over the one pool of K beside V and its one side buffer.  Four K/V
+    heads of 128: a lane a grid row, a page one copy of 2 KB rows, the tile
+    slots the 4 MiB the two pools' were.  Eight: two rows a lane, each a
+    copy of its columns of either half."""
+    from benchmarks.layer_metrics.paged_decode_us_per_call import KERNEL
+    from tpudist.ops.flash_decode import paged_flash_decode, paged_grid_rows
+
+    width = 2 * kv_heads * IDX_D
+    heads = IDX_Q * kv_heads // IDX_KV
+    hlo = _compile(
+        lambda q, kv, t, n, side, sl: paged_flash_decode(
+            q, kv, None, t, n, packed_kv_heads=kv_heads, side_k=side,
+            side_len=sl),
+        _sds(v5e, (IDX_LANES, 1, heads, IDX_D)),
+        _sds(v5e, (IDX_BLOCKS, BLOCK, width)),
+        _sds(v5e, (IDX_LANES, IDX_ENTRIES), jnp.int32),
+        _sds(v5e, (IDX_LANES,), jnp.int32),
+        _sds(v5e, (IDX_LANES, IDX_SIDE, width)),
+        _sds(v5e, (), jnp.int32))
+    assert _kernel_calls(hlo) == 1
+    op = _named_call(hlo, "paged_flash_decode", KERNEL)
+    # meta, q, the pool, the side buffer as its two halves
+    assert op["pallas"] and op["operands"] == 5
+    assert paged_grid_rows(IDX_LANES, kv_heads, IDX_D, BLOCK,
+                           IDX_ENTRIES) == IDX_LANES * kv_heads // IDX_KV
 
 
 def test_flash_chosen_rows_at_the_cells_shapes(v5e):
@@ -1026,7 +1058,7 @@ FAMILIES = {
                "moe_experts_down": "mlp/experts"},
         {"attn/proj", "attn/cache", "attn/index", "attn/rows", "attn/core",
          "mlp/route", "mlp/experts", "head"},
-        ("aba455a9d909a756", "4deb752c1e41b7d9", "54dec2630fe1e1ac")),
+        ("059976b13d2321df", "4deb752c1e41b7d9", "ae38c737057e0667")),
 }
 PROGRAMS = ("_segment_impl", "_prefill_chunk_impl", "_admit_finish_impl")
 # instructions that carry no routine, of those that are not parameters,
@@ -1142,7 +1174,10 @@ def test_programs_without_metadata_are_the_parents(compiled, family,
                                                    program):
     """Operation for operation, name for name: the hash of the compiled
     text with its metadata stripped is the one recorded from the commit
-    before the scopes (293f19f), on the same toy program."""
+    before the scopes (293f19f), on the same toy program.  The indexer's
+    segment and finish are recorded from the commit that gave its layers
+    ONE pool of K beside V (its chunk, and every other family's three,
+    stayed the text they were)."""
     want = FAMILIES[family][4][PROGRAMS.index(program)]
     text = strip_metadata(compiled[family][program])
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

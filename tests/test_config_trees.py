@@ -6,7 +6,11 @@ The five configurations older than PR 41 were read on the PARENT's tree
 norm and "no positions" entered ``TransformerConfig``) and are written here
 as constants: a new word of block vocabulary whose default moved a leaf of
 an old configuration, added one or renamed one fails here.  The sixth is
-the configuration that PR brought, read on its own tree."""
+the configuration that PR brought, read on its own tree.  The cache trees
+of ``keye-vl-2.0-30b-a3b`` were read anew on the tree of PR 42, which
+gave an indexer's layers ONE pool and ONE staging buffer of K beside V
+(``paged_kv`` / ``side_kv`` for the two pairs: 26 leaves -> 22); its
+parameters, and every other configuration's three trees, stayed."""
 
 import hashlib
 import importlib
@@ -27,7 +31,7 @@ TREES = {
     "mellum2-12b-a2.5b": ((39, "6449b1b6c08d19e4"),
                           (40, "74e4b734b903b89e")),
     "keye-vl-2.0-30b-a3b": ((35, "6181a46c1edeab4e"),
-                            (26, "fbba6736b8b4abef")),
+                            (22, "9e2e90a0148ff55e")),
     "olmo-hybrid-7b": ((51, "90c4b5ddcc5c5e64"), (22, "ef3c1ab0e982065f")),
 }
 

@@ -15,6 +15,7 @@ rounding, which the seeds below do not hit.
 
 import dataclasses
 import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,8 @@ from tpudist.models.generate import _blank_cache
 from tpudist.models.serving import _index_leaves, _kv_leaves
 from tpudist.ops.flash_attention import flash_chosen_rows
 from tpudist.ops.flash_decode import (index_queries_per_row, index_select,
-                                      index_select_mask, paged_index_scores,
+                                      index_select_mask, paged_flash_decode,
+                                      paged_grid_rows, paged_index_scores,
                                       sparse_gqa_attend)
 
 VOCAB, EMBED, SEQ, TOPK = 97, 48, 128, 16
@@ -180,10 +182,14 @@ def test_scalar_index_rollout_step_matches_reference():
 REQUESTS = [(61, 9), (5, 14), (20, 6), (33, 11), (17, 10)]
 
 
-def _serve(cfg, params, decode_attention, watch=None):
-    loop = ServeLoop(cfg, params, num_slots=3, cache_layout="paged",
+def _loop(cfg, params, decode_attention):
+    return ServeLoop(cfg, params, num_slots=3, cache_layout="paged",
                      kv_block_size=16, kv_num_blocks=32, prefill_chunk=16,
                      steps_per_sync=4, decode_attention=decode_attention)
+
+
+def _serve(cfg, params, decode_attention, watch=None):
+    loop = _loop(cfg, params, decode_attention)
     rng = np.random.default_rng(0)
     reqs = [Request(rng.integers(0, VOCAB, n).astype(np.int32), m,
                     rid=f"r{i}") for i, (n, m) in enumerate(REQUESTS)]
@@ -311,29 +317,47 @@ def test_queries_a_grid_row_follow_the_output_block():
     assert index_queries_per_row(7, 16, 128) == 1
 
 
-@pytest.mark.parametrize("h_kv,d", [(2, 128), (4, 128), (2, 16)],
-                         ids=["kv2_d128", "kv4_d128", "kv2_d16_paired"])
-def test_sparse_attend_over_given_rows(h_kv, d):
-    rng = np.random.default_rng(2)
-    t, g, k, n, cap = 5, 2, 16, 200, 4
-    h, flat = h_kv * g, h_kv * d
-    q = jnp.asarray(rng.normal(size=(t, h, d)), jnp.float32)
-    k_src = jnp.asarray(rng.normal(size=(n, flat)), jnp.float32)
-    v_src = jnp.asarray(rng.normal(size=(n, flat)), jnp.float32)
-    side_k = jnp.asarray(rng.normal(size=(t, cap, flat)), jnp.float32)
-    side_v = jnp.asarray(rng.normal(size=(t, cap, flat)), jnp.float32)
-    count = np.asarray([1, 5, 16, 9, 16], np.int32)
-    # a query's first ``count`` ids ascend, so its staged rows (ids from
-    # n on) come last among them; what follows is ignored
+# sizes of the cases below: queries, query heads a K/V head, ids a query,
+# source rows, side rows
+ROWS_T, ROWS_G, ROWS_K, ROWS_N, ROWS_CAP = 5, 2, 16, 200, 4
+
+
+def _given_rows(rng, h_kv, d, count):
+    """Queries, a K and a V source, side buffers and ids for
+    ``sparse_gqa_attend``: a query's first ``count`` ids ascend, so its
+    staged rows (ids from ``n`` on) come last among them; what follows is
+    ignored."""
+    t, g, k, n, cap = ROWS_T, ROWS_G, ROWS_K, ROWS_N, ROWS_CAP
+    flat = h_kv * d
+    q = jnp.asarray(rng.normal(size=(t, h_kv * g, d)), jnp.float32)
+    k_src, v_src = (jnp.asarray(rng.normal(size=(n, flat)), jnp.float32)
+                    for _ in range(2))
+    side_k, side_v = (jnp.asarray(rng.normal(size=(t, cap, flat)),
+                                  jnp.float32) for _ in range(2))
     ids = rng.integers(0, n + cap, (t, k))
     for i, c in enumerate(count):
         ids[i, :c] = np.sort(rng.choice(
             np.arange(n - 6, n + cap), size=c, replace=False)
             if c <= 10 else rng.choice(n + cap, size=c, replace=False))
-    ids, count = jnp.asarray(ids, jnp.int32), jnp.asarray(count)
-    got = sparse_gqa_attend(q, k_src, v_src, ids, count,
-                            packed_kv_heads=h_kv, side_k=side_k,
-                            side_v=side_v, interpret=True)
+    return (q, k_src, v_src, side_k, side_v, jnp.asarray(ids, jnp.int32),
+            jnp.asarray(count, jnp.int32))
+
+
+def _joined(k, v):
+    """K beside V, a row: what an indexer's layer keeps a token."""
+    return jnp.concatenate([k, v], axis=-1)
+
+
+@pytest.mark.parametrize("h_kv,d", [(2, 128), (4, 128), (2, 16)],
+                         ids=["kv2_d128", "kv4_d128", "kv2_d16_paired"])
+def test_sparse_attend_over_given_rows(h_kv, d):
+    rng = np.random.default_rng(2)
+    t, g, k, n, cap = ROWS_T, ROWS_G, ROWS_K, ROWS_N, ROWS_CAP
+    q, k_src, v_src, side_k, side_v, ids, count = _given_rows(
+        rng, h_kv, d, [1, 5, 16, 9, 16])
+    got = sparse_gqa_attend(q, _joined(k_src, v_src), ids, count,
+                            packed_kv_heads=h_kv,
+                            side_kv=_joined(side_k, side_v), interpret=True)
 
     def rows(src, side):
         out = jnp.where(
@@ -349,6 +373,81 @@ def test_sparse_attend_over_given_rows(h_kv, d):
     want = jnp.einsum("thk,tkhd->thd", jax.nn.softmax(logits, -1),
                       rows(v_src, side_v))
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("h_kv,d", [(4, 128), (2, 16)],
+                         ids=["kv4_d128", "kv2_d16_paired"])
+@pytest.mark.parametrize("staged,count", [
+    (True, (1, 5, 16, 9, 16)), (False, (16, 16, 16, 16, 16)),
+    (False, (3, 16, 7, 12, 1)), (True, (0, 16, 0, 4, 16))],
+    ids=["staged", "plain", "count_under_k", "a_lane_of_length_0"])
+def test_one_gather_gives_the_bits_of_two(h_kv, d, staged, count):
+    """The ONE gather of rows that hold K beside V, attended as the two
+    halves of a tile, against what it replaced, written here: a gather of
+    the K rows and one of the V rows (the staged rows patched into each),
+    attended by the walk over two pools.  The same rows, the same online
+    softmax in the same order: the same bits."""
+    rng = np.random.default_rng(9)
+    t, k, n, cap = ROWS_T, ROWS_K, ROWS_N, ROWS_CAP
+    q, k_src, v_src, side_k, side_v, ids, count = _given_rows(
+        rng, h_kv, d, count)
+    if not staged:
+        ids = jnp.minimum(ids, n - 1)
+    got = sparse_gqa_attend(
+        q, _joined(k_src, v_src), ids, count, packed_kv_heads=h_kv,
+        side_kv=_joined(side_k, side_v) if staged else None,
+        interpret=True)
+
+    def gathered(src, side):
+        rows = src[jnp.minimum(ids, n - 1)]
+        if staged:
+            rows = jnp.where(
+                ((ids >= n) & (jnp.arange(k) < count[:, None]))[..., None],
+                jnp.take_along_axis(
+                    side, jnp.clip(ids - n, 0, cap - 1)[..., None], 1),
+                rows)
+        block = 8
+        return rows.reshape(t * k // block, block, -1)
+
+    want = paged_flash_decode(
+        q[:, None], gathered(k_src, side_k), gathered(v_src, side_v),
+        jnp.arange(t * k // 8, dtype=jnp.int32).reshape(t, k // 8), count,
+        packed_kv_heads=h_kv, interpret=True)[:, 0]
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("lens", [(0, 37, 300), (320, 1, 129)],
+                         ids=["empty_short_long", "full_one_tile_edge"])
+@pytest.mark.parametrize("h_kv,d", [(4, 128), (2, 16), (8, 128)],
+                         ids=["kv4_d128", "kv2_d16_paired",
+                              "kv8_d128_two_rows_a_lane"])
+def test_every_row_walk_of_the_one_pool_gives_the_bits_of_two(lens, h_kv, d):
+    """The every-row branch of an indexer's layer: ``paged_flash_decode``
+    over the ONE pool of K beside V and its one side buffer against the
+    same call over the split pools and side buffers (8 K/V heads of 128:
+    a lane's heads take two grid rows, each with its columns of both
+    halves of a page)."""
+    rng = np.random.default_rng(4)
+    b, g, bs, m, n, cap = 3, 2, 16, 24, 40, 4
+    assert paged_grid_rows(b, h_kv, d, bs, m, itemsize=4) == (
+        2 * b if h_kv == 8 else b)
+    flat = h_kv * d
+    q = jnp.asarray(rng.normal(size=(b, 1, h_kv * g, d)), jnp.float32)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(n, bs, flat)),
+                                  jnp.float32) for _ in range(2))
+    side_k, side_v = (jnp.asarray(rng.normal(size=(b, cap, flat)),
+                                  jnp.float32) for _ in range(2))
+    table = jnp.asarray(rng.integers(0, n, (b, m)), jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    got = paged_flash_decode(
+        q, _joined(k_pool, v_pool), None, table, lens,
+        packed_kv_heads=h_kv, side_k=_joined(side_k, side_v), side_len=3,
+        interpret=True)
+    want = paged_flash_decode(
+        q, k_pool, v_pool, table, lens, packed_kv_heads=h_kv,
+        side_k=side_k, side_v=side_v, side_len=3, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("offset,rows", [(0, 64), (32, 64), (96, 128)])
@@ -499,57 +598,96 @@ def test_a_chunks_few_tied_rows_are_cut_apart(tied_rows):
         assert np.flatnonzero(mask[i]).tolist() == sorted(want[i].tolist())
 
 
-# -- (c) a block of three leaves of different width ----------------------------
+# -- (c) a block of leaves of different width ----------------------------------
 
-def test_three_leaves_ride_admission_side_buffer_and_release():
-    """The rows a live lane holds in ``paged_ikey`` (128 wide: 16 numbers
-    and zeros), ``paged_key`` and ``paged_value`` (32 wide), read back through its page
-    table after its prompt was inserted and segments merged their staged
-    rows, are the rows a one-chunk prefill of the same tokens computes;
-    ``check()`` holds at every poll, and every block comes back."""
+@functools.cache
+def _watched_run():
+    """One served run with ``check()`` at every poll and, from every poll,
+    a copy of a layer's cache (the next segment donates it), the page
+    table and the lanes' lengths."""
     snaps = []
 
     def watch(loop):
         loop.pool.check()
-        # copies: the next segment donates the cache
         snaps.append((
-            {"block1": jax.tree.map(jnp.copy, loop.cache["block1"])},
-            loop.pool.table.copy()))
+            jax.tree.map(jnp.copy, loop.cache["block1"]["attn"]),
+            loop.pool.table.copy(),
+            np.asarray(_index_leaves(loop.cache)[0])))
 
-    done = _serve(_cfg(), _params(), "flash", watch)
-    first = next(c for c in done if c.rid == "r0")
-    seq = np.concatenate([first.prompt, first.tokens])
+    return _serve(_cfg(), _params(), "flash", watch), snaps
+
+
+@pytest.mark.parametrize("rid", ["r1", "r2", "r0"],
+                         ids=["one_chunk", "two_chunks", "four_chunks"])
+def test_the_leaves_ride_admission_side_buffer_and_release(rid):
+    """The rows a live lane holds in ``paged_ikey`` (128 wide: 16 numbers
+    and zeros) and in ``paged_kv`` (64 wide: K's 32 columns, then V's),
+    read back through its page table after its prompt was inserted (the
+    finish joins the halves, whatever the number of chunks the prompt
+    took) and segments merged their staged rows (``side_kv``, joined by
+    the step), are the rows a one-chunk prefill of the same tokens
+    computes; ``check()`` holds at every poll, and every block comes
+    back."""
+    done, snaps = _watched_run()
+    mine = next(c for c in done if c.rid == rid)
+    seq = np.concatenate([mine.prompt, mine.tokens])
     model = TransformerLM(_cfg(), decode=True)
     _, mut = model.apply(
         {"params": _params(), "cache": _blank_cache(model, 1)},
         jnp.asarray(seq[None]), mutable=["cache"])
-    want = mut["cache"]["block1"]["attn"]
+    want = {leaf: np.asarray(rows)[0] for leaf, rows in
+            mut["cache"]["block1"]["attn"].items()
+            if leaf.startswith("cached_")}
+    assert sorted(want) == ["cached_ikey", "cached_key", "cached_value"]
+    halves = np.concatenate([want["cached_key"], want["cached_value"]], -1)
     checked = 0
-    for cache, table in snaps:
-        node = cache["block1"]["attn"]
-        assert _kv_leaves(node, "paged") == ["ikey", "key", "value"]
-        assert _kv_leaves(node, "side") == ["ikey", "key", "value"]
+    for node, table, lengths in snaps:
+        assert _kv_leaves(node, "paged") == ["ikey", "kv"]
+        assert _kv_leaves(node, "side") == ["ikey", "kv"]
         assert "side_index" in node
-        held = int(_index_leaves(cache)[0][0])
-        if held <= len(first.prompt):
-            continue
-        for leaf, width in (("ikey", 128), ("key", 32),
-                            ("value", 32)):
-            pool = np.asarray(node[f"paged_{leaf}"])
-            assert pool.shape[-1] == width
-            rows = pool[table[0]].reshape(-1, width)[:held]
-            if not np.allclose(rows[:8, :4], np.asarray(
-                    want[f"cached_{leaf}"])[0, :8, :4], atol=2e-5):
-                break           # lane 0 holds another request by now
-            np.testing.assert_allclose(
-                rows, np.asarray(want[f"cached_{leaf}"])[0, :held],
-                atol=2e-5)
-            if leaf == "ikey":
-                assert not rows[:, DIMS.index_dim:].any()
-                assert rows[:, :DIMS.index_dim].any()
-        else:
-            checked += 1
+        for lane, held in enumerate(lengths):
+            # the lane holds this request, past its prompt: the finish's
+            # rows and some a segment merged
+            if not len(mine.prompt) < held <= len(seq):
+                continue
+            for leaf, rows_want, width in (("ikey", want["cached_ikey"], 128),
+                                           ("kv", halves, 64)):
+                pool = np.asarray(node[f"paged_{leaf}"])
+                assert pool.shape[-1] == width
+                rows = pool[table[lane]].reshape(-1, width)[:held]
+                if not np.allclose(rows[:4, :4], rows_want[:4, :4],
+                                   atol=2e-5):
+                    break       # the lane holds another request
+                np.testing.assert_allclose(rows, rows_want[:held],
+                                           atol=2e-5)
+                if leaf == "ikey":
+                    assert not rows[:, DIMS.index_dim:].any()
+                    assert rows[:, :DIMS.index_dim].any()
+            else:
+                checked += 1
     assert checked
+
+
+# sha256 of the plain model's segment program (StableHLO text of
+# ``serve_programs()["_segment_impl"]`` at this file's sizes, the paged
+# kernel traced under interpret) as the commit BEFORE the one pool of K
+# beside V lowered it: the layout is an indexer's layer's alone, so no
+# other model's program moved by an operation.  A later change that means to
+# alter every model's segment records its own text's hash here.
+_PLAIN_SEGMENT_SHA256 = (
+    "7a43e3b3fb68f7e63e1d871f64b423bc31dc8d5b5be95f094293ad8fae1d3ed2")
+
+
+def test_a_model_without_an_indexer_keeps_its_leaves_and_its_program():
+    loop = _loop(_plain_cfg(), _plain_params(), "flash")
+    node = loop.cache["block1"]["attn"]
+    assert _kv_leaves(node, "paged") == ["key", "value"]
+    assert _kv_leaves(node, "side") == ["key", "value"]
+    assert loop._grid_rows == 3      # a lane's two narrow heads share a row
+    jitted, args, static = loop.serve_programs()["_segment_impl"]
+    text = jitted.lower(*args, **static).as_text()
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == _PLAIN_SEGMENT_SHA256)
 
 
 def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer():
@@ -632,11 +770,40 @@ def test_segments_and_chunks_say_what_was_scored_and_chosen():
     assert {a["sparse"] for a in chunks} == {True, False}
 
 
+@pytest.mark.parametrize("decode_attention", ["flash", "dense"])
+def test_segments_say_what_their_gathers_fetched(decode_attention):
+    """``gathers``: ONE gather of chosen rows a layer and step.
+    ``rows_gathered`` / ``serve/rows_gathered``: every lane's
+    ``index_topk`` rows a gather, a layer, in each step that found some
+    lane beyond ``index_topk`` rows; none on the route without kernels."""
+    before = obs.counter("serve/rows_gathered").value()
+    obs.tracer.clear()
+    _serve(_cfg(), _params(), decode_attention)
+    drains = [e["args"] for e in obs.tracer.events()
+              if e["name"] == "serve/segment_drain"]
+    a_step = 3 * TOPK * DIMS.layers        # lanes x rows x layers x 1
+    assert drains and all(a["gathers"] == 1 for a in drains)
+    assert all(a["rows_gathered"] % a_step == 0
+               and a["rows_gathered"] <= a_step * a["steps_run"]
+               for a in drains)
+    total = sum(a["rows_gathered"] for a in drains)
+    assert obs.counter("serve/rows_gathered").value() - before == total
+    if decode_attention == "dense":
+        assert total == 0
+    else:
+        # a segment whose longest lane starts beyond index_topk rows
+        # gathers in every step it ran
+        assert any(a["rows_gathered"] == a_step * a["steps_run"] > 0
+                   for a in drains)
+
+
 def test_a_model_without_an_indexer_says_neither():
     obs.tracer.clear()
     _serve(_plain_cfg(), _plain_params(), "dense")
     assert all("rows_selected" not in e["args"]
                and "rows_scored" not in e["args"]
+               and "gathers" not in e["args"]
+               and "rows_gathered" not in e["args"]
                and "sparse" not in e["args"]
                for e in obs.tracer.events()
                if e["name"] in ("serve/segment_drain",

@@ -71,8 +71,8 @@ from tpudist.models.speculative import (
     _set_cache_index,
 )
 from tpudist.models.transformer import TransformerConfig, TransformerLM
-from tpudist.ops.flash_decode import (paged_grid_rows, paged_tile_pages,
-                                      walk_rows)
+from tpudist.ops.flash_decode import (SPARSE_ATTEND_GATHERS, paged_grid_rows,
+                                      paged_tile_pages, walk_rows)
 
 # placeholder page row for the dense layout's admit signature (the insert
 # walk never reaches a paged node there)
@@ -232,14 +232,26 @@ def _kv_leaves(node: dict, prefix: str) -> list[str]:
     cache leaves ``<prefix>_<suffix>`` (``prefix`` one of ``cached``, the
     dense buffers, ``paged``, the block pools, ``side``, the segment's
     staging buffers).  ``["key", "value"]`` for MHA/GQA/MQA, ``["latent"]``
-    for latent attention, ``["ikey", "key", "value"]`` for grouped-query
-    attention with an indexer (its index key a token beside K and V): every
-    place that moves cache rows iterates over these and is indifferent to
-    their number and width.  No leaf's suffix may be ``index``: the
+    for latent attention; for grouped-query attention with an indexer
+    ``["ikey", "kv"]`` in the pools and the staging buffers (its index key
+    a token, and K beside V in ONE row of twice the width: its decode step
+    gathers the chosen rows, at a cost by the row) and ``["ikey", "key",
+    "value"]`` in the batch-1 prefill cache, whose kernels read K and V
+    each whole (:func:`_dense_parts` says which dense leaves make up a
+    pool's row).  Every place that moves cache rows iterates over these
+    and is indifferent to their number and width.  No leaf's suffix may be
+    ``index``: the
     staging buffer's cursor is the leaf ``side_index``, which shares the
     prefix and is left out here BY NAME (hence ``side_ikey``)."""
     return sorted(k[len(prefix) + 1:] for k in node
                   if k.startswith(prefix + "_") and k != "side_index")
+
+
+def _dense_parts(leaf: str) -> tuple[str, ...]:
+    """The batch-1 prefill cache's leaves whose rows, side by side, are a
+    row of pool leaf ``leaf``: ``kv`` is ``key`` beside ``value``; any
+    other leaf is its dense namesake."""
+    return ("key", "value") if leaf == "kv" else (leaf,)
 
 
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = ")
@@ -842,15 +854,18 @@ class ServeLoop:
         if self.pool is not None and decode_attention == "flash":
             nodes = self._paged_nodes(self.cache)
             leaves = _kv_leaves(nodes[0], "paged")
-            # the pools the attention kernel walks: a K/V pair (an
-            # indexer's keys have a walk of their own), or the latent rows
-            pair = {"key", "value"} <= set(leaves)
-            walked = ["key", "value"] if pair else leaves
-            pool0 = nodes[0]["paged_" + walked[0]]
-            h_kv = cfg.kv_heads if pair else 1
+            # what the attention kernel walks: K and V of the K/V heads,
+            # in a pool each or (an indexer's layer, whose index keys have
+            # a walk of their own) side by side in one that counts as the
+            # two; or the latent rows, one head of the row's width
+            pool0 = nodes[0]["paged_" + leaves[-1]]    # never the ikey
+            if {"key", "value"} <= set(leaves) or "kv" in leaves:
+                h_kv, d_head, n_pools = cfg.kv_heads, cfg.head_dim, 2
+            else:
+                h_kv, d_head, n_pools = 1, pool0.shape[2], len(leaves)
             self._grid_rows = paged_grid_rows(
-                num_slots, h_kv, pool0.shape[2] // h_kv, self.kv_block_size,
-                self.pool.max_blocks_per_slot, pools=len(walked),
+                num_slots, h_kv, d_head, self.kv_block_size,
+                self.pool.max_blocks_per_slot, pools=n_pools,
                 itemsize=pool0.dtype.itemsize)
             self._attn_layers = len(nodes)
         # expert layers (cfg.moe): the segment sums, step by step, the
@@ -964,6 +979,11 @@ class ServeLoop:
         self._obs_rows_scored = obs.counter("serve/index_rows_scored",
                                             unit="rows")
         self._obs_rows_selected = obs.counter("serve/index_rows_selected",
+                                              unit="rows")
+        # and the rows the gathers of its chosen rows fetched: every lane's
+        # index_topk a gather, a layer, in each step that took the
+        # chosen-rows branch (some lane held more than a query attends)
+        self._obs_rows_gathered = obs.counter("serve/rows_gathered",
                                               unit="rows")
         # the same two of the WINDOW layers' calls (a layer): walk_rows
         # with the window, and min(length, window)
@@ -1380,7 +1400,10 @@ class ServeLoop:
         (shared-prefix blocks owned by the cache) — target the
         (out-of-range) index ``num_blocks`` and are DROPPED — only this
         admission's own allocated pages are written, so no live or
-        cached block of another owner can be hit.
+        cached block of another owner can be hit.  A pool leaf whose row
+        is several dense leaves side by side (:func:`_dense_parts`: an
+        indexer's layer's ``kv``) has them joined a scatter's blocks at a
+        time.
 
         Two scatters a leaf, the second under a ``cond``: the chip runs
         a scatter's updates one after another, landed or dropped, so
@@ -1398,17 +1421,31 @@ class ServeLoop:
         for leaf in _kv_leaves(big, "paged"):
             name = f"paged_{leaf}"
             tgt = jnp.where(covered, pages, big[name].shape[0])
-            row = small[f"cached_{leaf}"][0]          # dense [S, F]
-            pad = m * bs - row.shape[0]
-            blocks = (jnp.pad(row, ((0, pad), (0, 0))).reshape(m, bs, -1)
-                      .astype(big[name].dtype))
-            pool = big[name].at[tgt[:head]].set(blocks[:head], mode="drop")
+            parts = []
+            for dense in _dense_parts(leaf):
+                row = small[f"cached_{dense}"][0]     # dense [S, F]
+                pad = m * bs - row.shape[0]
+                parts.append(jnp.pad(row, ((0, pad), (0, 0)))
+                             .reshape(m, bs, -1).astype(big[name].dtype))
+
+            def blocks(lo, hi):
+                """Pool rows of the blocks ``[lo, hi)``: the dense
+                leaves' side by side, joined HERE, for the blocks one
+                scatter takes (inside its branch, where it has one): no
+                joined copy of a whole prompt's rows outlives its layer's
+                scatter."""
+                if len(parts) == 1:
+                    return parts[0][lo:hi]
+                return jnp.concatenate([p[lo:hi] for p in parts], axis=-1)
+
+            pool = big[name].at[tgt[:head]].set(blocks(0, head),
+                                                mode="drop")
             if head < m:
                 # traced here and now, so the closure sees this leaf
                 pool = lax.cond(
                     true_len > head * bs,
                     lambda p: p.at[tgt[head:]].set(
-                        blocks[head:], mode="drop"),
+                        blocks(head, m), mode="drop"),
                     lambda p: p, pool)
             out[name] = pool
         out["page_table"] = big["page_table"].at[slot].set(pages)
@@ -3153,7 +3190,7 @@ class ServeLoop:
                            if st is not None and not st.get("zombie"))
                 k = (self._spec_k(live)
                      if self.decode_mode == "speculative" else 0)
-                pages = rows = rows_live = rows_selected = 0
+                pages = rows = rows_live = rows_selected = longest = 0
                 windowed = None
                 # the lanes whose state the slot cache holds (a lane in
                 # admission carries its state in its batch-1 cache)
@@ -3197,6 +3234,7 @@ class ServeLoop:
                             rows_live += held
                             if self._index_topk is not None:
                                 rows_selected += min(held, self._index_topk)
+                                longest = max(longest, held)
                             if wg is not None:
                                 rows_w += walk_rows(held, block, per_tile,
                                                     wg.window)
@@ -3254,7 +3292,7 @@ class ServeLoop:
                 pass
             inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
                              (pages, rows, rows_live, rows_selected,
-                              windowed, state_lanes)))
+                              longest, windowed, state_lanes)))
             seq += 1
             self._obs_depth.set(len(inflight))
             # fault harness: a configured kill-after-K-segments SIGKILLs
@@ -3296,13 +3334,19 @@ class ServeLoop:
             A model with an indexer adds ``rows_scored`` (the index keys its
             scores read, a layer: the live lanes' lengths) and
             ``rows_selected`` (the rows its attention reads:
-            ``min(length, index_topk)`` a lane).  A model with
+            ``min(length, index_topk)`` a lane), ``gathers`` (the gathers
+            of chosen rows a layer makes in a step:
+            ``ops.flash_decode.SPARSE_ATTEND_GATHERS``) and
+            ``rows_gathered`` (what they fetched over the segment, all
+            layers: every lane's ``index_topk`` rows a gather, in each
+            step that found some lane beyond ``index_topk`` rows; 0 where
+            no kernel runs).  A model with
             linear-attention layers adds ``state_lanes`` (the lanes whose
             state the slot cache held at dispatch: the decoding ones) and
             ``state_bytes`` (those lanes times what the state layers keep
             for one)."""
             (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
-             t_disp, (pages, rows, rows_live, rows_selected,
+             t_disp, (pages, rows, rows_live, rows_selected, longest,
                       windowed, state_lanes)) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
@@ -3414,8 +3458,18 @@ class ServeLoop:
                 if self._index_topk is not None:
                     self._obs_rows_scored.inc(rows_live * steps_run)
                     self._obs_rows_selected.inc(rows_selected * steps_run)
+                    # step j of the segment gathers when the longest lane's
+                    # rows and the j staged ones pass index_topk
+                    sparse_steps = steps_run - min(
+                        max(self._index_topk - longest, 0), steps_run)
+                    gathered = (self.B * self._index_topk
+                                * self._attn_layers * SPARSE_ATTEND_GATHERS
+                                * sparse_steps)
+                    self._obs_rows_gathered.inc(gathered)
                     routed.update(rows_scored=rows_live,
-                                  rows_selected=rows_selected)
+                                  rows_selected=rows_selected,
+                                  gathers=SPARSE_ATTEND_GATHERS,
+                                  rows_gathered=gathered)
                 if self._state_layers:
                     routed.update(
                         state_lanes=state_lanes,

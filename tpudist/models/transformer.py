@@ -603,7 +603,13 @@ class CausalSelfAttention(nn.Module):
     third row, its index key (``index_head_dim`` numbers after its
     LayerNorm and its rotation, zeros up to ``cfg.index_cache_width``),
     under ``cached_ikey`` / ``paged_ikey`` /
-    ``side_ikey`` beside the K and V leaves.  Both cached paths then run
+    ``side_ikey`` beside the K and V leaves; and in the PAGED cache its K
+    and V are ONE row (``paged_kv`` / ``side_kv``: K's heads in the first
+    ``kv_heads x head_dim`` columns, V's in the last), because a decode
+    step gathers each lane's chosen rows and the chip's gather costs by
+    the row: one gather a layer brings both.  The batch-1 prefill cache
+    keeps ``cached_key`` / ``cached_value`` apart (its kernels read each
+    whole); the finish's scatter joins them.  Both cached paths then run
     scores -> selection -> attention over the chosen rows
     (``ops.flash_decode.paged_index_scores``, ``index_select_mask`` /
     ``index_select``, ``sparse_gqa_attend``; a prefill chunk through
@@ -1109,13 +1115,16 @@ class CausalSelfAttention(nn.Module):
                 "cache_layout='paged' needs kv_block_size and "
                 f"kv_num_blocks > 0 (got {bs_}, {nb})")
         m_blocks = -(-cfg.max_seq_len // bs_)
-        paged_k = self.variable(
-            "cache", "paged_key", jnp.zeros, (nb, bs_, flat),
-            cfg.compute_dtype)
-        paged_v = self.variable(
-            "cache", "paged_value", jnp.zeros, (nb, bs_, flat),
-            cfg.compute_dtype)
-        # an indexer's keys ride in a third pool under the same table
+        # the leaves of a token's K and V, pool and side buffer: a pair of
+        # ``flat`` columns each or, in a layer with an indexer, ONE of
+        # twice that (K beside V: its decode step gathers the chosen rows,
+        # and a gather costs by the row), and its index keys in a further
+        # pool under the same table
+        kv_names, kv_width = ((("key", "value"), flat) if index is None
+                              else (("kv",), 2 * flat))
+        pools = [self.variable(
+            "cache", f"paged_{name}", jnp.zeros, (nb, bs_, kv_width),
+            cfg.compute_dtype) for name in kv_names]
         paged_ik = None if index is None else self.variable(
             "cache", "paged_ikey", jnp.zeros,
             (nb, bs_, cfg.index_cache_width), cfg.compute_dtype)
@@ -1161,25 +1170,22 @@ class CausalSelfAttention(nn.Module):
                 "(the pool is frozen within a segment; tokens stage in "
                 "the side buffer)")
         cap = self.serve_side_slots
-        side_k = self.variable(
-            "cache", "side_key", jnp.zeros, (b, cap, flat),
-            cfg.compute_dtype)
-        side_v = self.variable(
-            "cache", "side_value", jnp.zeros, (b, cap, flat),
-            cfg.compute_dtype)
+        sides = [self.variable(
+            "cache", f"side_{name}", jnp.zeros, (b, cap, kv_width),
+            cfg.compute_dtype) for name in kv_names]
         side_idx = self.variable(
             "cache", "side_index", lambda: jnp.zeros((), jnp.int32))
         s_base = side_idx.value
         with routine("attn/cache"):
             s_at = jnp.minimum(s_base, cap - s)
-            side_k.value = jax.lax.dynamic_update_slice(
-                side_k.value,
-                k.reshape(b, s, flat).astype(side_k.value.dtype),
-                (0, s_at, 0))
-            side_v.value = jax.lax.dynamic_update_slice(
-                side_v.value,
-                v.reshape(b, s, flat).astype(side_v.value.dtype),
-                (0, s_at, 0))
+            # the token's K and V, each to its buffer, or joined to the one
+            news = (k, v) if index is None else (jnp.concatenate(
+                [k.reshape(b, s, flat), v.reshape(b, s, flat)], axis=-1),)
+            for side, new in zip(sides, news):
+                side.value = jax.lax.dynamic_update_slice(
+                    side.value,
+                    new.reshape(b, s, kv_width).astype(side.value.dtype),
+                    (0, s_at, 0))
             side_idx.value = s_base + s
             if index is not None:
                 q_i, w_i, k_i = index
@@ -1194,11 +1200,14 @@ class CausalSelfAttention(nn.Module):
             from tpudist.ops.flash_decode import paged_flash_decode
 
             def every_row():
+                # the one pool of K beside V goes in alone
+                pool_v, side_v = ((None, None) if index is not None else
+                                  (pools[1].value, sides[1].value))
                 with routine("attn/core"):
                     return paged_flash_decode(
-                        q, paged_k.value, paged_v.value, table.value, idx,
-                        packed_kv_heads=h_kv, side_k=side_k.value,
-                        side_v=side_v.value, side_len=side_idx.value,
+                        q, pools[0].value, pool_v, table.value, idx,
+                        packed_kv_heads=h_kv, side_k=sides[0].value,
+                        side_v=side_v, side_len=side_idx.value,
                         window=window)
 
             if index is None:
@@ -1209,9 +1218,9 @@ class CausalSelfAttention(nn.Module):
             return jax.lax.cond(
                 jnp.max(idx) + side_idx.value > cfg.index_topk,
                 lambda: self._chosen_pages(
-                    q, q_i[:, 0], w_i[:, 0], paged_k.value, paged_v.value,
-                    paged_ik.value, table.value, idx, side_k.value,
-                    side_v.value, side_ik.value, side_idx.value),
+                    q, q_i[:, 0], w_i[:, 0], pools[0].value,
+                    paged_ik.value, table.value, idx, sides[0].value,
+                    side_ik.value, side_idx.value),
                 every_row)
         # dense fallback: gather the slot's pages into a contiguous view
         # (one full-logical-cache copy per step — fine on CPU, the reason
@@ -1221,8 +1230,15 @@ class CausalSelfAttention(nn.Module):
         from tpudist.ops.flash_decode import paged_gather_kv
 
         with routine("attn/core"):
-            k_main = paged_gather_kv(paged_k.value, table.value)
-            v_main = paged_gather_kv(paged_v.value, table.value)
+            if index is None:
+                k_main, v_main = (paged_gather_kv(pool.value, table.value)
+                                  for pool in pools)
+                side_k, side_v = (side.value for side in sides)
+            else:
+                # the one pool of K beside V, and its side buffer, halved
+                k_main, v_main = jnp.split(
+                    paged_gather_kv(pools[0].value, table.value), 2, -1)
+                side_k, side_v = jnp.split(sides[0].value, 2, -1)
             s_all = k_main.shape[1]
             live_main = jnp.arange(s_all)[None, :] < idx[:, None]
             if window is not None:
@@ -1246,15 +1262,15 @@ class CausalSelfAttention(nn.Module):
                          side_ik.value], axis=1)
                     mask = _index_choice(q_i, w_i, ikeys, mask,
                                          cfg.index_topk)
-            k_all = jnp.concatenate([k_main, side_k.value], axis=1)
-            v_all = jnp.concatenate([v_main, side_v.value], axis=1)
+            k_all = jnp.concatenate([k_main, side_k], axis=1)
+            v_all = jnp.concatenate([v_main, side_v], axis=1)
             k4 = k_all.reshape(b, s_all + cap, h_kv, d)
             v4 = v_all.reshape(b, s_all + cap, h_kv, d)
             k_rep, v_rep = repeat_kv(q, k4, v4)
             return _masked_attend(q, k_rep, v_rep, mask[:, None])
 
-    def _chosen_pages(self, q, q_i, w_i, k_pool, v_pool, ik_pool, table,
-                      idx, side_k, side_v, side_ik, side_len):
+    def _chosen_pages(self, q, q_i, w_i, kv_pool, ik_pool, table, idx,
+                      side_kv, side_ik, side_len):
         """The decode step's attention over each lane's chosen rows: index
         scores of the lane's pages on the paged walk, and of its staged
         rows (the tokens this segment decoded are candidates like any
@@ -1267,8 +1283,8 @@ class CausalSelfAttention(nn.Module):
                                               sparse_gqa_attend)
 
         cfg = self.cfg
-        nb, bs_, flat = k_pool.shape
-        b, cap = side_k.shape[:2]
+        nb, bs_, width = kv_pool.shape
+        b, cap = side_kv.shape[:2]
         reach = table.shape[1] * bs_
         with routine("attn/index"):
             main = paged_index_scores(q_i, w_i, ik_pool, table, idx)
@@ -1288,13 +1304,12 @@ class CausalSelfAttention(nn.Module):
                     table[:, None, :], 0), axis=-1)
             rows = page * bs_ + pos % bs_
             rows = jnp.where(ids >= reach, nb * bs_ + ids - reach, rows)
-        # the gathers open attn/rows and the kernel attn/core, inside
+        # the gather opens attn/rows and the kernel attn/core, inside
         out = sparse_gqa_attend(
-            q[:, 0], k_pool.reshape(nb * bs_, flat),
-            v_pool.reshape(nb * bs_, flat), rows,
+            q[:, 0], kv_pool.reshape(nb * bs_, width), rows,
             jnp.minimum(jnp.minimum(idx, reach) + side_len,
                         cfg.index_topk),
-            packed_kv_heads=cfg.kv_heads, side_k=side_k, side_v=side_v)
+            packed_kv_heads=cfg.kv_heads, side_kv=side_kv)
         return out[:, None]
 
     def _chosen_rows(self, q, k_all, v_all, idx, index):

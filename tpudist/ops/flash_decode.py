@@ -298,7 +298,7 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
                          block: int, pages_per_tile: int, m_blocks: int,
                          lanes: int, groups: int, slots: tuple | None,
                          side: bool, d_v: int | None = None,
-                         window: int | None = None):
+                         window: int | None = None, joined: bool = False):
     """Online-softmax decode over ONE grid row of a paged cache: a LANE
     and all its K/V heads, walking the lane's live pages only.
 
@@ -346,11 +346,17 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     slots need no zero fill: what a slot's uncopied pages hold never
     reaches a product.
 
-    The walk serves two layouts.  ``d_v`` None: TWO pools (keys, values)
+    The walk serves three layouts.  ``d_v`` None: TWO pools (keys, values)
     of one width, each with its side buffer (``paged_flash_decode``).
     ``d_v`` set: ONE pool whose rows are the keys and whose first ``d_v``
     columns are also the values, read once (``paged_mla_decode``: the
-    latent cache of multi-head latent attention).
+    latent cache of multi-head latent attention).  ``joined``: ONE pool
+    whose rows hold a token's keys in their first half and its values in
+    their second (an indexer's layer: ``sparse_gqa_attend`` and its
+    every-row branch).  One copy a page brings both, the tile slots hold
+    the bytes the two pools' held, and the key and value tiles are the two
+    static halves of a slot (a multiple of 128 lanes apart on the chip);
+    the side buffer comes in as its two halves.
 
     ``window`` (static; None is the program above, instruction for
     instruction): a SLIDING-WINDOW layer.  The query sits at position
@@ -365,19 +371,25 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     window starts at page 0 and walks as without one; the side buffer's
     rows (never more than the window) join the last tile's update as
     before.  :func:`walk_rows` counts the same."""
-    if window is not None and d_v is not None:
-        raise ValueError("the one-pool (latent) walk has no window")
-    n_pools = 2 if d_v is None else 1
+    if window is not None and (d_v is not None or joined):
+        raise ValueError("the one-pool walks have no window")
+    n_pools = 2 if d_v is None and not joined else 1
     pools, rest = rest[:n_pools], rest[n_pools:]
+    n_sides = 2 if joined else n_pools
     if side:
-        sides, rest = rest[:n_pools], rest[n_pools:]
+        sides, rest = rest[:n_sides], rest[n_sides:]
     o_ref, rest = rest[0], rest[1:]
     bufs, rest = rest[:n_pools], rest[n_pools:]
     sems, state_ref, m_scr, l_scr, acc_scr = rest[:5]
     q_scr = rest[5] if slots is not None else None
     g = pl.program_id(0)
     lane, r = g // groups, g % groups
-    d = bufs[0].shape[-1]
+    d = bufs[0].shape[-1] // (2 if joined else 1)
+    # (tile buffer, its columns) of the key tile and of the value tile
+    if joined:
+        key_at, value_at = (bufs[0], pl.ds(0, d)), (bufs[0], pl.ds(d, d))
+    else:
+        key_at, value_at = (bufs[0], slice(None)), (bufs[-1], slice(None))
 
     def values(keys, load_values):
         """The value tile beside the key tile ``keys``: the second pool's
@@ -416,6 +428,16 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
 
         def one_page(p, _):
             page = meta_ref[base + p]
+            if joined and groups > 1:
+                # the row's heads of the keys' half and of the values'
+                half = pools[0].shape[-1] // 2
+                for kv in range(2):
+                    fn(pltpu.make_async_copy(
+                        pools[0].at[page, :, pl.ds(pl.multiple_of(
+                            kv * half + r_ * d, d), d)],
+                        bufs[0].at[slot, p, :, pl.ds(kv * d, d)],
+                        sems.at[0, slot]))
+                return
             for kv, (hbm, buf) in enumerate(zip(pools, bufs)):
                 fn(pltpu.make_async_copy(
                     hbm.at[page, :, chunk], buf.at[slot, p],
@@ -493,18 +515,20 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         rows under it lie before the window."""
         rows = n * block
 
-        def load(buf, cleaned):
-            x = buf[slot, :n].reshape(rows, d)
+        def load(at, cleaned):
+            buf, cols = at
+            x = buf[slot, :n, :, cols].reshape(rows, d)
             if live is None or not cleaned:
                 return x
             row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
             return jnp.where(row < live, x, jnp.zeros_like(x))
 
-        # one pool: its rows are the values too, so the keys are cleaned
-        keys = load(bufs[0], d_v is not None)
+        # the latent pool: its rows are the values too, so the keys are
+        # cleaned
+        keys = load(key_at, d_v is not None)
         _softmax_update(
             m_scr, l_scr, acc_scr, scores(keys, live, below), None,
-            values(keys, lambda: load(bufs[-1], True)),
+            values(keys, lambda: load(value_at, True)),
             also=side_tiles() if side and live is not None else None)
 
     @pl.when(n_tiles > 0)
@@ -1040,7 +1064,7 @@ def paged_gather_kv(pool: jnp.ndarray, page_table: jnp.ndarray
 def paged_flash_decode(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray | None,
     page_table: jnp.ndarray,
     cache_len: jnp.ndarray,
     *,
@@ -1079,6 +1103,10 @@ def paged_flash_decode(
       q: ``[B, 1, H, D]`` current-token queries.
       k_pool / v_pool: ``[num_blocks, block_size, Hkv*D]`` packed block
         pools (``block_size`` a multiple of 8 — the sublane tile).
+        ``v_pool`` None: ``k_pool`` is ONE pool of ``2*Hkv*D`` columns, a
+        token's K in the first half of its row and its V in the second
+        (what an indexer's layer keeps); ``side_k`` is then the one side
+        buffer of the same rows and ``side_v`` None.
       page_table: ``[B, max_blocks_per_slot]`` int32 pool indices; only
         a row's first ``ceil(cache_len / block_size)`` entries are read.
       cache_len: ``[B]`` per-row valid lengths INCLUDING the current
@@ -1112,6 +1140,11 @@ def paged_flash_decode(
             "a windowed paged decode takes one query a call with the "
             "side buffers (the query's position is cache_len + side_len "
             "- 1) and a window >= 1")
+    if v_pool is None and (window is not None or s_q != 1
+                           or side_v is not None):
+        raise ValueError(
+            "the one pool of K beside V takes one query a call, its one "
+            "side buffer and no window")
     if s_q > 1:
         if side_k is None:
             raise ValueError(
@@ -1133,9 +1166,10 @@ def paged_flash_decode(
             f"{k_pool.shape}")
     _, block, flat = k_pool.shape
     h_kv = packed_kv_heads
-    if flat != h_kv * d:
+    if flat != h_kv * d * (2 if v_pool is None else 1):
         raise ValueError(
-            f"pool minor dim {flat} != H_kv*D = {h_kv * d}")
+            f"pool minor dim {flat} != H_kv*D = {h_kv * d} (twice that "
+            f"for the one pool of K beside V)")
     if h % h_kv:
         raise ValueError(f"num_heads {h} not a multiple of kv heads {h_kv}")
     if block < 8 or block % 8:
@@ -1155,7 +1189,8 @@ def paged_flash_decode(
             raise ValueError(
                 "side buffers must be packed 3-D [B, cap, Hkv*D]")
         side_k = _pad_side(side_k, k_pool.dtype)
-        side_v = _pad_side(side_v, v_pool.dtype)
+        if v_pool is not None:
+            side_v = _pad_side(side_v, v_pool.dtype)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
 
@@ -1170,7 +1205,8 @@ def paged_flash_decode(
 def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
                       side_k, side_v, *, h_kv: int, interpret: bool,
                       window: int | None = None, name: str | None = None):
-    """The validated single-query call of :func:`paged_flash_decode`.
+    """The validated single-query call of :func:`paged_flash_decode`
+    (``v_pool`` None: the one pool of K beside V, ``side_v`` None with it).
     Under its own ``jit``: a segment program calls it once a layer with
     the same shapes, and the kernel body is then traced and lowered once
     a program and not once a layer (measured: 36 calls lowered in 7 s
@@ -1199,10 +1235,12 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
     else:
         r_kv = h_kv
         q3 = q4.reshape(b * h_kv, gp, d)
+    joined = v_pool is None
     out = _paged_call(
-        meta, q3, (k_pool, v_pool), (side_k, side_v) if side else None,
+        meta, q3, (k_pool,) if joined else (k_pool, v_pool),
+        None if not side else (side_k,) if joined else (side_k, side_v),
         scale=d ** -0.5, lanes=b, r_kv=r_kv, paired=paired, d_v=None,
-        interpret=interpret, window=window,
+        interpret=interpret, window=window, joined=joined,
         name=name or ("paged_flash_decode" if window is None
                       else "paged_window_decode"))
     if paired:
@@ -1213,19 +1251,22 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
 
 def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
                 r_kv: int, paired: bool, d_v: int | None, interpret: bool,
-                name: str, window: int | None = None):
-    """The ``pallas_call`` both paged decode kernels share: one grid row a
+                name: str, window: int | None = None, joined: bool = False):
+    """The ``pallas_call`` the paged decode kernels share: one grid row a
     lane and all its ``r_kv`` K/V-head chunks where their tile slots fit
     (:func:`paged_grid_rows`; several rows a lane, each a group of
     chunks, where not), the pools left in HBM for the body's own copies,
     ``q3 [lanes * r_kv, gp, d]`` (``[.., 2, gp, d]`` paired) in and an
     output of its shape (``d_v`` wide where the one pool's rows double as
-    values) out."""
+    values) out.  ``joined``: the one pool (and the one side buffer) holds
+    keys beside values a row; its tile slots are the two pools' together
+    and it is counted as the two."""
     block, m_blocks = pools[0].shape[1], (meta.shape[0] - 1 - lanes) // lanes
     members = 2 if paired else 1
     gp, d_head = q3.shape[-2:]
     rows = paged_grid_rows(
-        lanes, r_kv * members, d_head, block, m_blocks, pools=len(pools),
+        lanes, r_kv * members, d_head, block, m_blocks,
+        pools=2 if joined else len(pools),
         itemsize=pools[0].dtype.itemsize)
     groups = rows // lanes
     chunks = r_kv // groups                # of the lane's r_kv, a grid row
@@ -1251,19 +1292,28 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     args = [meta, q3, *pools]
     in_specs = [row_spec] + [pool_spec] * len(pools)
-    if sides is not None:
+    if sides is not None and joined:
+        # the one side buffer as its two halves: the row's key columns and,
+        # a half further (``G`` blocks of ``d``), its value columns
+        args += [sides[0]] * 2
+        in_specs += [
+            pl.BlockSpec((1, sides[0].shape[1], d),
+                         lambda g_, m, at=at: (g_ // G, 0, at + g_ % G))
+            for at in (0, G)]
+    elif sides is not None:
         side_spec = pl.BlockSpec(
             (1, sides[0].shape[1], d), lambda g_, m: (g_ // G, 0, g_ % G))
         args += list(sides)
         in_specs += [side_spec] * len(sides)
 
-    tile_buf = pltpu.VMEM((2, pages_per_tile, block, d), pools[0].dtype)
+    tile_buf = pltpu.VMEM(
+        (2, pages_per_tile, block, 2 * d if joined else d), pools[0].dtype)
     return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, scale=scale, block=block,
             pages_per_tile=pages_per_tile, m_blocks=m_blocks, lanes=lanes,
             groups=groups, slots=slots, side=sides is not None, d_v=d_v,
-            window=window),
+            window=window, joined=joined),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(rows,),
@@ -1776,81 +1826,81 @@ def index_select(scores: jnp.ndarray, k: int) -> jnp.ndarray:
         return jnp.minimum(chunk * _SELECT_CHUNK + within, r - 1)
 
 
+# gathers of ``[T, k]`` rows one call of :func:`sparse_gqa_attend` makes
+# (the host's count of what a decode step fetches, ``serve/rows_gathered``)
+SPARSE_ATTEND_GATHERS = 1
+
+
 def sparse_gqa_attend(
     q: jnp.ndarray,
-    k_source: jnp.ndarray,
-    v_source: jnp.ndarray,
+    kv_source: jnp.ndarray,
     ids: jnp.ndarray,
     count: jnp.ndarray,
     *,
     packed_kv_heads: int,
-    side_k: jnp.ndarray | None = None,
-    side_v: jnp.ndarray | None = None,
+    side_kv: jnp.ndarray | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Grouped-query attention of each query over ITS OWN chosen rows.
 
     Args:
       q: ``[T, H, D]`` queries (a decode step: one a lane).
-      k_source / v_source: ``[N, Hkv*D]`` packed K and V rows by flat row
-        id (a pool seen flat: ``page x block + offset``).
+      kv_source: ``[N, 2*Hkv*D]`` rows by flat row id (a pool seen flat:
+        ``page x block + offset``): a token's packed K in the first
+        ``Hkv*D`` columns of its row, its packed V in the last.
       ids: ``[T, k]`` row ids; the first ``count[t]`` of query ``t``'s are
         attended, the others ignored (any value).
-      side_k / side_v: ``[T, cap, Hkv*D]``: an id of ``N + j`` names row
-        ``j`` of the query's own side buffers (the segment's staging
-        rows).  Those are the highest ids, and ``ids`` must list a query's
-        first ``count`` in ASCENDING order (:func:`index_select`'s), so
-        that the staged rows are the last ``cap`` or fewer of them.
+      side_kv: ``[T, cap, 2*Hkv*D]``: an id of ``N + j`` names row ``j`` of
+        the query's own side buffer (the segment's staging rows).  Those
+        are the highest ids, and ``ids`` must list a query's first
+        ``count`` in ASCENDING order (:func:`index_select`'s), so that the
+        staged rows are the last ``cap`` or fewer of them.
 
-    This version gathers the rows with XLA (``[T, k, Hkv*D]`` a pool:
-    written once and read once, where a kernel with row-granular copies
-    would read them once) and attends with the paged walk over those
-    buffers, the chosen rows their pages: the arithmetic is
-    :func:`paged_flash_decode`'s.  Returns ``[T, H, D]``.  In a trace the
-    kernel is ``sparse_gqa_attend``."""
+    The rows are gathered with XLA, ONCE: K and V of a token are one row,
+    because the chip's gather costs by the row and hardly by the byte
+    (``[T, k, 2*Hkv*D]``: written once and read once, where a kernel with
+    row-granular copies would read them once).  The paged walk then attends
+    that one buffer, the chosen rows its pages, keys and values the two
+    halves of a tile: the arithmetic is :func:`paged_flash_decode`'s.
+    Returns ``[T, H, D]``.  In a trace the kernel is
+    ``sparse_gqa_attend``."""
     t, h, d = q.shape
-    n, k = k_source.shape[0], ids.shape[1]
-    flat = packed_kv_heads * d
-    if (k_source.shape != (n, flat) or v_source.shape != (n, flat)
-            or ids.shape[0] != t or count.shape != (t,)
-            or h % packed_kv_heads):
+    n, k = kv_source.shape[0], ids.shape[1]
+    width = 2 * packed_kv_heads * d
+    if (kv_source.shape != (n, width) or ids.shape[0] != t
+            or count.shape != (t,) or h % packed_kv_heads):
         raise ValueError(
-            f"q [T, H, D], sources [N, Hkv*D], ids [T, k], count [T] "
-            f"needed; got {q.shape}, {k_source.shape}, {v_source.shape}, "
-            f"{ids.shape}, {count.shape}")
+            f"q [T, H, D], a source [N, 2*Hkv*D], ids [T, k], count [T] "
+            f"needed; got {q.shape}, {kv_source.shape}, {ids.shape}, "
+            f"{count.shape}")
     count = jnp.minimum(jnp.asarray(count, jnp.int32), k)
-    at = window = None
-    if side_k is not None:
-        # the staged rows lie in the last ``cap`` places before ``count``:
-        # only that window of the gathered buffers is looked at again
-        cap = min(side_k.shape[1], k)
-        with routine("attn/rows"):
-            at = (jnp.clip(count - cap, 0, k - cap)[:, None]
-                  + jnp.arange(cap))
-            window = jnp.take_along_axis(ids, at, axis=1)       # [T, cap]
-
-    def chosen(source, side):
-        rows = jnp.take(source, jnp.minimum(ids, n - 1), axis=0,
-                        mode="clip")
-        if side is None:
-            return rows
-        staged = jnp.take_along_axis(
-            side, jnp.clip(window - n, 0, side.shape[1] - 1)[..., None],
-            axis=1).astype(rows.dtype)
-        fixed = jnp.where((window >= n)[..., None], staged,
-                          jnp.take_along_axis(rows, at[..., None], axis=1))
-        return rows.at[jnp.arange(t)[:, None], at].set(fixed)
-
-    block = block_of(k, 8)   # rows a page of the gathered buffers
+    block = block_of(k, 8)   # rows a page of the gathered buffer
     pages = k // block
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     with routine("attn/rows"):
-        k_rows = chosen(k_source, side_k).reshape(t * pages, block, flat)
-        v_rows = chosen(v_source, side_v).reshape(t * pages, block, flat)
+        rows = jnp.take(kv_source, jnp.minimum(ids, n - 1), axis=0,
+                        mode="clip")
+        if side_kv is not None:
+            # the staged rows lie in the last ``cap`` places before
+            # ``count``: only that window of the gathered buffer is looked
+            # at again
+            cap = min(side_kv.shape[1], k)
+            at = (jnp.clip(count - cap, 0, k - cap)[:, None]
+                  + jnp.arange(cap))
+            window = jnp.take_along_axis(ids, at, axis=1)       # [T, cap]
+            staged = jnp.take_along_axis(
+                side_kv,
+                jnp.clip(window - n, 0, side_kv.shape[1] - 1)[..., None],
+                axis=1).astype(rows.dtype)
+            fixed = jnp.where(
+                (window >= n)[..., None], staged,
+                jnp.take_along_axis(rows, at[..., None], axis=1))
+            rows = rows.at[jnp.arange(t)[:, None], at].set(fixed)
+        rows = rows.reshape(t * pages, block, width)
     with routine("attn/core"):
         out = _paged_decode_one(
-            q[:, None], k_rows, v_rows,
+            q[:, None], rows, None,
             jnp.arange(t * pages, dtype=jnp.int32).reshape(t, pages), count,
             jnp.zeros((), jnp.int32), None, None, h_kv=packed_kv_heads,
             interpret=bool(interpret), name="sparse_gqa_attend")
