@@ -108,6 +108,19 @@ def _kernel_calls(hlo: str) -> int:
     return hlo.count('custom_call_target="tpu_custom_call"')
 
 
+def _entry_products(hlo: str, shape: str) -> int:
+    """How many instructions of the entry computation give ``shape`` out of
+    a product: fusions whose computation holds a ``convolution``, which is
+    how the TPU compiler writes a ``dot``."""
+    bodies = dict(re.findall(r"^%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo,
+                             re.M | re.S))
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.M | re.S).group(1)
+    fusions = re.findall(
+        rf"^\s*%[\w.\-]+ = {re.escape(shape)}\S* fusion\(.*calls=%([\w.\-]+)",
+        entry, re.M)
+    return sum(" convolution(" in bodies[calls] for calls in fusions)
+
+
 def _custom_call(hlo: str, name: str) -> dict:
     """The one custom call whose instruction is named ``name``, parsed as
     the benchmark parses a trace's event (a device event is named by its
@@ -950,6 +963,11 @@ def test_composed_train_step_names_its_kernels(v5e):
                                   hlo, re.M))
              for name in scopes}
     assert calls == {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+    # and the MLP's pre-activation, so a layer holds two products as wide as
+    # ``ffn_dim`` (``up``, and the gradient of ``down``'s input) and no
+    # second ``up``: 4 in two layers where a recomputed ``up`` made 6
+    # (the compiler folds the one row away)
+    assert _entry_products(hlo, f"bf16[{SEQ},{4 * EMBED}]") == 4
 
 
 # Off this PR's path (ROADMAP R1 and D7): compiled and REPORTED, not gated —

@@ -288,6 +288,12 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
+def _wide_products(eqns, width):
+    """How many ``dot_general`` equations give an output ``width`` wide."""
+    return sum(e.primitive.name == "dot_general"
+               and e.outvars[0].aval.shape[-1] == width for e in eqns)
+
+
 def _grad_of(model, toks):
     from tpudist.ops.losses import cross_entropy
 
@@ -299,10 +305,42 @@ def _grad_of(model, toks):
     return jax.value_and_grad(loss)
 
 
+def _grad_eqns(model, toks, params):
+    """Every equation of the jaxpr of ``model``'s loss and gradient."""
+    return list(_eqns(jax.make_jaxpr(_grad_of(model, toks))(params).jaxpr))
+
+
+def _bare_remat(monkeypatch):
+    """``_remat_block`` as a bare ``nn.remat``: a block's input alone."""
+    import flax.linen as nn
+
+    from tpudist.models import transformer
+
+    monkeypatch.setattr(
+        transformer, "_remat_block",
+        lambda: nn.remat(transformer.DecoderBlock, static_argnums=(2,)))
+
+
+def _assert_trees_equal(a, b):
+    jax.tree.map(lambda x, y: np.testing.assert_array_equal(
+        np.asarray(x), np.asarray(y)), a, b)
+
+
+def _assert_matches_no_remat(got, want, scan_layers):
+    """Equal to the bit; under ``scan_layers`` to rounding: a scan body
+    compiled with its remat is another CPU program than the plain body (so
+    it was under the bare remat)."""
+    if scan_layers:
+        jax.tree.map(lambda x, y: np.testing.assert_allclose(
+            np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-7), got, want)
+    else:
+        _assert_trees_equal(got, want)
+
+
 @pytest.mark.parametrize("attention,scan_layers,fwd_calls", [
     ("flash", False, 2),    # one forward kernel a layer (a bare remat: 4)
     ("flash", True, 1),     # the forward scan's body alone (a bare remat: 2)
-    ("sdpa", False, 0),     # no kernel, no names: the bare remat's step
+    ("sdpa", False, 0),     # no kernel and none of its names
 ], ids=["flash_unrolled", "flash_scan_layers", "sdpa"])
 def test_remat_keeps_the_kernels_residuals(monkeypatch, attention,
                                            scan_layers, fwd_calls):
@@ -310,9 +348,7 @@ def test_remat_keeps_the_kernels_residuals(monkeypatch, attention,
     log-sum-exp, so the backward pass's recomputation holds no second
     forward kernel; what it hands the backward kernels is bit for bit what
     a second call would have produced."""
-    import flax.linen as nn
-
-    from tpudist.models import transformer
+    from tpudist.models.transformer import MLP_PRE_NAME
     from tpudist.ops.flash_attention import FLASH_RESIDUALS
 
     cfg = TransformerConfig(vocab_size=32, num_layers=2, num_heads=2,
@@ -327,48 +363,130 @@ def test_remat_keeps_the_kernels_residuals(monkeypatch, attention,
     params = plain.init(jax.random.key(0), toks)["params"]
 
     def census(model):
-        eqns = list(_eqns(jax.make_jaxpr(
-            _grad_of(model, toks))(params).jaxpr))
+        eqns = _grad_eqns(model, toks, params)
         kernels = [e.params["name"] for e in eqns
                    if e.primitive.name == "pallas_call"]
         names = sorted(e.params["name"] for e in eqns
                        if e.primitive.name == "name")
-        return len(eqns), kernels, names
+        return _wide_products(eqns, cfg.ffn_dim), kernels, names
 
-    n_eqns, kernels, names = census(remat)
+    wide, kernels, names = census(remat)
     assert kernels.count("flash_fwd") == fwd_calls
     if attention == "flash":
         # the backward kernels stand: one of each a layer (one scan body)
         assert kernels.count("flash_bwd_dq") == fwd_calls
         assert kernels.count("flash_bwd_dkv") == fwd_calls
-        assert set(names) == set(FLASH_RESIDUALS)
+        assert set(names) == {*FLASH_RESIDUALS, MLP_PRE_NAME}
     else:
-        assert names == []
-
-    def same(a, b):
-        jax.tree.map(lambda x, y: np.testing.assert_array_equal(
-            np.asarray(x), np.asarray(y)), a, b)
+        # another attention function carries none of the kernel's names:
+        # the block keeps its MLP's pre-activation alone
+        assert set(names) == {MLP_PRE_NAME}
 
     got = _grad_of(remat, toks)(params)
     # the same model under a bare remat, which keeps a block's input alone:
     # twice the forward kernels, and the same numbers to the bit (the saved
     # tensor IS the recomputed one)
     with monkeypatch.context() as m:
-        m.setattr(
-            transformer, "_remat_block",
-            lambda: nn.remat(transformer.DecoderBlock, static_argnums=(2,)))
-        bare_eqns, bare_kernels, _ = census(remat)
+        _bare_remat(m)
+        bare_wide, bare_kernels, _ = census(remat)
         if attention == "flash":
             assert bare_kernels.count("flash_fwd") == 2 * fwd_calls
         else:
-            assert (bare_eqns, bare_kernels) == (n_eqns, kernels)
-        same(got, _grad_of(remat, toks)(params))
+            assert bare_kernels == kernels == []
+        # and one more up-projection a layer (a scan body)
+        assert bare_wide - wide == (1 if scan_layers else cfg.num_layers)
+        _assert_trees_equal(got, _grad_of(remat, toks)(params))
 
-    want = _grad_of(plain, toks)(params)
-    if scan_layers:
-        # a scan body compiled with its remat is another CPU program than
-        # the plain body (so it was under the bare remat): to rounding
-        jax.tree.map(lambda x, y: np.testing.assert_allclose(
-            np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-7), got, want)
-    else:
-        same(got, want)
+    _assert_matches_no_remat(got, _grad_of(plain, toks)(params), scan_layers)
+
+
+@pytest.mark.parametrize("scan_layers", [False, True],
+                         ids=["unrolled", "scan_layers"])
+@pytest.mark.parametrize("mlp,products", [("gelu", 1), ("gated_silu", 2)])
+def test_remat_keeps_the_mlps_pre_activation(monkeypatch, mlp, products,
+                                             scan_layers):
+    """Under ``remat`` a block keeps what its MLP's ``ffn_dim``-wide
+    products gave (``up``; ``up`` and ``gate`` in the gated form), so the
+    backward pass's recomputation holds none of them a second time; the
+    activation is redone on the kept tensor, which is bit for bit what a
+    second product would have given."""
+    from tpudist.models.transformer import MLP_PRE_NAME
+
+    cfg = TransformerConfig(vocab_size=48, num_layers=2, num_heads=2,
+                            embed_dim=32, max_seq_len=16, mlp=mlp,
+                            scan_layers=scan_layers)
+    assert cfg.ffn_dim == 128     # no other tensor of the model is as wide
+    toks = jnp.asarray(
+        np.random.default_rng(1).integers(0, 48, (2, 16)), jnp.int32)
+    plain = TransformerLM(cfg)
+    remat = TransformerLM(cfg, remat=True)
+    params = plain.init(jax.random.key(1), toks)["params"]
+    bodies = 1 if scan_layers else cfg.num_layers
+
+    def wide_products(model):
+        return _wide_products(_grad_eqns(model, toks, params), cfg.ffn_dim)
+
+    assert {e.params["name"] for e in _grad_eqns(remat, toks, params)
+            if e.primitive.name == "name"} == {MLP_PRE_NAME}
+    # as many products that wide as a step without remat: none runs twice
+    wide = wide_products(remat)
+    assert wide == wide_products(plain)
+    got = _grad_of(remat, toks)(params)
+    with monkeypatch.context() as m:
+        _bare_remat(m)
+        assert wide_products(remat) - wide == bodies * products
+        _assert_trees_equal(got, _grad_of(remat, toks)(params))
+    _assert_matches_no_remat(got, _grad_of(plain, toks)(params), scan_layers)
+
+
+@pytest.mark.parametrize("first_k_dense,kept_products", [(0, 0), (1, 1)],
+                         ids=["experts_alone", "one_dense_layer"])
+def test_remat_leaves_an_expert_layer_to_its_own_vjp(monkeypatch,
+                                                     first_k_dense,
+                                                     kept_products):
+    """An expert layer carries no name: under ``remat`` it is recomputed as
+    under a bare ``remat``, and only a dense layer beside it keeps its
+    product."""
+    from tpudist.models import MoEConfig
+    from tpudist.models.transformer import MLP_PRE_NAME
+
+    cfg = TransformerConfig(vocab_size=48, num_layers=2, num_heads=2,
+                            embed_dim=32, max_seq_len=16,
+                            moe=MoEConfig(num_experts=4, top_k=2),
+                            first_k_dense=first_k_dense)
+    toks = jnp.asarray(
+        np.random.default_rng(2).integers(0, 48, (2, 16)), jnp.int32)
+    remat = TransformerLM(cfg, remat=True)
+    params = TransformerLM(cfg).init(jax.random.key(2), toks)["params"]
+
+    def census():
+        eqns = _grad_eqns(remat, toks, params)
+        return (sum(e.primitive.name == "dot_general" for e in eqns),
+                any(e.primitive.name == "name"
+                    and e.params["name"] == MLP_PRE_NAME for e in eqns))
+
+    products, named = census()
+    # the name rides in a dense layer's trace, never in an expert layer's
+    assert named == bool(kept_products)
+    got = _grad_of(remat, toks)(params)
+    with monkeypatch.context() as m:
+        _bare_remat(m)
+        assert census() == (products + kept_products, named)
+        _assert_trees_equal(got, _grad_of(remat, toks)(params))
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no_remat"])
+def test_remat_publishes_how_many_names_it_keeps(remat):
+    """Tracing a program through ``_remat_block`` sets the gauge to the
+    policy's names (the kernel's two and the MLP's one); a model without
+    ``remat`` never touches it."""
+    from tpudist import obs
+
+    cfg = TransformerConfig(vocab_size=32, num_layers=1, num_heads=2,
+                            embed_dim=32, max_seq_len=16)
+    toks = jnp.zeros((1, 16), jnp.int32)
+    params = TransformerLM(cfg).init(jax.random.key(0), toks)["params"]
+    gauge = obs.gauge("train/remat_kept_names")
+    gauge.clear()
+    jax.make_jaxpr(_grad_of(TransformerLM(cfg, remat=remat), toks))(params)
+    assert gauge.value() == (3.0 if remat else None)

@@ -31,7 +31,9 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from tpudist import obs
 from tpudist.obs.spans import routine
 
 # (q, k, v, *, causal, window=None) on [batch, seq, heads, head_dim]
@@ -1759,6 +1761,13 @@ class LinearAttention(nn.Module):
                 o.reshape(b, s, h * dv).astype(cfg.compute_dtype))
 
 
+# The dense MLP's pre-activation: what its ``ffn_dim``-wide products (``up``,
+# and ``gate`` in the gated form) give, named so that a ``jax.checkpoint``
+# policy can keep it (see _remat_block).  The activation is elementwise on it
+# and is redone.  Outside ``jax.checkpoint`` the name lowers to its operand.
+MLP_PRE_NAME = "mlp_pre"
+
+
 class MLPBlock(nn.Module):
     cfg: TransformerConfig
 
@@ -1768,11 +1777,14 @@ class MLPBlock(nn.Module):
         dense = functools.partial(nn.Dense, use_bias=False,
                                   dtype=cfg.compute_dtype)
         with routine("mlp/dense"):
-            h = dense(cfg.ffn_dim, name="up")(x)
+            h = checkpoint_name(dense(cfg.ffn_dim, name="up")(x),
+                                MLP_PRE_NAME)
             if cfg.mlp == "gelu":
                 h = nn.gelu(h)
             elif cfg.mlp == "gated_silu":
-                h = nn.silu(dense(cfg.ffn_dim, name="gate")(x)) * h
+                gate = checkpoint_name(dense(cfg.ffn_dim, name="gate")(x),
+                                       MLP_PRE_NAME)
+                h = nn.silu(gate) * h
             else:
                 raise ValueError(f"mlp must be 'gelu' or 'gated_silu', got "
                                  f"{cfg.mlp!r}")
@@ -1868,20 +1880,26 @@ class DecoderBlock(nn.Module):
 
 def _remat_block():
     """:class:`DecoderBlock` under ``nn.remat``, for both layer layouts:
-    the backward pass recomputes a block from its input, except the two
-    residuals its flash-attention kernel produced (the output and the
-    log-sum-exp), which are kept.  They are the one activation dearer to
-    recompute than to hold: giving them back costs a whole second run of
-    the forward kernel, holding them costs about one more block input
-    (``B x S x E`` in the compute dtype, plus 1/64 of it for the
-    log-sum-exp).  Another attention function carries no such names, so
-    the policy keeps nothing and the block is recomputed whole."""
+    the backward pass recomputes a block from its input, except what a
+    whole product or kernel call would have to give back, which is kept by
+    name: the two residuals of its flash-attention kernel (the output and
+    the log-sum-exp: about one more block input, ``B x S x E`` in the
+    compute dtype plus 1/64 of it) and the dense MLP's pre-activation
+    (:data:`MLP_PRE_NAME`: ``ffn_dim / embed_dim`` block inputs, twice that
+    in the gated form).  Everything elementwise on them (the activation,
+    the norms) and the narrow projections are redone.  A layer observes
+    its own type: another attention function carries no flash names and an
+    expert layer no MLP name (its grouped product rematerializes by its
+    own ``custom_vjp``), so those parts are recomputed whole."""
     from tpudist.ops.flash_attention import FLASH_RESIDUALS
 
+    kept = (*FLASH_RESIDUALS, MLP_PRE_NAME)
+    # called where a program that uses it is traced, once a program: the
+    # step builders see only a loss function
+    obs.gauge("train/remat_kept_names").set(float(len(kept)))
     return nn.remat(
         DecoderBlock, static_argnums=(2,),
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *FLASH_RESIDUALS))
+        policy=jax.checkpoint_policies.save_only_these_names(*kept))
 
 
 class _ScanBody(nn.Module):
@@ -1981,12 +1999,18 @@ class TransformerLM(nn.Module):
         # remat: recompute each block's activations in backward instead of
         # storing them — the jax.checkpoint memory/FLOPs trade that makes
         # long-context training fit in HBM.  What is kept a layer is the
-        # block's input and, where the attention is the flash kernel, its
-        # output and log-sum-exp (about a second block input): the backward
-        # kernels need them and only a second forward kernel call could
-        # give them back (see _remat_block).  Default prevent_cse=True:
-        # under plain jit XLA could otherwise CSE the recomputation back
-        # into the stored forward and silently undo the memory savings.
+        # block's input, where the attention is the flash kernel its
+        # output and log-sum-exp (about a second block input), and the
+        # dense MLP's pre-activation (ffn_dim / embed_dim block inputs,
+        # twice that in the gated form): 6 block inputs a layer at ffn_dim
+        # = 4 x embed_dim in the gelu form, 2 x ffn_dim / embed_dim + 2 in
+        # the gated one, where no remat holds about 30.  Each would cost a
+        # whole kernel call or product to give back (see _remat_block).
+        # ONE policy, no option: a step that does not fit is refused by
+        # the compiler at its first compile, in the compiler's words.
+        # Default prevent_cse=True: under plain jit XLA could otherwise
+        # CSE the recomputation back into the stored forward and silently
+        # undo the memory savings.
         if cfg.scan_layers:
             if (cfg.moe is not None or cfg.positions == "rotary"
                     or len(set(cfg.windows)) > 1):
