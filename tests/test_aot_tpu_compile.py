@@ -698,60 +698,63 @@ def test_a_page_of_64_wide_index_keys_is_refused(v5e):
 
 
 def test_sparse_gqa_attend_at_the_cells_shapes(v5e):
-    """The chosen rows of the ONE pool of K beside V gathered by flat row
-    id, once (the staged rows out of the one side buffer), and attended as
-    pages of the gathered buffer, keys and values the two halves of a
-    tile, on the shared walk under its own name."""
+    """The chosen rows of the ONE pool of K and V gathered by flat row
+    id, once, as rows of 32-bit words (the staged rows out of the one side
+    buffer), and attended as pages of the gathered buffer, a tile's keys
+    and values the two halves of its words, on the shared walk under its
+    own name."""
     from benchmarks.layer_metrics import _index_spans
     from benchmarks.layer_metrics.paged_decode_us_per_call import KERNEL
-    from tpudist.ops.flash_decode import sparse_gqa_attend
+    from tpudist.ops.flash_decode import kv_row, sparse_gqa_attend
 
-    width = 2 * IDX_KV * IDX_D
+    width, words = kv_row(IDX_KV * IDX_D, jnp.bfloat16)
+    assert (width, words) == (IDX_KV * IDX_D, jnp.uint32)
     hlo = _compile(
         lambda q, kv, i, c, side: sparse_gqa_attend(
             q, kv, i, c, packed_kv_heads=IDX_KV, side_kv=side),
         _sds(v5e, (IDX_LANES, IDX_Q, IDX_D)),
-        _sds(v5e, (IDX_BLOCKS * BLOCK, width)),
+        _sds(v5e, (IDX_BLOCKS * BLOCK, width), words),
         _sds(v5e, (IDX_LANES, IDX_TOPK), jnp.int32),
         _sds(v5e, (IDX_LANES,), jnp.int32),
-        _sds(v5e, (IDX_LANES, IDX_SIDE, width)))
+        _sds(v5e, (IDX_LANES, IDX_SIDE, width), words))
     assert _kernel_calls(hlo) == 1
     op = _named_call(hlo, "sparse_gqa_attend", _index_spans.ATTEND)
     assert op["pallas"] and op["operands"] == 3   # meta, q, the rows
     assert op["outputs"] == (
         f"bf16[{IDX_LANES * IDX_KV},{IDX_Q // IDX_KV},{IDX_D}]",)
     assert not any(KERNEL.match(l.strip()) for l in hlo.splitlines())
-    # ONE gather of the chosen rows, K and V of a token in one row
+    # ONE gather of the chosen rows, K and V of a token in one row of words
     assert len(re.findall(
-        rf"= bf16\[{IDX_LANES},{IDX_TOPK},{width}\]\S* gather\(", hlo)) == 1
+        rf"= u32\[{IDX_LANES},{IDX_TOPK},{width}\]\S* gather\(", hlo)) == 1
 
 
 @pytest.mark.parametrize("kv_heads", [IDX_KV, 2 * IDX_KV])
 def test_every_row_walk_of_the_one_pool_at_the_cells_shapes(v5e, kv_heads):
     """The every-row branch of an indexer's layer: ``paged_flash_decode``
-    over the one pool of K beside V and its one side buffer.  Four K/V
-    heads of 128: a lane a grid row, a page one copy of 2 KB rows, the tile
-    slots the 4 MiB the two pools' were.  Eight: two rows a lane, each a
-    copy of its columns of either half."""
+    over the one pool of K and V, rows of 32-bit words, and its one side
+    buffer.  Four K/V heads of 128: a lane a grid row, a page one copy of
+    2 KB rows, the tile slots the 4 MiB the two pools' were.  Eight: two
+    rows a lane, each ONE copy of its heads' words."""
     from benchmarks.layer_metrics.paged_decode_us_per_call import KERNEL
-    from tpudist.ops.flash_decode import paged_flash_decode, paged_grid_rows
+    from tpudist.ops.flash_decode import (kv_row, paged_flash_decode,
+                                          paged_grid_rows)
 
-    width = 2 * kv_heads * IDX_D
+    width, words = kv_row(kv_heads * IDX_D, jnp.bfloat16)
     heads = IDX_Q * kv_heads // IDX_KV
     hlo = _compile(
         lambda q, kv, t, n, side, sl: paged_flash_decode(
             q, kv, None, t, n, packed_kv_heads=kv_heads, side_k=side,
             side_len=sl),
         _sds(v5e, (IDX_LANES, 1, heads, IDX_D)),
-        _sds(v5e, (IDX_BLOCKS, BLOCK, width)),
+        _sds(v5e, (IDX_BLOCKS, BLOCK, width), words),
         _sds(v5e, (IDX_LANES, IDX_ENTRIES), jnp.int32),
         _sds(v5e, (IDX_LANES,), jnp.int32),
-        _sds(v5e, (IDX_LANES, IDX_SIDE, width)),
+        _sds(v5e, (IDX_LANES, IDX_SIDE, width), words),
         _sds(v5e, (), jnp.int32))
     assert _kernel_calls(hlo) == 1
     op = _named_call(hlo, "paged_flash_decode", KERNEL)
-    # meta, q, the pool, the side buffer as its two halves
-    assert op["pallas"] and op["operands"] == 5
+    # meta, q, the pool, the side buffer (one block of words)
+    assert op["pallas"] and op["operands"] == 4
     assert paged_grid_rows(IDX_LANES, kv_heads, IDX_D, BLOCK,
                            IDX_ENTRIES) == IDX_LANES * kv_heads // IDX_KV
 
@@ -1076,7 +1079,7 @@ FAMILIES = {
                "moe_experts_down": "mlp/experts"},
         {"attn/proj", "attn/cache", "attn/index", "attn/rows", "attn/core",
          "mlp/route", "mlp/experts", "head"},
-        ("059976b13d2321df", "4deb752c1e41b7d9", "ae38c737057e0667")),
+        ("69cfabc9100ffeae", "4deb752c1e41b7d9", "21b9b593b1054222")),
 }
 PROGRAMS = ("_segment_impl", "_prefill_chunk_impl", "_admit_finish_impl")
 # instructions that carry no routine, of those that are not parameters,
@@ -1193,9 +1196,10 @@ def test_programs_without_metadata_are_the_parents(compiled, family,
     """Operation for operation, name for name: the hash of the compiled
     text with its metadata stripped is the one recorded from the commit
     before the scopes (293f19f), on the same toy program.  The indexer's
-    segment and finish are recorded from the commit that gave its layers
-    ONE pool of K beside V (its chunk, and every other family's three,
-    stayed the text they were)."""
+    segment and finish are recorded from the commit that made the ONE pool
+    row of K and V a row of 32-bit words (its chunk, and every other
+    family's three, stayed the text they were, as they had when the one
+    pool came)."""
     want = FAMILIES[family][4][PROGRAMS.index(program)]
     text = strip_metadata(compiled[family][program])
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

@@ -9,8 +9,10 @@ an old configuration, added one or renamed one fails here.  The sixth is
 the configuration that PR brought, read on its own tree.  The cache trees
 of ``keye-vl-2.0-30b-a3b`` were read anew on the tree of PR 42, which
 gave an indexer's layers ONE pool and ONE staging buffer of K beside V
-(``paged_kv`` / ``side_kv`` for the two pairs: 26 leaves -> 22); its
-parameters, and every other configuration's three trees, stayed."""
+(``paged_kv`` / ``side_kv`` for the two pairs: 26 leaves -> 22), and again
+on the tree of PR 46, which made that row 32-bit words (the same 22
+leaves; the two of a layer ``uint32`` of half the columns in bfloat16);
+its parameters, and every other configuration's three trees, stayed."""
 
 import hashlib
 import importlib
@@ -31,7 +33,7 @@ TREES = {
     "mellum2-12b-a2.5b": ((39, "6449b1b6c08d19e4"),
                           (40, "74e4b734b903b89e")),
     "keye-vl-2.0-30b-a3b": ((35, "6181a46c1edeab4e"),
-                            (22, "9e2e90a0148ff55e")),
+                            (22, "b5662f8cc9ab7060")),
     "olmo-hybrid-7b": ((51, "90c4b5ddcc5c5e64"), (22, "ef3c1ab0e982065f")),
 }
 

@@ -16,6 +16,7 @@ rounding, which the seeds below do not hit.
 import dataclasses
 import functools
 import hashlib
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -30,9 +31,10 @@ from tpudist.models.generate import _blank_cache
 from tpudist.models.serving import _index_leaves, _kv_leaves
 from tpudist.ops.flash_attention import flash_chosen_rows
 from tpudist.ops.flash_decode import (index_queries_per_row, index_select,
-                                      index_select_mask, paged_flash_decode,
-                                      paged_grid_rows, paged_index_scores,
-                                      sparse_gqa_attend)
+                                      index_select_mask, kv_row, pack_kv,
+                                      paged_flash_decode, paged_grid_rows,
+                                      paged_index_scores, sparse_gqa_attend,
+                                      unpack_kv)
 
 VOCAB, EMBED, SEQ, TOPK = 97, 48, 128, 16
 DIMS = ref.Dims(
@@ -234,6 +236,57 @@ def test_serve_loop_matches_reference(decode_attention):
     assert _worst_gap(done, _params()) <= LOGIT_TOL
 
 
+# a bfloat16 model against the float32 reference: 0.16 on both routes (the
+# worst position is a prefill's), 1.35 with the selection skipped, 4.1 with
+# a word's halves swapped on their way into the walk
+BF16_TOL = 0.4
+
+
+@functools.cache
+def _served_bf16(decode_attention, topk=TOPK):
+    return _serve(_cfg(topk=topk, compute_dtype=jnp.bfloat16), _params(),
+                  decode_attention)
+
+
+@pytest.mark.parametrize("decode_attention", ["flash", "dense"])
+def test_a_bfloat16_model_serves_over_word_rows(decode_attention):
+    """The same loop in bfloat16, where ``paged_kv`` / ``side_kv`` are rows
+    of ``uint32`` words: the finish packs the prompt's K and V, the step
+    its token's, the merge moves words, and the rows come back out in the
+    walk (``flash``) or through ``unpack_kv`` (``dense``).  The two routes
+    round differently from a prompt's first chunk on, so neither serves
+    the other's tokens to the last; each serves the reference's within
+    bfloat16's rounding, far under what wrong rows or swapped halves
+    read."""
+    done = _served_bf16(decode_attention)
+    assert len(done) == len(REQUESTS)
+    assert _worst_gap(done, _params()) <= BF16_TOL
+    # the routes agree on the requests no near-tie splits
+    other = {c.rid: c.tokens for c in _served_bf16(
+        "dense" if decode_attention == "flash" else "flash")}
+    assert sum(np.array_equal(c.tokens, other[c.rid]) for c in done) >= 2
+
+
+def test_a_bfloat16_loop_that_skips_the_selection_fails_that_tolerance():
+    done = _served_bf16("dense", SEQ + 8)
+    assert _worst_gap(done, _params()) > 3 * BF16_TOL
+
+
+def test_swapped_halves_of_a_word_fail_that_tolerance(monkeypatch):
+    """The planted fault: the walk takes a word's value for its key."""
+    fd = importlib.import_module("tpudist.ops.flash_decode")
+    half = fd._word_half
+    monkeypatch.setattr(fd, "_word_half",
+                        lambda w, h, dtype: half(w, 1 - h, dtype))
+    fd._paged_decode_one.clear_cache()
+    try:
+        done = _serve(_cfg(compute_dtype=jnp.bfloat16), _params(), "flash")
+    finally:
+        monkeypatch.undo()
+        fd._paged_decode_one.clear_cache()
+    assert _worst_gap(done, _params()) > 3 * BF16_TOL
+
+
 def test_serve_loop_that_skips_the_selection_fails_the_same_tolerance():
     """The control: a program that attends every row (the same weights,
     ``index_topk`` beyond every context) is not what the reference
@@ -322,18 +375,18 @@ def test_queries_a_grid_row_follow_the_output_block():
 ROWS_T, ROWS_G, ROWS_K, ROWS_N, ROWS_CAP = 5, 2, 16, 200, 4
 
 
-def _given_rows(rng, h_kv, d, count):
+def _given_rows(rng, h_kv, d, count, dtype=jnp.float32):
     """Queries, a K and a V source, side buffers and ids for
     ``sparse_gqa_attend``: a query's first ``count`` ids ascend, so its
     staged rows (ids from ``n`` on) come last among them; what follows is
     ignored."""
     t, g, k, n, cap = ROWS_T, ROWS_G, ROWS_K, ROWS_N, ROWS_CAP
     flat = h_kv * d
-    q = jnp.asarray(rng.normal(size=(t, h_kv * g, d)), jnp.float32)
-    k_src, v_src = (jnp.asarray(rng.normal(size=(n, flat)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(t, h_kv * g, d)), dtype)
+    k_src, v_src = (jnp.asarray(rng.normal(size=(n, flat)), dtype)
                     for _ in range(2))
-    side_k, side_v = (jnp.asarray(rng.normal(size=(t, cap, flat)),
-                                  jnp.float32) for _ in range(2))
+    side_k, side_v = (jnp.asarray(rng.normal(size=(t, cap, flat)), dtype)
+                      for _ in range(2))
     ids = rng.integers(0, n + cap, (t, k))
     for i, c in enumerate(count):
         ids[i, :c] = np.sort(rng.choice(
@@ -343,9 +396,54 @@ def _given_rows(rng, h_kv, d, count):
             jnp.asarray(count, jnp.int32))
 
 
-def _joined(k, v):
-    """K beside V, a row: what an indexer's layer keeps a token."""
-    return jnp.concatenate([k, v], axis=-1)
+# what an indexer's layer keeps a token: K and V in ONE row of 32-bit words
+_joined = pack_kv
+DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                                 ids=["f32_side_by_side", "bf16_words"])
+
+
+def _bits(x):
+    """An array's bits, to compare NaNs and signed zeros too."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16, jnp.float32])
+def test_a_row_of_words_gives_k_and_v_back_bit_for_bit(dtype):
+    """``pack_kv`` -> ``unpack_kv``: a 16-bit K and V share a word (K the
+    low half), 32-bit numbers lie side by side; signed zeros, infinities,
+    NaNs with a payload and denormals come back as they went in."""
+    size = jnp.dtype(dtype).itemsize
+    uint = {2: np.uint16, 4: np.uint32}[size]
+    rng = np.random.default_rng(11)
+    raw = rng.integers(0, 1 << (8 * size), (2, 7, 24), dtype=np.uint64)
+    top = 8 * size - 1
+    mantissa = {jnp.bfloat16: 7, jnp.float16: 10, jnp.float32: 23}[dtype]
+    ones = ((1 << top) - 1) >> mantissa << mantissa        # the exponent
+    special = np.array([
+        0, 1 << top,                                       # 0.0, -0.0
+        ones, ones | 1 << top,                             # inf, -inf
+        ones | 1, ones | 1 << top | 5,                     # NaN payloads
+        ones | 1 << (mantissa - 1) | 3,                    # a quiet NaN
+        1, 1 << top | 3, (1 << mantissa) - 1],             # denormals
+        np.uint64)
+    raw[:, 0, :len(special)] = special
+    raw[1, 0, :len(special)] = special[::-1]
+    k, v = (jax.lax.bitcast_convert_type(
+        jnp.asarray(half.astype(uint)), dtype) for half in raw)
+    row = pack_kv(k, v)
+    width, words = kv_row(24, dtype)
+    assert row.shape == (7, width) and row.dtype == words
+    assert row.dtype.itemsize == 4 and row.nbytes == k.nbytes + v.nbytes
+    if size == 2:
+        np.testing.assert_array_equal(
+            np.asarray(row), raw[0] | raw[1] << 16)
+    back_k, back_v = unpack_kv(row, dtype)
+    assert back_k.dtype == back_v.dtype == dtype
+    np.testing.assert_array_equal(_bits(back_k), _bits(k))
+    np.testing.assert_array_equal(_bits(back_v), _bits(v))
+    with pytest.raises(ValueError, match="hold K and V"):
+        unpack_kv(row, jnp.float32 if size == 2 else jnp.bfloat16)
 
 
 @pytest.mark.parametrize("h_kv,d", [(2, 128), (4, 128), (2, 16)],
@@ -375,22 +473,24 @@ def test_sparse_attend_over_given_rows(h_kv, d):
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
+@DTYPES
 @pytest.mark.parametrize("h_kv,d", [(4, 128), (2, 16)],
                          ids=["kv4_d128", "kv2_d16_paired"])
 @pytest.mark.parametrize("staged,count", [
     (True, (1, 5, 16, 9, 16)), (False, (16, 16, 16, 16, 16)),
     (False, (3, 16, 7, 12, 1)), (True, (0, 16, 0, 4, 16))],
     ids=["staged", "plain", "count_under_k", "a_lane_of_length_0"])
-def test_one_gather_gives_the_bits_of_two(h_kv, d, staged, count):
-    """The ONE gather of rows that hold K beside V, attended as the two
-    halves of a tile, against what it replaced, written here: a gather of
-    the K rows and one of the V rows (the staged rows patched into each),
-    attended by the walk over two pools.  The same rows, the same online
-    softmax in the same order: the same bits."""
+def test_one_gather_gives_the_bits_of_two(h_kv, d, staged, count, dtype):
+    """The ONE gather of rows that hold K and V (words of a key and a
+    value each; 32-bit keys beside values), attended as the two halves of
+    a tile's words (of its columns), against what it replaced, written
+    here: a gather of the K rows and one of the V rows (the staged rows
+    patched into each), attended by the walk over two pools.  The same
+    rows, the same online softmax in the same order: the same bits."""
     rng = np.random.default_rng(9)
     t, k, n, cap = ROWS_T, ROWS_K, ROWS_N, ROWS_CAP
     q, k_src, v_src, side_k, side_v, ids, count = _given_rows(
-        rng, h_kv, d, count)
+        rng, h_kv, d, count, dtype)
     if not staged:
         ids = jnp.minimum(ids, n - 1)
     got = sparse_gqa_attend(
@@ -413,31 +513,77 @@ def test_one_gather_gives_the_bits_of_two(h_kv, d, staged, count):
         q[:, None], gathered(k_src, side_k), gathered(v_src, side_v),
         jnp.arange(t * k // 8, dtype=jnp.int32).reshape(t, k // 8), count,
         packed_kv_heads=h_kv, interpret=True)[:, 0]
-    assert np.isfinite(np.asarray(got)).all()
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert got.dtype == dtype
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
+def test_word_rows_of_lanes_of_every_length_give_the_bits_of_two():
+    """bfloat16 at a width the chip runs (4 K/V heads of 128, 1024 chosen
+    rows: a tile of 8 pages of 128): lanes of 0, 5, 700 and ``k - 1``
+    rows, staged rows among each lane's chosen, over word rows against the
+    two gathers attended by the walk over two pools."""
+    rng = np.random.default_rng(46)
+    t, g, h_kv, d, k, n, cap = 4, 2, 4, 128, 1024, 1500, 8
+    flat = h_kv * d
+    q = jnp.asarray(rng.normal(size=(t, h_kv * g, d)), jnp.bfloat16)
+    k_src, v_src = (jnp.asarray(rng.normal(size=(n, flat)), jnp.bfloat16)
+                    for _ in range(2))
+    side_k, side_v = (jnp.asarray(rng.normal(size=(t, cap, flat)),
+                                  jnp.bfloat16) for _ in range(2))
+    count = np.array([0, 5, 700, k - 1])
+    ids = rng.integers(0, n + cap, (t, k))
+    for i, c in enumerate(count):
+        staged = min(c, 3 + i)
+        ids[i, :c] = np.concatenate([
+            np.sort(rng.choice(n, size=c - staged, replace=False)),
+            n + np.sort(rng.choice(cap, size=staged, replace=False))])
+    ids, count = jnp.asarray(ids, jnp.int32), jnp.asarray(count, jnp.int32)
+    got = sparse_gqa_attend(
+        q, pack_kv(k_src, v_src), ids, count, packed_kv_heads=h_kv,
+        side_kv=pack_kv(side_k, side_v), interpret=True)
+
+    def gathered(src, side):
+        rows = jnp.where(
+            ((ids >= n) & (jnp.arange(k) < count[:, None]))[..., None],
+            jnp.take_along_axis(
+                side, jnp.clip(ids - n, 0, cap - 1)[..., None], 1),
+            src[jnp.minimum(ids, n - 1)])
+        return rows.reshape(t * k // 128, 128, -1)
+
+    want = paged_flash_decode(
+        q[:, None], gathered(k_src, side_k), gathered(v_src, side_v),
+        jnp.arange(t * k // 128, dtype=jnp.int32).reshape(t, k // 128),
+        count, packed_kv_heads=h_kv, interpret=True)[:, 0]
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert np.asarray(got[1:], np.float32).any()
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@DTYPES
 @pytest.mark.parametrize("lens", [(0, 37, 300), (320, 1, 129)],
                          ids=["empty_short_long", "full_one_tile_edge"])
 @pytest.mark.parametrize("h_kv,d", [(4, 128), (2, 16), (8, 128)],
                          ids=["kv4_d128", "kv2_d16_paired",
                               "kv8_d128_two_rows_a_lane"])
-def test_every_row_walk_of_the_one_pool_gives_the_bits_of_two(lens, h_kv, d):
+def test_every_row_walk_of_the_one_pool_gives_the_bits_of_two(lens, h_kv, d,
+                                                              dtype):
     """The every-row branch of an indexer's layer: ``paged_flash_decode``
-    over the ONE pool of K beside V and its one side buffer against the
+    over the ONE pool of K and V and its one side buffer against the
     same call over the split pools and side buffers (8 K/V heads of 128:
-    a lane's heads take two grid rows, each with its columns of both
-    halves of a page)."""
+    a lane's heads take two grid rows, each with its heads' words of a
+    page, or its columns of both halves where keys lie beside values)."""
     rng = np.random.default_rng(4)
     b, g, bs, m, n, cap = 3, 2, 16, 24, 40, 4
-    assert paged_grid_rows(b, h_kv, d, bs, m, itemsize=4) == (
-        2 * b if h_kv == 8 else b)
+    size = jnp.dtype(dtype).itemsize
+    assert paged_grid_rows(b, h_kv, d, bs, m, itemsize=size) == (
+        2 * b if h_kv == 8 and size == 4 else b)
     flat = h_kv * d
-    q = jnp.asarray(rng.normal(size=(b, 1, h_kv * g, d)), jnp.float32)
-    k_pool, v_pool = (jnp.asarray(rng.normal(size=(n, bs, flat)),
-                                  jnp.float32) for _ in range(2))
-    side_k, side_v = (jnp.asarray(rng.normal(size=(b, cap, flat)),
-                                  jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(b, 1, h_kv * g, d)), dtype)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(n, bs, flat)), dtype)
+                      for _ in range(2))
+    side_k, side_v = (jnp.asarray(rng.normal(size=(b, cap, flat)), dtype)
+                      for _ in range(2))
     table = jnp.asarray(rng.integers(0, n, (b, m)), jnp.int32)
     lens = jnp.asarray(lens, jnp.int32)
     got = paged_flash_decode(
@@ -447,7 +593,32 @@ def test_every_row_walk_of_the_one_pool_gives_the_bits_of_two(lens, h_kv, d):
     want = paged_flash_decode(
         q, k_pool, v_pool, table, lens, packed_kv_heads=h_kv,
         side_k=side_k, side_v=side_v, side_len=3, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def test_a_word_pool_of_eight_heads_takes_two_rows_a_lane():
+    """bfloat16 at the widths where a lane's tile slots pass VMEM's share
+    (8 K/V heads of 128, pages of 128 rows, 8 a tile): two grid rows a
+    lane, each ONE copy of its four heads' words of a page."""
+    rng = np.random.default_rng(8)
+    b, g, h_kv, d, bs, m, n, cap = 3, 2, 8, 128, 128, 8, 10, 4
+    assert paged_grid_rows(b, h_kv, d, bs, m, itemsize=2) == 2 * b
+    flat = h_kv * d
+    q = jnp.asarray(rng.normal(size=(b, 1, h_kv * g, d)), jnp.bfloat16)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(n, bs, flat)),
+                                  jnp.bfloat16) for _ in range(2))
+    side_k, side_v = (jnp.asarray(rng.normal(size=(b, cap, flat)),
+                                  jnp.bfloat16) for _ in range(2))
+    table = jnp.asarray(rng.integers(0, n, (b, m)), jnp.int32)
+    lens = jnp.asarray((0, 700, 1024), jnp.int32)
+    got = paged_flash_decode(
+        q, pack_kv(k_pool, v_pool), None, table, lens,
+        packed_kv_heads=h_kv, side_k=pack_kv(side_k, side_v), side_len=2,
+        interpret=True)
+    want = paged_flash_decode(
+        q, k_pool, v_pool, table, lens, packed_kv_heads=h_kv,
+        side_k=side_k, side_v=side_v, side_len=2, interpret=True)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
 @pytest.mark.parametrize("offset,rows", [(0, 64), (32, 64), (96, 128)])
@@ -797,6 +968,44 @@ def test_segments_say_what_their_gathers_fetched(decode_attention):
                    for a in drains)
 
 
+@pytest.mark.parametrize("dtype,words", [
+    (jnp.float32, 2 * DIMS.kv_heads * DIMS.head_dim),
+    (jnp.bfloat16, DIMS.kv_heads * DIMS.head_dim)],
+    ids=["f32_a_number_a_word", "bf16_two_numbers_a_word"])
+def test_a_row_is_32_bit_words_whatever_the_compute_dtype(dtype, words):
+    """``paged_kv`` / ``side_kv`` by the compute dtype's width: a float32
+    model keeps K beside V in float32, ``[., 2 x kv_heads x head_dim]``
+    (the layout before words); a bfloat16 model ``uint32[., kv_heads x
+    head_dim]``.  The same bytes a token either way; the index keys stay
+    in the compute dtype.  ``serve/kv_row_words`` and the segment span's
+    ``row_words`` say which."""
+    cfg = _cfg(compute_dtype=dtype)
+    obs.tracer.clear()
+    assert len(_serve(cfg, _params(), "dense")) == len(REQUESTS)
+    flat = DIMS.kv_heads * DIMS.head_dim
+    node = _loop(cfg, _params(), "flash").cache["block1"]["attn"]
+    want = ((2 * flat, jnp.float32) if dtype == jnp.float32
+            else (flat, jnp.uint32))
+    assert kv_row(flat, dtype) == want
+    assert node["paged_kv"].shape == (32, 16, want[0])
+    assert node["side_kv"].shape == (3, 4, want[0])
+    assert node["paged_kv"].dtype == node["side_kv"].dtype == want[1]
+    assert node["paged_kv"].shape[2] * node["paged_kv"].dtype.itemsize == (
+        2 * flat * jnp.dtype(dtype).itemsize)
+    assert node["paged_ikey"].dtype == node["side_ikey"].dtype == dtype
+    assert obs.gauge("serve/kv_row_words").value() == words
+    drains = [e["args"] for e in obs.tracer.events()
+              if e["name"] == "serve/segment_drain"]
+    assert drains and all(a["row_words"] == words for a in drains)
+
+
+def test_a_model_without_an_indexer_gathers_rows_of_no_words():
+    _loop(_cfg(), _params(), "flash")
+    assert obs.gauge("serve/kv_row_words").value() > 0
+    _loop(_plain_cfg(), _plain_params(), "flash")
+    assert obs.gauge("serve/kv_row_words").value() == 0
+
+
 def test_a_model_without_an_indexer_says_neither():
     obs.tracer.clear()
     _serve(_plain_cfg(), _plain_params(), "dense")
@@ -804,6 +1013,7 @@ def test_a_model_without_an_indexer_says_neither():
                and "rows_scored" not in e["args"]
                and "gathers" not in e["args"]
                and "rows_gathered" not in e["args"]
+               and "row_words" not in e["args"]
                and "sparse" not in e["args"]
                for e in obs.tracer.events()
                if e["name"] in ("serve/segment_drain",

@@ -71,8 +71,9 @@ from tpudist.models.speculative import (
     _set_cache_index,
 )
 from tpudist.models.transformer import TransformerConfig, TransformerLM
-from tpudist.ops.flash_decode import (SPARSE_ATTEND_GATHERS, paged_grid_rows,
-                                      paged_tile_pages, walk_rows)
+from tpudist.ops.flash_decode import (SPARSE_ATTEND_GATHERS, pack_kv,
+                                      paged_grid_rows, paged_tile_pages,
+                                      walk_rows)
 
 # placeholder page row for the dense layout's admit signature (the insert
 # walk never reaches a paged node there)
@@ -234,12 +235,13 @@ def _kv_leaves(node: dict, prefix: str) -> list[str]:
     staging buffers).  ``["key", "value"]`` for MHA/GQA/MQA, ``["latent"]``
     for latent attention; for grouped-query attention with an indexer
     ``["ikey", "kv"]`` in the pools and the staging buffers (its index key
-    a token, and K beside V in ONE row of twice the width: its decode step
-    gathers the chosen rows, at a cost by the row) and ``["ikey", "key",
-    "value"]`` in the batch-1 prefill cache, whose kernels read K and V
-    each whole (:func:`_dense_parts` says which dense leaves make up a
-    pool's row).  Every place that moves cache rows iterates over these
-    and is indifferent to their number and width.  No leaf's suffix may be
+    a token, and K and V in ONE row of 32-bit words,
+    ``ops.flash_decode.kv_row``: its decode step gathers the chosen rows,
+    at a cost by the row) and ``["ikey", "key", "value"]`` in the batch-1
+    prefill cache, whose kernels read K and V each whole
+    (:func:`_dense_parts` says which dense leaves make up a pool's row).
+    Every place that moves cache rows iterates over these and is
+    indifferent to their number, width and dtype.  No leaf's suffix may be
     ``index``: the
     staging buffer's cursor is the leaf ``side_index``, which shares the
     prefix and is left out here BY NAME (hence ``side_ikey``)."""
@@ -248,9 +250,10 @@ def _kv_leaves(node: dict, prefix: str) -> list[str]:
 
 
 def _dense_parts(leaf: str) -> tuple[str, ...]:
-    """The batch-1 prefill cache's leaves whose rows, side by side, are a
-    row of pool leaf ``leaf``: ``kv`` is ``key`` beside ``value``; any
-    other leaf is its dense namesake."""
+    """The batch-1 prefill cache's leaves whose rows make up a row of pool
+    leaf ``leaf``: ``kv`` is ``key`` and ``value`` packed by
+    ``ops.flash_decode.pack_kv`` (a word of a 16-bit K and V each; 32-bit
+    numbers side by side); any other leaf is its dense namesake."""
     return ("key", "value") if leaf == "kv" else (leaf,)
 
 
@@ -860,13 +863,16 @@ class ServeLoop:
             # two; or the latent rows, one head of the row's width
             pool0 = nodes[0]["paged_" + leaves[-1]]    # never the ikey
             if {"key", "value"} <= set(leaves) or "kv" in leaves:
+                # numbers of the compute dtype, whatever row holds them
                 h_kv, d_head, n_pools = cfg.kv_heads, cfg.head_dim, 2
+                itemsize = jnp.dtype(cfg.compute_dtype).itemsize
             else:
                 h_kv, d_head, n_pools = 1, pool0.shape[2], len(leaves)
+                itemsize = pool0.dtype.itemsize
             self._grid_rows = paged_grid_rows(
                 num_slots, h_kv, d_head, self.kv_block_size,
                 self.pool.max_blocks_per_slot, pools=n_pools,
-                itemsize=pool0.dtype.itemsize)
+                itemsize=itemsize)
             self._attn_layers = len(nodes)
         # expert layers (cfg.moe): the segment sums, step by step, the
         # tokens each held expert was given and returns the sums as extra
@@ -985,6 +991,17 @@ class ServeLoop:
         # chosen-rows branch (some lane held more than a query attends)
         self._obs_rows_gathered = obs.counter("serve/rows_gathered",
                                               unit="rows")
+        # and the 32-bit words a gathered row is (a token's K and V in
+        # ``paged_kv``, ``ops.flash_decode.kv_row``: kv_heads x head_dim
+        # where two 16-bit numbers share a word, twice that where a number
+        # is a word; 0 for a model without an indexer): the layout is not
+        # an option, so this is how a run says which it had
+        self._kv_row_words = 0
+        if self._index_topk is not None:      # paged: refused otherwise
+            row = self._paged_nodes(self.cache)[0]["paged_kv"]
+            self._kv_row_words = row.shape[2] * row.dtype.itemsize // 4
+        obs.gauge("serve/kv_row_words", unit="words").set(
+            self._kv_row_words)
         # the same two of the WINDOW layers' calls (a layer): walk_rows
         # with the window, and min(length, window)
         self._obs_rows_window = obs.counter(
@@ -1426,17 +1443,25 @@ class ServeLoop:
                 row = small[f"cached_{dense}"][0]     # dense [S, F]
                 pad = m * bs - row.shape[0]
                 parts.append(jnp.pad(row, ((0, pad), (0, 0)))
-                             .reshape(m, bs, -1).astype(big[name].dtype))
+                             .reshape(m, bs, -1))
 
             def blocks(lo, hi):
-                """Pool rows of the blocks ``[lo, hi)``: the dense
-                leaves' side by side, joined HERE, for the blocks one
-                scatter takes (inside its branch, where it has one): no
-                joined copy of a whole prompt's rows outlives its layer's
-                scatter."""
+                """Pool rows of the blocks ``[lo, hi)``: the dense leaf's,
+                or the dense leaves' packed HERE (their bits: a cast to
+                the pool's dtype would take words for numbers), for the
+                blocks one scatter takes (inside its branch, where it has
+                one): no packed copy of a whole prompt's rows outlives its
+                layer's scatter."""
                 if len(parts) == 1:
-                    return parts[0][lo:hi]
-                return jnp.concatenate([p[lo:hi] for p in parts], axis=-1)
+                    return parts[0][lo:hi].astype(big[name].dtype)
+                rows = pack_kv(*(p[lo:hi] for p in parts))
+                if (rows.dtype, rows.shape[2:]) != (big[name].dtype,
+                                                    big[name].shape[2:]):
+                    raise ValueError(
+                        f"{name} holds rows of {big[name].shape[2:]} "
+                        f"{big[name].dtype}; the prefill cache's K and V "
+                        f"pack to {rows.shape[2:]} {rows.dtype}")
+                return rows
 
             pool = big[name].at[tgt[:head]].set(blocks(0, head),
                                                 mode="drop")
@@ -3340,7 +3365,8 @@ class ServeLoop:
             ``rows_gathered`` (what they fetched over the segment, all
             layers: every lane's ``index_topk`` rows a gather, in each
             step that found some lane beyond ``index_topk`` rows; 0 where
-            no kernel runs).  A model with
+            no kernel runs) and ``row_words`` (the 32-bit words of a
+            gathered row, ``serve/kv_row_words``).  A model with
             linear-attention layers adds ``state_lanes`` (the lanes whose
             state the slot cache held at dispatch: the decoding ones) and
             ``state_bytes`` (those lanes times what the state layers keep
@@ -3469,7 +3495,8 @@ class ServeLoop:
                     routed.update(rows_scored=rows_live,
                                   rows_selected=rows_selected,
                                   gathers=SPARSE_ATTEND_GATHERS,
-                                  rows_gathered=gathered)
+                                  rows_gathered=gathered,
+                                  row_words=self._kv_row_words)
                 if self._state_layers:
                     routed.update(
                         state_lanes=state_lanes,

@@ -606,12 +606,17 @@ class CausalSelfAttention(nn.Module):
     LayerNorm and its rotation, zeros up to ``cfg.index_cache_width``),
     under ``cached_ikey`` / ``paged_ikey`` /
     ``side_ikey`` beside the K and V leaves; and in the PAGED cache its K
-    and V are ONE row (``paged_kv`` / ``side_kv``: K's heads in the first
-    ``kv_heads x head_dim`` columns, V's in the last), because a decode
-    step gathers each lane's chosen rows and the chip's gather costs by
-    the row: one gather a layer brings both.  The batch-1 prefill cache
-    keeps ``cached_key`` / ``cached_value`` apart (its kernels read each
-    whole); the finish's scatter joins them.  Both cached paths then run
+    and V are ONE row of 32-bit words (``paged_kv`` / ``side_kv``,
+    ``ops.flash_decode.kv_row``: for a 16-bit ``compute_dtype``
+    ``uint32[kv_heads x head_dim]``, word ``j`` K's element ``j`` in its
+    low half and V's in its high, the raw bits; for a 32-bit one a number
+    is a word, ``[2 x kv_heads x head_dim]``, K's heads then V's),
+    because a decode step gathers each lane's chosen rows and the chip's
+    gather costs by the row, and least for a row of words: one gather a
+    layer brings both.  What differs follows from the dtype's width
+    alone.  The batch-1 prefill cache keeps ``cached_key`` /
+    ``cached_value`` apart (its kernels read each whole); the finish's
+    scatter packs them (``pack_kv``).  Both cached paths then run
     scores -> selection -> attention over the chosen rows
     (``ops.flash_decode.paged_index_scores``, ``index_select_mask`` /
     ``index_select``, ``sparse_gqa_attend``; a prefill chunk through
@@ -1118,15 +1123,19 @@ class CausalSelfAttention(nn.Module):
                 f"kv_num_blocks > 0 (got {bs_}, {nb})")
         m_blocks = -(-cfg.max_seq_len // bs_)
         # the leaves of a token's K and V, pool and side buffer: a pair of
-        # ``flat`` columns each or, in a layer with an indexer, ONE of
-        # twice that (K beside V: its decode step gathers the chosen rows,
-        # and a gather costs by the row), and its index keys in a further
-        # pool under the same table
-        kv_names, kv_width = ((("key", "value"), flat) if index is None
-                              else (("kv",), 2 * flat))
+        # ``flat`` columns each or, in a layer with an indexer, ONE row of
+        # 32-bit words that holds both (``kv_row``: its decode step
+        # gathers the chosen rows, and a gather costs by the row, least a
+        # row of words), and its index keys in a further pool under the
+        # same table
+        from tpudist.ops.flash_decode import kv_row, pack_kv, unpack_kv
+
+        kv_names, (kv_width, kv_dtype) = (
+            (("key", "value"), (flat, cfg.compute_dtype)) if index is None
+            else (("kv",), kv_row(flat, cfg.compute_dtype)))
         pools = [self.variable(
             "cache", f"paged_{name}", jnp.zeros, (nb, bs_, kv_width),
-            cfg.compute_dtype) for name in kv_names]
+            kv_dtype) for name in kv_names]
         paged_ik = None if index is None else self.variable(
             "cache", "paged_ikey", jnp.zeros,
             (nb, bs_, cfg.index_cache_width), cfg.compute_dtype)
@@ -1174,15 +1183,18 @@ class CausalSelfAttention(nn.Module):
         cap = self.serve_side_slots
         sides = [self.variable(
             "cache", f"side_{name}", jnp.zeros, (b, cap, kv_width),
-            cfg.compute_dtype) for name in kv_names]
+            kv_dtype) for name in kv_names]
         side_idx = self.variable(
             "cache", "side_index", lambda: jnp.zeros((), jnp.int32))
         s_base = side_idx.value
         with routine("attn/cache"):
             s_at = jnp.minimum(s_base, cap - s)
-            # the token's K and V, each to its buffer, or joined to the one
-            news = (k, v) if index is None else (jnp.concatenate(
-                [k.reshape(b, s, flat), v.reshape(b, s, flat)], axis=-1),)
+            # the token's K and V, each to its buffer, or packed to the
+            # one (its bits, in the cache's dtype: packed before any cast
+            # could take a word for a number)
+            news = (k, v) if index is None else (pack_kv(*(
+                x.reshape(b, s, flat).astype(cfg.compute_dtype)
+                for x in (k, v))),)
             for side, new in zip(sides, news):
                 side.value = jax.lax.dynamic_update_slice(
                     side.value,
@@ -1202,7 +1214,7 @@ class CausalSelfAttention(nn.Module):
             from tpudist.ops.flash_decode import paged_flash_decode
 
             def every_row():
-                # the one pool of K beside V goes in alone
+                # the one pool of K and V goes in alone
                 pool_v, side_v = ((None, None) if index is not None else
                                   (pools[1].value, sides[1].value))
                 with routine("attn/core"):
@@ -1237,10 +1249,12 @@ class CausalSelfAttention(nn.Module):
                                   for pool in pools)
                 side_k, side_v = (side.value for side in sides)
             else:
-                # the one pool of K beside V, and its side buffer, halved
-                k_main, v_main = jnp.split(
-                    paged_gather_kv(pools[0].value, table.value), 2, -1)
-                side_k, side_v = jnp.split(sides[0].value, 2, -1)
+                # the one pool of K and V, and its side buffer, unpacked
+                k_main, v_main = unpack_kv(
+                    paged_gather_kv(pools[0].value, table.value),
+                    cfg.compute_dtype)
+                side_k, side_v = unpack_kv(sides[0].value,
+                                           cfg.compute_dtype)
             s_all = k_main.shape[1]
             live_main = jnp.arange(s_all)[None, :] < idx[:, None]
             if window is not None:

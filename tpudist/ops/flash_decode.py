@@ -351,12 +351,17 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     ``d_v`` set: ONE pool whose rows are the keys and whose first ``d_v``
     columns are also the values, read once (``paged_mla_decode``: the
     latent cache of multi-head latent attention).  ``joined``: ONE pool
-    whose rows hold a token's keys in their first half and its values in
-    their second (an indexer's layer: ``sparse_gqa_attend`` and its
-    every-row branch).  One copy a page brings both, the tile slots hold
-    the bytes the two pools' held, and the key and value tiles are the two
-    static halves of a slot (a multiple of 128 lanes apart on the chip);
-    the side buffer comes in as its two halves.
+    whose rows are :func:`kv_row`'s (an indexer's layer:
+    ``sparse_gqa_attend`` and its every-row branch), one copy a page and
+    tile slots of the bytes the two pools' held.  Where the pool is
+    ``uint32`` a WORD holds a key in its low half and the value of the
+    same place in its high half: the copies and the side buffer are the
+    one-pool walk's, and the key tile and the value tile are made from the
+    word tile on the vector unit (:func:`_word_half`: bits, nothing
+    rounded).  Else a row holds its keys in its first half and its values
+    in its second: the key and value tiles are the two static halves of a
+    slot (a multiple of 128 lanes apart on the chip), and the side buffer
+    comes in as its two halves.
 
     ``window`` (static; None is the program above, instruction for
     instruction): a SLIDING-WINDOW layer.  The query sits at position
@@ -375,7 +380,11 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         raise ValueError("the one-pool walks have no window")
     n_pools = 2 if d_v is None and not joined else 1
     pools, rest = rest[:n_pools], rest[n_pools:]
-    n_sides = 2 if joined else n_pools
+    # the joined pool's rows: words of a key and a value each, or the keys
+    # beside the values
+    words = joined and pools[0].dtype == jnp.uint32
+    halves = joined and not words
+    n_sides = 2 if halves else n_pools
     if side:
         sides, rest = rest[:n_sides], rest[n_sides:]
     o_ref, rest = rest[0], rest[1:]
@@ -384,12 +393,21 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     q_scr = rest[5] if slots is not None else None
     g = pl.program_id(0)
     lane, r = g // groups, g % groups
-    d = bufs[0].shape[-1] // (2 if joined else 1)
-    # (tile buffer, its columns) of the key tile and of the value tile
-    if joined:
-        key_at, value_at = (bufs[0], pl.ds(0, d)), (bufs[0], pl.ds(d, d))
+    d = bufs[0].shape[-1] // (2 if halves else 1)
+    # (tile buffer, its columns, its words' half) of the key tile and of
+    # the value tile
+    if words:
+        key_at, value_at = ((bufs[0], slice(None), h) for h in range(2))
+    elif halves:
+        key_at, value_at = ((bufs[0], pl.ds(h * d, d), None)
+                            for h in range(2))
     else:
-        key_at, value_at = (bufs[0], slice(None)), (bufs[-1], slice(None))
+        key_at, value_at = ((buf, slice(None), None)
+                            for buf in (bufs[0], bufs[-1]))
+
+    def numbers(x, half):
+        """``x`` as the MXU's operand: itself, or one half of its words."""
+        return x if half is None else _word_half(x, half, q_ref.dtype)
 
     def values(keys, load_values):
         """The value tile beside the key tile ``keys``: the second pool's
@@ -428,7 +446,7 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
 
         def one_page(p, _):
             page = meta_ref[base + p]
-            if joined and groups > 1:
+            if halves and groups > 1:
                 # the row's heads of the keys' half and of the values'
                 half = pools[0].shape[-1] // 2
                 for kv in range(2):
@@ -503,8 +521,9 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
     def side_tiles():
         """The side buffer's ``(scores, values)``: its first ``side_len``
         positions join the same online softmax as the main cache."""
-        keys = sides[0][0]
-        return scores(keys, meta_ref[0]), values(keys, lambda: sides[-1][0])
+        keys = numbers(sides[0][0], key_at[2])
+        return scores(keys, meta_ref[0]), values(
+            keys, lambda: numbers(sides[-1][0], value_at[2]))
 
     def attend(slot, n, live=None, below=None):
         """One rank update over the first ``n`` pages of ``slot``.  ``live``
@@ -516,12 +535,12 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
         rows = n * block
 
         def load(at, cleaned):
-            buf, cols = at
+            buf, cols, half = at
             x = buf[slot, :n, :, cols].reshape(rows, d)
-            if live is None or not cleaned:
-                return x
-            row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-            return jnp.where(row < live, x, jnp.zeros_like(x))
+            if live is not None and cleaned:
+                row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+                x = jnp.where(row < live, x, jnp.zeros_like(x))
+            return numbers(x, half)
 
         # the latent pool: its rows are the values too, so the keys are
         # cleaned
@@ -591,6 +610,15 @@ def _paged_decode_kernel(meta_ref, q_ref, *rest, scale: float,
             _softmax_update(m_scr, l_scr, acc_scr, s, None, vals)
 
     _softmax_finalize(l_scr, acc_scr, o_ref, slots)
+
+
+def _word_half(words, half: int, dtype):
+    """The low (``half`` 0) or high (1) halves of ``uint32`` words as numbers
+    of the 16-bit ``dtype`` (in the walk: of a tile, on the vector unit):
+    the half moved down, the word cut to 16 bits, the bits taken as they
+    are."""
+    low = words if half == 0 else words >> 16
+    return jax.lax.bitcast_convert_type(low.astype(jnp.uint16), dtype)
 
 
 def _width_step(pages_per_tile: int) -> int:
@@ -1103,10 +1131,11 @@ def paged_flash_decode(
       q: ``[B, 1, H, D]`` current-token queries.
       k_pool / v_pool: ``[num_blocks, block_size, Hkv*D]`` packed block
         pools (``block_size`` a multiple of 8 — the sublane tile).
-        ``v_pool`` None: ``k_pool`` is ONE pool of ``2*Hkv*D`` columns, a
-        token's K in the first half of its row and its V in the second
-        (what an indexer's layer keeps); ``side_k`` is then the one side
-        buffer of the same rows and ``side_v`` None.
+        ``v_pool`` None: ``k_pool`` is ONE pool whose rows hold a token's
+        K and V as :func:`pack_kv` lays them (:func:`kv_row` of ``Hkv*D``
+        numbers in ``q``'s dtype; what an indexer's layer keeps);
+        ``side_k`` is then the one side buffer of the same rows and
+        ``side_v`` None.
       page_table: ``[B, max_blocks_per_slot]`` int32 pool indices; only
         a row's first ``ceil(cache_len / block_size)`` entries are read.
       cache_len: ``[B]`` per-row valid lengths INCLUDING the current
@@ -1143,7 +1172,7 @@ def paged_flash_decode(
     if v_pool is None and (window is not None or s_q != 1
                            or side_v is not None):
         raise ValueError(
-            "the one pool of K beside V takes one query a call, its one "
+            "the one pool of K and V takes one query a call, its one "
             "side buffer and no window")
     if s_q > 1:
         if side_k is None:
@@ -1166,10 +1195,13 @@ def paged_flash_decode(
             f"{k_pool.shape}")
     _, block, flat = k_pool.shape
     h_kv = packed_kv_heads
-    if flat != h_kv * d * (2 if v_pool is None else 1):
+    row = (h_kv * d, k_pool.dtype) if v_pool is not None else kv_row(
+        h_kv * d, q.dtype)
+    if (flat, k_pool.dtype) != row:
         raise ValueError(
-            f"pool minor dim {flat} != H_kv*D = {h_kv * d} (twice that "
-            f"for the one pool of K beside V)")
+            f"pool minor dim {flat} of {k_pool.dtype} != H_kv*D = "
+            f"{h_kv * d} (for the one pool of K and V: kv_row's {row[0]} "
+            f"of {row[1]})")
     if h % h_kv:
         raise ValueError(f"num_heads {h} not a multiple of kv heads {h_kv}")
     if block < 8 or block % 8:
@@ -1206,7 +1238,7 @@ def _paged_decode_one(q, k_pool, v_pool, table, cache_len, side_len,
                       side_k, side_v, *, h_kv: int, interpret: bool,
                       window: int | None = None, name: str | None = None):
     """The validated single-query call of :func:`paged_flash_decode`
-    (``v_pool`` None: the one pool of K beside V, ``side_v`` None with it).
+    (``v_pool`` None: the one pool of K and V, ``side_v`` None with it).
     Under its own ``jit``: a segment program calls it once a layer with
     the same shapes, and the kernel body is then traced and lowered once
     a program and not once a layer (measured: 36 calls lowered in 7 s
@@ -1259,15 +1291,18 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
     ``q3 [lanes * r_kv, gp, d]`` (``[.., 2, gp, d]`` paired) in and an
     output of its shape (``d_v`` wide where the one pool's rows double as
     values) out.  ``joined``: the one pool (and the one side buffer) holds
-    keys beside values a row; its tile slots are the two pools' together
-    and it is counted as the two."""
+    keys and values in rows of :func:`kv_row`; its tile slots are the two
+    pools' together and it is counted as the two, of the queries' dtype."""
     block, m_blocks = pools[0].shape[1], (meta.shape[0] - 1 - lanes) // lanes
     members = 2 if paired else 1
     gp, d_head = q3.shape[-2:]
+    # keys beside values a row (else a word holds one of each, and the
+    # copies and the side buffer are those of any one pool)
+    halves = joined and pools[0].dtype != jnp.uint32
     rows = paged_grid_rows(
         lanes, r_kv * members, d_head, block, m_blocks,
         pools=2 if joined else len(pools),
-        itemsize=pools[0].dtype.itemsize)
+        itemsize=(q3 if joined else pools[0]).dtype.itemsize)
     groups = rows // lanes
     chunks = r_kv // groups                # of the lane's r_kv, a grid row
     # where each head of a row sits in its query and output blocks (None:
@@ -1292,7 +1327,7 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     args = [meta, q3, *pools]
     in_specs = [row_spec] + [pool_spec] * len(pools)
-    if sides is not None and joined:
+    if sides is not None and halves:
         # the one side buffer as its two halves: the row's key columns and,
         # a half further (``G`` blocks of ``d``), its value columns
         args += [sides[0]] * 2
@@ -1307,7 +1342,7 @@ def _paged_call(meta, q3, pools, sides, *, scale: float, lanes: int,
         in_specs += [side_spec] * len(sides)
 
     tile_buf = pltpu.VMEM(
-        (2, pages_per_tile, block, 2 * d if joined else d), pools[0].dtype)
+        (2, pages_per_tile, block, 2 * d if halves else d), pools[0].dtype)
     return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, scale=scale, block=block,
@@ -1831,6 +1866,49 @@ def index_select(scores: jnp.ndarray, k: int) -> jnp.ndarray:
 SPARSE_ATTEND_GATHERS = 1
 
 
+def kv_row(flat: int, dtype) -> tuple[int, jnp.dtype]:
+    """``(columns, dtype)`` of the ONE row that holds ``flat`` numbers of K
+    and ``flat`` of V of a token in ``dtype`` (an indexer's layer's
+    ``paged_kv`` / ``side_kv``).  A row is 32-bit WORDS, because the chip
+    gathers a row of words a quarter cheaper than the same bytes as halves
+    (a 16-bit row shares its sublanes with its neighbour): two 16-bit
+    numbers share a word, ``uint32[flat]``, K's element ``j`` the low half
+    of word ``j`` and V's the high; numbers of any other width lie side by
+    side in their own dtype, ``[2 * flat]``, K then V."""
+    dtype = jnp.dtype(dtype)
+    return (flat, jnp.dtype(jnp.uint32)) if dtype.itemsize == 2 else (
+        2 * flat, dtype)
+
+
+def pack_kv(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """K and V of tokens, ``[..., flat]`` each in one dtype, as rows of
+    :func:`kv_row`.  Bits are moved, never rounded."""
+    if k.dtype != v.dtype or k.shape != v.shape:
+        raise ValueError(
+            f"K and V of one shape and dtype needed; got {k.shape} "
+            f"{k.dtype}, {v.shape} {v.dtype}")
+    if k.dtype.itemsize != 2:
+        return jnp.concatenate([k, v], axis=-1)
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+
+    return bits(k) | (bits(v) << 16)
+
+
+def unpack_kv(row: jnp.ndarray, dtype) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(K, V)`` in ``dtype`` out of rows of :func:`kv_row`."""
+    dtype = jnp.dtype(dtype)
+    _, held = kv_row(0, dtype)
+    if row.dtype != held:
+        raise ValueError(
+            f"rows of {held} hold K and V in {dtype}; got {row.dtype}")
+    if dtype.itemsize != 2:
+        k, v = jnp.split(row, 2, axis=-1)
+        return k, v
+    return _word_half(row, 0, dtype), _word_half(row, 1, dtype)
+
+
 def sparse_gqa_attend(
     q: jnp.ndarray,
     kv_source: jnp.ndarray,
@@ -1845,34 +1923,38 @@ def sparse_gqa_attend(
 
     Args:
       q: ``[T, H, D]`` queries (a decode step: one a lane).
-      kv_source: ``[N, 2*Hkv*D]`` rows by flat row id (a pool seen flat:
-        ``page x block + offset``): a token's packed K in the first
-        ``Hkv*D`` columns of its row, its packed V in the last.
+      kv_source: ``[N, W]`` rows by flat row id (a pool seen flat:
+        ``page x block + offset``), each a token's packed K and V as
+        :func:`pack_kv` lays them (:func:`kv_row` of ``Hkv*D`` numbers in
+        ``q``'s dtype: ``uint32[Hkv*D]`` words, K the low halves and V the
+        high, for a 16-bit ``q``; ``[2*Hkv*D]``, K then V, else).
       ids: ``[T, k]`` row ids; the first ``count[t]`` of query ``t``'s are
         attended, the others ignored (any value).
-      side_kv: ``[T, cap, 2*Hkv*D]``: an id of ``N + j`` names row ``j`` of
+      side_kv: ``[T, cap, W]``: an id of ``N + j`` names row ``j`` of
         the query's own side buffer (the segment's staging rows).  Those
         are the highest ids, and ``ids`` must list a query's first
         ``count`` in ASCENDING order (:func:`index_select`'s), so that the
         staged rows are the last ``cap`` or fewer of them.
 
-    The rows are gathered with XLA, ONCE: K and V of a token are one row,
-    because the chip's gather costs by the row and hardly by the byte
-    (``[T, k, 2*Hkv*D]``: written once and read once, where a kernel with
-    row-granular copies would read them once).  The paged walk then attends
-    that one buffer, the chosen rows its pages, keys and values the two
-    halves of a tile: the arithmetic is :func:`paged_flash_decode`'s.
-    Returns ``[T, H, D]``.  In a trace the kernel is
-    ``sparse_gqa_attend``."""
+    The rows are gathered with XLA, ONCE: K and V of a token are one row
+    of 32-bit words, because the chip's gather costs by the row, hardly by
+    the byte, and a quarter less for words than for halves (``[T, k, W]``:
+    written once and read once, where a kernel with row-granular copies
+    would read them once).  The paged walk then attends that one buffer,
+    the chosen rows its pages, a tile's keys and values the two halves of
+    its words (of its columns where a number is a word): the arithmetic is
+    :func:`paged_flash_decode`'s.  Returns ``[T, H, D]``.  In a trace the
+    kernel is ``sparse_gqa_attend``."""
     t, h, d = q.shape
     n, k = kv_source.shape[0], ids.shape[1]
-    width = 2 * packed_kv_heads * d
-    if (kv_source.shape != (n, width) or ids.shape[0] != t
-            or count.shape != (t,) or h % packed_kv_heads):
+    width, words = kv_row(packed_kv_heads * d, q.dtype)
+    if (kv_source.shape != (n, width) or kv_source.dtype != words
+            or ids.shape[0] != t or count.shape != (t,)
+            or h % packed_kv_heads):
         raise ValueError(
-            f"q [T, H, D], a source [N, 2*Hkv*D], ids [T, k], count [T] "
-            f"needed; got {q.shape}, {kv_source.shape}, {ids.shape}, "
-            f"{count.shape}")
+            f"q [T, H, D], a source [N, {width}] of {words} (kv_row), ids "
+            f"[T, k], count [T] needed; got {q.shape}, {kv_source.shape} "
+            f"of {kv_source.dtype}, {ids.shape}, {count.shape}")
     count = jnp.minimum(jnp.asarray(count, jnp.int32), k)
     block = block_of(k, 8)   # rows a page of the gathered buffer
     pages = k // block
