@@ -359,7 +359,21 @@ def test_rotary_positions_on_plain_attention():
     np.testing.assert_allclose(jnp.stack(got, 1), want, atol=2e-5, rtol=0)
 
 
-def test_a_latent_cache_refuses_the_kv_pair_payloads():
+@pytest.mark.parametrize("leaving", [dict(preempt="migrate"),
+                                     dict(role="prefill"),
+                                     dict(role="decode")],
+                         ids=["migrate", "role_prefill", "role_decode"])
+def test_a_latent_cache_refuses_the_kv_pair_payloads(leaving):
     with pytest.raises(ValueError, match="key/value pair"):
         ServeLoop(_cfg(), _params(), num_slots=2, cache_layout="paged",
-                  kv_block_size=16, preempt="migrate")
+                  kv_block_size=16, **leaving)
+
+
+def test_a_latent_cache_shares_prefixes_and_has_no_host_tier(monkeypatch):
+    """The tier's spill writes a K/V pair a layer: a latent cache keeps its
+    prefix cache and, whatever the budget says, no tier."""
+    monkeypatch.setenv("TPUDIST_KV_HOST_TIER_BYTES", str(1 << 20))
+    loop = ServeLoop(_cfg(), _params(), num_slots=2, cache_layout="paged",
+                     kv_block_size=16, prefix_sharing=True)
+    assert loop._prefix_cache is not None and loop._tier is None
+    assert loop._prefix_cache.spill_hook is None

@@ -884,10 +884,6 @@ def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer():
 
 def test_refusals_at_construction():
     kw = dict(num_slots=2, kv_block_size=16)
-    with pytest.raises(ValueError, match="indexer.*one token a lane"):
-        ServeLoop(_cfg(), _params(), cache_layout="paged",
-                  decode_mode="speculative", draft_cfg=_cfg(),
-                  draft_params=_params(), **kw)
     with pytest.raises(ValueError, match="indexer.*cache_layout='paged'"):
         ServeLoop(_cfg(), _params(), cache_layout="dense", **kw)
     with pytest.raises(ValueError, match="an indexer's key beside K and V"):
@@ -906,6 +902,12 @@ def test_refusals_at_construction():
         TransformerLM(_cfg()).apply({"params": _params()},
                                     jnp.zeros((1, 8), jnp.int32),
                                     causal=False)
+
+
+def test_an_indexer_refuses_the_decode_role():
+    with pytest.raises(ValueError, match="an indexer's key beside K and V"):
+        ServeLoop(_cfg(), _params(), num_slots=2, cache_layout="paged",
+                  kv_block_size=16, role="decode")
 
 
 def test_prefix_sharing_and_the_host_tier_are_off(monkeypatch):

@@ -1,7 +1,10 @@
-"""Fused multi-token decode + speculative serving (PR 8): the on-device
-N-step inner loop must stay token-identical to the single-token
-reference loop across layouts, depths, and decode modes, while paying
-~1/N of its host dispatches."""
+"""Fused multi-token decode (PR 8): the on-device N-step inner loop must
+stay token-identical to the single-token reference loop across layouts
+and depths, while paying ~1/N of its host dispatches.  A lane decodes ONE
+token a step: the per-row cache paths and the decode kernels refuse more."""
+
+import inspect
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,20 +17,12 @@ from tpudist.ops.flash_decode import flash_decode, paged_flash_decode
 
 CFG = TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
                         num_kv_heads=2, embed_dim=64, max_seq_len=96)
-DRAFT_CFG = TransformerConfig(vocab_size=64, num_layers=1, num_heads=2,
-                              num_kv_heads=1, embed_dim=32, max_seq_len=96)
 
 
 @pytest.fixture(scope="module")
 def params():
     return TransformerLM(CFG).init(
         jax.random.key(0), jnp.zeros((1, 2), jnp.int32))["params"]
-
-
-@pytest.fixture(scope="module")
-def draft_params():
-    return TransformerLM(DRAFT_CFG).init(
-        jax.random.key(7), jnp.zeros((1, 2), jnp.int32))["params"]
 
 
 def _prompt(seed, n):
@@ -180,108 +175,63 @@ class TestDeadlineClamp:
         assert len(c.tokens) <= 6
 
 
-class TestSpeculativeServe:
-    @pytest.mark.parametrize("kw", [
-        dict(pipeline_depth=1, decode_attention="dense", num_draft=3),
-        dict(pipeline_depth=2, decode_attention="dense",
-             num_draft="adaptive", spec_ladder=(2, 4)),
-        dict(pipeline_depth=2, decode_attention="flash", num_draft=3),
-        dict(pipeline_depth=2, decode_attention="flash", num_draft=3,
-             cache_layout="paged", kv_block_size=16),
-    ], ids=["dense-k3", "dense-adaptive", "flash-k3", "paged-k3"])
-    def test_greedy_exact_match(self, params, draft_params, reference, kw):
-        got, loop = _serve(params, _reqs(), steps_per_sync=8,
-                           decode_mode="speculative", draft_cfg=DRAFT_CFG,
-                           draft_params=draft_params, **kw)
-        assert got == reference
-        if loop.pool is not None:
-            assert loop.pool.used_blocks == 0
-            loop.pool.check()
-
-    def test_obs_and_policy_updates(self, params, draft_params):
-        got, loop = _serve(params, _reqs(), steps_per_sync=8,
-                           pipeline_depth=2, decode_attention="dense",
-                           decode_mode="speculative", draft_cfg=DRAFT_CFG,
-                           draft_params=draft_params,
-                           num_draft="adaptive", spec_ladder=(2, 4))
-        assert loop._obs_dispatches.value() > 0
-        assert loop._obs_spec_k.value() in (2, 4)
-        assert 0.0 <= loop._obs_spec_accept.value() <= 1.0
-        assert loop._spec_policy.rounds_seen > 0
-
-    def test_headroom_validation(self, params, draft_params):
-        loop = ServeLoop(CFG, params, num_slots=1, auto_unstack=False,
-                         decode_attention="dense",
-                         decode_mode="speculative", draft_cfg=DRAFT_CFG,
-                         draft_params=draft_params, num_draft=8)
-        # prompt + max_new + k - 1 = 60 + 30 + 7 = 97 > 96
-        with pytest.raises(ValueError, match="speculative serving"):
-            loop._validate(Request(prompt=_prompt(0, 60),
-                                   max_new_tokens=30))
-
-    def test_requires_draft(self, params):
-        with pytest.raises(ValueError, match="draft_cfg"):
-            ServeLoop(CFG, params, num_slots=1, auto_unstack=False,
-                      decode_mode="speculative")
+# the three per-row paths of CausalSelfAttention, by the loop that takes each
+PER_ROW = {
+    "dense_direct": dict(decode_attention="dense"),
+    "dense_sided": dict(decode_attention="flash"),
+    "paged": dict(decode_attention="flash", cache_layout="paged",
+                  kv_block_size=16),
+}
 
 
-class TestMultiQueryDecodeKernels:
-    """flash_decode / paged_flash_decode with s_q > 1 (the verify
-    chunk): per-query side visibility must match s_q independent calls."""
+class TestOneTokenALaneAStep:
+    @pytest.mark.parametrize("path", PER_ROW)
+    def test_per_row_cache_refuses_two_tokens(self, params, path):
+        loop = ServeLoop(CFG, params, num_slots=2, steps_per_sync=8,
+                         auto_unstack=False, **PER_ROW[path])
+        toks = jnp.ones((2, 2), jnp.int32)
+        with pytest.raises(ValueError, match="one token a lane a call"):
+            loop.model.apply({"params": params, "cache": loop.cache}, toks,
+                             positions=jnp.zeros((2, 2), jnp.int32),
+                             mutable=["cache"])
 
-    def _setup(self, b=2, h=4, h_kv=2, d=8, s_cache=32, cap=8):
+    @pytest.mark.parametrize("kernel", ["flash_decode",
+                                        "paged_flash_decode"])
+    def test_decode_kernels_refuse_two_queries(self, kernel):
+        b, h, h_kv, d, rows = 2, 4, 2, 8, 8
         ks = jax.random.split(jax.random.key(11), 5)
-        flat = h_kv * d
-        q = jax.random.normal(ks[0], (b, 3, h, d), jnp.float32)
-        k_cache = jax.random.normal(ks[1], (b, s_cache, flat), jnp.float32)
-        v_cache = jax.random.normal(ks[2], (b, s_cache, flat), jnp.float32)
-        side_k = jax.random.normal(ks[3], (b, cap, flat), jnp.float32)
-        side_v = jax.random.normal(ks[4], (b, cap, flat), jnp.float32)
-        lens = jnp.array([5, 9], jnp.int32)
-        return q, k_cache, v_cache, side_k, side_v, lens, h_kv
+        q = jax.random.normal(ks[0], (b, 2, h, d), jnp.float32)
+        k, v, side_k, side_v = (
+            jax.random.normal(key, (b, rows, h_kv * d), jnp.float32)
+            for key in ks[1:])
+        lens = jnp.array([3, 5], jnp.int32)
+        side = dict(side_k=side_k, side_v=side_v, side_len=2,
+                    packed_kv_heads=h_kv, interpret=True)
+        with pytest.raises(ValueError, match="one query token a call"):
+            if kernel == "flash_decode":
+                flash_decode(q, k, v, lens, **side)
+            else:
+                paged_flash_decode(
+                    q, k, v, jnp.arange(b, dtype=jnp.int32)[:, None], lens,
+                    **side)
 
-    def test_dense_multi_query_matches_per_token(self):
-        q, kc, vc, sk, sv, lens, h_kv = self._setup()
-        side_len = 6   # AFTER all 3 writes: queries see 4, 5, 6 side slots
-        got = flash_decode(q, kc, vc, lens, side_k=sk, side_v=sv,
-                           side_len=side_len, packed_kv_heads=h_kv,
-                           interpret=True)
-        for j in range(3):
-            want = flash_decode(q[:, j:j + 1], kc, vc, lens, side_k=sk,
-                                side_v=sv, side_len=side_len - (2 - j),
-                                packed_kv_heads=h_kv, interpret=True)
-            np.testing.assert_allclose(np.asarray(got[:, j:j + 1]),
-                                       np.asarray(want), rtol=2e-5,
-                                       atol=2e-5)
+    @pytest.mark.parametrize("path", ["dense_sided", "paged"])
+    def test_side_buffers_hold_a_segment(self, params, path):
+        loop = ServeLoop(CFG, params, num_slots=2, steps_per_sync=8,
+                         auto_unstack=False, **PER_ROW[path])
+        sides = {leaf.shape[1] for path_, leaf in
+                 jax.tree_util.tree_leaves_with_path(loop.cache)
+                 if getattr(path_[-1], "key", "").startswith("side_")
+                 and leaf.ndim == 3}
+        assert loop.side == 8 and sides == {8}
 
-    def test_multi_query_requires_side(self):
-        q, kc, vc, *_ , lens, h_kv = self._setup()
-        with pytest.raises(ValueError, match="side buffers"):
-            flash_decode(q, kc, vc, lens, packed_kv_heads=h_kv,
-                         interpret=True)
 
-    def test_paged_multi_query_matches_per_token(self):
-        b, h, h_kv, d, bs = 2, 4, 2, 8, 8
-        flat = h_kv * d
-        m = 4                                     # blocks per slot
-        ks = jax.random.split(jax.random.key(13), 5)
-        q = jax.random.normal(ks[0], (b, 3, h, d), jnp.float32)
-        pool_k = jax.random.normal(ks[1], (b * m + 1, bs, flat))
-        pool_v = jax.random.normal(ks[2], (b * m + 1, bs, flat))
-        table = jnp.arange(b * m, dtype=jnp.int32).reshape(b, m)
-        side_k = jax.random.normal(ks[3], (b, 8, flat))
-        side_v = jax.random.normal(ks[4], (b, 8, flat))
-        lens = jnp.array([5, 9], jnp.int32)
-        side_len = 5
-        got = paged_flash_decode(q, pool_k, pool_v, table, lens,
-                                 side_k=side_k, side_v=side_v,
-                                 side_len=side_len, packed_kv_heads=h_kv,
-                                 interpret=True)
-        for j in range(3):
-            want = paged_flash_decode(
-                q[:, j:j + 1], pool_k, pool_v, table, lens, side_k=side_k,
-                side_v=side_v, side_len=side_len - (2 - j),
-                packed_kv_heads=h_kv, interpret=True)
-            np.testing.assert_allclose(np.asarray(got[:, j:j + 1]),
-                                       np.asarray(want), rtol=2e-5,
-                                       atol=2e-5)
+def test_serve_loop_signature_is_documented():
+    """Every keyword of ``ServeLoop.__init__`` has an entry in the class
+    docstring, and the docstring's entries name no keyword it lacks."""
+    params = inspect.signature(ServeLoop.__init__).parameters
+    keywords = {n for n, p in params.items() if p.kind is p.KEYWORD_ONLY}
+    args = ServeLoop.__doc__.split("Args:")[1]
+    heads = re.findall(r"^      (\w[\w /]*):", args, flags=re.M)
+    named = {n for head in heads for n in head.split(" / ")}
+    assert named - {"cfg", "params", "num_slots"} == keywords

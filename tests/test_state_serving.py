@@ -206,16 +206,18 @@ def test_no_prefix_cache_and_no_host_tier():
     assert loop._state_layers == [0, 1, 2]
 
 
+def test_a_host_tier_budget_makes_no_tier(monkeypatch):
+    monkeypatch.setenv("TPUDIST_KV_HOST_TIER_BYTES", str(1 << 20))
+    loop = _loop(prefix_sharing=True)
+    assert loop._prefix_cache is None and loop._tier is None
+
+
 @pytest.mark.parametrize("kw,match", [
-    (dict(decode_mode="speculative"), "no rollback"),
     (dict(role="prefill"), "carry a lane's blocks and no state"),
     (dict(role="decode"), "carry a lane's blocks and no state"),
     (dict(preempt="migrate"), "carry a lane's blocks and no state"),
     (dict(chunked_prefill=False), "admitted chunk by chunk"),
 ])
 def test_what_state_cannot_do_yet_is_refused_in_words(kw, match):
-    cfg, params = _model()
-    extra = (dict(draft_cfg=cfg, draft_params=params)
-             if "decode_mode" in kw else {})
     with pytest.raises(ValueError, match=match):
-        _loop(**kw, **extra)
+        _loop(**kw)

@@ -298,10 +298,8 @@ PAGED = dict(num_slots=2, cache_layout="paged", kv_block_size=8,
     (dict(role="prefill"), "role='both'"),
     (dict(role="decode"), "role='both'"),
     (dict(preempt="migrate"), "preempt='degrade'"),
-    (dict(decode_mode="speculative", draft_cfg=1, draft_params=1),
-     "decode_mode='plain'"),
     (dict(steps_per_sync=32), "fit in the window"),
-], ids=["role_prefill", "role_decode", "migrate", "speculative",
+], ids=["role_prefill", "role_decode", "migrate",
         "segment_longer_than_window"])
 def test_a_window_group_refuses_at_construction(options, reason):
     with pytest.raises(ValueError, match=reason):

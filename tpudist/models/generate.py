@@ -101,6 +101,19 @@ def _blank_cache(model, batch: int):
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), struct)
 
 
+def _set_cache_index(cache: Any, idx: jnp.ndarray) -> Any:
+    """Roll the cache to ``idx`` tokens: every ``cache_index`` leaf is
+    reset (K/V buffers are left as-is — slots past the index are masked
+    by every cached-attention path and overwritten on the next write at
+    that position).  Index leaves are 0-D scalars in the unrolled layout
+    and [num_layers] vectors under ``cfg.scan_layers``; K/V buffers are
+    always >= 3-D (packed [B, S, Hkv·D]), so dimensionality separates
+    them."""
+    return jax.tree.map(
+        lambda leaf: (jnp.full_like(leaf, idx) if leaf.ndim <= 1 else leaf),
+        cache)
+
+
 def _prefill(model, params, cache, prompt: jnp.ndarray,
              prefill_chunk: int | None):
     """Ingest the prompt into the cache in chunks of ``prefill_chunk``

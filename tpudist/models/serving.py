@@ -54,6 +54,7 @@ from tpudist.models.generate import (
     _blank_cache,
     _make_select,
     _prefill,
+    _set_cache_index,
     _stop_array,
     serving_layout,
 )
@@ -64,12 +65,6 @@ from tpudist.models.kv_pages import (
     span_blocks,
 )
 from tpudist.models.kv_tier import HostTier, tier_budget_from_env
-from tpudist.models.speculative import (
-    AdaptiveDraftPolicy,
-    _accept_and_next,
-    _filtered_probs,
-    _set_cache_index,
-)
 from tpudist.models.transformer import TransformerConfig, TransformerLM
 from tpudist.ops.flash_decode import (SPARSE_ATTEND_GATHERS, pack_kv,
                                       paged_grid_rows, paged_tile_pages,
@@ -214,20 +209,6 @@ def _index_leaves(cache: Any) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     return main, side
 
 
-def _shift_index_leaves(cache: Any, delta, names) -> Any:
-    """Subtract ``delta`` from every index leaf named in ``names`` — the
-    speculative ROLLBACK: the verify chunk optimistically wrote K+1
-    tokens' K/V, and the accepted prefix kept only ``m + 1`` of them, so
-    the write cursor backs up by ``K - m`` and the next round's chunk
-    overwrites the rejected slots."""
-    def walk(node):
-        if not isinstance(node, dict):
-            return node
-        return {k: (v - delta if k in names else walk(v))
-                for k, v in node.items()}
-    return walk(cache)
-
-
 def _kv_leaves(node: dict, prefix: str) -> list[str]:
     """What a layer's attention keeps a token: the ``<suffix>`` of its
     cache leaves ``<prefix>_<suffix>`` (``prefix`` one of ``cached``, the
@@ -360,6 +341,13 @@ class ServeLoop:
         prefill executables.
       stop_tokens / pad_token: EOS semantics as in ``greedy_generate``.
       temperature / top_k / top_p: sampling controls (0 = greedy).
+      key: the sampling key the loop splits a key a prefill and a key a
+        step from (default ``jax.random.key(0)``; greedy decoding never
+        reads it).
+      auto_unstack: convert a scanned checkpoint (``cfg.scan_layers``) to
+        the unrolled layout the loop needs, via :func:`serving_layout`
+        (default).  ``False`` takes ``cfg`` / ``params`` as they are and
+        refuses a scanned ``cfg``.
       cache_layout: "dense" (per-slot ``[B, S]`` KV buffers) or "paged"
         (a shared block pool per layer + per-slot page tables —
         PagedAttention).  Paged serving's KV HBM scales with the tokens
@@ -414,28 +402,7 @@ class ServeLoop:
         engages BEFORE any request is rejected outright.
       degrade_max_new: the degraded-mode ``max_new_tokens`` clamp for
         best-effort traffic (default 32).
-      decode_mode: "plain" (one model step per generated token) or
-        "speculative" — the fused segment runs draft-K proposal +
-        one-chunk target verification per round
-        (:mod:`tpudist.models.speculative` folded into the serve loop),
-        emitting up to K+1 tokens per target forward.  Output follows
-        the TARGET's distribution exactly (greedy: exact-match against
-        plain decode); weight hot-swaps rebind the target only — the
-        draft may lag a version, which costs acceptance, never
-        exactness.
-      draft_cfg / draft_params: the proposal model (speculative only).
-        ``vocab_size`` must match the target and ``max_seq_len`` must
-        cover the target's (the draft cache mirrors each lane's
-        position); it is normalized via :func:`serving_layout` like the
-        target.  The draft always decodes DENSE per-row (its cache is
-        small by construction; paging it would buy nothing).
-      num_draft: draft tokens per verify round — a fixed int, or
-        "adaptive" (default) to let :class:`AdaptiveDraftPolicy` pick
-        from ``spec_ladder`` using the observed acceptance rate and
-        measured per-round costs (each ladder K compiles once).
-      spec_ladder: candidate K values for the adaptive policy.
-      chunked_prefill: interleave admission prefill with decode
-        (plain decode mode only; speculative keeps one-shot admission).
+      chunked_prefill: interleave admission prefill with decode.
         Instead of one fused prefill+insert dispatch, admission
         dispatches ONE ``prefill_chunk``-wide slice per host-loop
         iteration between fused decode segments, so a 10k-token prompt
@@ -476,8 +443,16 @@ class ServeLoop:
         migrated state; a missing or unverifiable payload falls back
         to an ordinary prefill of the same prompt, which greedy
         decoding over identical weights makes byte-identical.
-        ``"prefill"`` requires the paged layout + chunked prefill
-        (plain decode); ``"decode"`` requires the paged layout.
+        ``"prefill"`` requires the paged layout + chunked prefill;
+        ``"decode"`` requires the paged layout.
+      preempt: what a ``degrade_queue`` breach does to best-effort
+        traffic.  ``"degrade"`` (default) clamps its budgets
+        (``degrade_max_new``).  ``"migrate"`` (paged layout only) PAUSES
+        it instead: the victim slot's KV pages go to the host tier (or a
+        dict park) and are adopted again when pressure clears, so
+        best-effort output is byte-identical to the undisturbed run;
+        admission is then priority-first, and the worker can evacuate
+        in-flight work at drain and swap time.
     """
 
     def __init__(
@@ -503,11 +478,6 @@ class ServeLoop:
         max_queue: int | None = None,
         degrade_queue: int | None = None,
         degrade_max_new: int = 32,
-        decode_mode: str = "plain",
-        draft_cfg: TransformerConfig | None = None,
-        draft_params: Any = None,
-        num_draft: int | str = "adaptive",
-        spec_ladder: Sequence[int] = (2, 4, 8),
         chunked_prefill: bool = True,
         max_prefill_lanes: int | None = None,
         prefix_sharing: bool = True,
@@ -549,12 +519,6 @@ class ServeLoop:
                     f"the paged cache keeps ONE window block group: the "
                     f"windowed layers must share a width, got "
                     f"{sorted(widths)}")
-            if decode_mode != "plain":
-                raise ValueError(
-                    "a model with sliding-window layers serves paged "
-                    "under decode_mode='plain' only: a verify chunk "
-                    "would need the windowed kernel at several queries "
-                    "and a rollback of released blocks")
             if role != "both" or preempt == "migrate":
                 raise ValueError(
                     "KV handoff and migration payloads carry one block "
@@ -570,12 +534,11 @@ class ServeLoop:
                 f"role must be 'both', 'prefill', or 'decode', got "
                 f"{role!r}")
         if role == "prefill" and not (cache_layout == "paged"
-                                      and chunked_prefill
-                                      and decode_mode == "plain"):
+                                      and chunked_prefill):
             raise ValueError(
                 "role='prefill' needs cache_layout='paged' with chunked "
-                "prefill under plain decode: the handoff exports pool "
-                "pages at the chunked-admission finish")
+                "prefill: the handoff exports pool pages at the "
+                "chunked-admission finish")
         if role == "decode" and cache_layout != "paged":
             raise ValueError(
                 "role='decode' needs cache_layout='paged': handoff "
@@ -588,13 +551,7 @@ class ServeLoop:
             raise ValueError(
                 "preempt='migrate' needs cache_layout='paged': "
                 "preemption exports the victim slot's pool pages")
-        # pressure policy: 'degrade' clamps best-effort budgets under a
-        # degrade_queue breach (the PR 10 ladder); 'migrate' PAUSES them
-        # instead — the victim slot's KV pages export to the host tier
-        # (or a dict park) and re-adopt when pressure clears, so
-        # best-effort output is byte-identical to the undisturbed run.
-        # 'migrate' also makes admission priority-first and lets the
-        # worker evacuate in-flight work at drain/swap time.
+        # the pressure policy (the class docstring says what each does)
         self.preempt = preempt
         self.role = role
         self.cfg = cfg
@@ -620,41 +577,21 @@ class ServeLoop:
                 "whole cache instead of ~window positions",
                 stacklevel=2)
         self._select = _make_select(temperature, top_k, top_p)
-        self._temperature = float(temperature)
-        self._top_k, self._top_p = top_k, top_p
         self._key = key if key is not None else jax.random.key(0)
-        if decode_mode not in ("plain", "speculative"):
-            raise ValueError(
-                f"decode_mode must be 'plain' or 'speculative', got "
-                f"{decode_mode!r}")
-        self.decode_mode = decode_mode
         # rows a query attends at most where an indexer chooses them
         # (None: every row under its length)
         self._index_topk = cfg.index_topk
-        if self._index_topk is not None:
-            if decode_mode == "speculative":
-                raise ValueError(
-                    "a model with an indexer decodes one token a lane a "
-                    "step (its scores, selection and attention over the "
-                    "chosen rows have no verify-chunk form): "
-                    "decode_mode='plain'")
-            if cache_layout != "paged":
-                raise ValueError(
-                    "a model with an indexer serves through "
-                    "cache_layout='paged': the dense layout's per-row "
-                    "decode has no index scores or selection")
+        if self._index_topk is not None and cache_layout != "paged":
+            raise ValueError(
+                "a model with an indexer serves through "
+                "cache_layout='paged': the dense layout's per-row "
+                "decode has no index scores or selection")
         # layers whose past is a fixed-size state a lane (cfg.layer_kinds
         # "linear"): what such a cache cannot do yet is refused here with
         # the reason (docs/DESIGN.md has the list)
         self._state_layers = [i for i, kind in enumerate(cfg.kinds)
                               if kind == "linear"]
         if self._state_layers:
-            if decode_mode == "speculative":
-                raise ValueError(
-                    "a model with linear-attention layers decodes one "
-                    "token a lane a step: a rejected draft token has "
-                    "already moved the state and there is no rollback of "
-                    "it: decode_mode='plain'")
             if role != "both" or preempt == "migrate":
                 raise ValueError(
                     "KV handoff and migration payloads carry a lane's "
@@ -666,46 +603,6 @@ class ServeLoop:
                     "chunk by chunk (chunked_prefill=True): the one-shot "
                     "admission pads the prompt and has no mask for the "
                     "padded rows, which would move the state")
-        if decode_mode == "speculative":
-            if draft_cfg is None or draft_params is None:
-                raise ValueError(
-                    "decode_mode='speculative' needs draft_cfg and "
-                    "draft_params")
-            if auto_unstack:
-                draft_cfg, draft_params = serving_layout(
-                    draft_cfg, draft_params)
-            if draft_cfg.scan_layers:
-                raise ValueError(
-                    "the draft needs the unrolled layout; pass the "
-                    "scanned checkpoint with auto_unstack=True")
-            if draft_cfg.vocab_size != cfg.vocab_size:
-                raise ValueError(
-                    f"draft vocab {draft_cfg.vocab_size} != target "
-                    f"vocab {cfg.vocab_size}")
-            if draft_cfg.max_seq_len < cfg.max_seq_len:
-                raise ValueError(
-                    f"draft max_seq_len {draft_cfg.max_seq_len} < target "
-                    f"{cfg.max_seq_len}: the draft cache mirrors each "
-                    "lane's position, so it needs the same coverage")
-            if isinstance(num_draft, int):
-                if num_draft < 1:
-                    raise ValueError(
-                        f"num_draft must be >= 1, got {num_draft}")
-                self._spec_ladder = (int(num_draft),)
-            elif num_draft == "adaptive":
-                self._spec_ladder = tuple(sorted(
-                    int(x) for x in spec_ladder))
-                if not self._spec_ladder or self._spec_ladder[0] < 1:
-                    raise ValueError(
-                        f"spec_ladder must hold K >= 1, got {spec_ladder}")
-            else:
-                raise ValueError(
-                    f"num_draft must be an int or 'adaptive', got "
-                    f"{num_draft!r}")
-            self._k_max = self._spec_ladder[-1]
-        else:
-            self._spec_ladder = ()
-            self._k_max = 0
         # SIDE-BUFFER mode (flash, no window): steps write a segment-
         # local buffer at a SCALAR index (XLA keeps those in place;
         # per-row-indexed main-cache writes measured +0.35 ms/step on the
@@ -714,10 +611,7 @@ class ServeLoop:
         # the paged layout is sided UNCONDITIONALLY: the pool is frozen
         # within a segment (growth happens at dispatch boundaries), so
         # every in-segment token must stage in the side buffer.
-        # Speculative mode needs K_max extra slots: the last round before
-        # the emit count reaches steps_per_sync can still write a full
-        # K+1-token verify chunk past the steps_per_sync-1 already kept.
-        self.side = (steps_per_sync + self._k_max
+        self.side = (steps_per_sync
                      if (decode_attention == "flash"
                          and self._window is None)
                      or cache_layout == "paged" else 0)
@@ -738,11 +632,9 @@ class ServeLoop:
             self.pool = None
         wg = self.pool.window_group if self.pool is not None else None
         self.kv_window_blocks = wg.num_blocks if wg is not None else 0
-        # chunked-interleaved prefill: plain decode only (the
-        # speculative admit fuses a draft prefill into the same dispatch
-        # and keeps the one-shot path); prefix sharing additionally
+        # chunked-interleaved prefill; prefix sharing additionally
         # needs the paged layout — shared blocks live in the pool
-        self.chunked = bool(chunked_prefill) and decode_mode == "plain"
+        self.chunked = bool(chunked_prefill)
         if max_prefill_lanes is not None and max_prefill_lanes < 1:
             raise ValueError(
                 f"max_prefill_lanes must be >= 1, got {max_prefill_lanes}")
@@ -881,26 +773,6 @@ class ServeLoop:
                                if cfg.is_expert_layer(i)]
         self._held = ((cfg.moe.held or (0, cfg.moe.num_experts))[1]
                       if self._expert_blocks else 0)
-        if decode_mode == "speculative":
-            self.draft_cfg = draft_cfg
-            self.draft_params = draft_params
-            # the draft decodes DENSE per-row: verify chunks and single
-            # steps both go through the banded-mask path, so a draft
-            # step pays ONE masked matmul, and its cache
-            # is num_slots x draft_seq_len — small by construction
-            self.draft_model = TransformerLM(draft_cfg, decode=True,
-                                             decode_attention="dense")
-            d_blank = _blank_cache(self.draft_model, num_slots)
-            self.draft_cache = jax.tree.map(
-                lambda leaf: (jnp.zeros((num_slots,), jnp.int32)
-                              if leaf.ndim == 0 else leaf), d_blank)
-            self._draft_blank1 = _blank_cache(self.draft_model, 1)
-            self._spec_policy = (
-                AdaptiveDraftPolicy(self._spec_ladder)
-                if num_draft == "adaptive" else None)
-            # per-K dispatch counts: the first dispatch at each K carries
-            # its compile, so its timing is excluded from the cost model
-            self._spec_uses: dict[int, int] = {}
         self._tok = jnp.full((num_slots,), self.pad_token, jnp.int32)
         self._active = jnp.zeros((num_slots,), bool)
         self._remaining = jnp.zeros((num_slots,), jnp.int32)
@@ -1070,9 +942,6 @@ class ServeLoop:
                                            unit="dispatches")
         self._obs_steps_per_dispatch = obs.gauge("serve/steps_per_dispatch",
                                                  unit="tokens")
-        self._obs_spec_k = obs.gauge("serve/spec_k", unit="tokens")
-        self._obs_spec_accept = obs.gauge("serve/spec_accept_rate",
-                                          unit="ratio")
         # EMA of seconds per generated token as the deadline clamp in
         # _plan_steps sees them: dispatch -> drain wall time / tokens of
         # the segment.  Under pipelining that wall spans the segment in
@@ -1158,18 +1027,6 @@ class ServeLoop:
                                           static_argnames=("chunk",))
             self._admit_finish = jax.jit(self._admit_finish_impl,
                                          donate_argnums=(0, 1, 2, 3, 4))
-        if decode_mode == "speculative":
-            # num_draft is STATIC (the draft scan's length is a shape);
-            # each ladder K compiles once.  first (argnum 7) is NOT
-            # donated, as in the plain segment.
-            self._segment_spec = jax.jit(
-                self._segment_spec_impl,
-                donate_argnums=(2, 3, 4, 5, 6, 8),
-                static_argnames=("num_draft",))
-            self._admit_dev_spec = jax.jit(
-                self._admit_dev_spec_impl,
-                donate_argnums=(2, 3, 4, 5, 6, 7),
-                static_argnames=("true_chunk",))
 
     def _with_side_buffers(self, cache):
         def walk(node):
@@ -1697,158 +1554,6 @@ class ServeLoop:
 
         return walk(cache)
 
-    def _admit_dev_spec_impl(self, params, draft_params, cache, d_cache,
-                             tok, active, remaining, first_buf,
-                             prompt_padded, true_len, slot, max_new, pages,
-                             key, *, true_chunk):
-        """Speculative admission: the target's admit (prefill + insert +
-        lane stamps) plus a DRAFT prefill of the same prompt inserted
-        into the draft cache's matching slot — both in the same dispatch,
-        still no host sync."""
-        cache, tok, active, remaining, first_buf = self._admit_dev_impl(
-            params, cache, tok, active, remaining, first_buf,
-            prompt_padded, true_len, slot, max_new, pages, key,
-            true_chunk=true_chunk)
-        d1, _ = _prefill(self.draft_model, draft_params,
-                         self._draft_blank1, prompt_padded, true_chunk)
-        d1 = _set_cache_index(d1, true_len)
-        d_cache = self._insert_impl(d_cache, d1, slot, true_len, _NO_PAGES)
-        return cache, d_cache, tok, active, remaining, first_buf
-
-    def _segment_spec_impl(self, params, draft_params, cache, d_cache,
-                           tok, active, remaining, first, key, n_steps,
-                           *, num_draft):
-        """The speculative fused segment: rounds of draft-K proposal +
-        one-chunk target verification (``lax.while_loop``) until at
-        least ``n_steps`` tokens are emitted or every lane freezes.
-
-        Each round mirrors :func:`speculative_generate.round_body`, made
-        lane-aware:
-
-        * the draft runs K+1 per-row single-token steps (the last writes
-          d_K's K/V); the target verifies ``[tok, d_1..d_K]`` as ONE
-          s=K+1 chunk through the per-row cache path;
-        * frozen lanes are masked ALL-ACCEPT in ``_accept_and_next`` so
-          they never drag the batch-min prefix down — their garbage
-          emits are padded out and their K/V writes are dropped by the
-          ``lived``-masked merge exactly as in the plain segment;
-        * both caches ROLL BACK by ``K - m`` after the verify (the side
-          counter in sided/paged layouts, per-row ``cache_index`` in the
-          dense non-sided layout) so resident K/V tracks emitted tokens
-          — the invariant the segment-boundary merge and the host's
-          page-growth accounting both rely on;
-        * a lane that hits a stop or exhausts its budget mid-round
-          contributes ``min(stop_pos + 1, remaining)`` REAL tokens (the
-          same count the host's drain rules will consume) to ``lived``
-          and freezes.
-
-        ``stats`` returns ``[emitted, rounds, accepted_sum,
-        active_row_rounds]`` — the host feeds them to the adaptive-K
-        policy and the ``serve/spec_*`` gauges."""
-        stop_arr = self._stop
-        pad = jnp.int32(self.pad_token)
-        S = self.cfg.max_seq_len
-        Sd = self.draft_cfg.max_seq_len
-        k = num_draft
-        # the last round can start at emitted == n_steps - 1 and still
-        # append a full K+1 window, so the emit buffer needs K extra
-        # columns past steps_per_sync
-        cap_out = self.steps + k
-        t_idx = jnp.arange(k + 1)
-
-        def cond(carry):
-            return (carry[0] < n_steps) & jnp.any(carry[5])
-
-        def round_body(carry):
-            (com, cache, d_cache, tok, active, remaining, lived, key, E,
-             rounds, acc_sum, act_rounds) = carry
-            main_idx, side_idx = _index_leaves(cache)
-            n_pos = main_idx if side_idx is None else main_idx + side_idx
-            key, dk, vk = jax.random.split(key, 3)
-
-            # DRAFT: K single-token proposals with their distributions;
-            # K+1 steps so the last writes d_K's K/V (the all-accepted
-            # case needs it resident), its sampled output discarded
-            def chain(chain_carry, step_key):
-                d_cache, d_tok = chain_carry
-                d_idx, _ = _index_leaves(d_cache)
-                logits, mut = self.draft_model.apply(
-                    {"params": draft_params, "cache": d_cache},
-                    d_tok[:, None],
-                    positions=jnp.minimum(d_idx, Sd - 1)[:, None],
-                    mutable=["cache"])
-                q_probs = _filtered_probs(
-                    logits[:, -1], self._temperature, self._top_k,
-                    self._top_p)
-                nxt = self._select(logits[:, -1],
-                                   step_key).astype(jnp.int32)
-                return (mut["cache"], nxt), (nxt, q_probs)
-
-            (d_cache2, _), (drafts_t, q_t) = lax.scan(
-                chain, (d_cache, tok), jax.random.split(dk, k + 1))
-            drafts = drafts_t[:k].T                            # [B, K]
-            q = jnp.moveaxis(q_t[:k], 0, 1)                    # [B, K, V]
-
-            # VERIFY: one target chunk over [tok, d_1..d_K] per lane
-            verify = jnp.concatenate([tok[:, None], drafts], axis=1)
-            positions = jnp.minimum(
-                n_pos[:, None] + t_idx[None, :], S - 1)
-            t_logits, mut = self.model.apply(
-                {"params": params, "cache": cache}, verify,
-                positions=positions, mutable=["cache"])
-            p = _filtered_probs(t_logits, self._temperature, self._top_k,
-                                self._top_p)
-            m, emit, accepted = _accept_and_next(p, q, drafts, vk,
-                                                 active=active)
-            names = {"side_index"} if self.side else {"cache_index"}
-            cache2 = _shift_index_leaves(mut["cache"], k - m, names)
-            d_cache2 = _shift_index_leaves(d_cache2, k - m,
-                                           {"cache_index"})
-
-            # the round's emit window: accepted drafts then the verify
-            # token at column m (columns past m are garbage the host's
-            # slice/stop rules never consume)
-            e_buf = jnp.concatenate([drafts, emit[:, None]], axis=1)
-            e_buf = lax.dynamic_update_slice(e_buf, emit[:, None], (0, m))
-            hit = (jnp.isin(e_buf, stop_arr) if stop_arr is not None
-                   else jnp.zeros(e_buf.shape, bool))
-            stop_pos = jnp.min(
-                jnp.where(hit & (t_idx[None, :] <= m), t_idx[None, :],
-                          m + 1), axis=1)                      # [B]
-            no_stop = stop_pos >= m + 1
-            # REAL tokens this round = what the host's drain will
-            # consume: up to the stop (inclusive), capped by budget and
-            # the batch-min window — identical to plain-mode `lived`
-            real = jnp.where(
-                active,
-                jnp.minimum(jnp.minimum(stop_pos + 1, remaining), m + 1),
-                0)
-            lived = lived + real
-            remaining = remaining - real
-            active2 = active & no_stop & (remaining > 0)
-            cols = jnp.where(t_idx <= m, com + t_idx, cap_out)
-            E = E.at[:, cols].set(
-                jnp.where(active[:, None], e_buf, pad), mode="drop")
-            tok2 = jnp.where(active2, emit, pad)
-            return (com + m + 1, cache2, d_cache2, tok2, active2,
-                    remaining, lived, key, E, rounds + 1,
-                    acc_sum + jnp.sum(jnp.where(active, accepted, 0)),
-                    act_rounds + jnp.sum(active.astype(jnp.int32)))
-
-        lived0 = jnp.zeros((self.B,), jnp.int32)
-        E0 = jnp.full((self.B, cap_out), pad, jnp.int32)
-        cache = _bound_paged_walk(cache, active)
-        (com, cache, d_cache, tok, active, remaining, lived, key, E,
-         rounds, acc_sum, act_rounds) = lax.while_loop(
-            cond, round_body,
-            (jnp.int32(0), cache, d_cache, tok, active, remaining,
-             lived0, key, E0, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
-        if self.side:
-            cache = self._merge_impl(cache, lived)
-        emits = jnp.concatenate([first[:, None], E], axis=1)
-        stats = jnp.stack([com, rounds, acc_sum, act_rounds])
-        return cache, d_cache, tok, active, remaining, key, emits, stats
-
     def _merge_impl(self, cache, lived):
         """End-of-segment: scatter each layer's side buffer into the main
         cache at every row's own offset (per-row-index writes, but ONCE
@@ -1955,15 +1660,6 @@ class ServeLoop:
             raise ValueError(
                 f"request needs {prompt.size + req.max_new_tokens} cache "
                 f"slots > max_seq_len {self.cfg.max_seq_len}")
-        if (self.decode_mode == "speculative"
-                and prompt.size + req.max_new_tokens + self._k_max - 1
-                > self.cfg.max_seq_len):
-            raise ValueError(
-                f"speculative serving needs prompt + max_new + "
-                f"num_draft - 1 <= max_seq_len "
-                f"({prompt.size + req.max_new_tokens + self._k_max - 1} "
-                f"> {self.cfg.max_seq_len}): the verify chunk writes up "
-                "to num_draft slots past the last emitted token")
         if self.pool is not None:
             need = self.pool.request_blocks(prompt.size, req.max_new_tokens)
             if need > self.pool.num_blocks:
@@ -2287,21 +1983,12 @@ class ServeLoop:
         padded = np.full((1, Lp), self.pad_token, np.int32)
         padded[0, :L] = prompt
         self._key, pk = jax.random.split(self._key)
-        if self.decode_mode == "speculative":
-            (self.cache, self.draft_cache, self._tok, self._active,
-             self._remaining, self._first) = self._admit_dev_spec(
-                self.params, self.draft_params, self.cache,
-                self.draft_cache, self._tok, self._active,
-                self._remaining, self._first, padded, np.int32(L),
-                np.int32(slot), np.int32(req.max_new_tokens), pages, pk,
-                true_chunk=chunk)
-        else:
-            (self.cache, self._tok, self._active, self._remaining,
-             self._first) = self._admit_dev(
-                self.params, self.cache, self._tok, self._active,
-                self._remaining, self._first, padded, np.int32(L),
-                np.int32(slot), np.int32(req.max_new_tokens), pages, pk,
-                true_chunk=chunk)
+        (self.cache, self._tok, self._active, self._remaining,
+         self._first) = self._admit_dev(
+            self.params, self.cache, self._tok, self._active,
+            self._remaining, self._first, padded, np.int32(L),
+            np.int32(slot), np.int32(req.max_new_tokens), pages, pk,
+            true_chunk=chunk)
         return {"req": req, "tokens": [], "pending_first": True,
                 "chunks": -(-Lp // chunk)}
 
@@ -2602,17 +2289,6 @@ class ServeLoop:
             return 1
         return max(1, min(self.steps, int(slack / self._step_ema)))
 
-    def _spec_k(self, live: int) -> int:
-        """The round's draft length: the fixed ``num_draft`` or the
-        adaptive policy's pick at the current live-lane count
-        (``allow_plain=False`` — inside the fused segment a K=0 round
-        does not exist; the break-even fallback is choosing the smallest
-        ladder K)."""
-        if self._spec_policy is None:
-            return self._spec_ladder[0]
-        return int(self._spec_policy.best_k(batch=max(live, 1),
-                                            allow_plain=False))
-
     def request_swap(self, params_fn, *, version: int | None = None,
                      on_swapped=None) -> None:
         """Schedule a DRAIN-GATED weight hot-swap: admission pauses,
@@ -2711,7 +2387,7 @@ class ServeLoop:
         pending: deque[tuple[Request, float]] = deque()
         slot_state: list[dict | None] = [None] * self.B
         done: list[Completion] = []
-        # (seq, emits, stats|None, n_steps, k, t_dispatch)
+        # (seq, emits, corrupt, n_steps, t_dispatch, the plan's sums)
         inflight: deque[tuple] = deque()
         seq = 0   # segments dispatched so far == index of the next one
         closed = source is None
@@ -3000,8 +2676,7 @@ class ServeLoop:
                 if not 0 <= t < vocab:
                     # host-side range net: an id outside the vocab can
                     # only come from scrambled device memory or a bad
-                    # transfer (the sampler indexes [0, vocab)).  Covers
-                    # the speculative path, which has no in-graph guard.
+                    # transfer (the sampler indexes [0, vocab)).
                     self._obs_corrupt.inc()
                     obs.recorder.record(
                         "serve_corrupt_segment", slot=slot,
@@ -3211,10 +2886,6 @@ class ServeLoop:
             nonlocal seq
             with obs.span("serve/segment_plan", seq=seq):
                 n = self._plan_steps(slot_state)
-                live = sum(1 for st in slot_state
-                           if st is not None and not st.get("zombie"))
-                k = (self._spec_k(live)
-                     if self.decode_mode == "speculative" else 0)
                 pages = rows = rows_live = rows_selected = longest = 0
                 windowed = None
                 # the lanes whose state the slot cache holds (a lane in
@@ -3238,11 +2909,10 @@ class ServeLoop:
                     # page coverage by the segment's worst case (drawn
                     # from its admit-time reservation, so this cannot
                     # fail), then stamp the fresh table into the carry
-                    # this segment consumes.  Speculative segments can
-                    # emit up to n + k tokens (the last round's full K+1
-                    # window).  Lanes already frozen on device (host
-                    # hasn't drained the stop yet) grow harmlessly within
-                    # their reservation and refund it at finalize.
+                    # this segment consumes.  Lanes already frozen on
+                    # device (host hasn't drained the stop yet) grow
+                    # harmlessly within their reservation and refund it
+                    # at finalize.
                     # Zombie lanes are dead (their reservation was
                     # dropped at finalize); their held blocks just wait
                     # for the refund.
@@ -3264,7 +2934,7 @@ class ServeLoop:
                                 rows_w += walk_rows(held, block, per_tile,
                                                     wg.window)
                                 rows_w_live += min(held, wg.window)
-                            self.pool.grow(slot, n + k)
+                            self.pool.grow(slot, n)
                     if wg is not None:
                         # the window layers' walk (a layer) and the
                         # blocks this segment's growth let go of
@@ -3279,28 +2949,12 @@ class ServeLoop:
             # key — no per-wave host-side split dispatch needed
             t_disp = time.perf_counter()
             with obs.span("serve/segment", steps=n, seq=seq):
-                if self.decode_mode == "speculative":
-                    # the speculative segment has no in-graph guard;
-                    # the host-side token-range check in drain() is the
-                    # integrity net for this path
-                    corrupt = None
-                    (self.cache, self.draft_cache, self._tok,
-                     self._active, self._remaining, self._key, emits,
-                     stats) = self._segment_spec(
-                        self.params, self.draft_params, self.cache,
-                        self.draft_cache, self._tok, self._active,
-                        self._remaining, self._first, self._key,
-                        jnp.int32(n), num_draft=k)
-                    self._obs_spec_k.set(k)
-                else:
-                    stats = None
-                    poison = faults.poison_logits(self._served_tokens)
-                    (self.cache, self._tok, self._active,
-                     self._remaining, self._key, emits,
-                     corrupt) = self._segment(
-                        self.params, self.cache, self._tok, self._active,
-                        self._remaining, self._first, self._key,
-                        jnp.int32(n), jnp.bool_(poison))
+                poison = faults.poison_logits(self._served_tokens)
+                (self.cache, self._tok, self._active, self._remaining,
+                 self._key, emits, corrupt) = self._segment(
+                    self.params, self.cache, self._tok, self._active,
+                    self._remaining, self._first, self._key,
+                    jnp.int32(n), jnp.bool_(poison))
             self._obs_segments.inc()
             self._obs_dispatches.inc()
             for slot in range(self.B):
@@ -3315,7 +2969,7 @@ class ServeLoop:
                 emits.copy_to_host_async()
             except AttributeError:  # non-jax array (test doubles)
                 pass
-            inflight.append((seq, emits, corrupt, stats, n, k, t_disp,
+            inflight.append((seq, emits, corrupt, n, t_disp,
                              (pages, rows, rows_live, rows_selected,
                               longest, windowed, state_lanes)))
             seq += 1
@@ -3328,11 +2982,9 @@ class ServeLoop:
             """Resolve the oldest in-flight segment: block on its fetch
             (usually already landed — the copy overlapped later compute),
             then feed every lane whose stamp says this segment carries
-            its tokens.  Plain segments carry exactly ``n`` emit columns
-            past the deferred-first column; speculative ones carry
-            ``stats[0]`` (the emitted count) — either way the drain
-            slices to the real width so pad columns past a short segment
-            are never consumed.
+            its tokens.  A segment carries exactly ``n`` emit columns
+            past the deferred-first column, and the drain slices to that
+            width so pad columns past a short segment are never consumed.
 
             Leaves one ``serve/segment_fetch`` span (the block on the
             device) and one ``serve/segment_drain`` span (the host's work
@@ -3371,9 +3023,9 @@ class ServeLoop:
             state the slot cache held at dispatch: the decoding ones) and
             ``state_bytes`` (those lanes times what the state layers keep
             for one)."""
-            (s_idx, emits_dev, corrupt_dev, stats_dev, n_disp, k_disp,
-             t_disp, (pages, rows, rows_live, rows_selected, longest,
-                      windowed, state_lanes)) = inflight.popleft()
+            (s_idx, emits_dev, corrupt_dev, n_disp, t_disp,
+             (pages, rows, rows_live, rows_selected, longest, windowed,
+              state_lanes)) = inflight.popleft()
             self._obs_depth.set(len(inflight))
             if any(st is not None and not st.get("zombie")
                    and "seq" in st and st["seq"] <= s_idx
@@ -3381,65 +3033,39 @@ class ServeLoop:
                 t0 = time.perf_counter()
                 with obs.span("serve/segment_fetch", seq=s_idx):
                     emits = np.asarray(emits_dev)
-                    stats = (np.asarray(stats_dev)
-                             if stats_dev is not None else None)
                 # the one stamp of "this segment's tokens are on the
                 # host": host_wait's end, the first-token time of the
                 # requests whose first token it carried, the inter-token
                 # sample and the clamp's EMA all read it
                 t_fetched = time.perf_counter()
                 self._obs_host_wait.record(t_fetched - t0)
-                n_tok = n_disp if stats is None else int(stats[0])
                 # inter-token latency sample: wall gap between
                 # consecutive decode-segment drains, per token of this
                 # segment.  A one-shot long-prompt admission lands
                 # between two segments and shows up here as one huge
                 # gap — exactly the stall chunked prefill removes.
-                if self._last_drain_t is not None and n_tok > 0:
+                if self._last_drain_t is not None:
                     self.intertoken_samples.append(
-                        ((t_fetched - self._last_drain_t) / n_tok, n_tok))
+                        ((t_fetched - self._last_drain_t) / n_disp,
+                         n_disp))
                 self._last_drain_t = t_fetched
-                dt = t_fetched - t_disp
-                if n_tok > 0:
-                    # dispatch->drain wall time per token; under
-                    # pipelining this spans overlapped segments, so it
-                    # OVERestimates — which only makes the deadline
-                    # clamp more conservative
-                    per = dt / n_tok
-                    self._step_ema = (
-                        per if self._step_ema is None
-                        else 0.7 * self._step_ema + 0.3 * per)
-                    self._obs_spt.set(self._step_ema)
-                self._obs_steps_per_dispatch.set(n_tok)
-                if stats is not None:
-                    rounds = int(stats[1])
-                    act_rounds = int(stats[3])
-                    if act_rounds > 0 and k_disp > 0:
-                        self._obs_spec_accept.set(
-                            float(stats[2]) / (act_rounds * k_disp))
-                        if self._spec_policy is not None:
-                            self._spec_policy.update(
-                                {"rounds": act_rounds,
-                                 "draft_accepted": int(stats[2])},
-                                batch=1, num_draft=k_disp)
-                    if self._spec_policy is not None and rounds > 0:
-                        # skip each K's first dispatch: its wall time is
-                        # compile-polluted and would poison the measured
-                        # cost model
-                        if self._spec_uses.get(k_disp, 0) >= 1:
-                            self._spec_policy.observe_round_cost(
-                                k_disp, dt / rounds)
-                        self._spec_uses[k_disp] = (
-                            self._spec_uses.get(k_disp, 0) + 1)
-                corrupt = (np.asarray(corrupt_dev)
-                           if corrupt_dev is not None else None)
+                # dispatch->drain wall time per token; under pipelining
+                # this spans overlapped segments, so it OVERestimates —
+                # which only makes the deadline clamp more conservative
+                per = (t_fetched - t_disp) / n_disp
+                self._step_ema = (
+                    per if self._step_ema is None
+                    else 0.7 * self._step_ema + 0.3 * per)
+                self._obs_spt.set(self._step_ema)
+                self._obs_steps_per_dispatch.set(n_disp)
+                corrupt = np.asarray(corrupt_dev)
                 lanes = tokens = first_tokens = steps_run = 0
                 for slot in range(self.B):
                     st = slot_state[slot]
                     if (st is not None and not st.get("zombie")
                             and "seq" in st and st["seq"] <= s_idx):
                         lanes += 1
-                        if corrupt is not None and bool(corrupt[slot]):
+                        if bool(corrupt[slot]):
                             # the in-graph guard froze this lane before
                             # emitting anything from the bad step, but
                             # this segment's earlier columns are from
@@ -3457,7 +3083,7 @@ class ServeLoop:
                             finalize(slot, "corrupt_segment")
                         else:
                             first = int(st["pending_first"])
-                            got = drain(slot, emits[slot, :1 + n_tok],
+                            got = drain(slot, emits[slot, :1 + n_disp],
                                         t_fetched)
                             first = min(first, got)  # none from a corrupt column 0
                             tokens += got

@@ -52,6 +52,7 @@ from tpudist.models.generate import (
     _is_stop,
     _make_select,
     _prefill,
+    _set_cache_index,
     _stop_array,
     apply_cache_constraint,
     sequence_lengths,
@@ -146,19 +147,6 @@ def _accept_and_next(p: jnp.ndarray, q: jnp.ndarray, draft: jnp.ndarray,
         draft, jnp.minimum(m, k - 1), axis=1, keepdims=False)
     emit = jnp.where(took_next, next_draft, resampled).astype(jnp.int32)
     return m, emit, accepted
-
-
-def _set_cache_index(cache: Any, idx: jnp.ndarray) -> Any:
-    """Roll the cache to ``idx`` tokens: every ``cache_index`` leaf is
-    reset (K/V buffers are left as-is — slots past the index are masked
-    by every cached-attention path and overwritten on the next write at
-    that position).  Index leaves are 0-D scalars in the unrolled layout
-    and [num_layers] vectors under ``cfg.scan_layers``; K/V buffers are
-    always >= 3-D (packed [B, S, Hkv·D]), so dimensionality separates
-    them."""
-    return jax.tree.map(
-        lambda leaf: (jnp.full_like(leaf, idx) if leaf.ndim <= 1 else leaf),
-        cache)
 
 
 def speculative_generate(

@@ -597,6 +597,16 @@ def _index_choice(q_i, w_i, ikeys, valid, k: int):
     return chosen & valid
 
 
+def _per_row_takes_one_token(s: int) -> None:
+    """The per-row cache paths (a vector ``cache_index``: the serve loop's
+    slot cache) decode ONE token a lane a call."""
+    if s != 1:
+        raise ValueError(
+            "a cache with a vector cache_index (the serve loop's slot "
+            f"cache) takes one token a lane a call, got {s}: a prompt is "
+            "prefilled through the batch-1 scalar-index cache and inserted")
+
+
 class CausalSelfAttention(nn.Module):
     """Multi-head / grouped-query attention with the serve loop's caches.
 
@@ -833,10 +843,10 @@ class CausalSelfAttention(nn.Module):
             # PER-ROW cache positions (vector cache_index [B]) — the
             # continuous-batching serve mode: every slot decodes at its
             # own length (tpudist.models.serving swaps the scalar index
-            # leaves for vectors when building the slot cache).  s == 1
-            # is the decode step, s > 1 the speculative verify chunk;
-            # prefill runs per-slot through a scalar-index side cache
-            # and is INSERTED (serving._insert).
+            # leaves for vectors when building the slot cache), one
+            # token a call; prefill runs per-slot through a scalar-index
+            # side cache and is INSERTED (serving._insert).
+            _per_row_takes_one_token(s)
             return self._serve_attend(
                 q, k, v, cached_k, cached_v, idx_var)
         with routine("attn/cache"):
@@ -978,13 +988,7 @@ class CausalSelfAttention(nn.Module):
         live positions (:meth:`_serve_attend_sided`); the ServeLoop
         scatters side → main once per segment (amortized to ~nothing).
         ``serve_side_slots == 0`` keeps the direct per-row-write path
-        (simple, correct, slower).
-
-        ``s > 1`` is the speculative VERIFY CHUNK inside a fused serve
-        segment: row ``r``'s ``s`` tokens land at its own
-        ``idx[r]..idx[r]+s-1`` and query ``j`` attends causally over the
-        row's first ``idx[r] + j + 1`` positions.  The ServeLoop rolls
-        the index back to the accepted prefix afterwards."""
+        (simple, correct, slower)."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
         idx = idx_var.value
@@ -1013,8 +1017,7 @@ class CausalSelfAttention(nn.Module):
             idx_var.value = idx + s
         with routine("attn/core"):
             n = idx + 1  # [B] valid lengths including the current token
-            if (s == 1 and self.decode_attention == "flash"
-                    and self.window is None):
+            if self.decode_attention == "flash" and self.window is None:
                 from tpudist.ops.flash_decode import flash_decode
 
                 return flash_decode(q, k_all, v_all, n,
@@ -1022,10 +1025,6 @@ class CausalSelfAttention(nn.Module):
             # NOTE: flash + attention_window falls back to the dense masked
             # path here (the per-row kernel has no per-row window trim yet) —
             # ServeLoop warns about the bandwidth cost at construction.
-            # Multi-query chunks (s > 1) are dense banded too: the chunk was
-            # just written to the main cache, so one banded mask covers main
-            # history and the in-chunk causal structure together (the flash
-            # s>1 wrapper exists for the sided/frozen-main-cache layout).
             positions = jnp.arange(cfg.max_seq_len)[None, None, :]  # [1,1,S]
             q_pos = idx[:, None] + jnp.arange(s)[None, :]           # [B, s]
             mask = positions < (q_pos + 1)[:, :, None]              # [B,s,S]
@@ -1066,9 +1065,6 @@ class CausalSelfAttention(nn.Module):
             cfg.compute_dtype)
         side_idx = self.variable(
             "cache", "side_index", lambda: jnp.zeros((), jnp.int32))
-        # s > 1 writes a verify chunk (speculative decode); the chunk
-        # lands contiguously and flash_decode's multi-query wrapper gives
-        # query j visibility over side positions [0, side_idx + j].
         with routine("attn/cache"):
             s_at = jnp.minimum(side_idx.value, cap - s)
             side_k.value = jax.lax.dynamic_update_slice(
@@ -1154,26 +1150,19 @@ class CausalSelfAttention(nn.Module):
                 "the paged cache decodes through per-row vector "
                 "cache_index only (ServeLoop with cache_layout='paged'); "
                 "scalar-index rollouts use the dense layout")
-        # s > 1 is the speculative verify chunk (staged in the side
-        # buffer like single steps; prefill still goes through a dense
-        # batch-1 side cache and serving._insert scatters it into pages)
+        # (prefill goes through a dense batch-1 side cache and
+        # serving._insert scatters it into pages)
+        _per_row_takes_one_token(s)
         if self.decode_shard is not None:
             raise NotImplementedError(
                 "sharded decode over the paged cache is not wired yet; "
                 "serve paged through the replicated path")
-        if index is not None and s != 1:
-            raise ValueError(
-                "a model with an indexer decodes one token a lane a step "
-                "over the paged cache (its scores, selection and attention "
-                f"over the chosen rows have no verify-chunk form); got "
-                f"s={s}")
         window = self.window
-        if window is not None and (s != 1
-                                   or self.serve_side_slots > window):
+        if window is not None and self.serve_side_slots > window:
             raise ValueError(
-                "a windowed layer decodes one token a step over the paged "
-                "cache, with a side buffer no longer than its window "
-                f"(got s={s}, serve_side_slots={self.serve_side_slots}, "
+                "a windowed layer decodes over the paged cache with a side "
+                "buffer no longer than its window (got "
+                f"serve_side_slots={self.serve_side_slots}, "
                 f"window={window})")
         if self.serve_side_slots <= 0:
             raise ValueError(
@@ -1238,9 +1227,7 @@ class CausalSelfAttention(nn.Module):
                 every_row)
         # dense fallback: gather the slot's pages into a contiguous view
         # (one full-logical-cache copy per step — fine on CPU, the reason
-        # the kernel exists on TPU) and mask main + side positions;
-        # chunk query j (s > 1, speculative verify) sees side positions
-        # [0, s_base + j] — causal within the chunk it just wrote
+        # the kernel exists on TPU) and mask main + side positions
         from tpudist.ops.flash_decode import paged_gather_kv
 
         with routine("attn/core"):

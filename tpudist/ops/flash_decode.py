@@ -732,6 +732,15 @@ def _pick_block_k(s: int, block_k: int) -> int:
         f"multiple of 8 (e.g. {-(-s // 8) * 8})")
 
 
+def _one_query(s_q: int) -> None:
+    """The decode kernels take ONE query token a row a call."""
+    if s_q != 1:
+        raise ValueError(
+            f"a decode kernel takes one query token a call (q [B, 1, H, "
+            f"D]), got {s_q}: a chunk of queries goes through the prefill "
+            "kernel")
+
+
 def flash_decode(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,
@@ -781,39 +790,8 @@ def flash_decode(
         softmax — no separate attend, no log-sum-exp merge.  Requires
         per-row ``cache_len`` and ``window=None``.
 
-    MULTI-QUERY DECODE (``q`` with ``s > 1``): the speculative verify
-    chunk of the fused serve segment — ``s`` tokens whose K/V were just
-    written to the side buffer (``side_len`` counts them), each query
-    ``j`` attending the frozen main cache at the per-row lengths plus
-    side positions ``< side_len - (s - 1 - j)`` (causal within the
-    chunk).  Requires side buffers: the main cache is frozen during a
-    segment, so in-segment queries can only see each other through the
-    side staging.  Implemented as ``s`` single-query kernel calls — the
-    main cache is streamed once per query, so this is for SHORT chunks
-    (draft-k verification), not prefill; ``return_lse`` is single-query
-    only.
-
-    Returns ``[B, s, H, D]`` (plus ``[B, H]`` lse when requested).
+    Returns ``[B, 1, H, D]`` (plus ``[B, H]`` lse when requested).
     """
-    s_q = q.shape[1]
-    if s_q > 1:
-        if side_k is None:
-            raise ValueError(
-                "multi-query flash_decode needs side buffers (the "
-                "in-segment tokens' K/V staging); prefill-style chunks "
-                "against the main cache go through the prefill kernel")
-        if return_lse:
-            raise ValueError(
-                "return_lse composes with single-query decode only")
-        sl = jnp.asarray(side_len, jnp.int32)
-        return jnp.concatenate([
-            _flash_decode_impl(
-                q[:, j:j + 1], k_cache, None, v_cache, None, cache_len,
-                window=window, block_k=block_k, interpret=interpret,
-                pos_offset=pos_offset, return_lse=False, side_k=side_k,
-                side_v=side_v, side_len=sl - (s_q - 1 - j),
-                packed_kv_heads=packed_kv_heads)
-            for j in range(s_q)], axis=1)
     return _flash_decode_impl(
         q, k_cache, None, v_cache, None, cache_len, window=window,
         block_k=block_k, interpret=interpret, pos_offset=pos_offset,
@@ -831,7 +809,7 @@ def _flash_decode_impl(q, k_cache, k_scale, v_cache, v_scale, cache_len,
     side = side_k is not None
     packed = k_cache.ndim == 3
     b, s_q, h, d = q.shape
-    assert s_q == 1, "flash_decode consumes one query token"
+    _one_query(s_q)
     if packed:
         if packed_kv_heads is None:
             raise ValueError(
@@ -1153,42 +1131,18 @@ def paged_flash_decode(
         side buffers and one query a call.  In a trace the kernel is
         ``paged_window_decode``.
 
-    MULTI-QUERY DECODE (``q`` with ``s > 1``): the speculative verify
-    chunk — the ``page_table`` already covers the segment's pre-reserved
-    growth (the ServeLoop grows every lane's coverage at dispatch), the
-    in-segment tokens stage in the side buffer, and query ``j`` attends
-    the pool at the per-row lengths plus side positions
-    ``< side_len - (s - 1 - j)``.  Implemented as ``s`` single-query
-    kernel calls (short verify chunks only).
-
-    Returns ``[B, s, H, D]``.
+    Returns ``[B, 1, H, D]``.
     """
     b, s_q, h, d = q.shape
-    if window is not None and (s_q != 1 or side_k is None or window < 1):
+    _one_query(s_q)
+    if window is not None and (side_k is None or window < 1):
         raise ValueError(
-            "a windowed paged decode takes one query a call with the "
-            "side buffers (the query's position is cache_len + side_len "
-            "- 1) and a window >= 1")
-    if v_pool is None and (window is not None or s_q != 1
-                           or side_v is not None):
+            "a windowed paged decode takes the side buffers (the query's "
+            "position is cache_len + side_len - 1) and a window >= 1")
+    if v_pool is None and (window is not None or side_v is not None):
         raise ValueError(
-            "the one pool of K and V takes one query a call, its one "
-            "side buffer and no window")
-    if s_q > 1:
-        if side_k is None:
-            raise ValueError(
-                "multi-query paged_flash_decode needs side buffers "
-                "(in-segment tokens stage there; the pool is frozen "
-                "within a segment)")
-        sl = jnp.asarray(side_len, jnp.int32)
-        return jnp.concatenate([
-            paged_flash_decode(
-                q[:, j:j + 1], k_pool, v_pool, page_table, cache_len,
-                packed_kv_heads=packed_kv_heads, side_k=side_k,
-                side_v=side_v, side_len=sl - (s_q - 1 - j),
-                interpret=interpret)
-            for j in range(s_q)], axis=1)
-    assert s_q == 1, "paged_flash_decode consumes one query token"
+            "the one pool of K and V takes its one side buffer and no "
+            "window")
     if k_pool.ndim != 3:
         raise ValueError(
             f"paged pools are packed 3-D [N, block, Hkv*D]; got "
