@@ -543,6 +543,56 @@ def test_mixed_window_model_names_its_kernels(v5e):
     _compile(loop._admit_finish, *finish)
 
 
+# The Command A+ cell (cmdaplus_rag_mixed) as its own files build it: the
+# parallel block, 128 query heads on 8 K/V heads of 128 (TWO grid rows of
+# four heads a lane in both paged walks), a window of 4096 in a group of 34
+# blocks a lane, 16 held experts of 4096 x 4096 of 128, four shared, a head
+# tied to 32768 rows.
+
+def _kernels_by_name(hlo: str) -> dict:
+    names = re.findall(
+        r'^\s*(?:ROOT )?%([a-z_]+)[.\d]* = [^\n]*'
+        r'custom_call_target="tpu_custom_call"', hlo, re.M)
+    return {n: names.count(n) for n in set(names)}
+
+
+def test_command_a_cell_programs_at_full_size(v5e):
+    """Segment, chunk and finish of the cell at the sizes its configuration
+    file states, for the described chip: what the chip's compiler refuses
+    of 8 K/V heads, a 4096-row window or 4096-wide experts shows here; the
+    kernels by the names a trace shows."""
+    from benchmarks.harness import common, serve_command_a as runner
+    from tpudist.ops.flash_decode import paged_grid_rows
+
+    cell = common.load_cell("cmdaplus_rag_mixed")
+    config = cell["config"]
+    dims = runner.model_dims(config)
+    params = jax.eval_shape(
+        lambda: runner.make_params(0, dims, jnp.bfloat16, 1.0))
+    assert "lm_head" not in params
+    loop = runner.build_loop(config, dims, params, tiny=False)
+    lanes = config["program"]["options"]["num_slots"]
+    assert loop.kv_window_blocks == lanes * 34
+    assert loop._grid_rows == 2 * lanes == paged_grid_rows(
+        lanes, 8, 128, 128, loop.pool.max_blocks_per_slot)
+    assert loop._row_heads == 4
+    chunk = config["program"]["options"]["prefill_chunk"]
+    assert loop._blank1["block0"]["attn"]["cached_key"].shape == (
+        1, 4096 + chunk, 1024)
+    assert loop._blank1["block3"]["attn"]["cached_key"].shape == (
+        1, 18432, 1024)
+    got = {name: _kernels_by_name(_compile(jitted, *_on(v5e, args),
+                                           **static))
+           for name, (jitted, args, static)
+           in loop.serve_programs().items()}
+    experts = {"moe_experts_gate_up": 4, "moe_experts_down": 4}
+    assert got == {
+        "_segment_impl": {"paged_window_decode": 3, "paged_flash_decode": 1,
+                          **experts},
+        "_prefill_chunk_impl": {"flash_fwd": 4, **experts},
+        "_admit_finish_impl": {}}
+
+
 # The Olmo-Hybrid cell (olmoh7b_doc_mixed): 16 lanes, 30 linear-attention
 # heads of a 96 x 192 float32 state each (kept [96, 30 x 192] a lane), prefill
 # chunks of 512 tokens; the full layers 30 query heads on 30 K/V heads of 128

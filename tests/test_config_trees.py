@@ -12,7 +12,9 @@ gave an indexer's layers ONE pool and ONE staging buffer of K beside V
 (``paged_kv`` / ``side_kv`` for the two pairs: 26 leaves -> 22), and again
 on the tree of PR 46, which made that row 32-bit words (the same 22
 leaves; the two of a layer ``uint32`` of half the columns in bfloat16);
-its parameters, and every other configuration's three trees, stayed."""
+its parameters, and every other configuration's three trees, stayed.  The
+seventh (PR 48: the parallel block, a scale-only LayerNorm, a tied head) was
+read on the tree that brought it; the six before it stayed to the digit."""
 
 import hashlib
 import importlib
@@ -35,6 +37,10 @@ TREES = {
     "keye-vl-2.0-30b-a3b": ((35, "6181a46c1edeab4e"),
                             (22, "b5662f8cc9ab7060")),
     "olmo-hybrid-7b": ((51, "90c4b5ddcc5c5e64"), (22, "ef3c1ab0e982065f")),
+    # PR 48, read on its own tree: no ln2, no bias, no lm_head; its tiny
+    # cache trees are Mellum's (the same K/V heads, window and two groups)
+    "command-a-plus-05-2026": ((46, "ed98ab414a7b6875"),
+                               (40, "74e4b734b903b89e")),
 }
 
 

@@ -70,6 +70,9 @@ class MoEConfig:
     # weights stay the raw scores): aux-loss-free balancing
     correction_bias: bool = False
     n_shared: int = 0                  # shared experts every token passes
+    # what the n_shared experts' outputs add to the routed sum: their "sum"
+    # or their "mean" (the sum times 1 / n_shared)
+    shared_combine: str = "sum"
     # (first, count): the routed experts THIS holder has, of num_experts.
     # Routing is over all num_experts; compute over the held ones, and a
     # choice held elsewhere adds nothing here (its holder adds it)
@@ -284,10 +287,17 @@ class MoEMLP(nn.Module):
         self.sow("stats", "expert_tokens", counts,
                  reduce_fn=lambda a, b: a + b,
                  init_fn=lambda: jnp.zeros((count,), jnp.int32))
+        if moe.shared_combine not in ("sum", "mean"):
+            raise ValueError(f"shared_combine must be 'sum' or 'mean', got "
+                             f"{moe.shared_combine!r}")
         if moe.n_shared:
             with routine("mlp/shared"):
-                out = out + GatedMLP(self.d_model, moe.n_shared * self.d_ff,
-                                     dt, name="shared")(x)
+                shared = GatedMLP(self.d_model, moe.n_shared * self.d_ff,
+                                  dt, name="shared")(x)
+                if moe.shared_combine == "mean":
+                    shared = shared * jnp.asarray(1.0 / moe.n_shared,
+                                                  shared.dtype)
+                out = out + shared
         return out, jnp.zeros((), jnp.float32)
 
     @nn.compact

@@ -742,7 +742,7 @@ class ServeLoop:
         # grid= from: a lane's K/V heads share a row where their tiles fit
         # VMEM) times the attention layers, each one call a step; 0 where
         # no kernel runs (the dense layout, the gather fallback)
-        self._grid_rows = self._attn_layers = 0
+        self._grid_rows = self._attn_layers = self._row_heads = 0
         # the device arrays _stamp_table makes a dispatch: a table a layer
         self._table_copies = (len(self._paged_nodes(self.cache))
                               if self.pool is not None else 0)
@@ -766,6 +766,9 @@ class ServeLoop:
                 self.pool.max_blocks_per_slot, pools=n_pools,
                 itemsize=itemsize)
             self._attn_layers = len(nodes)
+            # the K/V heads ONE grid row serves, by that fold: a lane whose
+            # heads do not all fit the kernel's tile budget takes several
+            self._row_heads = h_kv * num_slots // self._grid_rows
         # expert layers (cfg.moe): the segment sums, step by step, the
         # tokens each held expert was given and returns the sums as extra
         # rows of the emit buffer it already returns
@@ -874,6 +877,14 @@ class ServeLoop:
             self._kv_row_words = row.shape[2] * row.dtype.itemsize // 4
         obs.gauge("serve/kv_row_words", unit="words").set(
             self._kv_row_words)
+        # K and V of ONE token in ONE attention layer, in bytes (0 for a
+        # model whose layers keep no K/V heads: latent rows), and the K/V
+        # heads one grid row of a paged walk serves (0 where no kernel runs)
+        obs.gauge("serve/kv_row_bytes", unit="bytes").set(
+            0 if cfg.mla is not None else 2 * cfg.kv_heads * cfg.head_dim
+            * jnp.dtype(cfg.compute_dtype).itemsize)
+        obs.gauge("serve/kv_heads_per_grid_row", unit="heads").set(
+            self._row_heads)
         # the same two of the WINDOW layers' calls (a layer): walk_rows
         # with the window, and min(length, window)
         self._obs_rows_window = obs.counter(
@@ -3002,7 +3013,9 @@ class ServeLoop:
             ``rows`` (what the kernel's arithmetic covers for those lanes,
             ``walk_rows`` of each length), ``rows_live`` (the lengths) and
             ``grid_rows`` (the grid rows of one call of the paged kernel,
-            ``ops.flash_decode.paged_grid_rows`` of the loop's shapes).
+            ``ops.flash_decode.paged_grid_rows`` of the loop's shapes) with
+            ``heads_per_grid_row`` (the K/V heads one of them serves, by
+            the same fold: a two-row lane reads half its heads).
             A model with sliding-window layers adds ``rows_window`` /
             ``rows_window_live`` (the same two of a WINDOW layer's call:
             ``walk_rows`` with the window, ``min(length, window)``; ``rows``
@@ -3141,7 +3154,8 @@ class ServeLoop:
                     seq=s_idx, steps=n_disp, steps_run=steps_run,
                     lanes=lanes, tokens=tokens, first_tokens=first_tokens,
                     pages=pages, rows=rows, rows_live=rows_live,
-                    grid_rows=self._grid_rows, **routed)
+                    grid_rows=self._grid_rows,
+                    heads_per_grid_row=self._row_heads, **routed)
             # zombie refund: every segment dispatched before the kill
             # (index < free_at) has drained once s_idx reaches
             # free_at - 1 — no stale merge can touch the blocks now

@@ -290,14 +290,22 @@ class TransformerConfig:
     # block this class began as (LayerNorm, a learned position table, a
     # tanh-GELU MLP of mlp_ratio x embed_dim); every other value is read
     # where the block is built, and nothing is keyed on a model's name.
-    norm: str = "layernorm"            # | "rmsnorm"
+    # | "rmsnorm" | "layernorm_scale" (mean-centred, a scale and NO bias)
+    norm: str = "layernorm"
     norm_eps: float = 1e-6
     # "pre": x + f(norm(x)); "post": x + norm(f(x)) (the norm AFTER the
-    # sublayer, on its output alone)
+    # sublayer, on its output alone); "parallel": ONE norm a layer that
+    # both sublayers read, x + attn(norm(x)) + mlp(norm(x)), one add
     norm_order: str = "pre"
     # | "rotary" (no position table) | "none" (no positions at all: layers
     # that carry the order themselves, a convolution and a decay)
     positions: str = "learned"
+    # the FULL layers' positions where they are not the model's ("as_model":
+    # ``positions`` for every layer; "none": under rotary ``positions`` the
+    # layers without a window rotate nothing and build no angles, and the
+    # windowed layers alone carry the order).  Read through
+    # ``layer_positions(i)``.
+    full_layer_positions: str = "as_model"
     rope_theta: float = 10000.0
     rope_scaling: YarnScaling | None = None
     # the windowed layers' rotary scaling where it is not the full
@@ -329,11 +337,27 @@ class TransformerConfig:
     index_heads: int | None = None
     index_head_dim: int | None = None
     index_topk: int | None = None
+    # True: the head reads the embedding's table (no ``lm_head`` leaf);
+    # either head's logits are multiplied by ``logit_scale``
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
 
     def __post_init__(self):
-        if self.norm_order not in ("pre", "post"):
-            raise ValueError(f"norm_order must be 'pre' or 'post', got "
-                             f"{self.norm_order!r}")
+        if self.norm_order not in ("pre", "post", "parallel"):
+            raise ValueError(f"norm_order must be 'pre', 'post' or "
+                             f"'parallel', got {self.norm_order!r}")
+        if self.full_layer_positions not in ("as_model", "none"):
+            raise ValueError(
+                f"full_layer_positions must be 'as_model' or 'none', got "
+                f"{self.full_layer_positions!r}")
+        if self.full_layer_positions == "none" and (
+                self.positions != "rotary" or self.mla is not None
+                or self.index_topk is not None):
+            raise ValueError(
+                "position-free full layers stand beside ROTARY window "
+                "layers of multi-head / grouped-query attention (a learned "
+                "table is added once for every layer; latent attention and "
+                "an indexer rotate slices of their own)")
         if self.qk_norm not in (False, True, "whole"):
             raise ValueError(f"qk_norm must be False, True or 'whole', got "
                              f"{self.qk_norm!r}")
@@ -446,6 +470,14 @@ class TransformerConfig:
             return self.window_rope_scaling
         return self.rope_scaling
 
+    def layer_positions(self, i: int | None) -> str:
+        """Layer ``i``'s positions: ``positions``, except on a layer
+        without a window where ``full_layer_positions`` says otherwise."""
+        if (self.full_layer_positions != "as_model"
+                and self.layer_window(i) is None):
+            return self.full_layer_positions
+        return self.positions
+
     @property
     def kv_heads(self) -> int:
         kv = self.num_kv_heads or self.num_heads
@@ -461,8 +493,11 @@ def make_norm(cfg: TransformerConfig, name: str):
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
                           name=name)
-    raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
-                     f"{cfg.norm!r}")
+    if cfg.norm == "layernorm_scale":
+        return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
+                            use_bias=False, name=name)
+    raise ValueError(f"norm must be 'layernorm', 'rmsnorm' or "
+                     f"'layernorm_scale', got {cfg.norm!r}")
 
 
 def rope_inv_freq(dim: int, theta: float,
@@ -712,7 +747,7 @@ class CausalSelfAttention(nn.Module):
                                name="q_norm")(q)
                 k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.compute_dtype,
                                name="k_norm")(k)
-            if cfg.positions == "rotary":
+            if cfg.layer_positions(self.layer) == "rotary":
                 # keys are cached AFTER the rotation, at their own positions
                 if positions is None:
                     positions = jnp.arange(s)[None, :]
@@ -1818,6 +1853,7 @@ class DecoderBlock(nn.Module):
         cfg = self.cfg
         linear = cfg.layer_kind(self.layer) == "linear"
         post = cfg.norm_order == "post"
+        parallel = cfg.norm_order == "parallel"
         # a block's norm counts to the routine that reads it first (under
         # "post": that wrote what it reads)
         mixer_scope = "linear_attn" if linear else "attn/proj"
@@ -1858,11 +1894,16 @@ class DecoderBlock(nn.Module):
         if post:
             with routine(mixer_scope):
                 y = make_norm(cfg, "ln1")(y)
-        x = x + y
-        h = x
-        if not post:
-            with routine(mlp_scope):
-                h = make_norm(cfg, "ln2")(x)
+        if parallel:
+            # the expert layer / MLP reads the SAME h; no second norm, and
+            # the one add below takes both
+            attn_out = y
+        else:
+            x = x + y
+            h = x
+            if not post:
+                with routine(mlp_scope):
+                    h = make_norm(cfg, "ln2")(x)
         if not self.expert_layer:
             y = MLPBlock(cfg, name="mlp")(h)
         else:
@@ -1876,6 +1917,8 @@ class DecoderBlock(nn.Module):
         if post:
             with routine(mlp_scope):
                 y = make_norm(cfg, "ln2")(y)
+        if parallel:
+            return x + attn_out + y
         return x + y
 
 
@@ -1988,8 +2031,9 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
-        x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
-                     dtype=cfg.compute_dtype, name="tok_embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.embed_dim,
+                         dtype=cfg.compute_dtype, name="tok_embed")
+        x = embed(tokens)
         if cfg.positions == "learned":
             x = x + nn.Embed(cfg.max_seq_len, cfg.embed_dim,
                              dtype=cfg.compute_dtype,
@@ -2065,6 +2109,11 @@ class TransformerLM(nn.Module):
                     *(() if valid is None else (valid,)))
         with routine("head"):
             x = make_norm(cfg, "ln_f")(x)
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.compute_dtype, name="lm_head")(x)
+            if cfg.tie_embeddings:
+                logits = embed.attend(x)
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=cfg.compute_dtype, name="lm_head")(x)
+            if cfg.logit_scale != 1.0:
+                logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
             return logits.astype(jnp.float32)

@@ -58,7 +58,8 @@ __all__ = ["ROUTINE_SCOPES", "SpanTracer", "atomic_write_json", "routine",
 # (:func:`scope_of`).  What carries none reads as ``other``: embedding,
 # residual adds, the ``while_loop``'s bookkeeping, the expert counts.  A
 # block's norm counts to the routine that reads it first (``attn/proj``;
-# ``mlp/dense`` or, in an expert layer, ``mlp/route``).
+# ``mlp/dense`` or, in an expert layer, ``mlp/route``); a parallel block
+# has ONE norm, counted to ``attn/proj``.
 ROUTINE_SCOPES = (
     "attn/proj",    # q/k/v/latent/output projections, q-k norms, rotary
     "attn/cache",   # K/V/latent/index-key writes, the side -> pool merge
@@ -68,8 +69,9 @@ ROUTINE_SCOPES = (
     "mlp/dense",    # MLPBlock
     "mlp/route",    # router matmul, top-k, the counting sort
     "mlp/experts",  # the grouped products and the combine
-    "mlp/shared",   # the shared expert
-    "head",         # final norm, lm_head, sampling, the emit buffer's write
+    "mlp/shared",   # the shared experts (and, averaged, their 1/n)
+    "head",         # final norm, lm_head or the tied table, logit_scale,
+                    # sampling, the emit buffer's write
     # a linear-attention layer: its projections, conv, gates, norms and
     # state copies, and nested in it the recurrence itself, by its form
     "linear_attn",
