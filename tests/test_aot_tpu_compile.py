@@ -17,6 +17,7 @@ import base64
 import contextlib
 import dataclasses
 import hashlib
+import math
 import os
 import re
 from unittest import mock
@@ -705,32 +706,93 @@ def _named_call(hlo: str, name: str, pattern) -> dict:
     return _custom_call(hlo.replace("ROOT %", "%"), name)
 
 
+def _big_f32_moves(hlo: str, numbers: int) -> list:
+    """Instructions that only MOVE a float32 array of ``numbers`` numbers:
+    a ``copy`` or a ``transpose`` (a ``bitcast`` moves nothing)."""
+    moves = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = f32\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) == numbers:
+            moves.append(line.strip())
+    return moves
+
+
+def _chunk_scores(v5e, heads):
+    """Arguments of a chunk's ``paged_index_scores`` at the cell's sizes
+    with ``heads`` index heads, and the queries a grid row takes."""
+    from tpudist.ops.flash_decode import index_queries_per_row
+
+    tq = index_queries_per_row(IDX_CHUNK, heads, IDX_ENTRIES * BLOCK)
+    return tq, (_sds(v5e, (IDX_CHUNK, heads, IDX_WIDTH)),
+                _sds(v5e, (IDX_CHUNK, heads), jnp.float32),
+                _sds(v5e, (IDX_ENTRIES, BLOCK, IDX_WIDTH)),
+                _sds(v5e, (1, IDX_ENTRIES), jnp.int32),
+                _sds(v5e, (IDX_CHUNK // tq,), jnp.int32))
+
+
 @pytest.mark.parametrize("path", ["decode_step", "prefill_chunk"])
 def test_paged_index_scores_at_the_cells_shapes(v5e, path):
     """A decode step: a grid row a lane, each its own pages.  A prefill
     chunk: 16 queries a grid row over ONE table row (the batch-1 cache's
-    32768 rows seen as pages)."""
+    32768 rows seen as pages).  Either way the kernel writes a grid row's
+    queries by ALL the row's columns."""
     from benchmarks.layer_metrics import _index_spans
-    from tpudist.ops.flash_decode import (index_queries_per_row,
-                                          paged_index_scores)
+    from tpudist.ops.flash_decode import paged_index_scores
 
     if path == "decode_step":
-        t, rows, table, blocks = IDX_LANES, IDX_LANES, IDX_LANES, IDX_BLOCKS
+        tq, args = 1, (_sds(v5e, (IDX_LANES, IDX_HEADS, IDX_WIDTH)),
+                       _sds(v5e, (IDX_LANES, IDX_HEADS), jnp.float32),
+                       _sds(v5e, (IDX_BLOCKS, BLOCK, IDX_WIDTH)),
+                       _sds(v5e, (IDX_LANES, IDX_ENTRIES), jnp.int32),
+                       _sds(v5e, (IDX_LANES,), jnp.int32))
     else:
-        tq = index_queries_per_row(IDX_CHUNK, IDX_HEADS, IDX_ENTRIES * BLOCK)
+        tq, args = _chunk_scores(v5e, IDX_HEADS)
         assert tq == 16
-        t, rows, table, blocks = IDX_CHUNK, IDX_CHUNK // tq, 1, IDX_ENTRIES
-    hlo = _compile(
-        paged_index_scores, _sds(v5e, (t, IDX_HEADS, IDX_WIDTH)),
-        _sds(v5e, (t, IDX_HEADS), jnp.float32),
-        _sds(v5e, (blocks, BLOCK, IDX_WIDTH)),
-        _sds(v5e, (table, IDX_ENTRIES), jnp.int32),
-        _sds(v5e, (rows,), jnp.int32))
+    hlo = _compile(paged_index_scores, *args)
     assert _kernel_calls(hlo) == 1
     op = _named_call(hlo, "paged_index_scores", _index_spans.SCORES)
     assert op["pallas"] and op["operands"] == 4      # meta, q, w, pool
     assert op["outputs"] == (
-        f"f32[{rows},{IDX_ENTRIES * BLOCK // 1024},{t // rows},1024]",)
+        f"f32[{args[-1].shape[0]},{tq},{IDX_ENTRIES * BLOCK}]",)
+
+
+@pytest.mark.parametrize("heads, tq", [(IDX_HEADS, 16), (4 * IDX_HEADS, 8)])
+def test_a_chunks_scores_leave_the_kernel_as_their_readers_take_them(
+        v5e, heads, tq):
+    """``[2048, 32768]`` is a bitcast of what the kernel wrote wherever a
+    grid row's queries are whole tiles of 8 rows: nothing of 268 MB is
+    copied or transposed after the call (the form ``[grid rows, tiles, tq,
+    1024]`` cost a transposition and a re-tiling at 16 queries a row)."""
+    from tpudist.ops.flash_decode import paged_index_scores
+
+    got, args = _chunk_scores(v5e, heads)
+    assert got == tq
+    hlo = _compile(paged_index_scores, *args)
+    assert _kernel_calls(hlo) == 1
+    assert f"= f32[{IDX_CHUNK // tq},{tq},{IDX_ENTRIES * BLOCK}]" in hlo
+    assert not _big_f32_moves(hlo, IDX_CHUNK * IDX_ENTRIES * BLOCK)
+
+
+def test_a_chunks_selection_reads_the_scores_where_the_kernel_wrote_them(
+        v5e):
+    """``paged_index_scores`` then ``index_select_mask`` at a chunk's
+    shapes: the two kernels, and between and after them no copy and no
+    transposition of the 2048 x 32768 scores (the threshold kernel's
+    operand and the elementwise mask pass read the one array)."""
+    from tpudist.ops.flash_decode import (index_select_mask,
+                                          paged_index_scores)
+
+    def chosen(q, w, pool, table, seen, rows):
+        scores = paged_index_scores(q, w, pool, table, seen)
+        return index_select_mask(scores, IDX_TOPK, rows=rows)
+
+    _, args = _chunk_scores(v5e, IDX_HEADS)
+    hlo = _compile(chosen, *args, _sds(v5e, (), jnp.int32))
+    assert _kernel_calls(hlo) == 2
+    assert re.search(r"%paged_index_scores[.\d]* = ", hlo)
+    assert re.search(r"%index_select_threshold[.\d]* = ", hlo)
+    assert not _big_f32_moves(hlo, IDX_CHUNK * IDX_ENTRIES * BLOCK)
 
 
 def test_a_page_of_64_wide_index_keys_is_refused(v5e):
@@ -1129,7 +1191,7 @@ FAMILIES = {
                "moe_experts_down": "mlp/experts"},
         {"attn/proj", "attn/cache", "attn/index", "attn/rows", "attn/core",
          "mlp/route", "mlp/experts", "head"},
-        ("69cfabc9100ffeae", "4deb752c1e41b7d9", "21b9b593b1054222")),
+        ("0c4825c511bff81e", "9fbef28d9e9d9c60", "21b9b593b1054222")),
 }
 PROGRAMS = ("_segment_impl", "_prefill_chunk_impl", "_admit_finish_impl")
 # instructions that carry no routine, of those that are not parameters,
@@ -1246,10 +1308,10 @@ def test_programs_without_metadata_are_the_parents(compiled, family,
     """Operation for operation, name for name: the hash of the compiled
     text with its metadata stripped is the one recorded from the commit
     before the scopes (293f19f), on the same toy program.  The indexer's
-    segment and finish are recorded from the commit that made the ONE pool
-    row of K and V a row of 32-bit words (its chunk, and every other
-    family's three, stayed the text they were, as they had when the one
-    pool came)."""
+    finish is recorded from the commit that made the ONE pool row of K and
+    V a row of 32-bit words, its segment and chunk from the one that had
+    ``paged_index_scores`` write a grid row's queries by all its columns
+    (every other family's three stayed the text they were, both times)."""
     want = FAMILIES[family][4][PROGRAMS.index(program)]
     text = strip_metadata(compiled[family][program])
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

@@ -322,41 +322,59 @@ def _scores(q, w, keys, seen):
                      -jnp.inf)
 
 
-@pytest.mark.parametrize("lens", [(0, 37, 300), (320, 1, 129)],
-                         ids=["empty_short_long", "full_one_tile_edge"])
-def test_index_scores_of_a_decode_step(lens):
-    rng = np.random.default_rng(0)
-    b, h, d, bs, m, n = 3, 8, 16, 8, 40, 64
-    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(b, h)), jnp.float32)
-    pool = jnp.asarray(rng.normal(size=(n, bs, d)), jnp.float32)
-    table = jnp.asarray(rng.integers(0, n, (b, m)), jnp.int32)
-    lens = jnp.asarray(lens, jnp.int32)
-    got = paged_index_scores(q, w, pool, table, lens, interpret=True)
-    want = _scores(q, w, pool[table].reshape(b, m * bs, d), lens)
-    assert np.array_equal(np.isfinite(got), np.isfinite(want))
-    np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
-                               np.where(np.isfinite(want), want, 0),
-                               atol=1e-5)
+# (queries a grid row, one table row for all, rows a page, table entries,
+# rows the LAST query of each grid row sees).  A tile of the walk is 1024
+# rows: 40 pages of 8 are one short tile; 130 are a whole tile and two pages
+# of a second, so the kernel's block is 2048 columns wide and the table's
+# 1040 a slice of it; 16 pages of 8 are a prefill chunk's batch-1 cache
+SCORE_CASES = {
+    "step_empty_short_long": (1, False, 8, 40, (0, 37, 300)),
+    "step_full_one_tile_edge": (1, False, 8, 40, (320, 1, 129)),
+    "step_two_tiles_sliced": (1, False, 8, 130, (0, 1030, 1024, 500, 1040)),
+    "two_queries_own_tables": (2, False, 8, 130, (1, 1040, 777)),
+    "four_queries_chunk_at_100": (4, True, 8, 16, (104, 108, 112)),
+    "eight_queries_chunk_at_0": (8, True, 8, 16, (8,)),
+    "eight_queries_two_tiles": (8, True, 8, 130, (0, 8, 1029, 1040)),
+    "sixteen_queries_chunk_at_40": (16, True, 8, 16, (56,)),
+    "sixteen_queries_two_tiles": (16, True, 16, 70, (16, 1024, 1031, 0)),
+}
 
 
-@pytest.mark.parametrize("queries,idx", [(16, 40), (8, 0), (12, 100)])
-def test_index_scores_of_a_prefill_chunk(queries, idx):
-    """Queries by the grid row over ONE table row, each with its own
-    limit."""
-    rng = np.random.default_rng(1)
-    h, d, bs, rows = 8, 16, 8, 128
-    q = jnp.asarray(rng.normal(size=(queries, h, d)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(queries, h)), jnp.float32)
-    keys = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32)
-    tq = index_queries_per_row(queries, h, rows)
-    seen = idx + (jnp.arange(queries // tq) + 1) * tq
-    got = paged_index_scores(q, w, keys.reshape(rows // bs, bs, d),
-                             jnp.arange(rows // bs)[None], seen,
+@pytest.mark.parametrize("numbers", ["normal", "whole"])
+@pytest.mark.parametrize("case", SCORE_CASES)
+def test_index_scores_against_jax_numpy(case, numbers):
+    """The kernel's ``[T, table's rows]`` on both callers' forms: a decode
+    step (a query a grid row, each its own table row) and a prefill chunk
+    (``tq`` queries a grid row over ONE table row, each with its own limit:
+    query ``j`` of a row sees ``tq - 1 - j`` rows fewer than its last).  A
+    length of 0 leaves the block ``-inf``, one inside a tile cuts it there,
+    and a table that ends inside a tile is the slice after the call.  On
+    small whole numbers every product and sum is exact in float32, so the
+    scores are the reference's bit for bit, whatever form the kernel
+    writes them in."""
+    tq, shared, bs, m, last = SCORE_CASES[case]
+    rng = np.random.default_rng(len(case))
+    lanes, h, d, n = len(last), 8, 16, 160
+    t = lanes * tq
+    draw = (rng.normal if numbers == "normal"
+            else lambda size: rng.integers(-3, 4, size))
+    q = jnp.asarray(draw(size=(t, h, d)), jnp.float32)
+    w = jnp.asarray(draw(size=(t, h)), jnp.float32)
+    pool = jnp.asarray(draw(size=(n, bs, d)), jnp.float32)
+    table = jnp.asarray(rng.permutation(n)[:m][None] if shared
+                        else rng.integers(0, n, (lanes, m)), jnp.int32)
+    got = paged_index_scores(q, w, pool, table, jnp.asarray(last, jnp.int32),
                              interpret=True)
-    want = _scores(q, w, jnp.broadcast_to(keys, (queries, rows, d)),
-                   idx + jnp.arange(queries) + 1)
-    assert tq > 1 and np.array_equal(np.isfinite(got), np.isfinite(want))
+    keys = jnp.repeat(pool[jnp.broadcast_to(table, (lanes, m))]
+                      .reshape(lanes, m * bs, d), tq, axis=0)
+    seen = (jnp.repeat(jnp.asarray(last), tq) - (tq - 1)
+            + jnp.tile(jnp.arange(tq), lanes))
+    want = _scores(q, w, keys, seen)
+    assert got.shape == (t, m * bs) and got.dtype == jnp.float32
+    if numbers == "whole":
+        assert np.array_equal(got, want)
+        return
+    assert np.array_equal(np.isfinite(got), np.isfinite(want))
     np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
                                np.where(np.isfinite(want), want, 0),
                                atol=1e-5)

@@ -1442,9 +1442,11 @@ def _index_scores_kernel(meta_ref, q_ref, w_ref, pool, o_ref, buf, sems, *,
     query sees; query ``j`` sees ``len - (tq - 1 - j)`` (causal inside the
     row).  Columns are independent (no softmax runs across them), so a tile
     is computed whole and masked: what an uncopied page of a slot holds
-    lands only in columns at or beyond the length.  The output block
-    ``[tiles, tq, tile rows]`` is filled with ``-inf`` first: tiles the walk
-    never reaches stay so."""
+    lands only in columns at or beyond the length.  The output block is the
+    row's queries by ALL its columns, ``[tq, tiles x tile rows]`` (the form
+    the selection reads: no transposition follows the call), and tile ``t``
+    is stored at columns ``[t x tile rows, (t + 1) x tile rows)`` of it.  It
+    is filled with ``-inf`` first: tiles the walk never reaches stay so."""
     g = pl.program_id(0)
     n_len = meta_ref[g]
     n_pages = (n_len + block - 1) // block
@@ -1488,7 +1490,8 @@ def _index_scores_kernel(meta_ref, q_ref, w_ref, pool, o_ref, buf, sems, *,
                 jnp.int32, s.shape, 1)
             limit = n_len - (tq - 1) + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
-            o_ref[0, t] = jnp.where(col < limit, s, -jnp.inf)
+            at = pl.ds(pl.multiple_of(t * tile_rows, tile_rows), tile_rows)
+            o_ref[0, :, at] = jnp.where(col < limit, s, -jnp.inf)
 
         jax.lax.fori_loop(0, n_tiles, one_tile, None)
 
@@ -1557,6 +1560,11 @@ def paged_index_scores(
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _index_scores_one(q, w, pool, table, rows_seen, *, interpret: bool):
+    """The validated call of :func:`paged_index_scores`.  The kernel
+    writes ``[lanes, tq, tiles x tile rows]``, a grid row's block the last
+    two dimensions whole; ``[T, columns]`` is a reshape of it (on the chip
+    a bitcast where ``tq`` is a multiple of the 8 rows of a tile, as a
+    chunk's is) and the table's own columns a slice."""
     t, h, d = q.shape
     lanes, m_blocks = rows_seen.shape[0], table.shape[1]
     tq = t // lanes
@@ -1577,12 +1585,11 @@ def _index_scores_one(q, w, pool, table, rows_seen, *, interpret: bool):
             in_specs=[pl.BlockSpec((1, tq * h, d), row),
                       pl.BlockSpec((1, tq * h, 1), row),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, n_tiles, tq, tile_rows),
-                                   lambda g, m: (g, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, tq, n_tiles * tile_rows), row),
             scratch_shapes=[pltpu.VMEM((2, ppt, block, d), pool.dtype),
                             pltpu.SemaphoreType.DMA((2,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, n_tiles, tq, tile_rows),
+        out_shape=jax.ShapeDtypeStruct((lanes, tq, n_tiles * tile_rows),
                                        jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -1590,8 +1597,7 @@ def _index_scores_one(q, w, pool, table, rows_seen, *, interpret: bool):
         name="paged_index_scores",
     )(meta, q.reshape(lanes, tq * h, d).astype(pool.dtype),
       w.reshape(lanes, tq * h, 1), pool)
-    out = out.transpose(0, 2, 1, 3).reshape(t, n_tiles * tile_rows)
-    return out[:, : m_blocks * block]
+    return out.reshape(t, n_tiles * tile_rows)[:, : m_blocks * block]
 
 
 def block_of(n: int, least: int = 1, most: int = 128) -> int:
